@@ -108,10 +108,9 @@ telemetry-smoke:
 resilience-smoke:
 	rm -f /tmp/isotope_resilience_smoke.jsonl
 	ISOTOPE_FAULT_INJECT=transient:engine.run:1,oom:engine.run:1 \
-	ISOTOPE_COMPILE_CACHE=off \
 	$(PY) -m isotope_tpu simulate examples/topologies/chain-3-services.yaml \
 		--qps 50 --duration 2s --load-kind open --max-requests 256 \
-		--telemetry \
+		--telemetry --compile-cache off \
 		--telemetry-out /tmp/isotope_resilience_smoke.jsonl --flat \
 		> /tmp/isotope_resilience_smoke.json
 	$(PY) -c "import json; from isotope_tpu.telemetry import iter_jsonl; \

@@ -115,17 +115,17 @@ def _dot_flops(eqn) -> float:
 
 def jaxpr_cost(closed_jaxpr) -> JaxprCost:
     """Static cost of one ClosedJaxpr (recursing into sub-jaxprs)."""
-    import jax
+    from jax.extend import core as jex_core
 
     def cost(jxp) -> Tuple[float, float, int]:
         # -- liveness sweep: last use index per var -----------------------
         last_use: Dict[object, int] = {}
         for i, eqn in enumerate(jxp.eqns):
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, jex_core.Literal):
                     last_use[v] = i
         for v in jxp.outvars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 last_use[v] = len(jxp.eqns)
 
         live = sum(
@@ -146,9 +146,9 @@ def jaxpr_cost(closed_jaxpr) -> JaxprCost:
                 subs = v if isinstance(v, (list, tuple)) else (v,)
                 for s in subs:
                     inner = None
-                    if isinstance(s, jax.core.ClosedJaxpr):
+                    if isinstance(s, jex_core.ClosedJaxpr):
                         inner = s.jaxpr
-                    elif isinstance(s, jax.core.Jaxpr):
+                    elif isinstance(s, jex_core.Jaxpr):
                         inner = s
                     if inner is not None:
                         f, b, d = cost(inner)
@@ -179,7 +179,7 @@ def jaxpr_cost(closed_jaxpr) -> JaxprCost:
             live += out_bytes
             for v in eqn.invars:
                 if (
-                    not isinstance(v, jax.core.Literal)
+                    not isinstance(v, jex_core.Literal)
                     and last_use.get(v) == i
                 ):
                     live -= _aval_bytes(v.aval)
@@ -188,7 +188,7 @@ def jaxpr_cost(closed_jaxpr) -> JaxprCost:
                 (
                     depth_of.get(v, 0)
                     for v in eqn.invars
-                    if not isinstance(v, jax.core.Literal)
+                    if not isinstance(v, jex_core.Literal)
                 ),
                 default=0,
             )

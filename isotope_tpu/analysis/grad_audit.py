@@ -17,7 +17,7 @@ by test — then runs a forward dataflow over the ClosedJaxpr:
   (:data:`~isotope_tpu.sim.config.DESIGN_PARAMS` maps knob -> traced
   invar names or a trace-constant site);
 - **propagate** through every eqn, descending into ``scan`` / ``while``
-  / ``cond`` / ``pjit`` / custom-derivative sub-jaxprs (scan and while
+  / ``cond`` / ``jit`` / custom-derivative sub-jaxprs (scan and while
   carries iterate to a fixpoint — the lattice is monotone in the live
   bit, so a handful of sweeps converge);
 - **kill** liveness where the chain rule dies: ``argmin``/``argmax``,
@@ -85,7 +85,7 @@ KILLER_PRIMITIVES = frozenset({
 
 #: sub-jaxpr call-like primitives inlined under their own path segment
 _CALL_PRIMITIVES = (
-    "pjit",
+    "jit",
     "closed_call",
     "core_call",
     "remat2",
@@ -139,9 +139,7 @@ def _analyze(jaxpr, in_taints, path: str, state: _TaintState):
     ``in_taints[i]`` is the taint of ``jaxpr.invars[i]`` — a dict
     ``knob -> (live, killer)``.  Returns the taints of the outvars.
     """
-    import jax
-
-    Literal = jax.core.Literal
+    from jax.extend.core import Literal
 
     env: Dict[object, dict] = {}
 

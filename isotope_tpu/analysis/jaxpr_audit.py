@@ -137,7 +137,7 @@ def trace_entry(sim, load, num_requests: int = 8):
         # f64 is canonicalized away under the default x64-off config;
         # the seeded leak is only representable with x64 enabled for
         # the duration of the (still trace-only) trace
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             return jax.make_jaxpr(fn)(*args), n
     return jax.make_jaxpr(fn)(*args), n
 
@@ -152,21 +152,21 @@ def iter_eqns(closed_or_jaxpr) -> Iterator[tuple]:
     jaxprs — so a defect wrapped under any of them is still found
     (pinned by tests/test_vet.py).  Accepts a ClosedJaxpr or a bare
     Jaxpr."""
-    import jax
+    from jax.extend import core as jex_core
 
     def rec(jxp, depth):
         for eqn in jxp.eqns:
             yield eqn, depth
             for v in eqn.params.values():
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, jex_core.ClosedJaxpr):
                     yield from rec(v.jaxpr, depth + 1)
-                elif isinstance(v, jax.core.Jaxpr):
+                elif isinstance(v, jex_core.Jaxpr):
                     yield from rec(v, depth + 1)
                 elif isinstance(v, (list, tuple)):
                     for x in v:
-                        if isinstance(x, jax.core.ClosedJaxpr):
+                        if isinstance(x, jex_core.ClosedJaxpr):
                             yield from rec(x.jaxpr, depth + 1)
-                        elif isinstance(x, jax.core.Jaxpr):
+                        elif isinstance(x, jex_core.Jaxpr):
                             yield from rec(x, depth + 1)
 
     yield from rec(getattr(closed_or_jaxpr, "jaxpr", closed_or_jaxpr), 0)

@@ -11,7 +11,6 @@ the ``telemetry`` probe's ``--detail``) compose.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 
@@ -38,14 +37,23 @@ def arm_telemetry(mode: Optional[str] = None,
 
 def default_compile_cache(compile_cache: Optional[str],
                           mode: Optional[str]) -> Optional[str]:
-    """The telemetry-run compile-cache default (bench's ``.xla-cache``
-    convention): plain ``--telemetry`` runs measure cache
-    effectiveness, so they default the persistent cache ON unless the
-    user or environment said otherwise.  Detail mode is excluded —
-    eager execution would fill the cache with per-primitive noise."""
-    from isotope_tpu.compiler.cache import ENV_CACHE_DIR
-
-    if (mode == "on" and compile_cache is None
-            and ENV_CACHE_DIR not in os.environ):
-        return ".xla-cache"
+    """The telemetry-run compile-cache default: plain ``--telemetry``
+    runs measure cache effectiveness, so they ask for the persistent
+    cache (``"on"`` — the directory is compiler/cache.py's one rule)
+    unless the user said otherwise.  Detail mode is excluded — eager
+    execution would fill the cache with per-primitive noise."""
+    if mode == "on" and compile_cache is None:
+        return "on"
     return compile_cache
+
+
+def add_compile_cache_arg(parser) -> None:
+    """``--compile-cache``, shared by every run-executing subcommand
+    (the rule itself lives in compiler/cache.py)."""
+    parser.add_argument(
+        "--compile-cache", metavar="on|off|DIR", default=None,
+        help="persistent XLA compilation cache: repeated runs of one "
+             "topology family skip XLA.  The directory is "
+             "$JAX_COMPILATION_CACHE_DIR where set, else DIR, else "
+             "<checkout>/.xla-cache ('on'); 'off' disables.  Default: "
+             "on only where the variable is set")

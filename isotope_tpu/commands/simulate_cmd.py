@@ -29,7 +29,7 @@ def _add_resilience_args(parser) -> None:
     parser.add_argument(
         "--no-degrade", action="store_true",
         help="disable the OOM degradation ladder (halve request "
-             "chunk, sharded -> single-device -> CPU eager); an OOM "
+             "chunk, sharded -> single-device -> eager); an OOM "
              "then fails the case immediately")
 
 
@@ -225,6 +225,8 @@ def _add_vet_arg(parser) -> None:
 
 
 def register(sub) -> None:
+    from isotope_tpu.commands.common import add_compile_cache_arg
+
     s = sub.add_parser(
         "simulate", help="simulate one topology under one load"
     )
@@ -250,10 +252,7 @@ def register(sub) -> None:
     s.add_argument("--cpu-time", default=None,
                    help='per-request CPU demand, e.g. "77us"')
     s.add_argument("--seed", type=int, default=0)
-    s.add_argument("--compile-cache", metavar="DIR", default=None,
-                   help="persistent XLA compilation cache directory "
-                        "(default: $ISOTOPE_COMPILE_CACHE; repeated "
-                        "runs of one topology family skip XLA)")
+    add_compile_cache_arg(s)
     s.add_argument("--labels", default="")
     s.add_argument("--entry", default=None,
                    help="entrypoint service (for multi-instance "
@@ -278,9 +277,9 @@ def register(sub) -> None:
                         "telemetry.jsonl record, and a summary block on "
                         "stderr.  'detail' additionally fences at "
                         "segment granularity (eager execution — for "
-                        "diagnosis, not benchmarking).  Defaults the "
-                        "persistent compile cache to .xla-cache so "
-                        "repeated runs show cache hits")
+                        "diagnosis, not benchmarking).  Turns the "
+                        "persistent compile cache on (--compile-cache "
+                        "on) so repeated runs show cache hits")
     s.add_argument("--telemetry-out", metavar="FILE",
                    default="telemetry.jsonl",
                    help="where --telemetry appends its JSONL record")
@@ -366,9 +365,7 @@ def register(sub) -> None:
     w.add_argument("--fresh", action="store_true",
                    help="ignore an existing checkpoint and rerun "
                         "everything (default: resume a killed sweep)")
-    w.add_argument("--compile-cache", metavar="DIR", default=None,
-                   help="persistent XLA compilation cache directory "
-                        "(default: $ISOTOPE_COMPILE_CACHE)")
+    add_compile_cache_arg(w)
     w.add_argument("--profile", metavar="DIR",
                    help="capture a jax.profiler trace per run into "
                         "DIR/<label>/ (the reference's per-run flame "
@@ -431,8 +428,8 @@ def run_simulate(args) -> int:
     from isotope_tpu.compiler.cache import enable_persistent_cache
 
     arm_telemetry(args.telemetry)
-    # any explicit env setting — including the disable values "", "0",
-    # "off", "none" — wins over the telemetry-run cache default
+    # an explicit --compile-cache (including "off") wins over the
+    # telemetry-run cache default
     args.compile_cache = default_compile_cache(
         args.compile_cache, args.telemetry
     )

@@ -11,6 +11,8 @@ import sys
 
 
 def register(sub) -> None:
+    from isotope_tpu.commands.common import add_compile_cache_arg
+
     s = sub.add_parser(
         "suite",
         help="run a set of experiment configs as one published "
@@ -29,10 +31,7 @@ def register(sub) -> None:
                    help="alarm threshold, MiB")
     s.add_argument("--fresh", action="store_true",
                    help="ignore existing per-config checkpoints")
-    s.add_argument("--compile-cache", metavar="DIR", default=None,
-                   help="persistent XLA compilation cache directory "
-                        "(default: $ISOTOPE_COMPILE_CACHE); a suite "
-                        "re-run of the same topology set skips XLA")
+    add_compile_cache_arg(s)
     s.add_argument("--telemetry", nargs="?", const="on",
                    choices=("on", "detail"), default=None,
                    help="emit engine self-telemetry per run: "

@@ -16,6 +16,8 @@ import sys
 
 
 def register(sub) -> None:
+    from isotope_tpu.commands.common import add_compile_cache_arg
+
     t = sub.add_parser(
         "telemetry",
         help="probe the engine's self-telemetry on one topology",
@@ -35,9 +37,7 @@ def register(sub) -> None:
                         "of the Prometheus exposition")
     t.add_argument("--out", metavar="FILE", default=None,
                    help="also append the record to this JSONL file")
-    t.add_argument("--compile-cache", metavar="DIR", default=None,
-                   help="persistent XLA compilation cache directory "
-                        "(default: $ISOTOPE_COMPILE_CACHE)")
+    add_compile_cache_arg(t)
     t.add_argument("--xla-trace", metavar="DIR", default=None,
                    help="capture a jax.profiler trace of warmed steps "
                         "into DIR (TensorBoard/XProf-readable)")

@@ -15,15 +15,18 @@ XLA again:
 - **Persistent on-disk cache** (:func:`enable_persistent_cache`): JAX's
   compilation cache, keyed by XLA on the optimized HLO, so separate
   *processes* (bench.py's per-case subprocesses, repeated CLI runs of
-  one suite) skip the XLA backend compile entirely.  The directory
-  comes from the ``ISOTOPE_COMPILE_CACHE`` env knob or an explicit
-  path; unset means disabled.
+  one suite) skip the XLA backend compile entirely.  ONE rule picks
+  the directory: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+  already points there and this module sets no other; otherwise
+  ``<checkout>/.xla-cache``, resolved from this package's location —
+  the path is part of the cache key, so it never follows the cwd.
 """
 from __future__ import annotations
 
 import hashlib
 import logging
 import os
+import pathlib
 from collections import OrderedDict
 from typing import Callable, List, Optional
 
@@ -31,9 +34,18 @@ from isotope_tpu import telemetry
 
 logger = logging.getLogger(__name__)
 
-#: env knob for the persistent compilation cache directory; the values
-#: "", "0", "off" and "none" (case-insensitive) disable it explicitly.
-ENV_CACHE_DIR = "ISOTOPE_COMPILE_CACHE"
+#: JAX's own variable: when set, JAX reads the cache directory from it
+#: at import and :func:`enable_persistent_cache` sets no other.
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+#: the fallback directory: ``<checkout>/.xla-cache``, anchored at the
+#: package (never the cwd — a cache path that moves never hits)
+DEFAULT_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[2] / ".xla-cache"
+)
+
+#: request values (case-insensitive) that disable the cache
+_OFF = ("", "0", "off", "none")
 
 #: sidecar recording each cache entry's content digest (scan_cache_dir)
 DIGEST_SIDECAR = ".isotope-digests.json"
@@ -42,6 +54,9 @@ DIGEST_SIDECAR = ".isotope-digests.json"
 QUARANTINE_DIR = "quarantine"
 
 _persistent_dir: Optional[str] = None
+#: an explicit "off" holds against later un-asked enables (the sharded
+#: runner's) until something asks again
+_switched_off = False
 
 
 def scan_cache_dir(path: str) -> dict:
@@ -50,13 +65,22 @@ def scan_cache_dir(path: str) -> dict:
     A corrupted entry (truncated write on a killed run, bit rot, a
     concurrent writer) used to surface as an unpickle/deserialize crash
     *inside* XLA's cache read — killing the run that was supposed to be
-    saved compile time.  This scan runs at :func:`enable_persistent_cache`
-    time: every entry file is digested; an EMPTY file, an unreadable
-    file, or one whose digest no longer matches the recorded sidecar
-    digest is moved to ``<dir>/quarantine/`` (counter
+    saved compile time.  Every entry file is digested; an EMPTY file,
+    an unreadable file, or one whose digest no longer matches the
+    recorded sidecar digest is moved to ``<dir>/quarantine/`` (counter
     ``compile_cache_quarantined``) so XLA simply misses and retraces.
     Fresh entries get their digest recorded for the next scan.  Never
     raises — a broken cache must degrade to "no cache", not crash.
+
+    JAX owns the directory and writes entries non-atomically, so a
+    file another live process is still writing looks exactly like a
+    torn one.  The scan therefore does NOT run when the cache is
+    enabled (since PR 22) — only as the reaction to a corruption error
+    this process actually hit while reading
+    (``ExecutableCache._build_quarantining``), where a wrongly
+    quarantined entry costs one recompile and the alternative is a
+    crash.  JAX's ``*-atime`` bookkeeping files (rewritten on every
+    read with eviction on) are never entries and are skipped.
     """
     stats = {"checked": 0, "quarantined": [], "recorded": 0}
     try:
@@ -79,6 +103,7 @@ def scan_cache_dir(path: str) -> dict:
             if (
                 name == DIGEST_SIDECAR
                 or name.startswith(".")
+                or name.endswith("-atime")
                 or not os.path.isfile(fpath)
             ):
                 continue
@@ -128,19 +153,45 @@ def persistent_cache_dir() -> Optional[str]:
     return _persistent_dir
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path``.
+def enable_persistent_cache(request: Optional[str] = None) -> Optional[str]:
+    """Turn JAX's persistent compilation cache on, by the one rule.
 
-    ``path=None`` reads ``$ISOTOPE_COMPILE_CACHE``; when that is unset
-    (or explicitly off) this is a no-op returning ``None``.  Idempotent
-    — safe to call from every entry point (bench, CLI, sharded runner).
+    ``request`` says WHETHER, the rule says WHERE:
+
+    - ``None`` — not asked: on only where ``JAX_COMPILATION_CACHE_DIR``
+      is set (the environment asked), else a no-op returning ``None``;
+    - ``"off"`` / ``"0"`` / ``"none"`` / ``""`` — disabled (JAX's own
+      cache is switched off too when the variable is set);
+    - ``"on"`` — asked (bench, ``--telemetry`` runs, ``chip_smoke.py``);
+    - anything else — asked, naming a directory for the case where the
+      variable is unset.
+
+    The directory: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    already points there — that directory serves and JAX's own
+    setting is left untouched; otherwise the named
+    directory, else :data:`DEFAULT_CACHE_DIR`.  Idempotent — safe to
+    call from every entry point (bench, CLI, sharded runner).
     """
-    global _persistent_dir
-    if path is None:
-        path = os.environ.get(ENV_CACHE_DIR)
-    if not path or str(path).strip().lower() in ("0", "off", "none"):
+    global _persistent_dir, _switched_off
+    env_dir = os.environ.get(ENV_JAX_CACHE_DIR)
+    asked = None if request is None else str(request).strip()
+    if asked is not None and asked.lower() in _OFF:
+        if env_dir:
+            import jax
+
+            jax.config.update("jax_enable_compilation_cache", False)
+        _persistent_dir = None
+        _switched_off = True
         return None
-    path = os.path.abspath(os.path.expanduser(str(path)))
+    if asked is None and (_switched_off or not env_dir):
+        return None
+    _switched_off = False
+    if env_dir:
+        path = env_dir
+    elif asked.lower() == "on":
+        path = DEFAULT_CACHE_DIR
+    else:
+        path = os.path.abspath(os.path.expanduser(asked))
     if _persistent_dir == path:
         return path
     # persistent-cache hit/miss counts come from jax's own monitoring
@@ -150,30 +201,20 @@ def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
     import jax
 
     os.makedirs(path, exist_ok=True)
-    # evict corrupted entries BEFORE jax reads any (a bad entry then
-    # costs a retrace, never a crash)
-    scan_cache_dir(path)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # jax initializes its cache object lazily ONCE; re-pointing the dir
-    # after something already compiled needs an explicit reset
-    try:
+    jax.config.update("jax_enable_compilation_cache", True)
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", path)
+        # jax initializes its cache object lazily ONCE; re-pointing the
+        # dir after something already compiled needs an explicit reset
         from jax.experimental.compilation_cache import (
             compilation_cache as _cc,
         )
 
         _cc.reset_cache()
-    except Exception:  # pragma: no cover - cache not initialized yet
-        pass
     # cache every entry: the sweep programs are exactly the long-compile
     # artifacts the cache exists for, and tiny entries are harmless
-    for knob, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except AttributeError:  # pragma: no cover - newer/older jax
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _persistent_dir = path
     return path
 

@@ -18,14 +18,24 @@ f32 (latency accumulators are pinned to f32 by the <= 1 ULP contract).
 
 Execution modes:
 
-- TPU backends run the compiled Mosaic kernel;
-- everywhere else ``interpret=True`` evaluates the same kernel body
-  op-by-op on the host — the CPU fallback used by the equivalence
-  tests (tests/test_census_pallas.py), bit-identical to the kernel's
-  semantics and within 1 ULP of the XLA reference chain.
+- ``interpret=True`` (the default everywhere except a TPU backend)
+  evaluates the kernel body op-by-op on the host — what the
+  equivalence tests (tests/test_census_pallas.py) run, bit-identical
+  to the kernel's semantics and within 1 ULP of the XLA reference
+  chain;
+- on a TPU backend the call compiles through Mosaic.  The compiler
+  for the v5e (JAX 0.9.0 / libtpu 0.0.34) REFUSES the kernel as
+  written: ``cumsum`` has no Pallas TPU lowering; with it unrolled the
+  ``err`` variant dies on the ``bool[:, :, None]`` shape cast; and the
+  step axis P (4-8) is the minor dim, padded to 128 lanes, so the
+  ``f32[N, B, P]`` operands cost 32x their size in VMEM and HBM
+  (tests/test_chip_compile.py pins the verdict).  It needs P off the
+  lane axis — a layout change through the engine (ROADMAP S9/D2).
 
-The engine gates every call on ``SimParams.pallas_census`` (auto: on
-for TPU, off elsewhere); with the flag off this module is never
+The engine gates every call on ``SimParams.pallas_census``, which is
+OFF unless explicitly ``True`` on every backend (since PR 22); there
+is no catch and no interpret-mode rescue on a TPU — the compiler's
+own error propagates.  With the flag off this module is never
 imported and the op-by-op path is byte-identical to PR 5's.
 """
 from __future__ import annotations
@@ -139,7 +149,12 @@ def _census_kernel_dispatch(*refs, has_fail: bool, has_err: bool):
 
 
 def supported(num_hops: int, pmax: int) -> bool:
-    """Whether the kernel should serve a (B, P) step grid."""
+    """Whether the kernel should serve a (B, P) step grid.
+
+    An UPPER bound the chip's compiler has not confirmed: it counts
+    dense elements, while Mosaic pads P to 128 lanes and knows the
+    request count N — shapes this admits are refused for VMEM or HBM
+    on the v5e (module docstring)."""
     return num_hops * pmax <= MAX_GRID_ELEMS
 
 

@@ -77,22 +77,9 @@ class _RunPlan(NamedTuple):
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it top-level with ``check_vma``; older releases
-    (<= 0.4.x) ship ``jax.experimental.shard_map`` whose equivalent
-    knob is ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -118,8 +105,8 @@ class ShardedSimulator:
         # (any host count on a laptop); the shard_map entry points
         # raise a clear error instead of tracing
         self.emulated = isinstance(mesh, EmulatedMesh)
-        # persistent XLA cache (no-op unless $ISOTOPE_COMPILE_CACHE is
-        # set): the sharded sweep programs are the most expensive
+        # persistent XLA cache (no-op unless $JAX_COMPILATION_CACHE_DIR
+        # is set): the sharded sweep programs are the most expensive
         # compiles in the system, so wire the disk cache here too
         enable_persistent_cache()
         # lb laws ride _simulate_core's per-station wait selection, so

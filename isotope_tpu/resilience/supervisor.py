@@ -12,8 +12,12 @@ Two primitives, composed by the sweep driver (runner/run.py):
 
   sharded:        sharded -> sharded half-block -> single-device
                   (per-shard emulation, collectives replayed on host)
-                  -> CPU eager
-  single-device:  scan -> half-block -> CPU eager
+                  -> cpu-eager
+  single-device:  scan -> half-block -> cpu-eager
+
+  The last rung is NAMED ``cpu-eager`` but does not move to the CPU:
+  it is ``jax.disable_jit()`` on whatever device is the default —
+  op-by-op on the TPU where a TPU is the default.
 
   Every descent increments ``degradations_total`` (Prometheus:
   ``isotope_engine_degradations_total``); the rung that finally served
@@ -159,9 +163,10 @@ def execution_rungs(
     per-shard request chunk (same request count, twice the scan steps,
     half the live event-tensor footprint); the single-device rung
     replays the sharded program shard-by-shard on one device (bit-
-    compatible streams, collectives merged on host); CPU eager
-    (``jax.disable_jit``) is the rung of last resort — it also survives
-    compile-time OOM.
+    compatible streams, collectives merged on host); ``cpu-eager``
+    (``jax.disable_jit`` on the default device — NOT a move to the
+    CPU, whatever the name says) is the rung of last resort — it also
+    survives compile-time OOM.
     """
     import contextlib
 

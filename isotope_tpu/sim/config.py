@@ -132,10 +132,12 @@ class SimParams:
     # Pallas census kernel (native/census_pallas.py): fuse the per-step
     # census / WaitGroup-max join (max with the sleep floor, step mask,
     # busy row-sum, exclusive step prefix — today a chain of XLA ops)
-    # into one hand-written kernel.  None = auto: on for TPU backends,
-    # off elsewhere (the CPU interpreter-mode kernel is for equivalence
-    # tests, not speed).  False reproduces today's op-by-op path
-    # exactly; True forces the kernel (interpreter mode off-TPU).
+    # into one hand-written kernel.  None and False are both OFF, on
+    # every backend: the op-by-op XLA path.  True is an explicit
+    # request — interpreter mode off-TPU (the equivalence tests); on a
+    # TPU it compiles through Mosaic, which refuses the kernel as
+    # written (no ``cumsum`` lowering; P sits on the lane axis — see
+    # ROADMAP S9/D2), and the compiler's error propagates uncaught.
     pallas_census: Optional[bool] = None
     # Pack the census/blame carries where the <= 1 ULP pins allow:
     # attribution hop counters / blame-histogram censuses accumulate as

@@ -1375,14 +1375,10 @@ class Simulator:
         self._plan = tuple(plan)
         self._plan_sig = buckets.plan_signature(plan)
         # -- Pallas census kernel flag (native/census_pallas.py) ------------
-        # auto: on for TPU backends, off elsewhere (the CPU
-        # interpreter-mode kernel exists for equivalence tests, not
-        # speed); False keeps today's op-by-op census byte-identical.
-        self._pallas_census = (
-            params.pallas_census
-            if params.pallas_census is not None
-            else jax.default_backend() == "tpu"
-        )
+        # None resolves to OFF on every backend: Mosaic refuses the
+        # kernel as written (ROADMAP S9/D2), so only an explicit True
+        # requests it — and on a TPU that raises the compiler's error.
+        self._pallas_census = bool(params.pallas_census)
         self._census_mod = None
         if self._pallas_census:
             from isotope_tpu.native import census_pallas
@@ -1587,6 +1583,10 @@ class Simulator:
         self._search_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
         self._rate_cache: Dict[tuple, float] = {}
         telemetry.counter_inc("simulators_built")
+        # which census implementation this engine's programs call
+        telemetry.set_meta(
+            "census", "pallas" if self._pallas_census else "xla"
+        )
         telemetry.phase_add("engine.build", time.perf_counter() - _t_build)
 
     def _phase_reach_multipliers(self, svc_down_np: np.ndarray) -> np.ndarray:

@@ -96,9 +96,8 @@ class EnsembleSpec:
     INSIDE the program — still one trace / one compile / one dispatch
     for the whole fleet, but per-member op shapes stay the solo
     program's, which on CPU keeps scatters vectorized and working
-    sets cache-sized).  ``None`` auto-selects like
-    ``SimParams.pallas_census``: vmap on accelerator backends, map on
-    CPU.  Either mode keeps member k bit-identical to its solo run.
+    sets cache-sized).  ``None`` auto-selects by backend: vmap on
+    accelerator backends, map on CPU.  Either mode keeps member k bit-identical to its solo run.
     """
 
     seeds: Tuple[int, ...]

@@ -1,0 +1,114 @@
+"""Compile for the v5e without a v5e (on-chip-measurement guide, §2).
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached: what it refuses in this file it would refuse
+on the machine with the chip, at no chip time.  Nothing runs, so this
+says nothing about results or speed — ``chip_smoke.py`` does that.
+
+The ONLY file that describes a topology: only one process may load the
+TPU's library, so the call lives in a module-scoped fixture (never at
+import, in a ``skipif`` or a ``parametrize``) and every compile happens
+in this process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from isotope_tpu.compiler import compile_graph
+from isotope_tpu.metrics.prometheus import MetricsCollector
+from isotope_tpu.models.graph import ServiceGraph
+from isotope_tpu.native import census_pallas
+from isotope_tpu.sim import SimParams, Simulator
+
+TOPOLOGIES = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "examples", "topologies",
+)
+#: one v5e chip's HBM as libtpu reports it ("Used 32.00G of 15.75G")
+HBM_BYTES = 15.75 * 2**30
+#: the CLI's defaults (commands/simulate_cmd.py): closed loop, 64
+#: connections, capped at 1 M requests
+CONNECTIONS = 64
+REQUESTS = 1_000_000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding onto one described chip, with the persistent cache off
+    around the compiles (an entry written for a described chip cannot
+    be read back without one — the next compile would only warn)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name, hops, block", [
+    ("1000-svc_2000-end.yaml", 1000, 33_554),
+    ("tree-111-services.yaml", 111, 302_292),
+])
+def test_cli_summary_program_compiles_for_v5e(one_chip, name, hops, block):
+    """The program ``isotope-tpu simulate <graph> --qps 1000 --duration
+    1000s`` runs — the block scan behind ``run_summary`` with the
+    collector and the trim window, XLA census — fits one chip."""
+    compiled = compile_graph(
+        ServiceGraph.from_yaml_file(os.path.join(TOPOLOGIES, name))
+    )
+    sim = Simulator(compiled, SimParams())
+    assert sim._pallas_census is False
+    assert (compiled.num_hops, sim.default_block_size()) == (hops, block)
+    per = block // CONNECTIONS
+    blk = per * CONNECTIONS
+    fn = sim._get_summary(
+        blk, -(-REQUESTS // blk), "closed", CONNECTIONS,
+        MetricsCollector(compiled), True, sat=False,
+    )
+    # run_summary's argument list: the single-block entry's, plus the
+    # two trim-window bounds before the visit / phase tables
+    _, a = sim.trace_entry_args(blk, "closed", CONNECTIONS)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    args = [
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        for x in (*a[:5], scalar, scalar, *a[5:])
+    ]
+    mem = fn.lower(*args).compile().memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < HBM_BYTES
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_census_kernel_verdict_is_pinned(one_chip):
+    """Mosaic refuses the census kernel as written (ROADMAP S9/D2).
+    The day JAX or the kernel changes, this says so."""
+    n, b, p = 4096, 121, 4
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def kernel(base, mask, agg):
+        return census_pallas.census(base, mask, agg, interpret=False)
+
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        jax.jit(kernel).lower(
+            sds((b, p)), sds((b, p)), sds((n, b, p))
+        ).compile()

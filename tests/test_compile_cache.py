@@ -9,6 +9,7 @@ import os
 
 import jax
 import numpy as np
+import pytest
 
 from isotope_tpu.compiler import compile_graph
 from isotope_tpu.compiler.cache import (
@@ -125,33 +126,137 @@ def test_executable_cache_lru_bounds_memory():
     assert c.hits == 1 and c.misses == 3
 
 
-def test_persistent_cache_env_and_disable(tmp_path, monkeypatch):
-    import isotope_tpu.compiler.cache as cache_mod
+@pytest.fixture
+def cache_mod(jax_cache_config, monkeypatch):
+    """compiler/cache.py with its wired-dir memo cleared and JAX's
+    variable unset (conftest restores JAX's own settings)."""
+    monkeypatch.delenv(jax_cache_config.ENV_JAX_CACHE_DIR, raising=False)
+    return jax_cache_config
 
-    monkeypatch.setattr(cache_mod, "_persistent_dir", None)
-    monkeypatch.setenv(cache_mod.ENV_CACHE_DIR, "off")
+
+def _dir_updates(monkeypatch):
+    """Record every ``jax.config.update`` of the cache DIRECTORY."""
+    seen = []
+    real = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            seen.append(value)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    return seen
+
+
+@pytest.mark.parametrize("request_", [None, "on", "/somewhere/else"])
+def test_env_dir_serves_and_jax_setting_is_untouched(
+    cache_mod, tmp_path, monkeypatch, request_
+):
+    """JAX_COMPILATION_CACHE_DIR set: that directory, whatever was
+    asked, and no update of JAX's own directory setting."""
+    d = str(tmp_path / "env-cache")
+    monkeypatch.setenv(cache_mod.ENV_JAX_CACHE_DIR, d)
+    seen = _dir_updates(monkeypatch)
+    assert enable_persistent_cache(request_) == d
+    assert seen == []
+    assert os.path.isdir(d)
+    assert cache_mod.persistent_cache_dir() == d
+
+
+def test_unset_env_not_asked_is_a_noop(cache_mod, monkeypatch):
+    seen = _dir_updates(monkeypatch)
     assert enable_persistent_cache() is None
+    assert seen == [] and cache_mod.persistent_cache_dir() is None
+
+
+def test_unset_env_default_dir_ignores_cwd(
+    cache_mod, tmp_path, monkeypatch
+):
+    """Asked with the variable unset: <checkout>/.xla-cache from the
+    package's own location, from whichever cwd the run started."""
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".xla-cache")
+    assert cache_mod.DEFAULT_CACHE_DIR == want
+    made = []
+    monkeypatch.setattr(
+        cache_mod.os, "makedirs", lambda p, **kw: made.append(p)
+    )
+    for cwd in (tmp_path, tmp_path.parent):
+        monkeypatch.chdir(cwd)
+        monkeypatch.setattr(cache_mod, "_persistent_dir", None)
+        assert enable_persistent_cache("on") == want
+    assert made == [want, want]
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+@pytest.mark.parametrize("off", ["off", "OFF", "0", "none", ""])
+def test_off_disables(cache_mod, off, monkeypatch):
+    seen = _dir_updates(monkeypatch)
+    assert enable_persistent_cache(off) is None
+    assert seen == [] and cache_mod.persistent_cache_dir() is None
+
+
+def test_off_switches_jax_cache_off_under_env_dir(
+    cache_mod, tmp_path, monkeypatch
+):
+    monkeypatch.setenv(cache_mod.ENV_JAX_CACHE_DIR, str(tmp_path))
+    assert enable_persistent_cache("off") is None
+    assert jax.config.jax_enable_compilation_cache is False
+    # an un-asked enable (the sharded runner's) does not undo "off" ...
+    assert enable_persistent_cache() is None
+    assert jax.config.jax_enable_compilation_cache is False
+    # ... asking again does
+    assert enable_persistent_cache("on") == str(tmp_path)
+    assert jax.config.jax_enable_compilation_cache is True
+    assert enable_persistent_cache() == str(tmp_path)
+
+
+def test_explicit_dir_when_env_unset(cache_mod, tmp_path):
     d = tmp_path / "xla"
     got = enable_persistent_cache(str(d))
     assert got == str(d) and os.path.isdir(got)
+    assert jax.config.jax_compilation_cache_dir == got
     # idempotent re-enable
     assert enable_persistent_cache(str(d)) == got
 
 
-def test_persistent_cache_writes_entries(tmp_path, monkeypatch):
-    """Compiling through the wired cache leaves entries on disk."""
-    import isotope_tpu.compiler.cache as cache_mod
+def test_enable_never_scans_what_jax_owns(cache_mod, tmp_path):
+    """Enabling touches no file in the directory: an entry another
+    live process may still be writing (empty, or off its recorded
+    digest) and JAX's ``-atime`` bookkeeping all survive."""
+    d = tmp_path / "xla"
+    d.mkdir()
+    (d / "jit_half_written").write_bytes(b"")
+    (d / "jit_x-atime").write_bytes(b"\x00" * 8)
+    (d / cache_mod.DIGEST_SIDECAR).write_text('{"jit_y": "stale"}')
+    (d / "jit_y").write_bytes(b"rewritten")
+    enable_persistent_cache(str(d))
+    assert sorted(os.listdir(d)) == sorted(
+        ["jit_half_written", "jit_x-atime", "jit_y",
+         cache_mod.DIGEST_SIDECAR]
+    )
+    # the on-demand scan (corruption reaction) skips -atime files too
+    stats = cache_mod.scan_cache_dir(str(d))
+    assert "jit_x-atime" not in stats["quarantined"]
+    assert (d / "jit_x-atime").exists()
 
-    monkeypatch.setattr(cache_mod, "_persistent_dir", None)
+
+def test_persistent_cache_writes_entries(cache_mod, tmp_path):
+    """Compiling through the wired cache leaves entries on disk."""
     d = str(tmp_path / "xla")
     enable_persistent_cache(d)
-    try:
-        sim = _sim(SimParams(cpu_time_s=1.0 / 9_999.0))  # fresh program
-        sim.run(OPEN, 32, KEY)
-        assert os.listdir(d), "no persistent cache entries written"
-    finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-        monkeypatch.setattr(cache_mod, "_persistent_dir", None)
+    sim = _sim(SimParams(cpu_time_s=1.0 / 9_999.0))  # fresh program
+    sim.run(OPEN, 32, KEY)
+    assert os.listdir(d), "no persistent cache entries written"
+
+
+def test_telemetry_runs_ask_for_the_cache():
+    from isotope_tpu.commands.common import default_compile_cache
+
+    assert default_compile_cache(None, "on") == "on"
+    assert default_compile_cache(None, "detail") is None
+    assert default_compile_cache(None, None) is None
+    assert default_compile_cache("off", "on") == "off"
 
 
 def test_executable_cache_stats_visible():

@@ -138,7 +138,7 @@ def test_audit_flags_injected_host_callback_and_f64_leak():
         y = jax.lax.convert_element_type(x, jnp.float64)
         return (y * 2.0).astype(jnp.float32)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(defective)(
             jax.ShapeDtypeStruct((8,), jnp.float32)
         )

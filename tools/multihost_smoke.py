@@ -28,15 +28,7 @@ def main() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:  # jax < 0.5
-        import os
-
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        )
+    jax.config.update("jax_num_cpu_devices", 8)
     import numpy as np
 
     from isotope_tpu import telemetry

@@ -75,15 +75,7 @@ def main() -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:  # jax < 0.5
-        import os
-
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4"
-        )
+    jax.config.update("jax_num_cpu_devices", 4)
     import numpy as np
 
     from isotope_tpu.compiler import compile_graph, compile_rollouts
