@@ -14,6 +14,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from isotope_tpu import telemetry
+
 
 def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(
@@ -28,8 +30,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    parser = build_parser()
-    args = parser.parse_args(argv)
+    with telemetry.phase("cli.parse"):
+        parser = build_parser()
+        args = parser.parse_args(argv)
     if not getattr(args, "func", None):
         parser.print_help()
         return 2

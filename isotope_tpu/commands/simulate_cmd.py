@@ -427,54 +427,56 @@ def run_simulate(args) -> int:
     )
     from isotope_tpu.compiler.cache import enable_persistent_cache
 
-    arm_telemetry(args.telemetry)
-    # an explicit --compile-cache (including "off") wins over the
-    # telemetry-run cache default
-    args.compile_cache = default_compile_cache(
-        args.compile_cache, args.telemetry
-    )
-    enable_persistent_cache(args.compile_cache)
-    from isotope_tpu.runner.config import (
-        DEFAULT_ENVIRONMENTS,
-        ExperimentConfig,
-    )
-    from isotope_tpu.runner.run import run_experiment
-
-    if args.environment not in DEFAULT_ENVIRONMENTS:
-        raise ValueError(
-            f"unknown environment {args.environment!r} "
-            f"(expected one of {sorted(DEFAULT_ENVIRONMENTS)})"
+    with telemetry.phase("cli.config"):
+        arm_telemetry(args.telemetry)
+        # an explicit --compile-cache (including "off") wins over the
+        # telemetry-run cache default
+        args.compile_cache = default_compile_cache(
+            args.compile_cache, args.telemetry
         )
-    qps = None if args.qps == "max" else float(args.qps)
-    extra = {}
-    if args.cpu_time is not None:
-        extra["cpu_time_s"] = dur.parse_duration_seconds(args.cpu_time)
-    if args.service_time_param is not None:
-        extra["service_time_param"] = args.service_time_param
-    elif args.service_time == "pareto":
-        extra["service_time_param"] = 1.5  # a sane heavy-tail default
-    tl_window = _timeline_window(args)
-    config = ExperimentConfig(
-        topology_paths=(args.topology,),
-        environments=(DEFAULT_ENVIRONMENTS[args.environment],),
-        qps=(qps,),
-        connections=(args.connections,),
-        duration_s=dur.parse_duration_seconds(args.duration),
-        load_kind=args.load_kind,
-        num_requests=args.max_requests,
-        seed=args.seed,
-        labels=args.labels,
-        service_time=args.service_time,
-        entry=args.entry,
-        attribution=args.attribution is not None,
-        timeline=tl_window is not None,
-        policies=args.policies,
-        rollouts=args.rollouts,
-        mesh_spec=args.mesh,
-        overlap=args.overlap,
-        **_ensemble_config_kwargs(args),
-        **extra,
-    )
+        enable_persistent_cache(args.compile_cache)
+        from isotope_tpu.runner.config import (
+            DEFAULT_ENVIRONMENTS,
+            ExperimentConfig,
+        )
+        from isotope_tpu.metrics.fortio import write_artifact
+        from isotope_tpu.runner.run import run_experiment
+
+        if args.environment not in DEFAULT_ENVIRONMENTS:
+            raise ValueError(
+                f"unknown environment {args.environment!r} "
+                f"(expected one of {sorted(DEFAULT_ENVIRONMENTS)})"
+            )
+        qps = None if args.qps == "max" else float(args.qps)
+        extra = {}
+        if args.cpu_time is not None:
+            extra["cpu_time_s"] = dur.parse_duration_seconds(args.cpu_time)
+        if args.service_time_param is not None:
+            extra["service_time_param"] = args.service_time_param
+        elif args.service_time == "pareto":
+            extra["service_time_param"] = 1.5  # a sane heavy-tail default
+        tl_window = _timeline_window(args)
+        config = ExperimentConfig(
+            topology_paths=(args.topology,),
+            environments=(DEFAULT_ENVIRONMENTS[args.environment],),
+            qps=(qps,),
+            connections=(args.connections,),
+            duration_s=dur.parse_duration_seconds(args.duration),
+            load_kind=args.load_kind,
+            num_requests=args.max_requests,
+            seed=args.seed,
+            labels=args.labels,
+            service_time=args.service_time,
+            entry=args.entry,
+            attribution=args.attribution is not None,
+            timeline=tl_window is not None,
+            policies=args.policies,
+            rollouts=args.rollouts,
+            mesh_spec=args.mesh,
+            overlap=args.overlap,
+            **_ensemble_config_kwargs(args),
+            **extra,
+        )
     (result,) = run_experiment(config, policy=_policy(args),
                                vet=args.vet,
                                attribution=args.attribution,
@@ -577,17 +579,18 @@ def run_simulate(args) -> int:
             "warning: timeline pass produced no windowed series",
             file=sys.stderr,
         )
-    doc = result.flat if args.flat else result.fortio_json
-    json.dump(doc, sys.stdout, indent=None if args.flat else 2)
-    sys.stdout.write("\n")
-    if args.prometheus:
-        with open(args.prometheus, "w") as f:
-            f.write(result.prometheus_text)
-    if args.telemetry and result.telemetry is not None:
-        rec = telemetry.RunTelemetry.from_dict(result.telemetry)
-        rec.append_jsonl(args.telemetry_out)
-        print(f"{telemetry.summary_line()} -> {args.telemetry_out}",
-              file=sys.stderr)
+    with telemetry.phase("artifacts.write"):
+        doc = result.flat if args.flat else result.fortio_json
+        text = json.dumps(doc, indent=None if args.flat else 2) + "\n"
+        sys.stdout.write(text)
+        telemetry.counter_inc("artifact_bytes_written", len(text.encode()))
+        if args.prometheus:
+            write_artifact(args.prometheus, result.prometheus_text)
+        if args.telemetry and result.telemetry is not None:
+            rec = telemetry.RunTelemetry.from_dict(result.telemetry)
+            rec.append_jsonl(args.telemetry_out)
+            print(f"{telemetry.summary_line()} -> {args.telemetry_out}",
+                  file=sys.stderr)
     if args.trace:
         # traces are sampled: re-run a small dense batch (the load path
         # keeps only histograms, like the reference's samplers)
@@ -785,34 +788,37 @@ def run_sweep(args) -> int:
     from isotope_tpu.commands.common import arm_telemetry
     from isotope_tpu.compiler.cache import enable_persistent_cache
 
-    arm_telemetry(args.telemetry)
-    enable_persistent_cache(args.compile_cache)
-    from isotope_tpu.runner.config import load_toml
-    from isotope_tpu.runner.run import run_experiment
+    from isotope_tpu import telemetry
 
-    config = load_toml(args.config)
-    if args.attribution and not config.attribution:
-        config = dataclasses.replace(config, attribution=True)
-    if args.mesh:
-        config = dataclasses.replace(config, mesh_spec=args.mesh)
-    if args.overlap and not config.overlap:
-        config = dataclasses.replace(config, overlap=True)
-    if args.policies and not config.policies:
-        config = dataclasses.replace(config, policies=True)
-    if args.rollouts and not config.rollouts:
-        config = dataclasses.replace(config, rollouts=True)
-    ens_kw = _ensemble_config_kwargs(args)
-    if ens_kw:
-        config = dataclasses.replace(config, **ens_kw)
-    tl_window = _timeline_window(args)
-    if tl_window is None and config.timeline:
-        # [sim] timeline = true in the TOML arms the pass without a
-        # CLI flag
-        tl_window = config.timeline_window_s
-    if tl_window is not None and not config.timeline:
-        config = dataclasses.replace(
-            config, timeline=True, timeline_window_s=tl_window
-        )
+    with telemetry.phase("cli.config"):
+        arm_telemetry(args.telemetry)
+        enable_persistent_cache(args.compile_cache)
+        from isotope_tpu.runner.config import load_toml
+        from isotope_tpu.runner.run import run_experiment
+
+        config = load_toml(args.config)
+        if args.attribution and not config.attribution:
+            config = dataclasses.replace(config, attribution=True)
+        if args.mesh:
+            config = dataclasses.replace(config, mesh_spec=args.mesh)
+        if args.overlap and not config.overlap:
+            config = dataclasses.replace(config, overlap=True)
+        if args.policies and not config.policies:
+            config = dataclasses.replace(config, policies=True)
+        if args.rollouts and not config.rollouts:
+            config = dataclasses.replace(config, rollouts=True)
+        ens_kw = _ensemble_config_kwargs(args)
+        if ens_kw:
+            config = dataclasses.replace(config, **ens_kw)
+        tl_window = _timeline_window(args)
+        if tl_window is None and config.timeline:
+            # [sim] timeline = true in the TOML arms the pass without a
+            # CLI flag
+            tl_window = config.timeline_window_s
+        if tl_window is not None and not config.timeline:
+            config = dataclasses.replace(
+                config, timeline=True, timeline_window_s=tl_window
+            )
     results = run_experiment(
         config,
         out_dir=args.out,

@@ -6,8 +6,7 @@ padding waste, executable/persistent cache traffic, device-memory
 high-water) — the introspection counterpart of ``simulate``, which
 reports what the simulated *workload* did.  ``--xla-trace DIR``
 additionally captures a ``jax.profiler`` trace of warmed steps via
-:mod:`isotope_tpu.telemetry.profile` (the promoted
-``tools/capture_profile.py`` backend).
+:mod:`isotope_tpu.telemetry.profile`.
 """
 from __future__ import annotations
 

@@ -294,6 +294,30 @@ class ExecutableCache:
         )
         return fn
 
+    def get_or_jit(self, key: tuple, name: str, fun: Callable,
+                   **jit_kwargs):
+        """The jitted entry point for ``key``, its first call
+        phase-timed (telemetry.time_first_call).
+
+        The XLA module is named ``jit_<name>_<key digest>`` — one name
+        per distinct traced program, so a device profile's ``XLA
+        Modules`` line and ``telemetry.program_scopes()`` tell the
+        programs of one process apart."""
+        def build():
+            import jax
+
+            def entry(*args, **kwargs):
+                return fun(*args, **kwargs)
+
+            entry.__name__ = entry.__qualname__ = (
+                f"{name}_{self.key_digest(key)[:6]}"
+            )
+            return telemetry.time_first_call(
+                jax.jit(entry, **jit_kwargs), "compile.jit_first_call"
+            )
+
+        return self.get_or_build(key, build)
+
     @staticmethod
     def _build_quarantining(build: Callable[[], object]):
         """Build an entry, absorbing corrupted persistent-cache reads.

@@ -324,6 +324,7 @@ def _winner_charges(lvl: _LevelTables, w, sent_c, lat_c):
     return D, on_crit, att_dur, capped
 
 
+@jax.named_scope("attribution/block")
 def attribute_block(
     res,
     tables: AttrTables,
@@ -550,6 +551,7 @@ def merge_exemplars_host(
     return jax.tree.map(lambda a: a[order], cat)
 
 
+@jax.named_scope("attribution/reduce")
 def reduce_stacked(
     parts: AttributionSummary,
     exemplars: Optional[ExemplarBatch] = None,

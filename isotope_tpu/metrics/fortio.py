@@ -21,11 +21,13 @@ flattens (perf/benchmark/runner/fortio.py):
 from __future__ import annotations
 
 import dataclasses
+import json
 from datetime import datetime, timezone
 from typing import Dict, List, Optional
 
 import numpy as np
 
+from isotope_tpu import telemetry
 from isotope_tpu.sim.config import LoadModel
 from isotope_tpu.sim.engine import SimResults
 
@@ -153,6 +155,7 @@ def fortio_result(
     )
 
 
+@telemetry.phase("artifacts.fortio")
 def fortio_result_from_summary(
     summary,
     load: LoadModel,
@@ -219,6 +222,7 @@ def fortio_result_from_summary(
     )
 
 
+@telemetry.phase("artifacts.fortio")
 def convert_data(data: dict) -> Optional[dict]:
     """Flatten a Fortio result JSON exactly like fortio.py:38-75."""
     obj: dict = {}
@@ -383,6 +387,7 @@ def trim_window_summary(
     )
 
 
+@telemetry.phase("artifacts.window")
 def window_summary_from_summary(
     summary,
     service_names=(),
@@ -440,10 +445,24 @@ DEFAULT_CSV_KEYS = (
 )
 
 
+def write_artifact(path, text: str) -> None:
+    """Write one artifact file (UTF-8), counted in the engine counter
+    ``artifact_bytes_written``."""
+    data = text.encode()
+    with open(path, "wb") as f:
+        f.write(data)
+    telemetry.counter_inc("artifact_bytes_written", len(data))
+
+
+def write_json(path, doc) -> None:
+    write_artifact(path, json.dumps(doc, indent=2))
+
+
 def write_csv(keys: str, data: List[dict], path) -> None:
     """fortio.py:215-232: header then one row per record, '-' for gaps."""
     lst = keys.split(",")
-    with open(path, "w") as out:
-        out.write(keys + "\n")
-        for gd in data:
-            out.write(",".join(str(gd.get(k, "-")) for k in lst) + "\n")
+    write_artifact(path, "".join(
+        [keys + "\n"]
+        + [",".join(str(gd.get(k, "-")) for k in lst) + "\n"
+           for gd in data]
+    ))

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from isotope_tpu import telemetry
 from isotope_tpu.compiler.program import CompiledGraph
 from isotope_tpu.sim.engine import SimResults
 
@@ -169,59 +170,72 @@ class MetricsCollector:
         )
 
     def collect(self, res: SimResults) -> ServiceMetrics:
+        # each accumulator traces under a scope of its own, so a device
+        # profile can be read per accumulator (README: telemetry)
         c = self.compiled
         S, E = c.num_services, len(self.edges)
         sent = res.hop_sent
         sent_f = sent.astype(jnp.float32)
         code = res.hop_error.astype(jnp.int32)  # 0 => 200, 1 => 500
 
-        incoming = jnp.zeros(S).at[self._hop_service].add(sent_f.sum(0))
-        outgoing = jnp.zeros(E).at[self._hop_edge].add(sent_f.sum(0))
+        with jax.named_scope("collector/totals"):
+            incoming = (
+                jnp.zeros(S).at[self._hop_service].add(sent_f.sum(0))
+            )
+            outgoing = jnp.zeros(E).at[self._hop_edge].add(sent_f.sum(0))
 
-        out_size = (
-            jnp.zeros((E, len(SIZE_BUCKETS) + 1))
-            .at[self._hop_edge, self._hop_size_bucket]
-            .add(sent_f.sum(0))
-        )
-        out_size_sum = (
-            jnp.zeros(E)
-            .at[self._hop_edge]
-            .add(sent_f.sum(0) * jnp.asarray(
-                self.compiled.hop_request_size, jnp.float32))
-        )
+            out_size = (
+                jnp.zeros((E, len(SIZE_BUCKETS) + 1))
+                .at[self._hop_edge, self._hop_size_bucket]
+                .add(sent_f.sum(0))
+            )
+            out_size_sum = (
+                jnp.zeros(E)
+                .at[self._hop_edge]
+                .add(sent_f.sum(0) * jnp.asarray(
+                    self.compiled.hop_request_size, jnp.float32))
+            )
 
-        # duration histogram: scatter every sent hop into (svc, code, bucket)
-        # bucket index by counting edges below x — 32 fused compares beat a
-        # binary-search gather (element gathers run ~2 GiB/s on TPU)
-        edges = jnp.asarray(DURATION_BUCKETS, jnp.float32)
-        dbuckets = (
-            (res.hop_latency[..., None] > edges)
-            .sum(-1)
-            .astype(jnp.int32)
-        )
         svc = jnp.broadcast_to(self._hop_service, sent.shape)
-        dur_hist = (
-            jnp.zeros((S, 2, _NB))
-            .at[svc, code, dbuckets]
-            .add(sent_f)
-        )
-        dur_sum = (
-            jnp.zeros((S, 2))
-            .at[svc, code]
-            .add(jnp.where(sent, res.hop_latency, 0.0))
-        )
+        with jax.named_scope("collector/duration_hist"):
+            # scatter every sent hop into (svc, code, bucket); bucket
+            # index by counting edges below x — 32 fused compares beat a
+            # binary-search gather (element gathers run ~2 GiB/s on TPU)
+            edges = jnp.asarray(DURATION_BUCKETS, jnp.float32)
+            dbuckets = (
+                (res.hop_latency[..., None] > edges)
+                .sum(-1)
+                .astype(jnp.int32)
+            )
+            dur_hist = (
+                jnp.zeros((S, 2, _NB))
+                .at[svc, code, dbuckets]
+                .add(sent_f)
+            )
+        with jax.named_scope("collector/duration_sum"):
+            dur_sum = (
+                jnp.zeros((S, 2))
+                .at[svc, code]
+                .add(jnp.where(sent, res.hop_latency, 0.0))
+            )
 
-        rbucket = jnp.broadcast_to(self._svc_resp_bucket[c.hop_service], sent.shape)
-        resp_hist = (
-            jnp.zeros((S, 2, len(SIZE_BUCKETS) + 1))
-            .at[svc, code, rbucket]
-            .add(sent_f)
-        )
-        resp_sum = (
-            jnp.zeros((S, 2))
-            .at[svc, code]
-            .add(jnp.where(sent, self._svc_resp_size[c.hop_service], 0.0))
-        )
+        with jax.named_scope("collector/response_hist"):
+            rbucket = jnp.broadcast_to(
+                self._svc_resp_bucket[c.hop_service], sent.shape
+            )
+            resp_hist = (
+                jnp.zeros((S, 2, len(SIZE_BUCKETS) + 1))
+                .at[svc, code, rbucket]
+                .add(sent_f)
+            )
+        with jax.named_scope("collector/response_sum"):
+            resp_sum = (
+                jnp.zeros((S, 2))
+                .at[svc, code]
+                .add(jnp.where(
+                    sent, self._svc_resp_size[c.hop_service], 0.0
+                ))
+            )
         return ServiceMetrics(
             incoming_total=incoming,
             outgoing_total=outgoing,
@@ -235,6 +249,7 @@ class MetricsCollector:
 
     # -- host-side exposition ----------------------------------------------
 
+    @telemetry.phase("artifacts.exposition")
     def full_text(self, summary) -> str:
         """The complete exposition for a run summary: the five service
         series plus the sim-side resource series — what a scraper (and

@@ -247,6 +247,7 @@ def versioned_service_windows(
     return jnp.stack([diff[..., :V], diff[..., V:]], axis=1)
 
 
+@jax.named_scope("timeline/block")
 def timeline_block(
     res, spec: TimelineSpec, packed: bool = False
 ) -> TimelineSummary:
@@ -368,6 +369,7 @@ def zeros_summary(spec: TimelineSpec, packed: bool = False
     )
 
 
+@jax.named_scope("timeline/accumulate")
 def accumulate(
     acc: TimelineSummary, block: TimelineSummary
 ) -> TimelineSummary:

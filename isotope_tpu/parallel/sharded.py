@@ -182,22 +182,30 @@ class ShardedSimulator:
             (plan.num_blocks * plan.block * self.n_shards - num_requests)
             / max(num_requests, 1),
         )
-        fn = self._get(plan.block, plan.num_blocks, plan.kind,
-                       plan.conns_local, plan.trim, plan.sat_conns)
         vis, windows = self._args_put(plan)
-        faults.check("sharded.compute")
-        if self.dcn_axes:
-            # the dropped-DCN-collective chaos site: a mesh with a
-            # slice axis is about to issue cross-host collectives;
-            # injected transients here exercise the supervisor's retry
-            # path without real hosts (resilience/faults.py)
-            faults.check("sharded.dcn_collective")
-        out = fn(
-            key, jnp.float32(plan.offered), jnp.float32(plan.gap),
-            jnp.float32(plan.nominal_gap),
-            jnp.float32(plan.window[0]), jnp.float32(plan.window[1]),
-            vis, windows,
-        )
+        # up to the return of the async call (the first call of a
+        # program also traces and compiles in here)
+        with telemetry.phase("summary.dispatch"):
+            fn = self._get(plan.block, plan.num_blocks, plan.kind,
+                           plan.conns_local, plan.trim, plan.sat_conns)
+            faults.check("sharded.compute")
+            if self.dcn_axes:
+                # the dropped-DCN-collective chaos site: a mesh with a
+                # slice axis is about to issue cross-host collectives;
+                # injected transients here exercise the supervisor's
+                # retry path without real hosts (resilience/faults.py)
+                faults.check("sharded.dcn_collective")
+            rows = plan.num_blocks * plan.block * self.n_shards
+            telemetry.counter_inc("requests_simulated", rows)
+            telemetry.counter_inc(
+                "hop_events_simulated", rows * self.compiled.num_hops
+            )
+            out = fn(
+                key, jnp.float32(plan.offered), jnp.float32(plan.gap),
+                jnp.float32(plan.nominal_gap),
+                jnp.float32(plan.window[0]), jnp.float32(plan.window[1]),
+                vis, windows,
+            )
         if telemetry.detail_enabled():
             with telemetry.phase("sharded.gather"):
                 jax.block_until_ready(out.count)
@@ -325,9 +333,9 @@ class ShardedSimulator:
                 tuple(int(self.mesh.shape[a]) for a in self.mesh.axis_names),
                 tuple(d.id for d in self.mesh.devices.flat),
             )
-            self._fns[cache_key] = executable_cache.get_or_build(
+            self._fns[cache_key] = executable_cache.get_or_jit(
                 ("sharded", self.sim.signature, mesh_sig) + cache_key,
-                lambda: jax.jit(mapped),
+                "sharded_summary", mapped,
             )
         return self._fns[cache_key]
 
@@ -553,16 +561,19 @@ class ShardedSimulator:
         """
         dcn = self.dcn_axes
 
+        @jax.named_scope("merge/allsum")
         def allsum(x):
             x = jax.lax.psum(x, self.ici_axes)
             return jax.lax.psum(x, dcn) if dcn else x
 
+        @jax.named_scope("merge/extreme")
         def pextreme(op, x):
             x = op(x, self.ici_axes)
             return op(x, dcn) if dcn else x
 
         # per-service hists: reduce over the ICI request axes, scatter
         # over svc, THEN cross the DCN axis on the scattered tiles
+        @jax.named_scope("merge/scatter_svc")
         def scatter_svc(x):
             x = jax.lax.psum(x, self.ici_request_axes)
             pad = self.s_pad - x.shape[0]
@@ -901,11 +912,8 @@ class ShardedSimulator:
             in_specs=tuple(P(axes) for _ in range(10 + n_extra)),
             out_specs=out_specs,
         )
-        return executable_cache.get_or_build(
-            full_key,
-            lambda: telemetry.time_first_call(
-                jax.jit(mapped), "compile.jit_first_call",
-            ),
+        return executable_cache.get_or_jit(
+            full_key, "sharded_ensemble", mapped,
         )
 
     def run_ensemble_emulated(
@@ -1025,11 +1033,8 @@ class ShardedSimulator:
                 (P(axes), P(axes), P(axes)),
             ),
         )
-        return executable_cache.get_or_build(
-            full_key,
-            lambda: telemetry.time_first_call(
-                jax.jit(mapped), "compile.jit_first_call",
-            ),
+        return executable_cache.get_or_jit(
+            full_key, "sharded_search", mapped,
         )
 
     def run_search(self, load, num_requests: int, key, spec, *,
@@ -1346,11 +1351,8 @@ class ShardedSimulator:
                 axes, roll, attr=attribution
             ),
         )
-        fn = executable_cache.get_or_build(
-            full_key,
-            lambda: telemetry.time_first_call(
-                jax.jit(mapped), "compile.jit_first_call",
-            ),
+        fn = executable_cache.get_or_jit(
+            full_key, "sharded_protected_ensemble", mapped,
         )
         padded = self.sim._ensemble_pad_args(
             self.sim._ensemble_stacked_args(args) + cut_arg
@@ -1671,14 +1673,16 @@ class ShardedSimulator:
         )
         merged_summary = self._merge_summary_collective(summary, both)
         ex = attr.exemplars
-        psummed = jax.tree.map(
-            lambda x: jax.lax.psum(x, both),
-            attr._replace(tail_cut=jnp.float32(0.0), exemplars=None),
-        )
+        with jax.named_scope("merge/attribution"):
+            psummed = jax.tree.map(
+                lambda x: jax.lax.psum(x, both),
+                attr._replace(tail_cut=jnp.float32(0.0), exemplars=None),
+            )
         merged_attr = psummed._replace(tail_cut=attr.tail_cut)
         if ex is not None:
             k = ex.latency.shape[0]
 
+            @jax.named_scope("merge/exemplars")
             def gather(x):
                 # one new leading axis of size mesh.size; fold it into
                 # the K axis so top_k sees every shard's candidates
@@ -1720,12 +1724,10 @@ class ShardedSimulator:
                       for a in self.mesh.axis_names),
                 tuple(d.id for d in self.mesh.devices.flat),
             )
-            self._fns[key] = executable_cache.get_or_build(
+            self._fns[key] = executable_cache.get_or_jit(
                 ("sharded-attr", self.sim.signature, mesh_sig)
                 + cache_key,
-                lambda: telemetry.time_first_call(
-                    jax.jit(mapped), "compile.jit_first_call"
-                ),
+                "sharded_attr", mapped,
             )
         return self._fns[key]
 
@@ -1734,12 +1736,8 @@ class ShardedSimulator:
                      plan.conns_local, plan.trim, plan.sat_conns, tail)
         full_key = ("sharded-attr-local", self.sim.signature,
                     self.n_shards) + cache_key
-        return executable_cache.get_or_build(
-            full_key,
-            lambda: telemetry.time_first_call(
-                jax.jit(partial(self._local_scan_attr, *cache_key)),
-                "compile.jit_first_call",
-            ),
+        return executable_cache.get_or_jit(
+            full_key, "local_attr", partial(self._local_scan_attr, *cache_key),
         )
 
     # -- timeline runs (metrics/timeline.py) ----------------------------
@@ -1933,10 +1931,11 @@ class ShardedSimulator:
         merged_summary = self._merge_summary_collective(summary, both)
         # window_s is identical on every shard — exclude it from the
         # psum (the attribution tail_cut idiom)
-        psummed = jax.tree.map(
-            lambda x: jax.lax.psum(x, both),
-            tl._replace(window_s=jnp.float32(0.0)),
-        )
+        with jax.named_scope("merge/timeline"):
+            psummed = jax.tree.map(
+                lambda x: jax.lax.psum(x, both),
+                tl._replace(window_s=jnp.float32(0.0)),
+            )
         return merged_summary, psummed._replace(window_s=tl.window_s)
 
     def _get_tl(self, plan: _RunPlan, tl_plan: Tuple[int, float]):
@@ -1964,12 +1963,10 @@ class ShardedSimulator:
                       for a in self.mesh.axis_names),
                 tuple(d.id for d in self.mesh.devices.flat),
             )
-            self._fns[key] = executable_cache.get_or_build(
+            self._fns[key] = executable_cache.get_or_jit(
                 ("sharded-tl", self.sim.signature, mesh_sig)
                 + cache_key,
-                lambda: telemetry.time_first_call(
-                    jax.jit(mapped), "compile.jit_first_call"
-                ),
+                "sharded_timeline", mapped,
             )
         return self._fns[key]
 
@@ -1980,12 +1977,8 @@ class ShardedSimulator:
                      tl_plan)
         full_key = ("sharded-tl-local", self.sim.signature,
                     self.n_shards) + cache_key
-        return executable_cache.get_or_build(
-            full_key,
-            lambda: telemetry.time_first_call(
-                jax.jit(partial(self._local_scan_tl, *cache_key)),
-                "compile.jit_first_call",
-            ),
+        return executable_cache.get_or_jit(
+            full_key, "local_timeline", partial(self._local_scan_tl, *cache_key),
         )
 
     # -- protected co-sim runs (sim/policies.py + sim/rollout.py) -------
@@ -2455,12 +2448,13 @@ class ShardedSimulator:
             # exemplar batch (every shard returns the global top-K)
             local_attr = attribution.reduce_stacked(aparts, ex_final)
             ex = local_attr.exemplars
-            psummed = jax.tree.map(
-                lambda x: jax.lax.psum(x, both),
-                local_attr._replace(
-                    tail_cut=jnp.float32(0.0), exemplars=None
-                ),
-            )
+            with jax.named_scope("merge/attribution"):
+                psummed = jax.tree.map(
+                    lambda x: jax.lax.psum(x, both),
+                    local_attr._replace(
+                        tail_cut=jnp.float32(0.0), exemplars=None
+                    ),
+                )
             merged_attr = psummed._replace(
                 tail_cut=local_attr.tail_cut
             )
@@ -2742,12 +2736,10 @@ class ShardedSimulator:
                       for a in self.mesh.axis_names),
                 tuple(d.id for d in self.mesh.devices.flat),
             )
-            self._fns[key] = executable_cache.get_or_build(
+            self._fns[key] = executable_cache.get_or_jit(
                 ("sharded-prot", self.sim.signature, mesh_sig)
                 + cache_key,
-                lambda: telemetry.time_first_call(
-                    jax.jit(mapped), "compile.jit_first_call"
-                ),
+                "sharded_protected", mapped,
             )
         return self._fns[key]
 
@@ -2757,13 +2749,9 @@ class ShardedSimulator:
         cache_key = self._prot_cache_key(plan, tl_plan, attr, roll)
         full_key = ("sharded-prot-local", self.sim.signature,
                     self.n_shards) + cache_key
-        return executable_cache.get_or_build(
-            full_key,
-            lambda: telemetry.time_first_call(
-                jax.jit(partial(self._local_prot_scan_all,
-                                *cache_key)),
-                "compile.jit_first_call",
-            ),
+        return executable_cache.get_or_jit(
+            full_key, "local_protected",
+            partial(self._local_prot_scan_all, *cache_key),
         )
 
     # -- single-device degradation rung --------------------------------
@@ -2829,12 +2817,8 @@ class ShardedSimulator:
                      plan.conns_local, plan.trim, plan.sat_conns)
         full_key = ("sharded-local", self.sim.signature,
                     self.n_shards) + cache_key
-        return executable_cache.get_or_build(
-            full_key,
-            lambda: telemetry.time_first_call(
-                jax.jit(partial(self._local_scan, *cache_key)),
-                "compile.jit_first_call",
-            ),
+        return executable_cache.get_or_jit(
+            full_key, "local_summary", partial(self._local_scan, *cache_key),
         )
 
     def _merge_shard_summaries(self, shards) -> RunSummary:

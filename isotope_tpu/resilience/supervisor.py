@@ -174,7 +174,9 @@ def execution_rungs(
 
     from isotope_tpu.resilience import sentinels
 
+    @telemetry.phase("summary.wait")
     def _finish(summary):
+        # the time the host waited on the device for this run's work
         jax.block_until_ready(summary.count)
         sentinels.check_summary(summary)
         return summary

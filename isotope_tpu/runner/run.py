@@ -36,7 +36,9 @@ from isotope_tpu.metrics.fortio import (
     convert_data,
     fortio_result_from_summary,
     window_summary_from_summary,
+    write_artifact,
     write_csv,
+    write_json,
 )
 from isotope_tpu.metrics.prometheus import MetricsCollector
 from isotope_tpu.models.graph import ServiceGraph
@@ -188,7 +190,9 @@ class _LazyTopology:
     @property
     def compiled(self):
         if self._compiled is None:
-            graph = ServiceGraph.from_yaml_file(self.path)
+            with telemetry.phase("graph.decode"):
+                graph = ServiceGraph.from_yaml_file(self.path)
+            telemetry.counter_inc("graphs_decoded")
             self._graph = graph
             self._compiled = compile_graph(graph, entry=self.config.entry)
             self._entry_resp = float(
@@ -320,6 +324,15 @@ class _LazyTopology:
             )
             self._sims[env.name] = (sim, sharded)
         return self._sims[env.name]
+
+
+def _grid(config: ExperimentConfig, mesh_req):
+    """The sweep grid in run order: (topo_path, topo, env, load)."""
+    for topo_path in config.topology_paths:
+        topo = _LazyTopology(topo_path, config, mesh_req)
+        for env in config.environments:
+            for load in config.load_models():
+                yield topo_path, topo, env, load
 
 
 class _EnsembleGroups:
@@ -1216,109 +1229,221 @@ def run_experiment(
 
     try:
         run_index = 0
-        for topo_path in config.topology_paths:
-            topo = _LazyTopology(topo_path, config, mesh_req)
-            for env in config.environments:
-                for load in config.load_models():
-                    label = _label(topo_path, env.name, load, config.labels)
-                    rec = done.get(label)
-                    if rec is not None and not rec.get("failed"):
-                        results.append(_restore_result(rec, out))
-                        run_index += 1
-                        continue
-                    if progress:
-                        progress(label)
-                    if telemetry.emitting():
-                        # per-run records: each telemetry.jsonl line
-                        # covers exactly ONE run (the README reading
-                        # guide depends on it) — reset before this
-                        # run's simulators build/compile/execute
-                        telemetry.reset()
-                    run_key = jax.random.fold_in(key, run_index)
-                    if profile_dir is not None:
-                        prof_ctx = jax.profiler.trace(
-                            str(pathlib.Path(profile_dir) / label)
+        for topo_path, topo, env, load in _grid(config, mesh_req):
+            label = _label(topo_path, env.name, load, config.labels)
+            rec = done.get(label)
+            if rec is not None and not rec.get("failed"):
+                results.append(_restore_result(rec, out))
+                run_index += 1
+                continue
+            if progress:
+                progress(label)
+            if telemetry.emitting():
+                # per-run records: each telemetry.jsonl line
+                # covers exactly ONE run (the README reading
+                # guide depends on it) — reset before this
+                # run's simulators build/compile/execute
+                telemetry.reset()
+            with telemetry.phase("run.case", label=label,
+                                 run_index=run_index):
+                telemetry.counter_inc("runs_served")
+                run_key = jax.random.fold_in(key, run_index)
+                if profile_dir is not None:
+                    prof_ctx = jax.profiler.trace(
+                        str(pathlib.Path(profile_dir) / label)
+                    )
+                else:
+                    prof_ctx = contextlib.nullcontext()
+                try:
+                    with prof_ctx:
+                        # engine build (device-constant upload, first
+                        # compile triggers inside the run) is itself
+                        # a supervised phase
+                        sim, sharded = call_with_retries(
+                            lambda: topo.sims(env),
+                            site="engine.build", policy=policy,
                         )
-                    else:
-                        prof_ctx = contextlib.nullcontext()
-                    try:
-                        with prof_ctx:
-                            # engine build (device-constant upload, first
-                            # compile triggers inside the run) is itself
-                            # a supervised phase
-                            sim, sharded = call_with_retries(
-                                lambda: topo.sims(env),
-                                site="engine.build", policy=policy,
+                        n = _num_requests(
+                            load, sim.capacity_qps(),
+                            config.num_requests,
+                        )
+                        # the scan path is the product path: requests
+                        # stream through HBM-bounded blocks, metrics
+                        # and the trim window accumulate on device
+                        block = sim.default_block_size()
+                        use_sharded = sharded is not None and (
+                            load.kind == OPEN_LOOP
+                            or load.connections % sharded.n_shards
+                            == 0
+                        )
+                        rungs = execution_rungs(
+                            sim, sharded, use_sharded, load, n,
+                            run_key, block,
+                            collector=topo.collector, trim=True,
+                        )
+                        protected = (
+                            topo.policy_tables is not None
+                            or topo.rollout_tables is not None
+                        )
+                        start_rung = 0
+                        if vet is not None:
+                            start_rung = _vet_gate(
+                                vet, sim, topo, config, load,
+                                block, rungs, policy,
+                                # fleet verdicts for every case a
+                                # fleet serves — protected fleets
+                                # get the carry-aware VET-T025
+                                # variant
+                                ensemble=ens_spec,
+                                protected=protected,
+                                split_spec=config.ensemble_split,
+                                search_spec=search_spec_cfg,
                             )
-                            n = _num_requests(
-                                load, sim.capacity_qps(),
-                                config.num_requests,
-                            )
-                            # the scan path is the product path: requests
-                            # stream through HBM-bounded blocks, metrics
-                            # and the trim window accumulate on device
-                            block = sim.default_block_size()
-                            use_sharded = sharded is not None and (
-                                load.kind == OPEN_LOOP
-                                or load.connections % sharded.n_shards
-                                == 0
-                            )
-                            rungs = execution_rungs(
-                                sim, sharded, use_sharded, load, n,
-                                run_key, block,
-                                collector=topo.collector, trim=True,
-                            )
-                            protected = (
-                                topo.policy_tables is not None
-                                or topo.rollout_tables is not None
-                            )
-                            start_rung = 0
-                            if vet is not None:
-                                start_rung = _vet_gate(
-                                    vet, sim, topo, config, load,
-                                    block, rungs, policy,
-                                    # fleet verdicts for every case a
-                                    # fleet serves — protected fleets
-                                    # get the carry-aware VET-T025
-                                    # variant
-                                    ensemble=ens_spec,
-                                    protected=protected,
-                                    split_spec=config.ensemble_split,
-                                    search_spec=search_spec_cfg,
+                        tl_main = pol_main = roll_main = None
+                        pol_blame = pol_attr = None
+                        ens_summary = None
+                        prot_fleet = False
+                        prot_worst = None
+                        if ens_groups is not None \
+                                and not protected \
+                                and start_rung == 0:
+                            # Monte Carlo fleet: the case's N seed
+                            # members run as ONE vmapped dispatch
+                            # (same-shape grid cells collapse into
+                            # it); the reported summary pools the
+                            # members and the distributional view
+                            # lands in <label>.ensemble.json.  A
+                            # fleet failure falls back to the solo
+                            # ladder below — never fails the case.
+                            # Memory-degraded cases (the vet
+                            # verdict pre-selected a ladder rung)
+                            # skip the fleet outright: even a
+                            # one-member chunk runs the full
+                            # block, and a TPU HBM overflow is
+                            # not reliably a catchable exception.
+                            try:
+                                with telemetry.phase(
+                                    "ensemble.run"
+                                ):
+                                    ens_summary = ens_groups.run(
+                                        label, topo_path,
+                                        env.name, load, sim,
+                                        sharded, use_sharded, n,
+                                        block,
+                                        attribution=attribution,
+                                        timeline=timeline,
+                                    )
+                                telemetry.counter_inc(
+                                    "ensemble_cases"
                                 )
-                            tl_main = pol_main = roll_main = None
-                            pol_blame = pol_attr = None
-                            ens_summary = None
-                            prot_fleet = False
-                            prot_worst = None
-                            if ens_groups is not None \
-                                    and not protected \
+                                telemetry.set_meta(
+                                    "ensemble",
+                                    str(ens_summary.members),
+                                )
+                            except Exception as e:
+                                telemetry.counter_inc(
+                                    "ensemble_fallbacks"
+                                )
+                                # the solo fallback serves this
+                                # cell: keep later groups from
+                                # re-dispatching its members
+                                ens_groups.completed.add(label)
+                                print(
+                                    f"warning: ensemble dispatch "
+                                    f"for {label} failed "
+                                    f"({type(e).__name__}: {e}); "
+                                    "falling back to the solo "
+                                    "run",
+                                    file=sys.stderr,
+                                )
+                        if protected:
+                            # policy/rollout co-sim: the PROTECTED
+                            # run IS the measurement.  With the
+                            # ensemble axis armed it dispatches as
+                            # a FLEET (PR 15 — the pre-fleet
+                            # protected-solo fallback is deleted):
+                            # member 0 rides the run key, so it is
+                            # bit-equal to the solo protected run,
+                            # and the worst member's artifacts
+                            # become the postmortem.  Attributed
+                            # cases thread the blame pass through
+                            # the SAME fleet dispatch (PR 17 —
+                            # the solo-path detour is deleted);
+                            # memory-degraded cases keep the solo
+                            # path.
+                            degraded_to = None
+                            if ens_spec is not None \
                                     and start_rung == 0:
-                                # Monte Carlo fleet: the case's N seed
-                                # members run as ONE vmapped dispatch
-                                # (same-shape grid cells collapse into
-                                # it); the reported summary pools the
-                                # members and the distributional view
-                                # lands in <label>.ensemble.json.  A
-                                # fleet failure falls back to the solo
-                                # ladder below — never fails the case.
-                                # Memory-degraded cases (the vet
-                                # verdict pre-selected a ladder rung)
-                                # skip the fleet outright: even a
-                                # one-member chunk runs the full
-                                # block, and a TPU HBM overflow is
-                                # not reliably a catchable exception.
                                 try:
-                                    with telemetry.phase(
-                                        "ensemble.run"
-                                    ):
-                                        ens_summary = ens_groups.run(
+                                    # the same-shape collapse
+                                    # serves protected cases too
+                                    # (PR 18): grid cells sharing
+                                    # a fleet shape ride one
+                                    # protected dispatch
+                                    ens_summary = \
+                                        ens_groups.run_protected(
                                             label, topo_path,
                                             env.name, load, sim,
-                                            sharded, use_sharded, n,
-                                            block,
-                                            attribution=attribution,
+                                            sharded, use_sharded,
+                                            n, block,
+                                            topo.rollout_tables,
+                                            config
+                                            .chaos_jitter_spec(),
+                                            attribution=(
+                                                attribution
+                                            ),
                                             timeline=timeline,
+                                        )
+                                    prot_fleet = True
+                                    summary = \
+                                        ens_summary.pooled()
+                                    prot_worst = (
+                                        ens_summary
+                                        .worst_member()
+                                    )
+                                    tl_main = (
+                                        ens_summary
+                                        .member_timeline(
+                                            prot_worst
+                                        )
+                                    )
+                                    if ens_summary.policies \
+                                            is not None:
+                                        pol_main = (
+                                            ens_summary
+                                            .member_policies(
+                                                prot_worst
+                                            )
+                                        )
+                                    if ens_summary.rollouts \
+                                            is not None:
+                                        roll_main = (
+                                            ens_summary
+                                            .member_rollouts(
+                                                prot_worst
+                                            )
+                                        )
+                                    if ens_summary.attributions \
+                                            is not None:
+                                        # the worst member's
+                                        # blame IS the postmortem
+                                        # blame doc (stamped with
+                                        # member/seed below)
+                                        from isotope_tpu.metrics \
+                                            import attribution \
+                                            as attr_mod
+
+                                        pol_attr = (
+                                            ens_summary
+                                            .member_attribution(
+                                                prot_worst
+                                            )
+                                        )
+                                        pol_blame = (
+                                            attr_mod.to_doc(
+                                                topo.compiled,
+                                                pol_attr,
+                                            )
                                         )
                                     telemetry.counter_inc(
                                         "ensemble_cases"
@@ -1331,683 +1456,545 @@ def run_experiment(
                                     telemetry.counter_inc(
                                         "ensemble_fallbacks"
                                     )
-                                    # the solo fallback serves this
-                                    # cell: keep later groups from
-                                    # re-dispatching its members
-                                    ens_groups.completed.add(label)
+                                    # the solo fallback serves
+                                    # this cell: keep later
+                                    # groups from re-dispatching
+                                    # its members
+                                    ens_groups.completed.add(
+                                        label
+                                    )
                                     print(
-                                        f"warning: ensemble dispatch "
-                                        f"for {label} failed "
-                                        f"({type(e).__name__}: {e}); "
-                                        "falling back to the solo "
-                                        "run",
+                                        f"warning: protected "
+                                        f"fleet dispatch for "
+                                        f"{label} failed "
+                                        f"({type(e).__name__}: "
+                                        f"{e}); falling back to "
+                                        "the solo protected run",
                                         file=sys.stderr,
                                     )
-                            if protected:
-                                # policy/rollout co-sim: the PROTECTED
-                                # run IS the measurement.  With the
-                                # ensemble axis armed it dispatches as
-                                # a FLEET (PR 15 — the pre-fleet
-                                # protected-solo fallback is deleted):
-                                # member 0 rides the run key, so it is
-                                # bit-equal to the solo protected run,
-                                # and the worst member's artifacts
-                                # become the postmortem.  Attributed
-                                # cases thread the blame pass through
-                                # the SAME fleet dispatch (PR 17 —
-                                # the solo-path detour is deleted);
-                                # memory-degraded cases keep the solo
-                                # path.
-                                degraded_to = None
-                                if ens_spec is not None \
-                                        and start_rung == 0:
-                                    try:
-                                        # the same-shape collapse
-                                        # serves protected cases too
-                                        # (PR 18): grid cells sharing
-                                        # a fleet shape ride one
-                                        # protected dispatch
-                                        ens_summary = \
-                                            ens_groups.run_protected(
-                                                label, topo_path,
-                                                env.name, load, sim,
-                                                sharded, use_sharded,
-                                                n, block,
-                                                topo.rollout_tables,
-                                                config
-                                                .chaos_jitter_spec(),
-                                                attribution=(
-                                                    attribution
-                                                ),
-                                                timeline=timeline,
-                                            )
-                                        prot_fleet = True
-                                        summary = \
-                                            ens_summary.pooled()
-                                        prot_worst = (
-                                            ens_summary
-                                            .worst_member()
-                                        )
-                                        tl_main = (
-                                            ens_summary
-                                            .member_timeline(
-                                                prot_worst
-                                            )
-                                        )
-                                        if ens_summary.policies \
-                                                is not None:
-                                            pol_main = (
-                                                ens_summary
-                                                .member_policies(
-                                                    prot_worst
-                                                )
-                                            )
-                                        if ens_summary.rollouts \
-                                                is not None:
-                                            roll_main = (
-                                                ens_summary
-                                                .member_rollouts(
-                                                    prot_worst
-                                                )
-                                            )
-                                        if ens_summary.attributions \
-                                                is not None:
-                                            # the worst member's
-                                            # blame IS the postmortem
-                                            # blame doc (stamped with
-                                            # member/seed below)
-                                            from isotope_tpu.metrics \
-                                                import attribution \
-                                                as attr_mod
-
-                                            pol_attr = (
-                                                ens_summary
-                                                .member_attribution(
-                                                    prot_worst
-                                                )
-                                            )
-                                            pol_blame = (
-                                                attr_mod.to_doc(
-                                                    topo.compiled,
-                                                    pol_attr,
-                                                )
-                                            )
-                                        telemetry.counter_inc(
-                                            "ensemble_cases"
-                                        )
-                                        telemetry.set_meta(
-                                            "ensemble",
-                                            str(ens_summary.members),
-                                        )
-                                    except Exception as e:
-                                        telemetry.counter_inc(
-                                            "ensemble_fallbacks"
-                                        )
-                                        # the solo fallback serves
-                                        # this cell: keep later
-                                        # groups from re-dispatching
-                                        # its members
-                                        ens_groups.completed.add(
-                                            label
-                                        )
-                                        print(
-                                            f"warning: protected "
-                                            f"fleet dispatch for "
-                                            f"{label} failed "
-                                            f"({type(e).__name__}: "
-                                            f"{e}); falling back to "
-                                            "the solo protected run",
-                                            file=sys.stderr,
-                                        )
-                                if not prot_fleet:
-                                    (summary, tl_main, roll_main,
-                                     pol_main, pol_blame, pol_attr,
-                                     degraded_to) = _protected_run(
-                                        sim, sharded, use_sharded,
-                                        load, n, run_key, block,
-                                        config, topo.collector,
-                                        policy, timeline,
-                                        topo.policy_tables,
-                                        topo.rollout_tables,
-                                        attribution=attribution,
-                                    )
-                            elif ens_summary is not None:
-                                summary = ens_summary.pooled()
-                                degraded_to = None
-                            else:
-                                summary, degraded_to = run_ladder(
-                                    rungs[start_rung:], policy,
-                                    site_prefix="engine",
-                                )
-                            if start_rung and degraded_to is None \
-                                    and not protected \
-                                    and ens_summary is None:
-                                # the pre-selected rung IS a
-                                # degradation: record it exactly as a
-                                # ladder descent would have (bench
-                                # gates key on degraded_to presence)
-                                degraded_to = rungs[start_rung][0]
-                                telemetry.set_meta(
-                                    "degraded_to", degraded_to
-                                )
-                    except Exception as e:
-                        # unrecoverable for THIS case (deterministic
-                        # error, retries/ladder exhausted): record it,
-                        # keep the sweep alive — the reference's sweeps
-                        # survive one broken deployment the same way
-                        err_class = classify(e)
-                        err_text = f"{type(e).__name__}: {e}"
-                        telemetry.counter_inc("run_failures")
-                        print(
-                            f"error: run {label} failed "
-                            f"({err_class}): {err_text}",
-                            file=sys.stderr,
-                        )
-                        failed = RunResult(
-                            label=label,
-                            topology=topo_path,
-                            environment=env.name,
-                            flat={"Labels": label, "failed": True,
-                                  "error": err_text},
-                            window=_failed_window(err_text),
-                            fortio_json={},
-                            prometheus_text="",
-                            failed=True,
-                            error=err_text,
-                        )
-                        results.append(failed)
-                        if ckpt_file is not None:
-                            ckpt_file.write(
-                                json.dumps(
-                                    {
-                                        "label": label,
-                                        "topology": topo_path,
-                                        "environment": env.name,
-                                        "failed": True,
-                                        "error": err_text[:1000],
-                                        "error_class": err_class,
-                                    }
-                                )
-                                + "\n"
-                            )
-                            ckpt_file.flush()
-                        run_index += 1
-                        continue
-                    blame_doc = attr_summary = None
-                    if protected:
-                        # the protected attributed pass (if requested)
-                        # already ran inside _protected_run with the
-                        # same streams/trajectory as the measurement
-                        blame_doc, attr_summary = pol_blame, pol_attr
-                    elif attribution is not None:
-                        if ens_summary is not None and \
-                                ens_summary.attributions is not None:
-                            # the fleet already carried the blame
-                            # pass per member (PR 17): the worst
-                            # member's blame is the case's blame doc,
-                            # stamped so the bad day replays solo
-                            from isotope_tpu.metrics import (
-                                attribution as attr_mod,
-                            )
-
-                            worst = ens_summary.worst_member()
-                            attr_summary = (
-                                ens_summary.member_attribution(worst)
-                            )
-                            blame_doc = attr_mod.to_doc(
-                                topo.compiled, attr_summary,
-                            )
-                            blame_doc.update({
-                                "member": int(worst),
-                                "member_seed": int(
-                                    ens_summary.spec.seeds[worst]
-                                ),
-                                "fleet_members": (
-                                    ens_summary.members
-                                ),
-                                "worst_member": True,
-                            })
-                        else:
-                            # identical executor/key/blocking to the
-                            # main run, so the attributed pass replays
-                            # the same request streams the reported
-                            # metrics came from
-                            blame_doc, attr_summary = (
-                                _attribution_pass(
-                                    sim, sharded, use_sharded, topo,
+                            if not prot_fleet:
+                                (summary, tl_main, roll_main,
+                                 pol_main, pol_blame, pol_attr,
+                                 degraded_to) = _protected_run(
+                                    sim, sharded, use_sharded,
                                     load, n, run_key, block,
-                                    tail=attribution == "tail",
+                                    config, topo.collector,
+                                    policy, timeline,
+                                    topo.policy_tables,
+                                    topo.rollout_tables,
+                                    attribution=attribution,
                                 )
+                        elif ens_summary is not None:
+                            summary = ens_summary.pooled()
+                            degraded_to = None
+                        else:
+                            summary, degraded_to = run_ladder(
+                                rungs[start_rung:], policy,
+                                site_prefix="engine",
                             )
-                    tl_doc = tl_summary = None
-                    pol_doc = pol_summary_out = None
-                    roll_doc = roll_summary_out = None
-                    lb_doc = None
-                    if protected:
-                        # the protected run already reduced the
-                        # timeline next to the control series — no
-                        # separate recorder pass needed.  Fleet-served
-                        # cases report the MOST-SEVERE member's
-                        # artifacts, stamped with its member index and
-                        # seed, so a rare failure the fleet found is
-                        # immediately replayable solo.
+                        if start_rung and degraded_to is None \
+                                and not protected \
+                                and ens_summary is None:
+                            # the pre-selected rung IS a
+                            # degradation: record it exactly as a
+                            # ladder descent would have (bench
+                            # gates key on degraded_to presence)
+                            degraded_to = rungs[start_rung][0]
+                            telemetry.set_meta(
+                                "degraded_to", degraded_to
+                            )
+                except Exception as e:
+                    # unrecoverable for THIS case (deterministic
+                    # error, retries/ladder exhausted): record it,
+                    # keep the sweep alive — the reference's sweeps
+                    # survive one broken deployment the same way
+                    err_class = classify(e)
+                    err_text = f"{type(e).__name__}: {e}"
+                    telemetry.counter_inc("run_failures")
+                    print(
+                        f"error: run {label} failed "
+                        f"({err_class}): {err_text}",
+                        file=sys.stderr,
+                    )
+                    failed = RunResult(
+                        label=label,
+                        topology=topo_path,
+                        environment=env.name,
+                        flat={"Labels": label, "failed": True,
+                              "error": err_text},
+                        window=_failed_window(err_text),
+                        fortio_json={},
+                        prometheus_text="",
+                        failed=True,
+                        error=err_text,
+                    )
+                    results.append(failed)
+                    if ckpt_file is not None:
+                        ckpt_file.write(
+                            json.dumps(
+                                {
+                                    "label": label,
+                                    "topology": topo_path,
+                                    "environment": env.name,
+                                    "failed": True,
+                                    "error": err_text[:1000],
+                                    "error_class": err_class,
+                                }
+                            )
+                            + "\n"
+                        )
+                        ckpt_file.flush()
+                    run_index += 1
+                    continue
+                blame_doc = attr_summary = None
+                if protected:
+                    # the protected attributed pass (if requested)
+                    # already ran inside _protected_run with the
+                    # same streams/trajectory as the measurement
+                    blame_doc, attr_summary = pol_blame, pol_attr
+                elif attribution is not None:
+                    if ens_summary is not None and \
+                            ens_summary.attributions is not None:
+                        # the fleet already carried the blame
+                        # pass per member (PR 17): the worst
+                        # member's blame is the case's blame doc,
+                        # stamped so the bad day replays solo
+                        from isotope_tpu.metrics import (
+                            attribution as attr_mod,
+                        )
+
+                        worst = ens_summary.worst_member()
+                        attr_summary = (
+                            ens_summary.member_attribution(worst)
+                        )
+                        blame_doc = attr_mod.to_doc(
+                            topo.compiled, attr_summary,
+                        )
+                        blame_doc.update({
+                            "member": int(worst),
+                            "member_seed": int(
+                                ens_summary.spec.seeds[worst]
+                            ),
+                            "fleet_members": (
+                                ens_summary.members
+                            ),
+                            "worst_member": True,
+                        })
+                    else:
+                        # identical executor/key/blocking to the
+                        # main run, so the attributed pass replays
+                        # the same request streams the reported
+                        # metrics came from
+                        blame_doc, attr_summary = (
+                            _attribution_pass(
+                                sim, sharded, use_sharded, topo,
+                                load, n, run_key, block,
+                                tail=attribution == "tail",
+                            )
+                        )
+                tl_doc = tl_summary = None
+                pol_doc = pol_summary_out = None
+                roll_doc = roll_summary_out = None
+                lb_doc = None
+                if protected:
+                    # the protected run already reduced the
+                    # timeline next to the control series — no
+                    # separate recorder pass needed.  Fleet-served
+                    # cases report the MOST-SEVERE member's
+                    # artifacts, stamped with its member index and
+                    # seed, so a rare failure the fleet found is
+                    # immediately replayable solo.
+                    from isotope_tpu.metrics import (
+                        timeline as timeline_mod,
+                    )
+
+                    tl_summary = tl_main
+                    tl_doc = timeline_mod.to_doc(
+                        topo.compiled, tl_main
+                    )
+                    if pol_main is not None:
+                        from isotope_tpu.sim import (
+                            policies as policies_mod,
+                        )
+
+                        pol_summary_out = pol_main
+                        pol_doc = policies_mod.to_doc(
+                            topo.compiled, pol_main,
+                            topo.policy_tables,
+                        )
+                    if roll_main is not None:
+                        from isotope_tpu.sim import (
+                            rollout as rollout_mod,
+                        )
+
+                        roll_summary_out = roll_main
+                        roll_doc = rollout_mod.to_doc(
+                            topo.compiled, roll_main,
+                            topo.rollout_tables,
+                        )
+                    if prot_fleet:
+                        stamp = {
+                            "member": int(prot_worst),
+                            # member 0 is the CONTROL member: it
+                            # rides the RUN key itself, so the
+                            # replay recipe is the solo run, not
+                            # a folded seed
+                            "member_seed": (
+                                None if prot_worst == 0 else int(
+                                    ens_spec.seeds[prot_worst]
+                                )
+                            ),
+                            "member_key": (
+                                "run_key" if prot_worst == 0
+                                else "fold_in(run_key, "
+                                     "member_seed)"
+                            ),
+                            "fleet_members": (
+                                ens_summary.members
+                            ),
+                            "worst_member": True,
+                        }
+                        if ens_summary.member_chaos is not None:
+                            stamp["member_chaos"] = [
+                                {
+                                    "service": ev.service,
+                                    "start_s": float(ev.start_s),
+                                    "end_s": float(ev.end_s),
+                                    "replicas_down": (
+                                        ev.replicas_down
+                                    ),
+                                    "drain": ev.drain,
+                                }
+                                for ev in ens_summary
+                                .member_chaos[prot_worst]
+                            ]
+                        for d in (tl_doc, pol_doc, roll_doc,
+                                  blame_doc):
+                            if d is not None:
+                                d.update(stamp)
+                elif timeline is not None:
+                    if ens_summary is not None and \
+                            ens_summary.timelines is not None:
+                        # the fleet already carried the recorder
+                        # per member: the worst member's window
+                        # series is the case's timeline doc
                         from isotope_tpu.metrics import (
                             timeline as timeline_mod,
                         )
 
-                        tl_summary = tl_main
+                        worst = ens_summary.worst_member()
+                        tl_summary = (
+                            ens_summary.member_timeline(worst)
+                        )
                         tl_doc = timeline_mod.to_doc(
-                            topo.compiled, tl_main
+                            topo.compiled, tl_summary,
                         )
-                        if pol_main is not None:
-                            from isotope_tpu.sim import (
-                                policies as policies_mod,
-                            )
-
-                            pol_summary_out = pol_main
-                            pol_doc = policies_mod.to_doc(
-                                topo.compiled, pol_main,
-                                topo.policy_tables,
-                            )
-                        if roll_main is not None:
-                            from isotope_tpu.sim import (
-                                rollout as rollout_mod,
-                            )
-
-                            roll_summary_out = roll_main
-                            roll_doc = rollout_mod.to_doc(
-                                topo.compiled, roll_main,
-                                topo.rollout_tables,
-                            )
-                        if prot_fleet:
-                            stamp = {
-                                "member": int(prot_worst),
-                                # member 0 is the CONTROL member: it
-                                # rides the RUN key itself, so the
-                                # replay recipe is the solo run, not
-                                # a folded seed
-                                "member_seed": (
-                                    None if prot_worst == 0 else int(
-                                        ens_spec.seeds[prot_worst]
-                                    )
-                                ),
-                                "member_key": (
-                                    "run_key" if prot_worst == 0
-                                    else "fold_in(run_key, "
-                                         "member_seed)"
-                                ),
-                                "fleet_members": (
-                                    ens_summary.members
-                                ),
-                                "worst_member": True,
-                            }
-                            if ens_summary.member_chaos is not None:
-                                stamp["member_chaos"] = [
-                                    {
-                                        "service": ev.service,
-                                        "start_s": float(ev.start_s),
-                                        "end_s": float(ev.end_s),
-                                        "replicas_down": (
-                                            ev.replicas_down
-                                        ),
-                                        "drain": ev.drain,
-                                    }
-                                    for ev in ens_summary
-                                    .member_chaos[prot_worst]
-                                ]
-                            for d in (tl_doc, pol_doc, roll_doc,
-                                      blame_doc):
-                                if d is not None:
-                                    d.update(stamp)
-                    elif timeline is not None:
-                        if ens_summary is not None and \
-                                ens_summary.timelines is not None:
-                            # the fleet already carried the recorder
-                            # per member: the worst member's window
-                            # series is the case's timeline doc
-                            from isotope_tpu.metrics import (
-                                timeline as timeline_mod,
-                            )
-
-                            worst = ens_summary.worst_member()
-                            tl_summary = (
-                                ens_summary.member_timeline(worst)
-                            )
-                            tl_doc = timeline_mod.to_doc(
-                                topo.compiled, tl_summary,
-                            )
-                            tl_doc.update({
-                                "member": int(worst),
-                                "member_seed": int(
-                                    ens_summary.spec.seeds[worst]
-                                ),
-                                "fleet_members": (
-                                    ens_summary.members
-                                ),
-                                "worst_member": True,
-                            })
-                        else:
-                            tl_doc, tl_summary = _timeline_pass(
-                                sim, sharded, use_sharded, topo,
-                                load, n, run_key, block,
-                                window_s=timeline,
-                            )
-                    if (
-                        topo.lb_tables is not None
-                        and topo.lb_tables.active
-                    ):
-                        # ACTIVE laws only: an all-fifo/no-panic block
-                        # is the pinned neutral path — marking it _lb
-                        # would mislabel a plain-M/M/k measurement.
-                        # Static law/split always; the per-window
-                        # per-backend census when a recorder ran (and
-                        # the actuated pool sizes when PR 9 loops did)
-                        from isotope_tpu.sim import lb as lb_mod
-
-                        lb_doc = lb_mod.to_doc(
-                            topo.lb_tables,
-                            tl=tl_summary, pol=pol_summary_out,
+                        tl_doc.update({
+                            "member": int(worst),
+                            "member_seed": int(
+                                ens_summary.spec.seeds[worst]
+                            ),
+                            "fleet_members": (
+                                ens_summary.members
+                            ),
+                            "worst_member": True,
+                        })
+                    else:
+                        tl_doc, tl_summary = _timeline_pass(
+                            sim, sharded, use_sharded, topo,
+                            load, n, run_key, block,
+                            window_s=timeline,
                         )
-                    doc = fortio_result_from_summary(
-                        summary, load, labels=label,
-                        response_size_bytes=topo.entry_response_size,
+                if (
+                    topo.lb_tables is not None
+                    and topo.lb_tables.active
+                ):
+                    # ACTIVE laws only: an all-fifo/no-panic block
+                    # is the pinned neutral path — marking it _lb
+                    # would mislabel a plain-M/M/k measurement.
+                    # Static law/split always; the per-window
+                    # per-backend census when a recorder ran (and
+                    # the actuated pool sizes when PR 9 loops did)
+                    from isotope_tpu.sim import lb as lb_mod
+
+                    lb_doc = lb_mod.to_doc(
+                        topo.lb_tables,
+                        tl=tl_summary, pol=pol_summary_out,
                     )
-                    if ens_summary is not None:
-                        # the pooled count spans N member WORLDS of
-                        # one wall-clock each: normalize the rate to
-                        # per-member so ActualQPS stays comparable to
-                        # RequestedQPS (and to pre-ensemble rows in
-                        # report.py's label-joined regression view);
-                        # counts/histograms stay pooled — they are
-                        # sample sizes, and errorPercent is a ratio
-                        doc["ActualQPS"] /= ens_summary.members
-                    flat = convert_data(doc)
-                    window = window_summary_from_summary(
-                        summary,
-                        service_names=topo.compiled.services.names,
-                        replicas=topo.compiled.services.replicas,
+                doc = fortio_result_from_summary(
+                    summary, load, labels=label,
+                    response_size_bytes=topo.entry_response_size,
+                )
+                if ens_summary is not None:
+                    # the pooled count spans N member WORLDS of
+                    # one wall-clock each: normalize the rate to
+                    # per-member so ActualQPS stays comparable to
+                    # RequestedQPS (and to pre-ensemble rows in
+                    # report.py's label-joined regression view);
+                    # counts/histograms stay pooled — they are
+                    # sample sizes, and errorPercent is a ratio
+                    doc["ActualQPS"] /= ens_summary.members
+                flat = convert_data(doc)
+                window = window_summary_from_summary(
+                    summary,
+                    service_names=topo.compiled.services.names,
+                    replicas=topo.compiled.services.replicas,
+                )
+                if ens_summary is not None:
+                    window = dataclasses.replace(
+                        window,
+                        qps=window.qps / ens_summary.members,
                     )
-                    if ens_summary is not None:
-                        window = dataclasses.replace(
-                            window,
-                            qps=window.qps / ens_summary.members,
+                flat["windowDiscarded"] = window.discarded
+                if use_sharded and topo.mesh_layout:
+                    # the factorization that served the case is run
+                    # METADATA (like degraded_to): a record produced
+                    # by a different mesh layout is a different
+                    # measurement, and bench gates key on it
+                    flat["_mesh_layout"] = topo.mesh_layout
+                    telemetry.set_meta(
+                        "mesh_layout", topo.mesh_layout
+                    )
+                if degraded_to is not None:
+                    # degradation is run METADATA: a sweep row that
+                    # came off a fallback rung must say so (and
+                    # bench_regress fails a capture that degrades a
+                    # previously-clean case)
+                    flat["degraded_to"] = degraded_to
+                if pol_doc is not None:
+                    # the row came from PROTECTED physics — a
+                    # different measurement than an unprotected
+                    # run of the same grid cell
+                    flat["_policies"] = True
+                    telemetry.set_meta("policies", "on")
+                if roll_doc is not None:
+                    # likewise for the rollout controller: bench
+                    # and bench_regress key on the marker so a
+                    # rollout-enabled case is never compared
+                    # against an open-loop twin
+                    flat["_rollout"] = True
+                    telemetry.set_meta("rollouts", "on")
+                if lb_doc is not None:
+                    # lb laws change the wait physics of every run
+                    # kind — the marker keeps bench_regress from
+                    # comparing an lb row against a fifo twin
+                    flat["_lb"] = True
+                    telemetry.set_meta("lb", "on")
+                if config.ingest:
+                    # the row replays FITTED telemetry, not a
+                    # hand-written topology — different
+                    # provenance; bench_regress keys on the
+                    # marker so an ingested replay is never
+                    # compared against a hand-written twin
+                    flat["_ingest"] = str(
+                        config.ingest.get("label", "ingested")
+                    )
+                    telemetry.set_meta("ingest", flat["_ingest"])
+                ens_doc = None
+                fb_doc = None
+                if ens_summary is not None:
+                    # the row POOLS N seed members — a tighter
+                    # estimate than a solo run of the same cell,
+                    # but a different measurement; the marker
+                    # keeps comparisons honest and the artifact
+                    # carries the distributional view
+                    split_doc = None
+                    if config.ensemble_split:
+                        # importance splitting (sim/splitting.py):
+                        # resolve the rare-outage tail the fleet's
+                        # Wilson interval cannot, one short-
+                        # horizon fleet dispatch per level
+                        split_doc = _splitting_pass(
+                            sim, sharded, use_sharded, topo,
+                            load, n, run_key, block, config,
+                            timeline, protected,
+                            topo.rollout_tables,
+                            config.split_spec(),
+                            config.chaos_jitter_spec(),
                         )
-                    flat["windowDiscarded"] = window.discarded
-                    if use_sharded and topo.mesh_layout:
-                        # the factorization that served the case is run
-                        # METADATA (like degraded_to): a record produced
-                        # by a different mesh layout is a different
-                        # measurement, and bench gates key on it
-                        flat["_mesh_layout"] = topo.mesh_layout
-                        telemetry.set_meta(
-                            "mesh_layout", topo.mesh_layout
-                        )
-                    if degraded_to is not None:
-                        # degradation is run METADATA: a sweep row that
-                        # came off a fallback rung must say so (and
-                        # bench_regress fails a capture that degrades a
-                        # previously-clean case)
-                        flat["degraded_to"] = degraded_to
-                    if pol_doc is not None:
-                        # the row came from PROTECTED physics — a
-                        # different measurement than an unprotected
-                        # run of the same grid cell
-                        flat["_policies"] = True
-                        telemetry.set_meta("policies", "on")
-                    if roll_doc is not None:
-                        # likewise for the rollout controller: bench
-                        # and bench_regress key on the marker so a
-                        # rollout-enabled case is never compared
-                        # against an open-loop twin
-                        flat["_rollout"] = True
-                        telemetry.set_meta("rollouts", "on")
-                    if lb_doc is not None:
-                        # lb laws change the wait physics of every run
-                        # kind — the marker keeps bench_regress from
-                        # comparing an lb row against a fifo twin
-                        flat["_lb"] = True
-                        telemetry.set_meta("lb", "on")
-                    if config.ingest:
-                        # the row replays FITTED telemetry, not a
-                        # hand-written topology — different
-                        # provenance; bench_regress keys on the
-                        # marker so an ingested replay is never
-                        # compared against a hand-written twin
-                        flat["_ingest"] = str(
-                            config.ingest.get("label", "ingested")
-                        )
-                        telemetry.set_meta("ingest", flat["_ingest"])
-                    ens_doc = None
-                    fb_doc = None
-                    if ens_summary is not None:
-                        # the row POOLS N seed members — a tighter
-                        # estimate than a solo run of the same cell,
-                        # but a different measurement; the marker
-                        # keeps comparisons honest and the artifact
-                        # carries the distributional view
-                        split_doc = None
-                        if config.ensemble_split:
-                            # importance splitting (sim/splitting.py):
-                            # resolve the rare-outage tail the fleet's
-                            # Wilson interval cannot, one short-
-                            # horizon fleet dispatch per level
-                            split_doc = _splitting_pass(
-                                sim, sharded, use_sharded, topo,
-                                load, n, run_key, block, config,
-                                timeline, protected,
-                                topo.rollout_tables,
-                                config.split_spec(),
-                                config.chaos_jitter_spec(),
-                            )
-                        ens_doc = ens_summary.to_doc(
-                            label=label,
-                            slo_s=config.ensemble_slo_s,
-                            splitting=split_doc,
-                        )
-                        flat["_ensemble"] = ens_summary.members
-                        if prot_fleet:
-                            flat["_protected_fleet"] = True
-                            if ens_doc.get("worst_member") == 0:
-                                # the control member rides the RUN
-                                # key, not a folded seed — the
-                                # replay recipe is the solo run
-                                ens_doc["worst_member_seed"] = None
-                        if ens_summary.attributions is not None:
-                            # fleet divergence explainer (PR 17):
-                            # band the per-hop blame shares across
-                            # members, rank who diverged and why,
-                            # localize the window of onset — one
-                            # device reduce, one readback.  Best
-                            # effort: an explainer failure never
-                            # fails a case whose metrics landed.
-                            import numpy as _np
+                    ens_doc = ens_summary.to_doc(
+                        label=label,
+                        slo_s=config.ensemble_slo_s,
+                        splitting=split_doc,
+                    )
+                    flat["_ensemble"] = ens_summary.members
+                    if prot_fleet:
+                        flat["_protected_fleet"] = True
+                        if ens_doc.get("worst_member") == 0:
+                            # the control member rides the RUN
+                            # key, not a folded seed — the
+                            # replay recipe is the solo run
+                            ens_doc["worst_member_seed"] = None
+                    if ens_summary.attributions is not None:
+                        # fleet divergence explainer (PR 17):
+                        # band the per-hop blame shares across
+                        # members, rank who diverged and why,
+                        # localize the window of onset — one
+                        # device reduce, one readback.  Best
+                        # effort: an explainer failure never
+                        # fails a case whose metrics landed.
+                        import numpy as _np
 
-                            from isotope_tpu.metrics import (
-                                fleetblame,
-                            )
+                        from isotope_tpu.metrics import (
+                            fleetblame,
+                        )
 
-                            try:
-                                win_arr = None
-                                if ens_summary.timelines is not None:
-                                    win_arr = float(
-                                        _np.asarray(
-                                            ens_summary.timelines
-                                            .window_s
-                                        ).reshape(-1)[0]
-                                    )
-                                fb_doc = fleetblame.to_doc(
-                                    topo.compiled,
-                                    ens_summary.attributions,
-                                    ens_summary.timelines,
-                                    label=label,
-                                    severity=(
-                                        ens_summary.severity()
-                                    ),
-                                    seeds=ens_summary.spec.seeds,
-                                    window_s=win_arr,
-                                )
-                                flat["_fleet_blame"] = True
-                                telemetry.counter_inc(
-                                    "fleet_blame_docs"
-                                )
-                            except Exception as e:
-                                telemetry.counter_inc(
-                                    "fleet_blame_failures"
-                                )
-                                print(
-                                    f"warning: fleet-blame "
-                                    f"explainer for {label} failed "
-                                    f"({type(e).__name__}: {e})",
-                                    file=sys.stderr,
-                                )
-                    search_doc = None
-                    if search_spec_cfg is not None \
-                            and not protected \
-                            and start_rung == 0:
-                        # successive-halving config search
-                        # (sim/search.py): the bracket screens N
-                        # traced perturbations of THIS case and
-                        # rides its own key lane, so the reported
-                        # measurement above is untouched.  Best
-                        # effort like the ensemble axis: a bracket
-                        # failure never fails the case.  Memory-
-                        # degraded cases skip it outright (the
-                        # widest rung is the ensemble problem VET-M
-                        # pre-selected a rung for).
                         try:
-                            with telemetry.phase("search.run"):
-                                srch = (
-                                    sharded.run_search
-                                    if use_sharded
-                                    else sim.run_search
-                                )(
-                                    load, n,
-                                    jax.random.fold_in(
-                                        run_key, 911
-                                    ),
-                                    search_spec_cfg,
-                                    block_size=block,
+                            win_arr = None
+                            if ens_summary.timelines is not None:
+                                win_arr = float(
+                                    _np.asarray(
+                                        ens_summary.timelines
+                                        .window_s
+                                    ).reshape(-1)[0]
                                 )
-                            search_doc = srch.to_doc(label)
-                            # the marker keeps bench_regress from
-                            # comparing a search-carrying row
-                            # against a plain twin
-                            flat["_search"] = (
-                                search_spec_cfg.members
+                            fb_doc = fleetblame.to_doc(
+                                topo.compiled,
+                                ens_summary.attributions,
+                                ens_summary.timelines,
+                                label=label,
+                                severity=(
+                                    ens_summary.severity()
+                                ),
+                                seeds=ens_summary.spec.seeds,
+                                window_s=win_arr,
                             )
-                            telemetry.counter_inc("search_cases")
-                            telemetry.set_meta(
-                                "search",
-                                str(search_spec_cfg.members),
+                            flat["_fleet_blame"] = True
+                            telemetry.counter_inc(
+                                "fleet_blame_docs"
                             )
                         except Exception as e:
                             telemetry.counter_inc(
-                                "search_fallbacks"
+                                "fleet_blame_failures"
                             )
                             print(
-                                f"warning: config-search bracket "
-                                f"for {label} failed "
-                                f"({type(e).__name__}: {e}); the "
-                                "case keeps its solo measurement",
+                                f"warning: fleet-blame "
+                                f"explainer for {label} failed "
+                                f"({type(e).__name__}: {e})",
                                 file=sys.stderr,
                             )
-                    flat.update(
-                        {
-                            "cpu_cores_" + name: round(v, 4)
-                            for name, v in window.cpu_cores.items()
-                        }
-                    )
-                    # full exposition: the five service series plus the
-                    # sim-side resource series the alarm queries read
-                    prom_text = topo.collector.full_text(summary)
-                    run_telem = None
-                    if telemetry.emitting():
-                        # one scrape sees workload AND engine: append
-                        # the isotope_engine_* series to the exposition
-                        telemetry.record_device_memory()
-                        _record_vet_memory_ratio()
-                        run_telem = telemetry.snapshot(label=label)
-                        prom_text += run_telem.prometheus_text()
-                    result = RunResult(
-                        label=label,
-                        topology=topo_path,
-                        environment=env.name,
-                        flat=flat,
-                        window=window,
-                        fortio_json=doc,
-                        prometheus_text=prom_text,
-                        telemetry=(
-                            run_telem.to_dict() if run_telem else None
-                        ),
-                        degraded_to=degraded_to,
-                        blame=blame_doc,
-                        attribution=attr_summary,
-                        compiled=(
-                            topo.compiled
-                            if attr_summary is not None
-                            or tl_summary is not None
-                            else None
-                        ),
-                        timeline=tl_doc,
-                        timeline_summary=tl_summary,
-                        policies=pol_doc,
-                        policies_summary=pol_summary_out,
-                        rollouts=roll_doc,
-                        rollouts_summary=roll_summary_out,
-                        lb=lb_doc,
-                        ensemble=ens_doc,
-                        ensemble_summary=ens_summary,
-                        fleet_blame=fb_doc,
-                        search=search_doc,
-                    )
-                    results.append(result)
-                    if out is not None:
-                        # per-run artifacts + checkpoint line land NOW,
-                        # so a kill loses at most the in-flight run
-                        with open(out / f"{label}.json", "w") as f:
-                            json.dump(doc, f, indent=2)
-                        (out / f"{label}.prom").write_text(prom_text)
-                        if blame_doc is not None:
-                            with open(
-                                out / f"{label}.blame.json", "w"
-                            ) as f:
-                                json.dump(blame_doc, f, indent=2)
-                        if tl_doc is not None:
-                            with open(
-                                out / f"{label}.timeline.json", "w"
-                            ) as f:
-                                json.dump(tl_doc, f, indent=2)
-                        if pol_doc is not None:
-                            with open(
-                                out / f"{label}.policies.json", "w"
-                            ) as f:
-                                json.dump(pol_doc, f, indent=2)
-                        if roll_doc is not None:
-                            with open(
-                                out / f"{label}.rollout.json", "w"
-                            ) as f:
-                                json.dump(roll_doc, f, indent=2)
-                        if lb_doc is not None:
-                            with open(
-                                out / f"{label}.lb.json", "w"
-                            ) as f:
-                                json.dump(lb_doc, f, indent=2)
-                        if ens_doc is not None:
-                            with open(
-                                out / f"{label}.ensemble.json", "w"
-                            ) as f:
-                                json.dump(ens_doc, f, indent=2)
-                        if fb_doc is not None:
-                            with open(
-                                out / f"{label}.fleet-blame.json",
-                                "w",
-                            ) as f:
-                                json.dump(fb_doc, f, indent=2)
-                        if search_doc is not None:
-                            with open(
-                                out / f"{label}.search.json", "w"
-                            ) as f:
-                                json.dump(search_doc, f, indent=2)
+                search_doc = None
+                if search_spec_cfg is not None \
+                        and not protected \
+                        and start_rung == 0:
+                    # successive-halving config search
+                    # (sim/search.py): the bracket screens N
+                    # traced perturbations of THIS case and
+                    # rides its own key lane, so the reported
+                    # measurement above is untouched.  Best
+                    # effort like the ensemble axis: a bracket
+                    # failure never fails the case.  Memory-
+                    # degraded cases skip it outright (the
+                    # widest rung is the ensemble problem VET-M
+                    # pre-selected a rung for).
+                    try:
+                        with telemetry.phase("search.run"):
+                            srch = (
+                                sharded.run_search
+                                if use_sharded
+                                else sim.run_search
+                            )(
+                                load, n,
+                                jax.random.fold_in(
+                                    run_key, 911
+                                ),
+                                search_spec_cfg,
+                                block_size=block,
+                            )
+                        search_doc = srch.to_doc(label)
+                        # the marker keeps bench_regress from
+                        # comparing a search-carrying row
+                        # against a plain twin
+                        flat["_search"] = (
+                            search_spec_cfg.members
+                        )
+                        telemetry.counter_inc("search_cases")
+                        telemetry.set_meta(
+                            "search",
+                            str(search_spec_cfg.members),
+                        )
+                    except Exception as e:
+                        telemetry.counter_inc(
+                            "search_fallbacks"
+                        )
+                        print(
+                            f"warning: config-search bracket "
+                            f"for {label} failed "
+                            f"({type(e).__name__}: {e}); the "
+                            "case keeps its solo measurement",
+                            file=sys.stderr,
+                        )
+                flat.update(
+                    {
+                        "cpu_cores_" + name: round(v, 4)
+                        for name, v in window.cpu_cores.items()
+                    }
+                )
+                # full exposition: the five service series plus the
+                # sim-side resource series the alarm queries read
+                prom_text = topo.collector.full_text(summary)
+                run_telem = None
+                if telemetry.emitting():
+                    # one scrape sees workload AND engine: append
+                    # the isotope_engine_* series to the exposition
+                    telemetry.record_device_memory()
+                    _record_vet_memory_ratio()
+                    run_telem = telemetry.snapshot(label=label)
+                    prom_text += run_telem.prometheus_text()
+                result = RunResult(
+                    label=label,
+                    topology=topo_path,
+                    environment=env.name,
+                    flat=flat,
+                    window=window,
+                    fortio_json=doc,
+                    prometheus_text=prom_text,
+                    telemetry=(
+                        run_telem.to_dict() if run_telem else None
+                    ),
+                    degraded_to=degraded_to,
+                    blame=blame_doc,
+                    attribution=attr_summary,
+                    compiled=(
+                        topo.compiled
+                        if attr_summary is not None
+                        or tl_summary is not None
+                        else None
+                    ),
+                    timeline=tl_doc,
+                    timeline_summary=tl_summary,
+                    policies=pol_doc,
+                    policies_summary=pol_summary_out,
+                    rollouts=roll_doc,
+                    rollouts_summary=roll_summary_out,
+                    lb=lb_doc,
+                    ensemble=ens_doc,
+                    ensemble_summary=ens_summary,
+                    fleet_blame=fb_doc,
+                    search=search_doc,
+                )
+                results.append(result)
+                if out is not None:
+                    # per-run artifacts + checkpoint line land NOW,
+                    # so a kill loses at most the in-flight run
+                    with telemetry.phase("artifacts.write"):
+                        write_json(out / f"{label}.json", doc)
+                        write_artifact(out / f"{label}.prom", prom_text)
+                        for suffix, extra_doc in (
+                            ("blame", blame_doc),
+                            ("timeline", tl_doc),
+                            ("policies", pol_doc),
+                            ("rollout", roll_doc),
+                            ("lb", lb_doc),
+                            ("ensemble", ens_doc),
+                            ("fleet-blame", fb_doc),
+                            ("search", search_doc),
+                        ):
+                            if extra_doc is not None:
+                                write_json(
+                                    out / f"{label}.{suffix}.json",
+                                    extra_doc,
+                                )
                         if attr_summary is not None:
                             from isotope_tpu.metrics.export import (
                                 write_flamegraph,
@@ -2018,7 +2005,9 @@ def run_experiment(
                                 topo.compiled, attr_summary,
                             )
                         if run_telem is not None:
-                            run_telem.append_jsonl(out / "telemetry.jsonl")
+                            run_telem.append_jsonl(
+                                out / "telemetry.jsonl"
+                            )
                         rec_out = {
                             "label": label,
                             "topology": topo_path,
@@ -2031,29 +2020,33 @@ def run_experiment(
                             rec_out["degraded_to"] = degraded_to
                         ckpt_file.write(json.dumps(rec_out) + "\n")
                         ckpt_file.flush()
-                    run_index += 1
+            run_index += 1
     finally:
         if ckpt_file is not None:
             ckpt_file.close()
 
     ok = [r for r in results if not r.failed]
     if out is not None:
-        with open(out / "results.jsonl", "w") as f:
-            for r in results:
-                f.write(json.dumps(r.flat) + "\n")
-        # the per-service cpu_cores_<svc> columns are record-dependent;
-        # append them so `plot --metrics cpu_cores_<svc>` works off this CSV
-        extra_keys = sorted(
-            {k for r in ok for k in r.flat if k.startswith("cpu_cores_")}
-        )
-        keys = DEFAULT_CSV_KEYS
-        if extra_keys:
-            keys = keys + "," + ",".join(extra_keys)
-        write_csv(
-            keys,
-            [r.flat for r in ok],
-            out / "benchmark.csv",
-        )
+        with telemetry.phase("artifacts.write"):
+            write_artifact(
+                out / "results.jsonl",
+                "".join(json.dumps(r.flat) + "\n" for r in results),
+            )
+            # the per-service cpu_cores_<svc> columns are record-
+            # dependent; append them so `plot --metrics cpu_cores_<svc>`
+            # works off this CSV
+            extra_keys = sorted(
+                {k for r in ok for k in r.flat
+                 if k.startswith("cpu_cores_")}
+            )
+            keys = DEFAULT_CSV_KEYS
+            if extra_keys:
+                keys = keys + "," + ",".join(extra_keys)
+            write_csv(
+                keys,
+                [r.flat for r in ok],
+                out / "benchmark.csv",
+            )
         for exporter in exporters:
             print(exporter(results, out), file=sys.stderr)
     n_failed = len(results) - len(ok)

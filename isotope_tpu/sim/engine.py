@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from functools import partial
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -557,6 +556,13 @@ _call_outcome = levelscan.call_outcome
 _FOLD_MEMBER_KEYS = None
 
 
+def _seg_label(seg) -> str:
+    """A segment's name in detail-mode fences and device scopes."""
+    if isinstance(seg, levelscan.ScanBucket):
+        return f"scan[{seg.plan.d0}-{seg.plan.d1}]"
+    return f"lvl[{seg.d}]"
+
+
 def _fold_member_keys():
     """Cached jitted member-key derivation: fold_in vmapped over the
     fleet's seeds.  Eagerly the vmap re-traces on every fleet build;
@@ -574,6 +580,10 @@ def _fold_member_keys():
 class Simulator:
     """Holds a compiled graph's device constants and jitted entry points."""
 
+    # engine.build covers the whole constructor: device-constant upload,
+    # bucket planning, copula tables — the host-side cost a compile
+    # report should show next to trace/lower/backend seconds
+    @telemetry.phase("engine.build")
     def __init__(
         self,
         compiled: CompiledGraph,
@@ -585,12 +595,8 @@ class Simulator:
         rollouts=None,  # Optional[rollout.RolloutTables]
         lb=None,  # Optional[lb.LbTables]
     ):
-        # engine.build covers everything below: device-constant upload,
-        # bucket planning, copula tables — the host-side cost a compile
-        # report should show next to trace/lower/backend seconds
         telemetry.install_jax_hooks()
         faults.check("engine.build")
-        _t_build = time.perf_counter()
         self.compiled = compiled
         self.params = params
         # auto-mTLS switching: a time-phased extra one-way latency on
@@ -1587,7 +1593,6 @@ class Simulator:
         telemetry.set_meta(
             "census", "pallas" if self._pallas_census else "xla"
         )
-        telemetry.phase_add("engine.build", time.perf_counter() - _t_build)
 
     def _phase_reach_multipliers(self, svc_down_np: np.ndarray) -> np.ndarray:
         """(P, H) static reach multipliers from outage-driven script
@@ -1745,17 +1750,21 @@ class Simulator:
                     e_c, cc, sc = self._center_terms(
                         closed.census_sigma(pi_c), None, hs
                     )
-                    e = float(
-                        pilot(
-                            jax.random.fold_in(key, it),
-                            jnp.float32(c / connections),
-                            jnp.asarray(p0[hs], jnp.float32),
-                            jnp.asarray(coef[:, hs], jnp.float32),
-                            jnp.asarray(e_c, jnp.float32),
-                            jnp.float32(cc),
-                            jnp.asarray(sc, jnp.float32),
+                    # the probe on the device, sync included: the part
+                    # of closed_rate.mva that is not host arithmetic
+                    with telemetry.phase("closed_rate.sat_probe"):
+                        telemetry.counter_inc("closed_rate_pilot_runs")
+                        e = float(
+                            pilot(
+                                jax.random.fold_in(key, it),
+                                jnp.float32(c / connections),
+                                jnp.asarray(p0[hs], jnp.float32),
+                                jnp.asarray(coef[:, hs], jnp.float32),
+                                jnp.asarray(e_c, jnp.float32),
+                                jnp.float32(cc),
+                                jnp.asarray(sc, jnp.float32),
+                            )
                         )
-                    )
                     cs.append(c)
                     es.append(e)
                 b, a = np.polyfit(np.asarray(cs), np.asarray(es), 1)
@@ -1852,7 +1861,10 @@ class Simulator:
                     means.append(res.client_latency.mean())
                 return (means[0] + means[1]) / 2.0
 
-            self._sat_pilot_fns[connections] = jax.jit(fn)
+            self._sat_pilot_fns[connections] = executable_cache.get_or_jit(
+                ("sat_pilot", self.signature, connections, n),
+                "sat_pilot", fn,
+            )
         return self._sat_pilot_fns[connections]
 
     # -- public entry points ----------------------------------------------
@@ -2019,6 +2031,7 @@ class Simulator:
                 "dispatch; use a paced closed loop or open loop"
             )
 
+    @telemetry.phase("closed_rate.solve")
     def solve_closed_rate(
         self,
         load: LoadModel,
@@ -2045,11 +2058,13 @@ class Simulator:
             # (product-form) — no pilot runs needed.  Phased runs
             # time-weight the per-row rates over the chaos windows the
             # run actually spans.
-            thr = self._closed_tables(load.connections)[0]
-            return self._sat_phased_rate(thr, num_requests)
+            with telemetry.phase("closed_rate.mva"):
+                thr = self._closed_tables(load.connections)[0]
+                return self._sat_phased_rate(thr, num_requests)
         cache_key = (load.qps, load.connections, min(num_requests, 2048),
                      fixed_point_iters)
         if cache_key in self._rate_cache:
+            telemetry.counter_inc("closed_rate_memo_hits")
             return self._rate_cache[cache_key]
         cap = 0.999 * self.capacity_qps()
         hi = min(load.qps, cap) if load.qps is not None else cap
@@ -2061,14 +2076,16 @@ class Simulator:
             else jnp.float32(0.0)
         )
 
+        @telemetry.phase("closed_rate.pilot")
         def implied(lam: float, i: int) -> float:
+            telemetry.counter_inc("closed_rate_pilot_runs")
             res = pilot(
                 jax.random.fold_in(key, i), jnp.float32(lam), gap,
                 jnp.float32(lam), jnp.float32(load.connections / lam),
                 visits_pc=self._vis_arg(lam),
                 phase_windows=self._windows_arg(lam, False),
             )
-            mean_lat = float(res.client_latency.mean())
+            mean_lat = float(res.client_latency.mean())  # device sync
             out = load.connections / max(mean_lat, 1e-9)
             return min(out, load.qps) if load.qps is not None else out
 
@@ -2167,20 +2184,28 @@ class Simulator:
         else:
             window = (0.0, np.inf)
         sat = self._saturated(load)
-        fn = self._get_summary(block, num_blocks, load.kind, conns,
-                               collector, trim, sat=sat)
-        faults.check("engine.run")
-        self._check_lb_load(load)
-        telemetry.gauge_set("engine_block_requests", block)
-        telemetry.gauge_set("engine_num_blocks", num_blocks)
-        with self._detail_ctx():
-            return fn(
-                key, jnp.float32(offered), jnp.float32(pace),
-                jnp.float32(offered), jnp.float32(nominal),
-                jnp.float32(window[0]), jnp.float32(window[1]),
-                self._vis_arg(offered),
-                self._windows_arg(offered, sat),
+        # up to the return of the async call (the first call of a
+        # program also traces and compiles in here)
+        with telemetry.phase("summary.dispatch"):
+            fn = self._get_summary(block, num_blocks, load.kind, conns,
+                                   collector, trim, sat=sat)
+            faults.check("engine.run")
+            self._check_lb_load(load)
+            telemetry.gauge_set("engine_block_requests", block)
+            telemetry.gauge_set("engine_num_blocks", num_blocks)
+            telemetry.counter_inc("requests_simulated", num_blocks * block)
+            telemetry.counter_inc(
+                "hop_events_simulated",
+                num_blocks * block * self.compiled.num_hops,
             )
+            with self._detail_ctx():
+                return fn(
+                    key, jnp.float32(offered), jnp.float32(pace),
+                    jnp.float32(offered), jnp.float32(nominal),
+                    jnp.float32(window[0]), jnp.float32(window[1]),
+                    self._vis_arg(offered),
+                    self._windows_arg(offered, sat),
+                )
 
     # -- scenario ensembles (sim/ensemble.py) ---------------------------
 
@@ -2692,12 +2717,9 @@ class Simulator:
             else:
                 fleet = jax.vmap(member)
             self._ensemble_fns[cache_key] = (
-                executable_cache.get_or_build(
+                executable_cache.get_or_jit(
                     ("ensemble", self.signature) + cache_key,
-                    lambda: telemetry.time_first_call(
-                        jax.jit(fleet),
-                        "compile.jit_first_call",
-                    ),
+                    f"ensemble_{kind}", fleet,
                 )
             )
         return self._ensemble_fns[cache_key]
@@ -2735,12 +2757,9 @@ class Simulator:
                 () if jax.default_backend() == "cpu" else (11, 12, 13)
             )
             self._search_fns[cache_key] = (
-                executable_cache.get_or_build(
+                executable_cache.get_or_jit(
                     ("search", self.signature) + cache_key,
-                    lambda: telemetry.time_first_call(
-                        jax.jit(fleet, donate_argnums=donate),
-                        "compile.jit_first_call",
-                    ),
+                    f"search_{kind}", fleet, donate_argnums=donate,
                 )
             )
         return self._search_fns[cache_key]
@@ -3863,11 +3882,9 @@ class Simulator:
                     out = out + (a_out,)
                 return out
 
-            self._summary_fns[cache_key] = executable_cache.get_or_build(
+            self._summary_fns[cache_key] = executable_cache.get_or_jit(
                 (tag, self.signature) + cache_key,
-                lambda: telemetry.time_first_call(
-                    jax.jit(scanfn), "compile.jit_first_call"
-                ),
+                f"{tag}_{kind}", scanfn,
             )
         return self._summary_fns[cache_key]
 
@@ -3908,12 +3925,9 @@ class Simulator:
             else:
                 fleet = jax.vmap(member)
             self._ensemble_fns[cache_key] = (
-                executable_cache.get_or_build(
+                executable_cache.get_or_jit(
                     ("ensemble", self.signature) + cache_key,
-                    lambda: telemetry.time_first_call(
-                        jax.jit(fleet),
-                        "compile.jit_first_call",
-                    ),
+                    f"protected_ensemble_{kind}", fleet,
                 )
             )
         return self._ensemble_fns[cache_key]
@@ -4421,14 +4435,10 @@ class Simulator:
             # traced program would be identical (compiler/cache.py), so
             # a re-instantiated Simulator for the same topology family
             # skips retracing AND recompiling
-            self._fns[key] = executable_cache.get_or_build(
+            self._fns[key] = executable_cache.get_or_jit(
                 ("simulate", self.signature) + key,
-                lambda: telemetry.time_first_call(
-                    jax.jit(
-                        partial(self._simulate, n, kind, connections, sat)
-                    ),
-                    "compile.jit_first_call",
-                ),
+                f"simulate_{kind}",
+                partial(self._simulate, n, kind, connections, sat),
             )
         return self._fns[key]
 
@@ -4628,11 +4638,12 @@ class Simulator:
                         attribution.reduce_stacked(aparts, ex_final),
                     )
 
-            self._summary_fns[cache_key] = executable_cache.get_or_build(
+            self._summary_fns[cache_key] = executable_cache.get_or_jit(
                 ("summary", self.signature) + cache_key,
-                lambda: telemetry.time_first_call(
-                    jax.jit(scanfn), "compile.jit_first_call"
-                ),
+                f"summary_{kind}"
+                + ("_timeline" if timeline is not None
+                   else "_attr" if attr is not None else ""),
+                scanfn,
             )
         return self._summary_fns[cache_key]
 
@@ -4698,6 +4709,7 @@ class Simulator:
         )
         return res
 
+    @jax.named_scope("engine")
     def _simulate_core(
         self,
         n: int,
@@ -5286,91 +5298,92 @@ class Simulator:
                 if shed_coin is None
                 else (shed_coin | panic_coin)
             )
-        if sat_conns:
-            # finite-population law: per-hop quantile polynomial in
-            # v = -log(1 - u') — Horner with per-hop coefficient rows,
-            # zero gathers (coefficients broadcast over the request axis;
-            # phased runs expand the per-row tables with the same
-            # one-hot matmul as the open-loop phase tables).
-            # The wait draws stay in normal space: the sibling copula
-            # (if active) correlates concurrent branches positively, and
-            # the population copula (negative equicorrelation from the
-            # fixed in-flight census, chains only) centers across hops.
-            hi = jax.lax.Precision.HIGHEST
+        with jax.named_scope("waits"):
+            if sat_conns:
+                # finite-population law: per-hop quantile polynomial in
+                # v = -log(1 - u') — Horner with per-hop coefficient rows,
+                # zero gathers (coefficients broadcast over the request axis;
+                # phased runs expand the per-row tables with the same
+                # one-hot matmul as the open-loop phase tables).
+                # The wait draws stay in normal space: the sibling copula
+                # (if active) correlates concurrent branches positively, and
+                # the population copula (negative equicorrelation from the
+                # fixed in-flight census, chains only) centers across hops.
+                hi = jax.lax.Precision.HIGHEST
 
-            def _horner(v, coef_h):
-                w = coef_h[-1]
-                for ci in range(coef_h.shape[0] - 2, -1, -1):
-                    w = w * v + coef_h[ci]
-                return w
+                def _horner(v, coef_h):
+                    w = coef_h[-1]
+                    for ci in range(coef_h.shape[0] - 2, -1, -1):
+                        w = w * v + coef_h[ci]
+                    return w
 
-            if sat_override is not None:
-                # fixed-point pilot: tables AND centering are traced
-                # arguments — the pilot must sample exactly the
-                # composition the final tables deliver (a pilot without
-                # the partial population centering solves a cycle the
-                # delivered mean then misses; measured star9 thr +7%)
-                p0_h, coef_h, e_o, c_o, scale_o = sat_override
-                z = z_wait
-                zproj = (z * e_o).sum(-1, keepdims=True)
-                z = (z - c_o * e_o * zproj) * scale_o
-                eval_poly = partial(_horner, coef_h=coef_h)
-            elif num_phases == 1:
-                (_, p0_R, coef_R, e_R, c_R,
-                 scale_R) = self._closed_tables(sat_conns)
-                p0_h = p0_R[0]
-                c_center = float(c_R[0])
-                z = z_wait
-                if c_center > 0.0:
-                    zproj = (z * e_R[0]).sum(-1, keepdims=True)
-                    z = (z - c_center * e_R[0] * zproj) * scale_R[0]
-                eval_poly = partial(_horner, coef_h=coef_R[0])
-            else:
-                # per-phase tables selected by each request's arrival
-                # phase (``oh`` from the phase-table expansion above);
-                # a chaos fleet member's own stacked rows when traced
-                if chaos_fx is not None and chaos_fx.sat_p0 is not None:
-                    p0_R = chaos_fx.sat_p0
-                    coef_R = chaos_fx.sat_coef
-                    e_R = chaos_fx.sat_e
-                    c_col = chaos_fx.sat_c[:, None]
-                    scale_R = chaos_fx.sat_scale
-                else:
+                if sat_override is not None:
+                    # fixed-point pilot: tables AND centering are traced
+                    # arguments — the pilot must sample exactly the
+                    # composition the final tables deliver (a pilot without
+                    # the partial population centering solves a cycle the
+                    # delivered mean then misses; measured star9 thr +7%)
+                    p0_h, coef_h, e_o, c_o, scale_o = sat_override
+                    z = z_wait
+                    zproj = (z * e_o).sum(-1, keepdims=True)
+                    z = (z - c_o * e_o * zproj) * scale_o
+                    eval_poly = partial(_horner, coef_h=coef_h)
+                elif num_phases == 1:
                     (_, p0_R, coef_R, e_R, c_R,
                      scale_R) = self._closed_tables(sat_conns)
-                    c_col = jnp.asarray(c_R)[:, None]
-                p0_h = jnp.matmul(oh, p0_R, precision=hi)
-                e_n = jnp.matmul(oh, e_R, precision=hi)
-                c_n = jnp.matmul(oh, c_col, precision=hi)
-                scale_n = jnp.matmul(oh, scale_R, precision=hi)
-                z = z_wait
-                zproj = (z * e_n).sum(-1, keepdims=True)
-                z = (z - c_n * e_n * zproj) * scale_n
+                    p0_h = p0_R[0]
+                    c_center = float(c_R[0])
+                    z = z_wait
+                    if c_center > 0.0:
+                        zproj = (z * e_R[0]).sum(-1, keepdims=True)
+                        z = (z - c_center * e_R[0] * zproj) * scale_R[0]
+                    eval_poly = partial(_horner, coef_h=coef_R[0])
+                else:
+                    # per-phase tables selected by each request's arrival
+                    # phase (``oh`` from the phase-table expansion above);
+                    # a chaos fleet member's own stacked rows when traced
+                    if chaos_fx is not None and chaos_fx.sat_p0 is not None:
+                        p0_R = chaos_fx.sat_p0
+                        coef_R = chaos_fx.sat_coef
+                        e_R = chaos_fx.sat_e
+                        c_col = chaos_fx.sat_c[:, None]
+                        scale_R = chaos_fx.sat_scale
+                    else:
+                        (_, p0_R, coef_R, e_R, c_R,
+                         scale_R) = self._closed_tables(sat_conns)
+                        c_col = jnp.asarray(c_R)[:, None]
+                    p0_h = jnp.matmul(oh, p0_R, precision=hi)
+                    e_n = jnp.matmul(oh, e_R, precision=hi)
+                    c_n = jnp.matmul(oh, c_col, precision=hi)
+                    scale_n = jnp.matmul(oh, scale_R, precision=hi)
+                    z = z_wait
+                    zproj = (z * e_n).sum(-1, keepdims=True)
+                    z = (z - c_n * e_n * zproj) * scale_n
 
-                def eval_poly(v, coef_R=coef_R):
-                    deg = coef_R.shape[1]
-                    w = jnp.matmul(
-                        oh, coef_R[:, deg - 1, :], precision=hi
-                    )
-                    for ci in range(deg - 2, -1, -1):
-                        w = w * v + jnp.matmul(
-                            oh, coef_R[:, ci, :], precision=hi
+                    def eval_poly(v, coef_R=coef_R):
+                        deg = coef_R.shape[1]
+                        w = jnp.matmul(
+                            oh, coef_R[:, deg - 1, :], precision=hi
                         )
-                    return w
-            u_sat = jax.scipy.special.ndtr(z)
-            u_c = jnp.clip(
-                (u_sat - p0_h) / jnp.maximum(1.0 - p0_h, 1e-9),
-                0.0,
-                1.0 - 1e-7,
-            )
-            v = -jnp.log1p(-u_c)
-            wait = jnp.where(
-                u_sat < p0_h, 0.0, jnp.maximum(eval_poly(v), 0.0)
-            )
-        else:
-            wait = queueing.sample_wait_conditional(
-                p_wait_nh, wait_rate_nh, u_wait
-            )  # (N, H)
+                        for ci in range(deg - 2, -1, -1):
+                            w = w * v + jnp.matmul(
+                                oh, coef_R[:, ci, :], precision=hi
+                            )
+                        return w
+                u_sat = jax.scipy.special.ndtr(z)
+                u_c = jnp.clip(
+                    (u_sat - p0_h) / jnp.maximum(1.0 - p0_h, 1e-9),
+                    0.0,
+                    1.0 - 1e-7,
+                )
+                v = -jnp.log1p(-u_c)
+                wait = jnp.where(
+                    u_sat < p0_h, 0.0, jnp.maximum(eval_poly(v), 0.0)
+                )
+            else:
+                wait = queueing.sample_wait_conditional(
+                    p_wait_nh, wait_rate_nh, u_wait
+                )  # (N, H)
         if shed_coin is not None:
             # a shed request fast-fails at admission: it takes the
             # error path below, NOT the queue (Envoy overflow 503s
@@ -5480,428 +5493,430 @@ class Simulator:
                 seg = self._segments[_idx]
                 B = seg.plan.bound_hops
                 d0, d1 = seg.plan.d0, seg.plan.d1
-                lat_init = levelscan.pad_cols(lat_lvls[d1 + 1], B)
-                err_init = None
-                if self._track_err:
-                    ce = err_lvls[d1 + 1]
-                    err_init = (
-                        levelscan.pad_cols(ce, B)
-                        if ce is not None
-                        else jnp.zeros((n, B), bool)
-                    )
-                ys = levelscan.up_sweep(ctx, seg, lat_init, err_init)
-                bucket_ys[_idx] = ys
-                s0 = seg.sizes[0]
-                lat_lvls[d0] = ys["lat"][0][:, :s0]
-                if self._track_err:
-                    err_lvls[d0] = ys["err"][0][:, :s0]
+                with jax.named_scope(f"up/{_seg_label(seg)}"):
+                    lat_init = levelscan.pad_cols(lat_lvls[d1 + 1], B)
+                    err_init = None
+                    if self._track_err:
+                        ce = err_lvls[d1 + 1]
+                        err_init = (
+                            levelscan.pad_cols(ce, B)
+                            if ce is not None
+                            else jnp.zeros((n, B), bool)
+                        )
+                    ys = levelscan.up_sweep(ctx, seg, lat_init, err_init)
+                    bucket_ys[_idx] = ys
+                    s0 = seg.sizes[0]
+                    lat_lvls[d0] = ys["lat"][0][:, :s0]
+                    if self._track_err:
+                        err_lvls[d0] = ys["err"][0][:, :s0]
                 if nan_seg == _si:
                     lat_lvls[d0] = lat_lvls[d0].at[:, 0].set(jnp.nan)
                 telemetry.segment_fence(
-                    f"up.scan[{d0}-{d1}]", lat_lvls[d0]
+                    f"up.{_seg_label(seg)}", lat_lvls[d0]
                 )
                 continue
             d = _idx
-            lvl = self._levels[d]
-            sl = slice(lvl.offset, lvl.offset + lvl.size)
-            P = lvl.pmax
-            fail_step = None
-            dense_excl = None  # census-kernel exclusive step prefix
-            if lvl.num_children > 0:
-                nxt = self._levels[d + 1]
-                csl = slice(nxt.offset, nxt.offset + nxt.size)
-                C = lvl.num_children
-                child_err = err_lvls[d + 1]
-                if lvl.ident_attempts:
-                    # single attempt, call k <-> child k: the whole attempt
-                    # loop reduces to elementwise ops — no scatters
-                    tt = lvl.child_rtt + lat_lvls[d + 1]  # (N, C)
-                    if tax is not None:
-                        tt = tt + 2.0 * tax[:, None]
-                    down_child = down[:, csl] if down is not None else None
-                    transport_a, dur_a = _call_outcome(
-                        tt,
-                        lvl.call_timeout if lvl.finite_timeout else None,
-                        down_child,
-                    )
-                    if self._need_send:
-                        prob = lvl.child_send_prob
-                        if self._churn:
-                            prob = prob * churn_w[:, lvl.child_churn_entry]
-                        coin = u_send[:, csl] < prob  # (N, C)
-                        used_lvls[d] = coin
-                        dur_call = jnp.where(coin, dur_a, 0.0)
-                        # an unsent call cannot fail anything
-                        final_transport = (
-                            coin & transport_a
-                            if transport_a is not None
-                            else None
-                        )
-                    else:
-                        dur_call = dur_a
-                        final_transport = transport_a
-                    att_off = None
-                else:
-                    # general path: serial retry attempts.  dummy column C
-                    # absorbs invalid attempt slots
-                    pad = lambda x: jnp.pad(x, ((0, 0), (0, 1)))  # noqa: E731
-                    lat_child = pad(lat_lvls[d + 1])
-                    err_child = (
-                        pad(child_err.astype(jnp.float32)) > 0
-                        if child_err is not None
-                        else None
-                    )
-                    down_child = (
-                        pad(down[:, csl].astype(jnp.float32)) > 0
-                        if down is not None
-                        else None
-                    )
-                    rtt_child = jnp.pad(lvl.child_rtt, (0, 1))
-
-                    a0 = lvl.att_child[0]  # (K,) attempt-0 local child idx
-                    if self._need_send:
-                        prob = lvl.child_send_prob[a0]
-                        if self._churn:
-                            # current schedule weight scales the send prob
-                            prob = prob * churn_w[
-                                :, lvl.child_churn_entry[a0]
-                            ]
-                        coin = u_send[:, csl][:, a0] < prob  # (N, K)
-                    else:
-                        coin = jnp.ones((n, lvl.num_calls), bool)
-                    transportable = (
-                        down_child is not None or lvl.finite_timeout
-                    )
-                    # retry-budget gate (sim/policies.py): attempt >= 1
-                    # runs only when its budget coin admits it — a
-                    # suppressed retry surfaces the PREVIOUS attempt's
-                    # failure to the caller (Envoy budget semantics)
-                    retry_gate = None
-                    if retry_coin is not None and lvl.max_attempts > 1:
-                        retry_gate = (
-                            pad(retry_coin[:, csl].astype(jnp.float32))
-                            > 0
-                        )  # (N, C + 1); pad col False is dead (invalid)
-                    dur_call = jnp.zeros((n, lvl.num_calls))
-                    final_transport = (
-                        jnp.zeros((n, lvl.num_calls), bool)
-                        if transportable
-                        else None
-                    )
-                    used = jnp.zeros((n, C + 1), bool)
-                    att_off = jnp.zeros((n, C + 1))
-                    used_a = coin
-                    for a in range(lvl.max_attempts):
-                        idx = lvl.att_child[a]       # (K,) in [0, C]
-                        valid = lvl.att_valid[a]     # (K,) static
-                        use = used_a & valid
-                        if retry_gate is not None and a > 0:
-                            use = use & retry_gate[:, idx]
-                        t = rtt_child[idx] + lat_child[:, idx]
+            with jax.named_scope(f"up/lvl[{d}]"):
+                lvl = self._levels[d]
+                sl = slice(lvl.offset, lvl.offset + lvl.size)
+                P = lvl.pmax
+                fail_step = None
+                dense_excl = None  # census-kernel exclusive step prefix
+                if lvl.num_children > 0:
+                    nxt = self._levels[d + 1]
+                    csl = slice(nxt.offset, nxt.offset + nxt.size)
+                    C = lvl.num_children
+                    child_err = err_lvls[d + 1]
+                    if lvl.ident_attempts:
+                        # single attempt, call k <-> child k: the whole attempt
+                        # loop reduces to elementwise ops — no scatters
+                        tt = lvl.child_rtt + lat_lvls[d + 1]  # (N, C)
                         if tax is not None:
-                            t = t + 2.0 * tax[:, None]
+                            tt = tt + 2.0 * tax[:, None]
+                        down_child = down[:, csl] if down is not None else None
                         transport_a, dur_a = _call_outcome(
-                            t,
+                            tt,
                             lvl.call_timeout if lvl.finite_timeout else None,
-                            down_child[:, idx]
-                            if down_child is not None
-                            else None,
+                            down_child,
                         )
-                        failed_a = transport_a
-                        if err_child is not None:
-                            ec = err_child[:, idx]
-                            failed_a = (
-                                ec if failed_a is None else failed_a | ec
-                            )
-                        att_off = att_off.at[:, idx].set(
-                            jnp.where(use, dur_call, 0.0)
-                        )
-                        used = used.at[:, idx].set(use)
-                        dur_call = dur_call + jnp.where(use, dur_a, 0.0)
-                        if final_transport is not None:
-                            final_transport = jnp.where(
-                                use, transport_a, final_transport
-                            )
-                        used_a = (
-                            use & failed_a
-                            if failed_a is not None
-                            else jnp.zeros_like(use)
-                        )
-                    used_lvls[d] = used[:, :C]
-
-                # -- aggregate calls into (parent, step) slots -------------
-                if lvl.sparse is not None:
-                    # sparse call-slot path (skewed wide level): per-hop
-                    # busy times are packed segment sums, pure-sleep
-                    # steps are static (_sparse_level_sweep — shared
-                    # with the tiled encoding's residual part).
-                    busy, fail_step, off = _sparse_level_sweep(
-                        lvl.sparse, n, P, lvl.size, dur_call,
-                        final_transport,
-                        (
-                            err_coin[:, sl]
-                            if err_coin is not None
-                            else None
-                        ),
-                        lvl.child_parent_local,
-                        lvl.child_step,
-                    )
-                    if att_off is not None:
-                        off = off + used_lvls[d] * att_off[:, :C]
-                    off_lvls[d] = off
-                    step_dur = None
-                elif lvl.tiled is not None:
-                    # dense-blocked tiles + sparse residual (see
-                    # _TiledSteps): every tile runs the dense step-grid
-                    # ops restricted to its rows — bit-identical to the
-                    # full dense grid on those hops — and the residual
-                    # keeps the sparse call-slot sweep; per-part
-                    # busy/fail/off re-assemble into level order by the
-                    # static inverse gathers.
-                    tl = lvl.tiled
-                    err_lvl = (
-                        err_coin[:, sl] if err_coin is not None else None
-                    )
-                    transportable = final_transport is not None
-                    busy_parts: List[jax.Array] = []
-                    fail_parts: List[jax.Array] = []
-                    off_parts: List[jax.Array] = []
-                    for tile in tl.tiles:
-                        T, W = len(tile.hops), tile.width
-                        need_off = tile.child_sel.size > 0
-                        if tile.call_sel.size:
-                            dc = dur_call[:, tile.call_sel]
-                            if tile.uniform_calls is not None:
-                                agg = dc.reshape(
-                                    n, T, W, tile.uniform_calls
-                                ).max(-1)
-                            else:
-                                agg = (
-                                    jnp.zeros((n, T * W))
-                                    .at[:, tile.call_seg]
-                                    .max(dc)
-                                    .reshape(n, T, W)
-                                )
-                        else:
-                            agg = None
-                        fail_t = None
-                        if transportable:
-                            if tile.call_sel.size:
-                                ft = final_transport[:, tile.call_sel]
-                                fail_contrib = jnp.where(
-                                    ft, tile.call_step, P
-                                ).astype(jnp.int32)
-                                if tile.uniform_calls is not None:
-                                    fail_t = fail_contrib.reshape(
-                                        n, T, W * tile.uniform_calls
-                                    ).min(-1)
-                                else:
-                                    fail_t = (
-                                        jnp.full((n, T), P, jnp.int32)
-                                        .at[:, tile.call_pos]
-                                        .min(fail_contrib)
-                                    )
-                            else:
-                                # call-free rows cannot transport-fail
-                                fail_t = jnp.full((n, T), P, jnp.int32)
-                        prefix = None
-                        if agg is None:
-                            # the dense grid's agg is all-zero here
-                            busy_t = jnp.broadcast_to(
-                                (
-                                    jnp.maximum(tile.step_base, 0.0)
-                                    * tile.step_mask
-                                ).sum(-1),
-                                (n, T),
-                            )
-                        elif (
-                            self._census_mod is not None
-                            and self._census_mod.supported(T, W)
-                        ):
-                            busy_t, excl = self._census_mod.census(
-                                tile.step_base, tile.step_mask, agg,
-                                fail_t, None,
-                            )
-                            prefix = excl if need_off else None
-                        else:
-                            step_dur_t = (
-                                jnp.maximum(tile.step_base, agg)
-                                * tile.step_mask
-                            )
-                            if fail_t is not None:
-                                step_dur_t = step_dur_t * (
-                                    jnp.arange(W, dtype=jnp.int32)
-                                    <= fail_t[:, :, None]
-                                )
-                            busy_t = step_dur_t.sum(-1)
-                            if need_off:
-                                prefix = (
-                                    jnp.cumsum(step_dur_t, axis=-1)
-                                    - step_dur_t
-                                )
-                        busy_parts.append(busy_t)
-                        if transportable:
-                            fail_parts.append(fail_t)
-                        if need_off:
-                            off_t = prefix.reshape(n, -1)[
-                                :, tile.child_pos * W + tile.child_step
-                            ]
-                            if err_lvl is not None:
-                                # dense zeroes the grid before the
-                                # prefix for a 500ing parent — match
-                                off_t = off_t * ~err_lvl[
-                                    :, tile.hops
-                                ][:, tile.child_pos]
-                            off_parts.append(off_t)
-                    if tl.residual is not None:
-                        busy_r, fail_r, off_r = _sparse_level_sweep(
-                            tl.residual, n, P, len(tl.res_hops),
-                            dur_call[:, tl.res_call_sel],
-                            (
-                                final_transport[:, tl.res_call_sel]
-                                if transportable
+                        if self._need_send:
+                            prob = lvl.child_send_prob
+                            if self._churn:
+                                prob = prob * churn_w[:, lvl.child_churn_entry]
+                            coin = u_send[:, csl] < prob  # (N, C)
+                            used_lvls[d] = coin
+                            dur_call = jnp.where(coin, dur_a, 0.0)
+                            # an unsent call cannot fail anything
+                            final_transport = (
+                                coin & transport_a
+                                if transport_a is not None
                                 else None
-                            ),
-                            (
-                                err_lvl[:, tl.res_hops]
-                                if err_lvl is not None
-                                else None
-                            ),
-                            tl.res_child_pos,
-                            tl.res_child_step,
-                        )
-                        busy_parts.append(busy_r)
-                        if transportable:
-                            # a call-free residual cannot fail: carry
-                            # the sentinel so the assembly stays dense
-                            fail_parts.append(
-                                fail_r
-                                if fail_r is not None
-                                else jnp.full(
-                                    (n, len(tl.res_hops)), P, jnp.int32
-                                )
                             )
-                        if tl.res_child_sel.size:
-                            off_parts.append(off_r)
-                    busy = jnp.concatenate(busy_parts, axis=1)[
-                        :, tl.hop_inv
-                    ]
-                    fail_step = (
-                        jnp.concatenate(fail_parts, axis=1)[
-                            :, tl.hop_inv
-                        ]
-                        if transportable
-                        else None
-                    )
-                    off = jnp.concatenate(off_parts, axis=1)[
-                        :, tl.child_inv
-                    ]
-                    if att_off is not None:
-                        off = off + used_lvls[d] * att_off[:, :C]
-                    off_lvls[d] = off
-                    step_dur = None
-                else:
-                    if lvl.uniform_calls is not None:
-                        # call_seg == repeat(arange(size*P), c):
-                        # reshape-reduce
-                        agg = dur_call.reshape(
-                            n, lvl.size, P, lvl.uniform_calls
-                        ).max(-1)
+                        else:
+                            dur_call = dur_a
+                            final_transport = transport_a
+                        att_off = None
                     else:
-                        agg = (
-                            jnp.zeros((n, lvl.size * P))
-                            .at[:, lvl.call_seg]
-                            .max(dur_call)
-                            .reshape(n, lvl.size, P)
+                        # general path: serial retry attempts.  dummy column C
+                        # absorbs invalid attempt slots
+                        pad = lambda x: jnp.pad(x, ((0, 0), (0, 1)))  # noqa: E731
+                        lat_child = pad(lat_lvls[d + 1])
+                        err_child = (
+                            pad(child_err.astype(jnp.float32)) > 0
+                            if child_err is not None
+                            else None
                         )
-                    if final_transport is not None:
-                        fail_contrib = jnp.where(
-                            final_transport, lvl.call_step, P
-                        ).astype(jnp.int32)
-                        if lvl.uniform_calls is not None:
-                            fail_step = fail_contrib.reshape(
-                                n, lvl.size, P * lvl.uniform_calls
-                            ).min(-1)
+                        down_child = (
+                            pad(down[:, csl].astype(jnp.float32)) > 0
+                            if down is not None
+                            else None
+                        )
+                        rtt_child = jnp.pad(lvl.child_rtt, (0, 1))
+
+                        a0 = lvl.att_child[0]  # (K,) attempt-0 local child idx
+                        if self._need_send:
+                            prob = lvl.child_send_prob[a0]
+                            if self._churn:
+                                # current schedule weight scales the send prob
+                                prob = prob * churn_w[
+                                    :, lvl.child_churn_entry[a0]
+                                ]
+                            coin = u_send[:, csl][:, a0] < prob  # (N, K)
                         else:
-                            fail_step = (
-                                jnp.full((n, lvl.size), P, jnp.int32)
-                                .at[:, lvl.call_seg // P]
-                                .min(fail_contrib)
+                            coin = jnp.ones((n, lvl.num_calls), bool)
+                        transportable = (
+                            down_child is not None or lvl.finite_timeout
+                        )
+                        # retry-budget gate (sim/policies.py): attempt >= 1
+                        # runs only when its budget coin admits it — a
+                        # suppressed retry surfaces the PREVIOUS attempt's
+                        # failure to the caller (Envoy budget semantics)
+                        retry_gate = None
+                        if retry_coin is not None and lvl.max_attempts > 1:
+                            retry_gate = (
+                                pad(retry_coin[:, csl].astype(jnp.float32))
+                                > 0
+                            )  # (N, C + 1); pad col False is dead (invalid)
+                        dur_call = jnp.zeros((n, lvl.num_calls))
+                        final_transport = (
+                            jnp.zeros((n, lvl.num_calls), bool)
+                            if transportable
+                            else None
+                        )
+                        used = jnp.zeros((n, C + 1), bool)
+                        att_off = jnp.zeros((n, C + 1))
+                        used_a = coin
+                        for a in range(lvl.max_attempts):
+                            idx = lvl.att_child[a]       # (K,) in [0, C]
+                            valid = lvl.att_valid[a]     # (K,) static
+                            use = used_a & valid
+                            if retry_gate is not None and a > 0:
+                                use = use & retry_gate[:, idx]
+                            t = rtt_child[idx] + lat_child[:, idx]
+                            if tax is not None:
+                                t = t + 2.0 * tax[:, None]
+                            transport_a, dur_a = _call_outcome(
+                                t,
+                                lvl.call_timeout if lvl.finite_timeout else None,
+                                down_child[:, idx]
+                                if down_child is not None
+                                else None,
                             )
-                    if (
-                        self._census_mod is not None
-                        and self._census_mod.supported(lvl.size, P)
-                    ):
-                        # fused census kernel (native/census_pallas.py):
-                        # max + mask + fail/err truncation + row-sum +
-                        # exclusive prefix in one pass; the masked
-                        # (N, size, P) step grid never round-trips HBM
-                        busy, dense_excl = self._census_mod.census(
-                            lvl.step_base, lvl.step_mask, agg,
-                            fail_step,
+                            failed_a = transport_a
+                            if err_child is not None:
+                                ec = err_child[:, idx]
+                                failed_a = (
+                                    ec if failed_a is None else failed_a | ec
+                                )
+                            att_off = att_off.at[:, idx].set(
+                                jnp.where(use, dur_call, 0.0)
+                            )
+                            used = used.at[:, idx].set(use)
+                            dur_call = dur_call + jnp.where(use, dur_a, 0.0)
+                            if final_transport is not None:
+                                final_transport = jnp.where(
+                                    use, transport_a, final_transport
+                                )
+                            used_a = (
+                                use & failed_a
+                                if failed_a is not None
+                                else jnp.zeros_like(use)
+                            )
+                        used_lvls[d] = used[:, :C]
+
+                    # -- aggregate calls into (parent, step) slots -------------
+                    if lvl.sparse is not None:
+                        # sparse call-slot path (skewed wide level): per-hop
+                        # busy times are packed segment sums, pure-sleep
+                        # steps are static (_sparse_level_sweep — shared
+                        # with the tiled encoding's residual part).
+                        busy, fail_step, off = _sparse_level_sweep(
+                            lvl.sparse, n, P, lvl.size, dur_call,
+                            final_transport,
                             (
                                 err_coin[:, sl]
                                 if err_coin is not None
                                 else None
                             ),
+                            lvl.child_parent_local,
+                            lvl.child_step,
                         )
+                        if att_off is not None:
+                            off = off + used_lvls[d] * att_off[:, :C]
+                        off_lvls[d] = off
+                        step_dur = None
+                    elif lvl.tiled is not None:
+                        # dense-blocked tiles + sparse residual (see
+                        # _TiledSteps): every tile runs the dense step-grid
+                        # ops restricted to its rows — bit-identical to the
+                        # full dense grid on those hops — and the residual
+                        # keeps the sparse call-slot sweep; per-part
+                        # busy/fail/off re-assemble into level order by the
+                        # static inverse gathers.
+                        tl = lvl.tiled
+                        err_lvl = (
+                            err_coin[:, sl] if err_coin is not None else None
+                        )
+                        transportable = final_transport is not None
+                        busy_parts: List[jax.Array] = []
+                        fail_parts: List[jax.Array] = []
+                        off_parts: List[jax.Array] = []
+                        for tile in tl.tiles:
+                            T, W = len(tile.hops), tile.width
+                            need_off = tile.child_sel.size > 0
+                            if tile.call_sel.size:
+                                dc = dur_call[:, tile.call_sel]
+                                if tile.uniform_calls is not None:
+                                    agg = dc.reshape(
+                                        n, T, W, tile.uniform_calls
+                                    ).max(-1)
+                                else:
+                                    agg = (
+                                        jnp.zeros((n, T * W))
+                                        .at[:, tile.call_seg]
+                                        .max(dc)
+                                        .reshape(n, T, W)
+                                    )
+                            else:
+                                agg = None
+                            fail_t = None
+                            if transportable:
+                                if tile.call_sel.size:
+                                    ft = final_transport[:, tile.call_sel]
+                                    fail_contrib = jnp.where(
+                                        ft, tile.call_step, P
+                                    ).astype(jnp.int32)
+                                    if tile.uniform_calls is not None:
+                                        fail_t = fail_contrib.reshape(
+                                            n, T, W * tile.uniform_calls
+                                        ).min(-1)
+                                    else:
+                                        fail_t = (
+                                            jnp.full((n, T), P, jnp.int32)
+                                            .at[:, tile.call_pos]
+                                            .min(fail_contrib)
+                                        )
+                                else:
+                                    # call-free rows cannot transport-fail
+                                    fail_t = jnp.full((n, T), P, jnp.int32)
+                            prefix = None
+                            if agg is None:
+                                # the dense grid's agg is all-zero here
+                                busy_t = jnp.broadcast_to(
+                                    (
+                                        jnp.maximum(tile.step_base, 0.0)
+                                        * tile.step_mask
+                                    ).sum(-1),
+                                    (n, T),
+                                )
+                            elif (
+                                self._census_mod is not None
+                                and self._census_mod.supported(T, W)
+                            ):
+                                busy_t, excl = self._census_mod.census(
+                                    tile.step_base, tile.step_mask, agg,
+                                    fail_t, None,
+                                )
+                                prefix = excl if need_off else None
+                            else:
+                                step_dur_t = (
+                                    jnp.maximum(tile.step_base, agg)
+                                    * tile.step_mask
+                                )
+                                if fail_t is not None:
+                                    step_dur_t = step_dur_t * (
+                                        jnp.arange(W, dtype=jnp.int32)
+                                        <= fail_t[:, :, None]
+                                    )
+                                busy_t = step_dur_t.sum(-1)
+                                if need_off:
+                                    prefix = (
+                                        jnp.cumsum(step_dur_t, axis=-1)
+                                        - step_dur_t
+                                    )
+                            busy_parts.append(busy_t)
+                            if transportable:
+                                fail_parts.append(fail_t)
+                            if need_off:
+                                off_t = prefix.reshape(n, -1)[
+                                    :, tile.child_pos * W + tile.child_step
+                                ]
+                                if err_lvl is not None:
+                                    # dense zeroes the grid before the
+                                    # prefix for a 500ing parent — match
+                                    off_t = off_t * ~err_lvl[
+                                        :, tile.hops
+                                    ][:, tile.child_pos]
+                                off_parts.append(off_t)
+                        if tl.residual is not None:
+                            busy_r, fail_r, off_r = _sparse_level_sweep(
+                                tl.residual, n, P, len(tl.res_hops),
+                                dur_call[:, tl.res_call_sel],
+                                (
+                                    final_transport[:, tl.res_call_sel]
+                                    if transportable
+                                    else None
+                                ),
+                                (
+                                    err_lvl[:, tl.res_hops]
+                                    if err_lvl is not None
+                                    else None
+                                ),
+                                tl.res_child_pos,
+                                tl.res_child_step,
+                            )
+                            busy_parts.append(busy_r)
+                            if transportable:
+                                # a call-free residual cannot fail: carry
+                                # the sentinel so the assembly stays dense
+                                fail_parts.append(
+                                    fail_r
+                                    if fail_r is not None
+                                    else jnp.full(
+                                        (n, len(tl.res_hops)), P, jnp.int32
+                                    )
+                                )
+                            if tl.res_child_sel.size:
+                                off_parts.append(off_r)
+                        busy = jnp.concatenate(busy_parts, axis=1)[
+                            :, tl.hop_inv
+                        ]
+                        fail_step = (
+                            jnp.concatenate(fail_parts, axis=1)[
+                                :, tl.hop_inv
+                            ]
+                            if transportable
+                            else None
+                        )
+                        off = jnp.concatenate(off_parts, axis=1)[
+                            :, tl.child_inv
+                        ]
+                        if att_off is not None:
+                            off = off + used_lvls[d] * att_off[:, :C]
+                        off_lvls[d] = off
                         step_dur = None
                     else:
-                        step_dur = (
-                            jnp.maximum(lvl.step_base, agg)
-                            * lvl.step_mask
+                        if lvl.uniform_calls is not None:
+                            # call_seg == repeat(arange(size*P), c):
+                            # reshape-reduce
+                            agg = dur_call.reshape(
+                                n, lvl.size, P, lvl.uniform_calls
+                            ).max(-1)
+                        else:
+                            agg = (
+                                jnp.zeros((n, lvl.size * P))
+                                .at[:, lvl.call_seg]
+                                .max(dur_call)
+                                .reshape(n, lvl.size, P)
+                            )
+                        if final_transport is not None:
+                            fail_contrib = jnp.where(
+                                final_transport, lvl.call_step, P
+                            ).astype(jnp.int32)
+                            if lvl.uniform_calls is not None:
+                                fail_step = fail_contrib.reshape(
+                                    n, lvl.size, P * lvl.uniform_calls
+                                ).min(-1)
+                            else:
+                                fail_step = (
+                                    jnp.full((n, lvl.size), P, jnp.int32)
+                                    .at[:, lvl.call_seg // P]
+                                    .min(fail_contrib)
+                                )
+                        if (
+                            self._census_mod is not None
+                            and self._census_mod.supported(lvl.size, P)
+                        ):
+                            # fused census kernel (native/census_pallas.py):
+                            # max + mask + fail/err truncation + row-sum +
+                            # exclusive prefix in one pass; the masked
+                            # (N, size, P) step grid never round-trips HBM
+                            busy, dense_excl = self._census_mod.census(
+                                lvl.step_base, lvl.step_mask, agg,
+                                fail_step,
+                                (
+                                    err_coin[:, sl]
+                                    if err_coin is not None
+                                    else None
+                                ),
+                            )
+                            step_dur = None
+                        else:
+                            step_dur = (
+                                jnp.maximum(lvl.step_base, agg)
+                                * lvl.step_mask
+                            )
+                else:
+                    # call-free level: busy time is fully static
+                    busy = jnp.broadcast_to(lvl.leaf_busy, (n, lvl.size))
+                    step_dur = None
+                fail_lvls[d] = fail_step
+                if step_dur is not None:
+                    # executed-step mask: errorRate 500s skip the whole
+                    # script; transport errors truncate after the failing
+                    # step
+                    if fail_step is not None:
+                        executed = (
+                            jnp.arange(P, dtype=jnp.int32)
+                            <= fail_step[:, :, None]
                         )
-            else:
-                # call-free level: busy time is fully static
-                busy = jnp.broadcast_to(lvl.leaf_busy, (n, lvl.size))
-                step_dur = None
-            fail_lvls[d] = fail_step
-            if step_dur is not None:
-                # executed-step mask: errorRate 500s skip the whole
-                # script; transport errors truncate after the failing
-                # step
-                if fail_step is not None:
-                    executed = (
-                        jnp.arange(P, dtype=jnp.int32)
-                        <= fail_step[:, :, None]
-                    )
-                    if err_coin is not None:
-                        executed = executed & ~err_coin[:, sl][:, :, None]
-                    step_dur = step_dur * executed
+                        if err_coin is not None:
+                            executed = executed & ~err_coin[:, sl][:, :, None]
+                        step_dur = step_dur * executed
+                    elif err_coin is not None:
+                        step_dur = step_dur * ~err_coin[:, sl][:, :, None]
+                    busy = step_dur.sum(-1)
                 elif err_coin is not None:
-                    step_dur = step_dur * ~err_coin[:, sl][:, :, None]
-                busy = step_dur.sum(-1)
-            elif err_coin is not None:
-                # errorRate 500 skips the whole script
-                busy = busy * ~err_coin[:, sl]
-            lat_lvls[d] = wait[:, sl] + svc_time[:, sl] + busy
-            # this hop's own response status: 500 iff errorRate coin or a
-            # transport-failed step
-            if err_coin is not None and fail_step is not None:
-                err_lvls[d] = err_coin[:, sl] | (fail_step < P)
-            elif err_coin is not None:
-                err_lvls[d] = err_coin[:, sl]
-            elif fail_step is not None:
-                err_lvls[d] = fail_step < P
-            if lvl.num_children > 0 and step_dur is not None:
-                prefix = jnp.cumsum(step_dur, axis=-1) - step_dur
-                off = prefix.reshape(n, -1)[:, lvl.child_seg]
-                if att_off is not None:
-                    off = off + (
-                        used_lvls[d] * att_off[:, : lvl.num_children]
-                    )
-                off_lvls[d] = off
-            elif lvl.num_children > 0 and dense_excl is not None:
-                # census-kernel path: the fused prefix already carries
-                # the fail/err truncation the masked grid would
-                off = dense_excl.reshape(n, -1)[:, lvl.child_seg]
-                if att_off is not None:
-                    off = off + (
-                        used_lvls[d] * att_off[:, : lvl.num_children]
-                    )
-                off_lvls[d] = off
-            if nan_seg == _si:
-                lat_lvls[d] = lat_lvls[d].at[:, 0].set(jnp.nan)
+                    # errorRate 500 skips the whole script
+                    busy = busy * ~err_coin[:, sl]
+                lat_lvls[d] = wait[:, sl] + svc_time[:, sl] + busy
+                # this hop's own response status: 500 iff errorRate coin or a
+                # transport-failed step
+                if err_coin is not None and fail_step is not None:
+                    err_lvls[d] = err_coin[:, sl] | (fail_step < P)
+                elif err_coin is not None:
+                    err_lvls[d] = err_coin[:, sl]
+                elif fail_step is not None:
+                    err_lvls[d] = fail_step < P
+                if lvl.num_children > 0 and step_dur is not None:
+                    prefix = jnp.cumsum(step_dur, axis=-1) - step_dur
+                    off = prefix.reshape(n, -1)[:, lvl.child_seg]
+                    if att_off is not None:
+                        off = off + (
+                            used_lvls[d] * att_off[:, : lvl.num_children]
+                        )
+                    off_lvls[d] = off
+                elif lvl.num_children > 0 and dense_excl is not None:
+                    # census-kernel path: the fused prefix already carries
+                    # the fail/err truncation the masked grid would
+                    off = dense_excl.reshape(n, -1)[:, lvl.child_seg]
+                    if att_off is not None:
+                        off = off + (
+                            used_lvls[d] * att_off[:, : lvl.num_children]
+                        )
+                    off_lvls[d] = off
+                if nan_seg == _si:
+                    lat_lvls[d] = lat_lvls[d].at[:, 0].set(jnp.nan)
             telemetry.segment_fence(f"up.lvl[{d}]", lat_lvls[d])
 
         # ---- downward pass: which hops actually execute ------------------
@@ -5922,57 +5937,58 @@ class Simulator:
         sent_chunks: List[jax.Array] = []
         refused_chunks: List[jax.Array] = []
         for si, seg in enumerate(self._segments):
-            if isinstance(seg, levelscan.ScanBucket):
-                if track_refused:
-                    own, ref_own, sent_cur, refused_cur = (
-                        levelscan.sent_sweep(
-                            ctx, seg, bucket_ys[si],
-                            levelscan.pad_cols(
-                                sent_cur, seg.plan.bound_hops
-                            ),
-                            refused_init=levelscan.pad_cols(
-                                refused_cur, seg.plan.bound_hops
-                            ),
+            with jax.named_scope(f"sent/{_seg_label(seg)}"):
+                if isinstance(seg, levelscan.ScanBucket):
+                    if track_refused:
+                        own, ref_own, sent_cur, refused_cur = (
+                            levelscan.sent_sweep(
+                                ctx, seg, bucket_ys[si],
+                                levelscan.pad_cols(
+                                    sent_cur, seg.plan.bound_hops
+                                ),
+                                refused_init=levelscan.pad_cols(
+                                    refused_cur, seg.plan.bound_hops
+                                ),
+                            )
                         )
+                        refused_chunks.append(
+                            levelscan.gather_levels(ref_own, seg.sizes)
+                        )
+                    else:
+                        own, sent_cur = levelscan.sent_sweep(
+                            ctx, seg, bucket_ys[si],
+                            levelscan.pad_cols(sent_cur, seg.plan.bound_hops),
+                        )
+                    sent_chunks.append(
+                        levelscan.gather_levels(own, seg.sizes)
                     )
-                    refused_chunks.append(
-                        levelscan.gather_levels(ref_own, seg.sizes)
+                    continue
+                d = seg.d
+                sent_chunks.append(sent_cur)
+                if track_refused:
+                    refused_chunks.append(refused_cur)
+                if d >= last_level:
+                    continue
+                lvl = self._levels[d]
+                sl = slice(lvl.offset, lvl.offset + lvl.size)
+                nxt = self._levels[d + 1]
+                csl = slice(nxt.offset, nxt.offset + nxt.size)
+                sent = sent_cur[:, lvl.child_parent_local]
+                if err_coin is not None:
+                    sent = sent & ~err_coin[:, sl][:, lvl.child_parent_local]
+                if fail_lvls[d] is not None:
+                    sent = sent & (
+                        lvl.child_step
+                        <= fail_lvls[d][:, lvl.child_parent_local]
                     )
+                if used_lvls[d] is not None:
+                    sent = sent & used_lvls[d]
+                if down is not None:
+                    refused_cur = sent & down[:, csl]
+                    sent = sent & ~down[:, csl]
                 else:
-                    own, sent_cur = levelscan.sent_sweep(
-                        ctx, seg, bucket_ys[si],
-                        levelscan.pad_cols(sent_cur, seg.plan.bound_hops),
-                    )
-                sent_chunks.append(
-                    levelscan.gather_levels(own, seg.sizes)
-                )
-                continue
-            d = seg.d
-            sent_chunks.append(sent_cur)
-            if track_refused:
-                refused_chunks.append(refused_cur)
-            if d >= last_level:
-                continue
-            lvl = self._levels[d]
-            sl = slice(lvl.offset, lvl.offset + lvl.size)
-            nxt = self._levels[d + 1]
-            csl = slice(nxt.offset, nxt.offset + nxt.size)
-            sent = sent_cur[:, lvl.child_parent_local]
-            if err_coin is not None:
-                sent = sent & ~err_coin[:, sl][:, lvl.child_parent_local]
-            if fail_lvls[d] is not None:
-                sent = sent & (
-                    lvl.child_step
-                    <= fail_lvls[d][:, lvl.child_parent_local]
-                )
-            if used_lvls[d] is not None:
-                sent = sent & used_lvls[d]
-            if down is not None:
-                refused_cur = sent & down[:, csl]
-                sent = sent & ~down[:, csl]
-            else:
-                refused_cur = jnp.zeros_like(sent)
-            sent_cur = sent
+                    refused_cur = jnp.zeros_like(sent)
+                sent_cur = sent
 
         # ---- closed-loop arrivals (need latencies) -----------------------
         # a refused connection to the entry costs one wire round trip
@@ -5989,23 +6005,24 @@ class Simulator:
         else:
             root_lat = root_wire + lat_lvls[0][:, 0]
         if kind == CLOSED_LOOP:
-            c = max(connections, 1)
-            per = n // c
-            rem = n - c * per
-            lat_conn = root_lat[: c * per].reshape(c, per)
-            spent = jnp.maximum(lat_conn, pace_gap)
-            starts = conn_t0[:, None] + jnp.cumsum(spent, axis=-1) - spent
-            conn_end = conn_t0 + spent.sum(-1)
-            if rem:
-                # remainder requests (n % c) continue on the first ``rem``
-                # connections — each starts when its connection frees up
-                arrivals = jnp.concatenate(
-                    [starts.reshape(-1), conn_end[:rem]]
-                )
-                spent_rem = jnp.maximum(root_lat[c * per:], pace_gap)
-                conn_end = conn_end.at[:rem].add(spent_rem)
-            else:
-                arrivals = starts.reshape(-1)
+            with jax.named_scope("arrivals"):
+                c = max(connections, 1)
+                per = n // c
+                rem = n - c * per
+                lat_conn = root_lat[: c * per].reshape(c, per)
+                spent = jnp.maximum(lat_conn, pace_gap)
+                starts = conn_t0[:, None] + jnp.cumsum(spent, axis=-1) - spent
+                conn_end = conn_t0 + spent.sum(-1)
+                if rem:
+                    # remainder requests (n % c) continue on the first ``rem``
+                    # connections — each starts when its connection frees up
+                    arrivals = jnp.concatenate(
+                        [starts.reshape(-1), conn_end[:rem]]
+                    )
+                    spent_rem = jnp.maximum(root_lat[c * per:], pace_gap)
+                    conn_end = conn_end.at[:rem].add(spent_rem)
+                else:
+                    arrivals = starts.reshape(-1)
         else:
             conn_end = conn_t0
 
@@ -6017,31 +6034,33 @@ class Simulator:
         start_chunks: List[jax.Array] = []
         telemetry.fence_reset()
         for si, seg in enumerate(self._segments):
-            if isinstance(seg, levelscan.ScanBucket):
-                own, start_cur = levelscan.start_sweep(
-                    ctx, seg, bucket_ys[si],
-                    levelscan.pad_cols(start_cur, seg.plan.bound_hops),
-                )
-                start_chunks.append(
-                    levelscan.gather_levels(own, seg.sizes)
-                )
+            with jax.named_scope(f"start/{_seg_label(seg)}"):
+                if isinstance(seg, levelscan.ScanBucket):
+                    own, start_cur = levelscan.start_sweep(
+                        ctx, seg, bucket_ys[si],
+                        levelscan.pad_cols(start_cur, seg.plan.bound_hops),
+                    )
+                    start_chunks.append(
+                        levelscan.gather_levels(own, seg.sizes)
+                    )
+                    telemetry.segment_fence(
+                        f"start.{_seg_label(seg)}", start_chunks[-1]
+                    )
+                    continue
+                d = seg.d
+                start_chunks.append(start_cur)
                 telemetry.segment_fence(
-                    f"start.scan[{seg.plan.d0}-{seg.plan.d1}]",
-                    start_chunks[-1],
+                    f"start.{_seg_label(seg)}", start_cur
                 )
-                continue
-            d = seg.d
-            start_chunks.append(start_cur)
-            telemetry.segment_fence(f"start.lvl[{d}]", start_cur)
-            if d >= last_level:
-                continue
-            lvl = self._levels[d]
-            sl = slice(lvl.offset, lvl.offset + lvl.size)
-            base = (start_cur + wait[:, sl])[:, lvl.child_parent_local]
-            out_wire = lvl.child_net_out
-            if tax is not None:
-                out_wire = out_wire + tax[:, None]
-            start_cur = base + off_lvls[d] + out_wire
+                if d >= last_level:
+                    continue
+                lvl = self._levels[d]
+                sl = slice(lvl.offset, lvl.offset + lvl.size)
+                base = (start_cur + wait[:, sl])[:, lvl.child_parent_local]
+                out_wire = lvl.child_net_out
+                if tax is not None:
+                    out_wire = out_wire + tax[:, None]
+                start_cur = base + off_lvls[d] + out_wire
 
         # ---- per-segment assembly into BFS hop order ---------------------
         lat_chunks: List[jax.Array] = []

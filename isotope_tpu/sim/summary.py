@@ -86,35 +86,43 @@ def summarize(
     lat = res.client_latency
     n = lat.shape[0]
     count = jnp.float32(n)
-    error_count = res.client_error.sum().astype(jnp.float32)
-    lat_sum = lat.sum()
-    # centered second moment: conditioned for cv << 1 where the raw
-    # E[x^2] - mean^2 form cancels catastrophically in f32
-    mean = lat_sum / jnp.float32(max(n, 1))
-    m2 = ((lat - mean) ** 2).sum()
-    hist = latency_histogram(lat)
+    with jax.named_scope("summary/moments"):
+        error_count = res.client_error.sum().astype(jnp.float32)
+        lat_sum = lat.sum()
+        # centered second moment: conditioned for cv << 1 where the raw
+        # E[x^2] - mean^2 form cancels catastrophically in f32
+        mean = lat_sum / jnp.float32(max(n, 1))
+        m2 = ((lat - mean) ** 2).sum()
+        lat_min, lat_max = lat.min(), lat.max()
+        hop_events = res.hop_events.astype(jnp.float32)
+        end_max = res.client_end.max()
+    with jax.named_scope("summary/latency_hist"):
+        hist = latency_histogram(lat)
     if window is None:
         win_lo, win_hi = jnp.float32(0.0), jnp.float32(np.inf)
         win_count, win_error_count, win_hist = count, error_count, hist
     else:
         win_lo, win_hi = window
-        in_win = (res.client_start >= win_lo) & (res.client_start < win_hi)
-        win_w = in_win.astype(jnp.float32)
-        win_count = win_w.sum()
-        win_error_count = (
-            (res.client_error & in_win).sum().astype(jnp.float32)
-        )
-        win_hist = latency_histogram(lat, win_w)
+        with jax.named_scope("summary/window"):
+            in_win = (
+                (res.client_start >= win_lo) & (res.client_start < win_hi)
+            )
+            win_w = in_win.astype(jnp.float32)
+            win_count = win_w.sum()
+            win_error_count = (
+                (res.client_error & in_win).sum().astype(jnp.float32)
+            )
+            win_hist = latency_histogram(lat, win_w)
     return RunSummary(
         count=count,
         error_count=error_count,
-        hop_events=res.hop_events.astype(jnp.float32),
+        hop_events=hop_events,
         latency_sum=lat_sum,
         latency_m2=m2,
-        latency_min=lat.min(),
-        latency_max=lat.max(),
+        latency_min=lat_min,
+        latency_max=lat_max,
         latency_hist=hist,
-        end_max=res.client_end.max(),
+        end_max=end_max,
         win_lo=jnp.asarray(win_lo, jnp.float32),
         win_hi=jnp.asarray(win_hi, jnp.float32),
         win_count=win_count,
@@ -228,6 +236,7 @@ def merge_m2(counts, sums, m2s, axis=0):
     return m2s.sum(axis) + (counts * (mean_i - mean_tot) ** 2).sum(axis)
 
 
+@jax.named_scope("summary/reduce")
 def reduce_stacked(parts: RunSummary) -> RunSummary:
     """Reduce a summary whose leaves carry a leading block axis (the
     stacked output of ``lax.scan``) to a single RunSummary."""

@@ -7,6 +7,7 @@ profile``) from command handlers only.
 """
 from isotope_tpu.telemetry.core import (  # noqa: F401
     SCHEMA,
+    SCOPE_ROOTS,
     RunTelemetry,
     counter_get,
     counter_inc,
@@ -24,10 +25,12 @@ from isotope_tpu.telemetry.core import (  # noqa: F401
     phase,
     phase_add,
     phase_seconds,
+    program_scopes,
     prometheus_text,
     record_device_memory,
     record_trace,
     reset,
+    scope_of,
     segment_fence,
     set_meta,
     snapshot,

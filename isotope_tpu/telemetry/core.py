@@ -15,6 +15,16 @@ report through:
   compiled programs.  Counters recorded inside a jitted function body
   therefore count *traces* (host executions), not executed requests —
   which is exactly what makes them retrace detectors.
+- **One span primitive, two clocks**: :func:`phase` accumulates host
+  seconds in the registry AND opens a ``jax.profiler.TraceAnnotation``
+  of the same name, so under a profiler session every phase lies in
+  the trace's host plane on the clock the device planes use (outside a
+  session: an inactive TraceMe).  It is a context manager and a
+  decorator.
+- **Device scopes, on demand** (:func:`program_scopes`): the engine
+  traces under ``jax.named_scope`` (SCOPE_ROOTS); the map from a
+  compiled program's instructions to those scopes is computed only
+  when asked for, from the signature :func:`time_first_call` kept.
 - **JAX monitoring hooks** (:func:`install_jax_hooks`) subscribe to
   jax's own event stream, splitting compile wall time into trace /
   lower / backend-compile phases and counting persistent-compilation-
@@ -42,7 +52,9 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import json
+import re
 import time
+import weakref
 from typing import Any, Dict, Iterator, List, Optional, Set
 
 SCHEMA = "isotope-engine-telemetry/v1"
@@ -172,17 +184,35 @@ def phase_seconds(name: str) -> float:
     return _STATE.phases.get(name, 0.0)
 
 
+def _trace_annotation(name: str, attrs: Dict[str, Any]):
+    """The profiler's span for a phase: a ``TraceAnnotation`` (an
+    inactive TraceMe outside a profiler session), or nothing where jax
+    is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # converter-only env: the timer alone
+        return contextlib.nullcontext()
+    return TraceAnnotation(name, **attrs)
+
+
 @contextlib.contextmanager
-def phase(name: str) -> Iterator[None]:
-    """Accumulating wall-clock phase timer.
+def phase(name: str, **attrs: Any) -> Iterator[None]:
+    """Accumulating wall-clock phase timer AND profiler span.
 
     Re-entering the same name sums; nested phases time independently,
     so an enclosing phase's seconds include its children's (each name
     is its own accumulator — there is no implicit hierarchy).
+
+    Under a ``jax.profiler`` session (``sweep --profile``, ``telemetry
+    --xla-trace``) the phase also lands in the trace's host plane as an
+    event called ``name`` carrying ``attrs``, on the clock the device
+    planes use — so device idle gaps can be set against the host phase
+    open in them.  Outside a session that costs one inactive TraceMe.
     """
     t0 = time.perf_counter()
     try:
-        yield
+        with _trace_annotation(name, attrs):
+            yield
     finally:
         phase_add(name, time.perf_counter() - t0)
 
@@ -209,11 +239,13 @@ def time_first_call(fn, phase_name: str, counter: str = "jit_first_calls"):
     """
 
     class _Timed:
-        __slots__ = ("_fn", "_first_done")
+        __slots__ = ("_fn", "_first_done", "_signature", "__weakref__")
 
         def __init__(self, inner):
             self._fn = inner
             self._first_done = False
+            self._signature = None  # abstract (args, kwargs), first call
+            _PROGRAMS.add(self)
 
         def __call__(self, *args, **kwargs):
             if self._first_done:
@@ -229,12 +261,143 @@ def time_first_call(fn, phase_name: str, counter: str = "jit_first_calls"):
             phase_add(phase_name, time.perf_counter() - t0)
             counter_inc(counter)
             self._first_done = True
+            # what program_scopes() re-lowers at: shapes, not buffers
+            self._signature = _abstract((args, kwargs))
             return out
 
         def __getattr__(self, item):  # lower()/compile() passthrough
             return getattr(self._fn, item)
 
     return _Timed(fn)
+
+
+# -- device scopes, on demand ----------------------------------------------
+
+#: first components of every ``jax.named_scope`` the engine opens — the
+#: vocabulary a device profile is read in (README: telemetry)
+SCOPE_ROOTS = (
+    "engine", "summary", "collector", "attribution", "timeline", "merge",
+)
+
+#: every live time_first_call wrapper (the executable cache holds them)
+_PROGRAMS: "weakref.WeakSet" = weakref.WeakSet()
+
+_HLO_MODULE = re.compile(r"^HloModule (\S+?),", re.M)
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r"metadata=\{[^}]*op_name=\"([^\"]*)\"")
+_HLO_CALLEE = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+
+
+def _abstract(tree):
+    """Array leaves -> ShapeDtypeStructs (the sharding of a committed
+    array kept: jit placed the others itself); the rest as is."""
+    import jax
+
+    def leaf(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=x.sharding if x.committed else None,
+            )
+        return x
+
+    return jax.tree.map(leaf, tree)
+
+
+def scope_of(op_name: str) -> str:
+    """The engine's scope inside a compiled op's ``op_name``: the path
+    from the first SCOPE_ROOTS component on (``jit(..)/while/body/``
+    wrappers dropped), ``""`` where the op carries none."""
+    parts = op_name.split("/")
+    for i, part in enumerate(parts):
+        if part in SCOPE_ROOTS:
+            return "/".join(parts[i:])
+    return ""
+
+
+def hlo_scopes(text: str) -> Dict[str, str]:
+    """``{instruction name: scope}`` from one optimised HLO module's
+    text.  The TPU compiler leaves some instructions without metadata
+    (a scatter it flattened, the fusion it put round it): those take
+    the scope of the computation they call - its root's, else its
+    first scoped instruction's - down to a scatter's combiner, which
+    keeps the scope it was traced under."""
+    comps: Dict[str, list] = {}      # name -> [(inst, scope, callees, root)]
+    rows = None
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head is not None:
+            rows = comps.setdefault(head.group(1), [])
+            continue
+        inst = _HLO_INSTRUCTION.match(line)
+        if inst is None or rows is None:
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        rows.append((
+            inst.group(2),
+            scope_of(op_name.group(1)) if op_name else "",
+            _HLO_CALLEE.findall(line),
+            inst.group(1) is not None,
+        ))
+    memo: Dict[str, str] = {}
+
+    def of_row(row) -> str:
+        _, scope, callees, _ = row
+        if scope:
+            return scope
+        for callee in callees:
+            scope = of_computation(callee)
+            if scope:
+                return scope
+        return ""
+
+    def of_computation(name: str) -> str:
+        if name not in memo:
+            memo[name] = ""          # a cycle resolves to nothing
+            rows = comps.get(name, ())
+            for row in sorted(rows, key=lambda r: not r[3]):  # root first
+                memo[name] = of_row(row)
+                if memo[name]:
+                    break
+        return memo[name]
+
+    return {row[0]: of_row(row) for rows in comps.values() for row in rows}
+
+
+def program_scopes() -> Dict[str, Dict[str, str]]:
+    """``{XLA module name: {instruction name: scope}}`` for every jitted
+    entry point this process has called (see :func:`scope_of`).
+
+    A v5e profile's ``XLA Ops`` events carry instruction names only;
+    this is the map from them to the engine's named scopes.  Computed
+    when called, never on the served path: each program is re-lowered
+    at the abstract signature of its first call, compiled (a
+    compilation-cache hit) and the optimised HLO text parsed
+    (:func:`hlo_scopes`).  The registry is left as found.
+    """
+    saved = (dict(_STATE.counters), dict(_STATE.phases),
+             dict(_STATE.gauges), set(_STATE.trace_keys))
+    out: Dict[str, Dict[str, str]] = {}
+    try:
+        for timed in list(_PROGRAMS):
+            if timed._signature is None:
+                continue
+            args, kwargs = timed._signature
+            text = timed._fn.lower(*args, **kwargs).compile().as_text()
+            module = _HLO_MODULE.search(text)
+            if module is not None:
+                out.setdefault(module.group(1), {}).update(
+                    hlo_scopes(text)
+                )
+    finally:
+        for live, was in zip(
+            (_STATE.counters, _STATE.phases, _STATE.gauges,
+             _STATE.trace_keys), saved,
+        ):
+            live.clear()
+            live.update(was)
+    return out
 
 
 # -- engine hooks ----------------------------------------------------------

@@ -1,10 +1,10 @@
 """XLA trace capture: the backend of ``isotope-tpu telemetry --xla-trace``.
 
-Promoted from ``tools/capture_profile.py`` (which remains as a thin
-shim): captures a ``jax.profiler`` trace of warmed summary steps —
-the same capture path the sweep runner uses per-run via ``--profile``
+Captures a ``jax.profiler`` trace of warmed summary steps — the same
+capture path the sweep runner uses per-run via ``--profile``
 (runner/run.py wraps each run in ``jax.profiler.trace``) — readable in
-TensorBoard/XProf.
+TensorBoard/XProf; the README's "Reading a profile" says what the host
+and device planes show.
 """
 from __future__ import annotations
 

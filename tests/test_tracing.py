@@ -1,0 +1,236 @@
+"""The span primitive on the profiler's clock, the served path's host
+phases and the engine's device scopes (ISSUE 25).
+
+One tiny ``simulate`` through ``cli.main`` (the entry point an operator
+calls) is the fixture most tests read: its phases, its counters, the
+scopes in its compiled programs, and what a second, warm call may not
+do (trace, lower, compile, or ask for ``program_scopes()``).
+"""
+import contextlib
+import io
+import json
+import sys
+
+import jax
+import pytest
+
+from isotope_tpu import cli, telemetry
+from isotope_tpu.telemetry import core
+
+TOPOLOGY = "examples/topologies/canonical.yaml"   # 4 services, 6 hops
+HOPS = 6
+
+#: the leaf phases of one served call (README: telemetry); they do not
+#: overlap one another, so their seconds can be summed
+CASE_LEAVES = (
+    "graph.decode", "compile.unroll", "engine.build",
+    "closed_rate.solve", "summary.dispatch", "summary.wait",
+    "artifacts.fortio", "artifacts.window", "artifacts.exposition",
+)
+CALL_LEAVES = CASE_LEAVES + ("artifacts.write", "cli.parse", "cli.config")
+
+
+def serve(tmp_path, tag, seed=7, connections=4, qps="100"):
+    """One ``isotope-tpu simulate`` in-process: (rc, stdout, .prom)."""
+    prom = tmp_path / f"{tag}.prom"
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out), \
+            contextlib.redirect_stderr(io.StringIO()):
+        rc = cli.main([
+            "simulate", TOPOLOGY, "--qps", qps, "-c", str(connections),
+            "--duration", "20s", "--load-kind", "closed", "--seed",
+            str(seed), "--prometheus", str(prom), "--no-degrade",
+            "--compile-cache", "off",
+        ])
+    return rc, out.getvalue(), prom.read_bytes()
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """The registry after one served call on a clean registry."""
+    import isotope_tpu.compiler.cache as cache_mod
+
+    memo = (cache_mod._persistent_dir, cache_mod._switched_off)
+    telemetry.reset()
+    rc, stdout, prom = serve(tmp_path_factory.mktemp("served"), "first")
+    assert rc == 0
+    yield telemetry.snapshot(), json.loads(stdout), prom
+    cache_mod._persistent_dir, cache_mod._switched_off = memo
+
+
+# -- (a) one primitive, two clocks -----------------------------------------
+
+def test_phase_lands_in_the_profilers_host_plane(tmp_path):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with telemetry.phase("probe.phase", label="canonical", run_index=3):
+            jax.block_until_ready(jax.numpy.ones(8) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    host = next(p for p in data.planes if p.name == "/host:CPU")
+    events = [e for line in host.lines for e in line.events
+              if e.name == "probe.phase"]
+    assert len(events) == 1
+    stats = dict(events[0].stats)
+    assert stats["label"] == "canonical" and int(stats["run_index"]) == 3
+    assert events[0].duration_ns > 0
+    # and the timer still accrued, on its own clock
+    assert telemetry.phase_seconds("probe.phase") > 0
+
+
+def test_phase_times_without_jax(monkeypatch):
+    monkeypatch.setitem(sys.modules, "jax", None)
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    before = telemetry.phase_seconds("probe.nojax")
+    with telemetry.phase("probe.nojax", why="converter-only"):
+        pass
+    assert telemetry.phase_seconds("probe.nojax") > before
+
+
+def test_phase_decorates_a_function():
+    @telemetry.phase("probe.decorated")
+    def work(x):
+        return x + 1
+
+    before = telemetry.phase_seconds("probe.decorated")
+    assert work(1) == 2 and work(2) == 3     # a fresh span per call
+    assert telemetry.phase_seconds("probe.decorated") > before
+
+
+# -- (b) the served call's phases and counters -----------------------------
+
+def test_served_call_accrues_every_leaf_phase(served):
+    snap, doc, _ = served
+    missing = [p for p in CALL_LEAVES + ("run.case", "closed_rate.pilot")
+               if not snap.phases.get(p, 0.0) > 0]
+    assert not missing
+    # the leaves under run.case do not overlap, so they fit inside it
+    assert sum(snap.phases[p] for p in CASE_LEAVES) <= snap.phases["run.case"]
+    assert snap.phases["closed_rate.pilot"] <= snap.phases["closed_rate.solve"]
+
+
+def test_served_call_counters(served):
+    snap, doc, _ = served
+    count = doc["DurationHistogram"]["Count"]
+    assert snap.counters["requests_simulated"] == count
+    assert snap.counters["hop_events_simulated"] == count * HOPS
+    assert snap.counters["runs_served"] == 1
+    assert snap.counters["graphs_decoded"] == 1
+    assert snap.counters["closed_rate_pilot_runs"] >= 1
+    assert snap.counters["artifact_bytes_written"] > 1000
+
+
+def test_sharded_call_accrues_its_phases(served, tmp_path):
+    """64 connections divide over the 8 virtual devices: the default
+    mesh serves the call through ShardedSimulator.run."""
+    assert jax.device_count() == 8
+    telemetry.reset()
+    rc, stdout, _ = serve(tmp_path, "sharded", connections=64)
+    snap = telemetry.snapshot()
+    assert rc == 0 and snap.counters["sharded_runs"] == 1
+    for name in ("sharded.args_put", "summary.dispatch", "summary.wait",
+                 "closed_rate.solve", "run.case"):
+        assert snap.phases.get(name, 0.0) > 0, name
+    count = json.loads(stdout)["DurationHistogram"]["Count"]
+    assert snap.counters["hop_events_simulated"] == count * HOPS
+
+
+def test_saturated_solve_is_the_mva_child(served, tmp_path):
+    telemetry.reset()
+    rc, _, _ = serve(tmp_path, "qpsmax", qps="max")
+    snap = telemetry.snapshot()
+    assert rc == 0
+    assert 0 < snap.phases["closed_rate.mva"] <= \
+        snap.phases["closed_rate.solve"]
+    assert "closed_rate.pilot" not in snap.phases
+
+
+# -- (c) device scopes and their map ---------------------------------------
+
+def _segments(scopes, prefix):
+    """Scopes (the primitive's own name cut off) of the modules whose
+    name starts with ``prefix``."""
+    modules = [m for m in scopes if m.startswith(prefix)]
+    assert modules, prefix
+    return {s.rsplit("/", 1)[0] for m in modules
+            for s in scopes[m].values() if s}
+
+
+def test_program_scopes_names_every_scope(served):
+    scopes = telemetry.program_scopes()
+    assert "jit_scanfn" not in scopes
+    summary = _segments(scopes, "jit_summary_closed_")
+    for want in ("collector/totals", "collector/duration_hist",
+                 "collector/duration_sum", "collector/response_hist",
+                 "collector/response_sum", "summary/moments",
+                 "summary/latency_hist", "summary/window",
+                 "summary/reduce", "engine/waits", "engine/arrivals"):
+        assert want in summary, want
+    for family in ("engine/up/", "engine/sent/"):
+        assert any(s.startswith(family) for s in summary), family
+    # the pilot is a program of its own, under its own name; it returns
+    # the hop start times the summary never reads (dead code there)
+    pilot = _segments(scopes, "jit_simulate_closed_")
+    assert any(s.startswith("engine/start/") for s in pilot)
+
+
+def test_scope_of():
+    assert core.scope_of(
+        "jit(summary_closed_ab12cd)/jit(main)/while/body/collector/"
+        "duration_hist/scatter-add") == "collector/duration_hist/scatter-add"
+    assert core.scope_of("jit(f)/jit(main)/add") == ""
+
+
+def test_program_scopes_leaves_the_registry_as_found(served):
+    before = telemetry.snapshot()
+    telemetry.program_scopes()
+    after = telemetry.snapshot()
+    assert after.counters == before.counters
+    assert after.phases == before.phases
+
+
+# -- (d) nothing of it on the served path ----------------------------------
+
+def test_warm_call_neither_compiles_nor_asks_for_scopes(
+        served, tmp_path, monkeypatch):
+    asked = []
+    monkeypatch.setattr(
+        core, "program_scopes", lambda: asked.append(1) or {})
+    monkeypatch.setattr(
+        telemetry, "program_scopes", lambda: asked.append(1) or {})
+    serve(tmp_path, "warm", seed=8)         # warms the eager helpers too
+    before = telemetry.snapshot()
+    rc, _, _ = serve(tmp_path, "counted", seed=9)
+    after = telemetry.snapshot()
+    assert rc == 0 and not asked
+    for name in ("compile.trace", "compile.lower", "compile.backend",
+                 "compile.jit_first_call"):
+        assert after.phases.get(name) == before.phases.get(name), name
+    for name in ("jit_first_calls", "engine_traces", "engine_retraces",
+                 "executable_cache_misses"):
+        assert after.counters.get(name) == before.counters.get(name), name
+
+
+# -- (e) artifacts do not depend on the profiler ---------------------------
+
+def test_artifacts_identical_under_a_profiler_session(served, tmp_path):
+    """Same seed, with and without an open session: the exposition is
+    byte-identical, the Fortio document too apart from its wall-clock
+    ``StartTime``."""
+    _, stdout_plain, prom_plain = serve(tmp_path, "plain", seed=11)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(
+        str(tmp_path / "trace"), profiler_options=options)
+    try:
+        _, stdout_traced, prom_traced = serve(tmp_path, "traced", seed=11)
+    finally:
+        jax.profiler.stop_trace()
+    assert prom_traced == prom_plain
+    plain, traced = json.loads(stdout_plain), json.loads(stdout_traced)
+    assert plain.pop("StartTime") and traced.pop("StartTime")
+    assert json.dumps(traced) == json.dumps(plain)
