@@ -14,9 +14,13 @@ In the reference each pod exposes its own ``/metrics`` and Prometheus adds
 pod identity at scrape time (kubernetes.go:49-52); the simulator has no
 pods, so every series carries an explicit ``service`` label instead.
 
-Collection is a jit-friendly scatter-add over the (request x hop) event
-tensor; exposition renders the standard text format so any Prometheus
-parser/scraper tooling keeps working.
+Collection is jit-friendly and reduces before it scatters: a hop column
+has one service, one response bucket and one response size, so the
+(request x hop) event tensor is first reduced over requests, per hop
+column and response code (counts and duration-edge exceedances as exact
+integers, duration sums in float32), and only those H rows are
+scatter-added onto services.  Exposition renders the standard text
+format so any Prometheus parser/scraper tooling keeps working.
 """
 from __future__ import annotations
 
@@ -96,7 +100,9 @@ def timestamped_series(
 
 
 class ServiceMetrics(NamedTuple):
-    """Device-side accumulators (all counts are float32 for scatter-adds)."""
+    """Device-side accumulators.  Counts are whole numbers held in
+    float32 (exact below 2**24 a block; a block's are reduced as int32
+    and cast), so every field merges with one ``+`` / ``psum``."""
 
     incoming_total: jax.Array        # (S,)
     outgoing_total: jax.Array        # (E,) per static call edge
@@ -171,12 +177,15 @@ class MetricsCollector:
 
     def collect(self, res: SimResults) -> ServiceMetrics:
         # each accumulator traces under a scope of its own, so a device
-        # profile can be read per accumulator (README: telemetry)
+        # profile can be read per accumulator (README: telemetry); the
+        # per-hop counts the response series share are made, and traced,
+        # under collector/duration_hist
         c = self.compiled
         S, E = c.num_services, len(self.edges)
+        nsb = len(SIZE_BUCKETS) + 1
         sent = res.hop_sent
         sent_f = sent.astype(jnp.float32)
-        code = res.hop_error.astype(jnp.int32)  # 0 => 200, 1 => 500
+        err = res.hop_error  # False => 200, True => 500
 
         with jax.named_scope("collector/totals"):
             incoming = (
@@ -185,7 +194,7 @@ class MetricsCollector:
             outgoing = jnp.zeros(E).at[self._hop_edge].add(sent_f.sum(0))
 
             out_size = (
-                jnp.zeros((E, len(SIZE_BUCKETS) + 1))
+                jnp.zeros((E, nsb))
                 .at[self._hop_edge, self._hop_size_bucket]
                 .add(sent_f.sum(0))
             )
@@ -196,45 +205,64 @@ class MetricsCollector:
                     self.compiled.hop_request_size, jnp.float32))
             )
 
-        svc = jnp.broadcast_to(self._hop_service, sent.shape)
+        # One service, one response bucket and one response size per
+        # hop column: only the code (2 values) and the duration bucket
+        # (33) vary with the request.  So reduce over requests first,
+        # per hop column and code, and scatter H rows onto services.
         with jax.named_scope("collector/duration_hist"):
-            # scatter every sent hop into (svc, code, bucket); bucket
-            # index by counting edges below x — 32 fused compares beat a
-            # binary-search gather (element gathers run ~2 GiB/s on TPU)
+            masks = (sent & ~err, sent & err)  # by code: 200, 500
             edges = jnp.asarray(DURATION_BUCKETS, jnp.float32)
-            dbuckets = (
-                (res.hop_latency[..., None] > edges)
-                .sum(-1)
-                .astype(jnp.int32)
-            )
+            cnt = jnp.stack(
+                [m.sum(0, dtype=jnp.int32) for m in masks], 1
+            )                                                    # (H, 2)
+            # above[h, c, k] = executions slower than edge k: the same
+            # compares a bucket index would count over edges, summed
+            # over requests instead (a NaN is above nothing: bucket 0).
+            # Edges lead so that the compare fuses into the reduction
+            # and no (N, H, 32) tensor is ever stored.
+            slower = res.hop_latency[None] > edges[:, None, None]
+            above = jnp.stack(
+                [
+                    (m[None] & slower).sum(1, dtype=jnp.int32).T
+                    for m in masks
+                ],
+                1,
+            )                                                    # (H, 2, 32)
+            # `le` buckets as differences of the counts, in integers
+            hop_hist = jnp.concatenate(
+                [
+                    cnt[..., None] - above[..., :1],
+                    above[..., :-1] - above[..., 1:],
+                    above[..., -1:],
+                ],
+                -1,
+            ).astype(jnp.float32)
             dur_hist = (
-                jnp.zeros((S, 2, _NB))
-                .at[svc, code, dbuckets]
-                .add(sent_f)
+                jnp.zeros((S, 2, _NB)).at[self._hop_service].add(hop_hist)
             )
+            cnt_f = cnt.astype(jnp.float32)
         with jax.named_scope("collector/duration_sum"):
-            dur_sum = (
-                jnp.zeros((S, 2))
-                .at[svc, code]
-                .add(jnp.where(sent, res.hop_latency, 0.0))
+            hop_dsum = jnp.stack(
+                [jnp.where(m, res.hop_latency, 0.0).sum(0) for m in masks],
+                1,
             )
+            dur_sum = jnp.zeros((S, 2)).at[self._hop_service].add(hop_dsum)
 
         with jax.named_scope("collector/response_hist"):
-            rbucket = jnp.broadcast_to(
-                self._svc_resp_bucket[c.hop_service], sent.shape
-            )
             resp_hist = (
-                jnp.zeros((S, 2, len(SIZE_BUCKETS) + 1))
-                .at[svc, code, rbucket]
-                .add(sent_f)
+                jnp.zeros((S, 2, nsb))
+                .at[
+                    self._hop_service[:, None],
+                    jnp.arange(2),
+                    self._svc_resp_bucket[c.hop_service][:, None],
+                ]
+                .add(cnt_f)
             )
         with jax.named_scope("collector/response_sum"):
             resp_sum = (
                 jnp.zeros((S, 2))
-                .at[svc, code]
-                .add(jnp.where(
-                    sent, self._svc_resp_size[c.hop_service], 0.0
-                ))
+                .at[self._hop_service]
+                .add(cnt_f * self._svc_resp_size[c.hop_service][:, None])
             )
         return ServiceMetrics(
             incoming_total=incoming,

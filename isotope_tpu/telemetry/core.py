@@ -287,6 +287,7 @@ _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r"metadata=\{[^}]*op_name=\"([^\"]*)\"")
 _HLO_CALLEE = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+_HLO_LOOP = re.compile(r"\b(?:body|condition)=%?([\w.\-]+)")
 
 
 def _abstract(tree):
@@ -322,13 +323,17 @@ def hlo_scopes(text: str) -> Dict[str, str]:
     (a scatter it flattened, the fusion it put round it): those take
     the scope of the computation they call - its root's, else its
     first scoped instruction's - down to a scatter's combiner, which
-    keeps the scope it was traced under."""
+    keeps the scope it was traced under.  What is still bare inside a
+    loop (the slices and updates of the ``while`` the compiler expands
+    a scatter into) takes the scope of that ``while``."""
     comps: Dict[str, list] = {}      # name -> [(inst, scope, callees, root)]
-    rows = None
+    loops: Dict[str, tuple] = {}     # body/condition -> (computation, row)
+    comp = rows = None
     for line in text.splitlines():
         head = _HLO_COMPUTATION.match(line)
         if head is not None:
-            rows = comps.setdefault(head.group(1), [])
+            comp = head.group(1)
+            rows = comps.setdefault(comp, [])
             continue
         inst = _HLO_INSTRUCTION.match(line)
         if inst is None or rows is None:
@@ -340,6 +345,8 @@ def hlo_scopes(text: str) -> Dict[str, str]:
             _HLO_CALLEE.findall(line),
             inst.group(1) is not None,
         ))
+        for looped in _HLO_LOOP.findall(line):
+            loops[looped] = (comp, rows[-1])
     memo: Dict[str, str] = {}
 
     def of_row(row) -> str:
@@ -362,7 +369,18 @@ def hlo_scopes(text: str) -> Dict[str, str]:
                     break
         return memo[name]
 
-    return {row[0]: of_row(row) for rows in comps.values() for row in rows}
+    def of_loop(name: str) -> str:
+        """The scope of the ``while`` that ``name`` is the body (or the
+        condition) of, itself inside a loop perhaps."""
+        if name not in loops:
+            return ""
+        outer, row = loops[name]
+        return of_row(row) or of_loop(outer)
+
+    return {
+        row[0]: of_row(row) or of_loop(name)
+        for name, rows in comps.items() for row in rows
+    }
 
 
 def program_scopes() -> Dict[str, Dict[str, str]]:

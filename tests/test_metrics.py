@@ -2,6 +2,7 @@
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -215,3 +216,191 @@ def test_bucket_index_matches_searchsorted_edges():
     # NaN keeps searchsorted's overflow-bucket behavior
     nan_idx = np.asarray(bucket_index(jnp.asarray([np.nan])))
     assert nan_idx[0] == NUM_BUCKETS - 1
+
+
+# -- collect against a plain NumPy reference -------------------------------
+
+DIAMOND_YAML = """
+defaults:
+  requestSize: 300
+  responseSize: 2000
+services:
+- name: entry
+  isEntrypoint: true
+  script:
+  - call: left
+  - call: right
+- name: left
+  script:
+  - call: shared
+- name: right
+  errorRate: 30%
+  script:
+  - call: shared
+- name: shared
+  errorRate: 20%
+  responseSize: 70
+"""
+
+
+def _simulated(yaml_text, n, seed):
+    compiled = compile_graph(ServiceGraph.from_yaml(yaml_text))
+    sim = Simulator(compiled, SimParams(service_time="deterministic"))
+    res = sim.run(LoadModel(kind="open", qps=10.0), n, jax.random.PRNGKey(seed))
+    return compiled, res
+
+
+def _case_error_codes():
+    return _simulated(YAML, 2000, 3)
+
+
+def _case_shared_service():
+    # `shared` is called from two parents: 5 hop columns fold onto 4
+    # services
+    compiled, res = _simulated(DIAMOND_YAML, 1500, 5)
+    assert compiled.num_hops > compiled.num_services
+    return compiled, res
+
+
+def _case_bucket_edges():
+    # latencies planted exactly on every bucket edge (as the float32
+    # the collector compares against), one ulp either side of it, below
+    # the first edge and above the last: `le` semantics, bucket 0, +Inf
+    compiled, res = _simulated(DIAMOND_YAML, 1500, 7)
+    edges = DURATION_BUCKETS.astype(np.float32)
+    planted = np.concatenate([
+        edges,
+        np.nextafter(edges, np.float32(0)),
+        np.nextafter(edges, np.float32(1)),
+        np.asarray([0.0, 1e-6, 0.00699, 0.5001, 0.75, 3.0, 1e9, np.inf],
+                   np.float32),
+    ])
+    rng = np.random.default_rng(11)
+    lat = rng.choice(planted, size=res.hop_latency.shape).astype(np.float32)
+    sent = rng.random(lat.shape) < 0.8
+    err = rng.random(lat.shape) < 0.4
+    return compiled, res._replace(
+        hop_latency=jnp.asarray(lat), hop_sent=jnp.asarray(sent),
+        hop_error=jnp.asarray(err),
+    )
+
+
+def _case_unsent_garbage():
+    # hops that never executed carry NaN / huge / infinite latencies and
+    # an error flag: they must count nowhere and poison no sum
+    compiled, res = _simulated(DIAMOND_YAML, 1500, 9)
+    rng = np.random.default_rng(13)
+    sent = np.asarray(res.hop_sent) & (rng.random(res.hop_sent.shape) < 0.7)
+    garbage = rng.choice(
+        np.asarray([np.nan, 3e38, np.inf, -np.inf, 0.25], np.float32),
+        size=sent.shape,
+    )
+    lat = np.where(sent, np.asarray(res.hop_latency), garbage)
+    err = np.where(sent, np.asarray(res.hop_error), rng.random(sent.shape) < 0.5)
+    return compiled, res._replace(
+        hop_latency=jnp.asarray(lat, jnp.float32),
+        hop_sent=jnp.asarray(sent), hop_error=jnp.asarray(err),
+    )
+
+
+def _reference_collect(compiled, res):
+    """Every (request, hop) event added where it belongs with
+    ``np.add.at``, sums in float64 — nothing of ``collect`` is used but
+    the order of its edge list."""
+    sent = np.asarray(res.hop_sent)
+    err = np.asarray(res.hop_error)
+    lat = np.asarray(res.hop_latency)
+    n, h = np.nonzero(sent)
+    svc = np.asarray(compiled.hop_service)[h]
+    code = err[n, h].astype(np.int64)
+    S = compiled.num_services
+    nsb = len(SIZE_BUCKETS) + 1
+
+    # duration: bucket = the first edge that is >= x (`le`), as float32
+    x = lat[n, h]
+    dbucket = np.searchsorted(
+        DURATION_BUCKETS.astype(np.float32), x, side="left"
+    )
+    dbucket[np.isnan(x)] = 0  # a NaN is above no edge
+    dur_hist = np.zeros((S, 2, len(DURATION_BUCKETS) + 1))
+    np.add.at(dur_hist, (svc, code, dbucket), 1)
+    dur_sum = np.zeros((S, 2))
+    np.add.at(dur_sum, (svc, code), x.astype(np.float64))
+
+    resp = compiled.services.response_size.astype(np.float64)
+    rbucket = np.searchsorted(SIZE_BUCKETS, resp, side="left")
+    resp_hist = np.zeros((S, 2, nsb))
+    np.add.at(resp_hist, (svc, code, rbucket[svc]), 1)
+    resp_sum = np.zeros((S, 2))
+    np.add.at(resp_sum, (svc, code), resp[svc])
+
+    # call edges: (caller service or -1 for the client, callee service)
+    parent = np.asarray(compiled.hop_parent)
+    hop_svc = np.asarray(compiled.hop_service)
+    pairs = [
+        (int(hop_svc[parent[k]]) if parent[k] >= 0 else -1, int(hop_svc[k]))
+        for k in range(compiled.num_hops)
+    ]
+    order = {p: i for i, p in enumerate(MetricsCollector(compiled).edges)}
+    edge = np.asarray([order[p] for p in pairs])[h]
+    E = len(order)
+    req = np.asarray(compiled.hop_request_size, np.float64)[h]
+    incoming = np.zeros(S)
+    np.add.at(incoming, svc, 1)
+    outgoing = np.zeros(E)
+    np.add.at(outgoing, edge, 1)
+    out_hist = np.zeros((E, nsb))
+    np.add.at(out_hist, (edge, np.searchsorted(SIZE_BUCKETS, req, "left")), 1)
+    out_sum = np.zeros(E)
+    np.add.at(out_sum, edge, req)
+    return dict(
+        incoming_total=incoming, outgoing_total=outgoing,
+        outgoing_size_hist=out_hist, outgoing_size_sum=out_sum,
+        duration_hist=dur_hist, duration_sum=dur_sum,
+        response_size_hist=resp_hist, response_size_sum=resp_sum,
+    )
+
+
+@pytest.mark.parametrize("case", [
+    _case_error_codes, _case_shared_service, _case_bucket_edges,
+    _case_unsent_garbage,
+])
+def test_collect_matches_numpy_reference(case):
+    compiled, res = case()
+    got = jax.jit(MetricsCollector(compiled).collect)(res)
+    want = _reference_collect(compiled, res)
+    assert np.asarray(res.hop_sent).sum() > 1000
+    for field in got._fields:
+        g = np.asarray(getattr(got, field), np.float64)
+        if field == "duration_sum":
+            w = want[field]
+            finite = np.isfinite(w)
+            np.testing.assert_allclose(g[finite], w[finite], rtol=1e-6)
+            np.testing.assert_array_equal(g[~finite], w[~finite])
+        else:
+            np.testing.assert_array_equal(g, want[field], err_msg=field)
+    # both codes and (where planted) the first and the +Inf bucket hold
+    # something, so the equalities above are not 0 == 0
+    assert (want["duration_hist"].sum((0, 2)) > 0).all()
+
+
+def _scatter_add_update_sizes(jaxpr):
+    sizes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scatter-add", "scatter_add"):
+            sizes.append(int(np.prod(eqn.invars[2].aval.shape)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            sizes.extend(_scatter_add_update_sizes(sub))
+    return sizes
+
+
+def test_collect_scatters_hop_rows_not_hop_events(run):
+    """The collector reduces over requests BEFORE it scatters: no
+    scatter-add takes more than one row per hop column, whatever the
+    number of requests."""
+    compiled, res = run
+    jaxpr = jax.make_jaxpr(MetricsCollector(compiled).collect)(res)
+    sizes = _scatter_add_update_sizes(jaxpr.jaxpr)
+    assert sizes, "collect no longer scatters: rewrite this guard"
+    assert max(sizes) <= compiled.num_hops * 2 * (len(DURATION_BUCKETS) + 1)
+    assert res.hop_sent.size > max(sizes)
