@@ -488,6 +488,9 @@ def plan_stats(shapes: Sequence[LevelShape],
         "num_buckets": len(buckets_list),
         "levels_bucketed": sum(b.num_levels for b in buckets_list),
         "levels_unrolled": len(segs) - len(buckets_list),
+        "hops_bucketed": sum(
+            s.size for b in buckets_list for s in shapes[b.d0:b.d1 + 1]
+        ),
         "padded_elems": padded,
         "real_elems": real,
         "padding_waste_fraction": (
@@ -503,6 +506,10 @@ def _record_plan(shapes: Sequence[LevelShape],
     st = plan_stats(shapes, segs)
     telemetry.counter_inc("bucket_plans")
     telemetry.counter_inc("buckets_formed", st["num_buckets"])
+    # the same count and the hops the buckets sweep, under the names
+    # the benchmark's per-layer readers use
+    telemetry.counter_inc("scan_buckets_planned", st["num_buckets"])
+    telemetry.counter_inc("hops_in_scan_buckets", st["hops_bucketed"])
     telemetry.counter_inc("levels_bucketed", st["levels_bucketed"])
     telemetry.counter_inc("levels_unrolled", st["levels_unrolled"])
     telemetry.counter_inc("bucket_padded_elems", st["padded_elems"])

@@ -200,6 +200,9 @@ class ShardedSimulator:
             telemetry.counter_inc(
                 "hop_events_simulated", rows * self.compiled.num_hops
             )
+            telemetry.counter_inc(
+                "blocks_scanned", plan.num_blocks * self.n_shards
+            )
             out = fn(
                 key, jnp.float32(plan.offered), jnp.float32(plan.gap),
                 jnp.float32(plan.nominal_gap),

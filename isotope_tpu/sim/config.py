@@ -163,7 +163,16 @@ class SimParams:
     # fix.  ``level_bucket_waste`` caps the padded/real element ratio
     # a bucket may cost (compiler/buckets.py); raise it to force wider
     # buckets (tests do), set ``bucketed_scan=False`` to fall back to
-    # the fully unrolled trace.  Results are bit-identical either way.
+    # the fully unrolled trace.  The two executors are pinned against
+    # each other in two places: on the CPU, bit for bit, by
+    # tests/test_levelscan.py (eager runs on every SimResults field,
+    # the default plan through the collector's per-service sums); on
+    # the chip by the pre-check of the benchmark's ``svc10k_served``
+    # cell, the one cell whose hops the buckets sweep - its
+    # deterministic quiet run is held to the plain walk's duration of
+    # every service (benchmark/harness/checks.py ``precheck``).  Tests
+    # on the CPU cannot see a fault of the chip's compiler: PERF.md
+    # section 6, PR 29, has the one that was found there.
     bucketed_scan: bool = True
     level_bucket_waste: float = 1.6
     # Critical-path blame attribution (metrics/attribution.py): when

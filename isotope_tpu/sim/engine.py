@@ -2198,6 +2198,7 @@ class Simulator:
                 "hop_events_simulated",
                 num_blocks * block * self.compiled.num_hops,
             )
+            telemetry.counter_inc("blocks_scanned", num_blocks)
             with self._detail_ctx():
                 return fn(
                     key, jnp.float32(offered), jnp.float32(pace),

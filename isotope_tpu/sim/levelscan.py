@@ -244,12 +244,21 @@ def _dslice(seg: jax.Array, start: jax.Array, width: int) -> jax.Array:
 
 
 def gather_levels(stacked: jax.Array, sizes: Tuple[int, ...]) -> jax.Array:
-    """(Lb, N, B) stacked per-level values -> (N, sum(sizes)) hop order."""
-    L, n, B = stacked.shape
-    cols = np.concatenate(
-        [l * B + np.arange(s) for l, s in enumerate(sizes)]
+    """(Lb, N, B) stacked per-level values -> (N, sum(sizes)) hop order.
+
+    One static slice a level, concatenated.  The one-gather form this
+    replaces (``moveaxis(stacked, 0, 1).reshape(n, Lb * B)[:, cols]``)
+    is the same selection and was right on the CPU; on the v5e, at
+    10,000 hops and 3,328 requests a block, the program holding it
+    dropped the widest bucket's result (its hops read the buffer
+    beneath: 2,956 services' durations were the bare CPU time), wrote
+    garbage into another bucket's level at 2,048 requests, or never
+    finished (PERF.md section 6, PR 29).  Slices and a concatenate have
+    no index vector for the compiler to expand.
+    """
+    return jnp.concatenate(
+        [stacked[l, :, :s] for l, s in enumerate(sizes)], axis=1
     )
-    return jnp.moveaxis(stacked, 0, 1).reshape(n, L * B)[:, cols]
 
 
 # ---------------------------------------------------------------------------

@@ -92,6 +92,30 @@ def mmk_params(
     )
 
 
+#: ``jax.random.uniform`` draws float32 from the lattice k * 2**-23,
+#: k = 0 .. 2**23 - 1: it is exactly 0 once in 2**23 draws
+_LATTICE_STEP = 2.0**-23
+
+
+def delay_coin(uniform: jax.Array, p_wait: jax.Array) -> jax.Array:
+    """The Erlang-C delay coin ``uniform < p_wait``, with the lattice
+    point ``uniform == 0`` no delay where ``p_wait`` is under one
+    lattice step.
+
+    A plain-uniform draw is exactly 0 with probability 2**-23, and 0 is
+    under ANY positive ``p_wait``: a station that should delay one
+    request in 1e10 delayed one in 8e6, each by the 46 mean waits the
+    conditional draw's clamp gives ``uniform == 0``.  Below one step the
+    lattice cannot resolve ``p_wait`` at all, so that point stands for
+    no delay there (a quiet run has no wait); from one step up the coin
+    fires ceil(p_wait * 2**23) times in 2**23 as before, and every draw
+    with ``uniform > 0`` is untouched at any ``p_wait``.
+    """
+    return (uniform < p_wait) & (
+        (uniform > 0.0) | (p_wait >= _LATTICE_STEP)
+    )
+
+
 def sample_wait(
     params: QueueParams,
     uniform: jax.Array,
@@ -103,7 +127,7 @@ def sample_wait(
     the station parameters (typically (N, H) vs per-hop-gathered params).
     """
     wait = exponential / params.wait_rate
-    return jnp.where(uniform < params.p_wait, wait, 0.0)
+    return jnp.where(delay_coin(uniform, params.p_wait), wait, 0.0)
 
 
 def sample_wait_conditional(
@@ -122,7 +146,7 @@ def sample_wait_conditional(
     # floor must stay in f32 normal range: subnormals (e.g. 1e-38) are
     # flushed to zero on TPU/CPU XLA, which would let u == 0 produce inf
     return jnp.where(
-        uniform < p_wait,
+        delay_coin(uniform, p_wait),
         -jnp.log(jnp.maximum(ratio, 1e-20)) / wait_rate,
         0.0,
     )

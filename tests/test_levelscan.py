@@ -26,11 +26,12 @@ from isotope_tpu.compiler.buckets import (
     UnrolledLevelPlan,
     plan_segments,
 )
+from isotope_tpu.metrics.prometheus import MetricsCollector
 from isotope_tpu.models.generators import realistic_topology, tree_topology
 from isotope_tpu.models.graph import ServiceGraph
 from isotope_tpu.sim import LoadModel, SimParams, Simulator
 from isotope_tpu.sim.config import OPEN_LOOP, ChaosEvent
-from isotope_tpu.sim.levelscan import ScanBucket
+from isotope_tpu.sim.levelscan import ScanBucket, gather_levels
 
 KEY = jax.random.PRNGKey(11)
 OPEN = LoadModel(kind="open", qps=500.0)
@@ -217,6 +218,55 @@ def test_run_summary_equivalent():
     np.testing.assert_allclose(
         np.asarray(s1.latency_hist), np.asarray(s2.latency_hist)
     )
+
+
+@pytest.mark.parametrize("dtype", [np.float32, bool])
+def test_gather_levels_takes_each_levels_real_lanes(dtype):
+    """(Lb, N, B) stacked scan outputs -> (N, sum(sizes)) in hop order:
+    the selection the one-gather form made (level l's first ``sizes[l]``
+    lanes), which the chip's compiler got wrong at svc10k's size."""
+    sizes, n, B = (3, 7, 5, 1), 4, 7
+    rng = np.random.default_rng(0)
+    stacked = rng.random((len(sizes), n, B)) > 0.5
+    if dtype is np.float32:
+        stacked = rng.random((len(sizes), n, B)).astype(np.float32)
+    cols = np.concatenate(
+        [l * B + np.arange(s) for l, s in enumerate(sizes)])
+    want = np.moveaxis(stacked, 0, 1).reshape(n, len(sizes) * B)[:, cols]
+    got = np.asarray(gather_levels(jnp.asarray(stacked), sizes))
+    assert got.dtype == stacked.dtype
+    np.testing.assert_array_equal(got, want)
+
+
+def test_default_plan_equals_unrolled_through_the_collector():
+    """The default plan of a 500-service multitier graph (the shape of
+    the benchmark's ``svc10k``) sweeps most hops in scan buckets, and
+    the collector's per-service tables come out as the unrolled
+    trace's: the counts exactly, the float32 sums to the last bit.
+    This pins the CPU; the chip is pinned by the pre-check of the
+    ``svc10k_served`` cell (PERF.md section 6, PR 29)."""
+    compiled = compile_graph(ServiceGraph.decode(
+        realistic_topology(500, archetype="multitier", seed=0)))
+    bucketed = Simulator(compiled, SimParams())
+    unrolled = Simulator(compiled, SimParams(**UNROLLED))
+    swept = sum(s.num_hops for s in bucketed._segments
+                if isinstance(s, ScanBucket))
+    assert swept > compiled.num_hops // 2 and _num_scan(unrolled) == 0
+    load = LoadModel(kind="closed", qps=200.0, connections=8)
+    collector = MetricsCollector(compiled)
+    a = bucketed.run_summary(load, 1024, KEY, block_size=256,
+                             collector=collector, trim=True)
+    b = unrolled.run_summary(load, 1024, KEY, block_size=256,
+                             collector=collector, trim=True)
+    for field in a.metrics._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(a.metrics, field)),
+            np.asarray(getattr(b.metrics, field)), err_msg=field)
+    for field in ("count", "hop_events", "latency_sum", "latency_min",
+                  "latency_max", "latency_hist"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(a, field)), np.asarray(getattr(b, field)),
+            err_msg=field)
 
 
 def test_default_on_engages_for_deep_chain():

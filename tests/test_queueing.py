@@ -89,6 +89,34 @@ def test_conditional_wait_zero_p_wait_is_zero():
     assert float(w[0]) == 0.0
 
 
+@pytest.mark.parametrize("sampler", ["two_tensor", "conditional"])
+@pytest.mark.parametrize("p_wait, fires", [
+    (0.0, False), (1e-10, False), (2.0**-23, True), (0.3, True)])
+def test_delay_coin_at_the_zero_lattice_point(sampler, p_wait, fires):
+    """``jax.random.uniform`` is exactly 0 once in 2**23 draws.  Under a
+    ``p_wait`` of less than one lattice step that draw is no delay (it
+    was one, of 46 mean waits, for ANY positive ``p_wait``: a quiet run
+    waited); from one step up the coin is the lattice's, as before."""
+    u = jnp.asarray([0.0])
+    p = jnp.asarray([p_wait], jnp.float32)
+    rate = jnp.asarray([100.0])
+    if sampler == "conditional":
+        w = queueing.sample_wait_conditional(p, rate, u)
+    else:
+        w = queueing.sample_wait(
+            queueing.QueueParams(p, rate, p, p < 0), u, jnp.asarray([1.0]))
+    assert bool(w[0] > 0) is fires
+    assert bool(jnp.isfinite(w[0]))
+
+
+def test_delay_coin_leaves_every_positive_draw_as_it_was():
+    u = jnp.asarray([2.0**-23, 1e-6, 0.25, 0.5])
+    for p_wait in (0.0, 1e-10, 2.0**-23, 3e-7, 0.3, 1.0):
+        p = jnp.full(u.shape, p_wait, jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(queueing.delay_coin(u, p)), np.asarray(u < p))
+
+
 def test_convolution_matches_mva_on_k1_networks():
     # the cross-check mva_load_dependent's docstring promises: on k=1
     # networks (where exact MVA is numerically sound) the stable Buzen
