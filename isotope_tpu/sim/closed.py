@@ -56,6 +56,8 @@ from typing import Dict, NamedTuple, Tuple
 import numpy as np
 from scipy.special import gammainc
 
+from isotope_tpu import telemetry
+
 # shared wait-quantile polynomial degree (tables_from_pi and the
 # engine's degenerate-row stubs must agree on the coefficient count)
 DEFAULT_QUANTILE_DEGREE = 10
@@ -260,25 +262,64 @@ def mva_load_dependent(
 
 
 def repairman_distribution(
-    sources: int, k: int, mu: float, theta: float
+    sources: int, k: np.ndarray, mu: float, theta: np.ndarray
 ) -> np.ndarray:
-    """Stationary census of an M/M/k//N station (machine repairman).
+    """Stationary census of M/M/k//N stations (machine repairman), one
+    row per station.
 
-    ``sources`` requests each cycle between a think phase of mean
-    ``theta`` and this station; birth rate (N - j)/theta, death rate
-    min(j, k) * mu.  Returns pi over j = 0..N (float64, normalized).
+    At station s, ``sources`` requests each cycle between a think phase
+    of mean ``theta[s]`` and the station: birth rate (N - j)/theta[s],
+    death rate min(j, k[s]) * mu.  ``k`` and ``theta`` are (S,); returns
+    ``pi`` (S, N + 1) over j = 0..N (float64, each row normalized).
+
+    The running sum over j is a sequential loop over the columns, one
+    addition and one subtraction a step in this order, on purpose: a
+    ``cumsum`` of the differences, or the two logs swapped, rounds
+    differently, and the tables built from this census feed bit-pinned
+    tests (tests/test_closed.py holds the per-station form).
     """
     n = int(sources)
-    pi = np.zeros(n + 1)
-    # log-space recursion for numerical range
-    logp = np.zeros(n + 1)
+    k = np.asarray(k, int)
+    theta = np.asarray(theta, np.float64)
+    j = np.arange(n)
+    # log-space recursion for numerical range, laid out (N, S) so that
+    # step j reads and writes one contiguous row of all stations
+    log_birth = np.log((n - j)[:, None] / theta)
+    log_death = np.log(np.minimum(j[:, None] + 1, k) * mu)
+    logp = np.zeros((n + 1, len(k)))
     for j_ in range(n):
-        birth = (n - j_) / theta
-        death = min(j_ + 1, k) * mu
-        logp[j_ + 1] = logp[j_] + np.log(birth) - np.log(death)
-    logp -= logp.max()
+        logp[j_ + 1] = logp[j_] + log_birth[j_] - log_death[j_]
+    # (S, N + 1) from here: a station's max and sum run along its row
+    logp = np.ascontiguousarray(logp.T)
+    logp -= logp.max(axis=1, keepdims=True)
     pi = np.exp(logp)
-    return pi / pi.sum()
+    return pi / pi.sum(axis=1, keepdims=True)
+
+
+@telemetry.phase("closed_rate.census")
+def _census_sweep(
+    v: np.ndarray,
+    k: np.ndarray,
+    mu: float,
+    cycle: float,
+    w: np.ndarray,
+    population: int,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """One Jacobi sweep of the finite-source decomposition over the
+    stations given (the callers pass the visited ones).
+
+    Every station's think time theta_s = cycle / v_s - W_s reads the OLD
+    ``w``: no station sees what another wrote in this sweep, so all of
+    them are one batched census.  An arriving request sees
+    ``population - 1`` sources.  Returns (pi (S, population), the new
+    mean response W (S,)).
+    """
+    telemetry.counter_inc("closed_rate_census_sweeps")
+    theta = np.maximum(cycle / v - w, 1e-9)
+    pi = repairman_distribution(population - 1, k, mu, theta)
+    queued = np.maximum(np.arange(population) - k[:, None] + 1, 0)
+    mean_wait = (pi * queued).sum(axis=1) / (k * mu)
+    return pi, mean_wait + 1.0 / mu
 
 
 def fork_join_decomposition(
@@ -303,6 +344,12 @@ def fork_join_decomposition(
     ``cycle_visits``).  Damped fixed point; an arriving request sees
     the census with C-1 sources (finite-source arrival theorem).
 
+    Each sweep is Jacobi: every theta_s reads the old ``w``, and the
+    damping toward the new one comes after the sweep.  That is what
+    makes the batched census (``_census_sweep``, all visited stations
+    at once) exactly the per-station loop it replaced.  A station with
+    no visits keeps a zero row of ``pi_seen`` and its ``w``.
+
     Returns (lambda(N), pi_seen[(S, N)], cycle_s).
     """
     v = np.asarray(visits, np.float64)
@@ -319,17 +366,9 @@ def fork_join_decomposition(
         cycle_new = z + float((cv * w).sum())
         cycle = 0.5 * cycle + 0.5 * cycle_new
         w_new = w.copy()
-        for s in range(S):
-            if not active[s]:
-                continue
-            theta = max(cycle / v[s] - w[s], 1e-9)
-            pi = repairman_distribution(N - 1, int(k[s]), mu, theta)
-            pi_seen[s, : len(pi)] = pi
-            j = np.arange(len(pi))
-            mean_wait = float(
-                (pi * np.maximum(j - k[s] + 1, 0)).sum()
-            ) / (k[s] * mu)
-            w_new[s] = mean_wait + 1.0 / mu
+        pi_seen[active], w_new[active] = _census_sweep(
+            v[active], k[active], mu, cycle, w[active], N
+        )
         if float(np.abs(w_new - w).max()) < tol / mu:
             w = w_new
             break
@@ -396,25 +435,23 @@ def repairman_marginals(
     mean response W_s.  Used by the engine's self-consistent fork-join
     fixed point (the cycle is re-measured from the engine's own
     fork-join composition each iteration).
+
+    The sweep is Jacobi (every theta_s reads ``w_prev``), so the
+    visited stations are solved in one batched census
+    (``_census_sweep``).  A station with no visits keeps a point mass
+    at 0 and its ``w_prev``.
     """
     v = np.asarray(visits, np.float64)
     k = np.asarray(replicas, int)
-    S = len(v)
     N = int(population)
-    pi_seen = np.zeros((S, N))
+    active = v > 1e-12
+    pi_seen = np.zeros((len(v), N))
     pi_seen[:, 0] = 1.0
-    w_new = np.asarray(w_prev, np.float64).copy()
-    for s in range(S):
-        if v[s] <= 1e-12:
-            continue
-        theta = max(cycle_s / v[s] - w_prev[s], 1e-9)
-        pi = repairman_distribution(N - 1, int(k[s]), mu, theta)
-        pi_seen[s, : len(pi)] = pi
-        j = np.arange(len(pi))
-        mean_wait = float(
-            (pi * np.maximum(j - k[s] + 1, 0)).sum()
-        ) / (k[s] * mu)
-        w_new[s] = mean_wait + 1.0 / mu
+    w_prev = np.asarray(w_prev, np.float64)
+    w_new = w_prev.copy()
+    pi_seen[active], w_new[active] = _census_sweep(
+        v[active], k[active], mu, cycle_s, w_prev[active], N
+    )
     return pi_seen, w_new
 
 

@@ -122,6 +122,9 @@ def test_served_call_counters(served):
     assert snap.counters["graphs_decoded"] == 1
     assert snap.counters["closed_rate_pilot_runs"] >= 1
     assert snap.counters["artifact_bytes_written"] > 1000
+    # a paced call never reaches sim/closed.py's census
+    assert "closed_rate_census_sweeps" not in snap.counters
+    assert "closed_rate.census" not in snap.phases
 
 
 def test_sharded_call_accrues_its_phases(served, tmp_path):
@@ -147,6 +150,11 @@ def test_saturated_solve_is_the_mva_child(served, tmp_path):
     assert 0 < snap.phases["closed_rate.mva"] <= \
         snap.phases["closed_rate.solve"]
     assert "closed_rate.pilot" not in snap.phases
+    # canonical.yaml has a concurrent group: the tables come from the
+    # fork-join decomposition, every sweep of it one batched census
+    assert snap.counters["closed_rate_census_sweeps"] >= 1
+    assert 0 < snap.phases["closed_rate.census"] <= \
+        snap.phases["closed_rate.mva"]
 
 
 # -- (c) device scopes and their map ---------------------------------------
