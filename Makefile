@@ -6,7 +6,7 @@ PY ?= python
 QPS ?= 1000
 DURATION ?= 120s
 
-.PHONY: test lint vet-smoke grad-smoke bench telemetry-smoke \
+.PHONY: test lint vet-smoke grad-smoke telemetry-smoke \
 	resilience-smoke \
 	attribution-smoke sparse-smoke timeline-smoke multihost-smoke \
 	policies-smoke rollout-smoke lb-smoke ensemble-smoke \
@@ -76,15 +76,6 @@ grad-smoke:
 		assert any('lt' in k['kills'][0] for k in err), err; \
 		print('grad-smoke: all', len(names), 'knobs classified,', \
 		      'killer named:', err[0]['kills'][0])"
-
-# bench prints the one-line JSON capture AND gates it against the
-# previous round's driver capture (>15% per-case regression fails).
-# No pipe: a bench.py crash must fail the target, not hand an empty
-# capture to the regression gate.
-bench:
-	$(PY) bench.py > .bench_capture.json
-	@cat .bench_capture.json
-	$(PY) tools/bench_regress.py .bench_capture.json
 
 # tiny end-to-end engine-telemetry check: run a 3-service chain with
 # --telemetry=detail (segment fences armed) and validate the emitted
@@ -192,7 +183,7 @@ timeline-smoke:
 
 # sparse-executor end-to-end check: force the non-dense encodings
 # (sparse_level_elems lowered) on a small star graph, run the dense /
-# tiled / sparse / tiled+pallas executors, and diff their summaries —
+# tiled / sparse executors, and diff their summaries —
 # counts must be equal, latency sums within f32 reduction noise.
 sparse-smoke:
 	$(PY) tools/sparse_smoke.py

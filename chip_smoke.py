@@ -91,14 +91,6 @@ def _bytes_reserved():
     return (jax.devices()[0].memory_stats() or {}).get("bytes_reserved")
 
 
-def _census_impl():
-    """Which census implementation the last-built engine calls (the
-    engine stamps it into the run record's meta)."""
-    from isotope_tpu import telemetry
-
-    return telemetry.get_meta("census")
-
-
 def _cli(argv) -> tuple:
     """Run ``isotope-tpu <argv>`` in-process: (rc, stdout, seconds)."""
     from isotope_tpu import cli
@@ -214,7 +206,6 @@ def phase_simulate(topo: str, hops: int, sizes: Sizes, out_dir: str) -> bool:
         "peak_device_bytes": telemetry.record_device_memory(),
         # XLA's program temps are reserved, not counted "in use"
         "device_bytes_reserved": _bytes_reserved(),
-        "census": _census_impl(),
         "problems": problems,
     })
     return ok
@@ -380,8 +371,7 @@ def phase_mesh(sizes: Sizes, out_dir: str) -> bool:
     ok = not problems
     _emit({"phase": "mesh", "ok": ok, "devices": n_dev,
            "one_device": one, "default_mesh": runs.get("default"),
-           "mesh_2x2": runs.get("2x2"), "census": _census_impl(),
-           "problems": problems})
+           "mesh_2x2": runs.get("2x2"), "problems": problems})
     return ok
 
 

@@ -402,8 +402,7 @@ def comm_table(
     and — when the layout has a DCN axis — the cross-slice ``psum`` of
     both groups (issued LAST, on the already-scattered tiles, so DCN
     carries 1/svc of the per-service state).  ``num_merges`` scales the
-    whole table (1 = the post-scan merge; collective/compute overlap
-    issues one merge per block).
+    whole table (1 = the post-scan merge).
 
     Bytes are per-shard payloads; ``time_s`` prices each row with the
     ICI/DCN constants above.

@@ -200,15 +200,6 @@ def _add_mesh_args(parser) -> None:
              "crosses DCN), or 'data=4,svc=2,slice=1'.  Also env "
              "$ISOTOPE_MESH; default: the TOML mesh_data/mesh_svc "
              "keys, else all devices on the data axis")
-    parser.add_argument(
-        "--overlap", action="store_true",
-        help="overlap the sharded metric-merge collectives with the "
-             "next request block's compute (double-buffered carry; "
-             "hides DCN merge latency).  Identical results up to f32 "
-             "reduction order; off by default (byte-identical "
-             "single-merge path).  Applies to the main summary run — "
-             "the --attribution/--timeline diagnostic passes keep "
-             "their single post-scan merge")
 
 
 def _add_vet_arg(parser) -> None:
@@ -473,7 +464,6 @@ def run_simulate(args) -> int:
             policies=args.policies,
             rollouts=args.rollouts,
             mesh_spec=args.mesh,
-            overlap=args.overlap,
             **_ensemble_config_kwargs(args),
             **extra,
         )
@@ -801,8 +791,6 @@ def run_sweep(args) -> int:
             config = dataclasses.replace(config, attribution=True)
         if args.mesh:
             config = dataclasses.replace(config, mesh_spec=args.mesh)
-        if args.overlap and not config.overlap:
-            config = dataclasses.replace(config, overlap=True)
         if args.policies and not config.policies:
             config = dataclasses.replace(config, policies=True)
         if args.rollouts and not config.rollouts:

@@ -14,8 +14,7 @@ XLA again:
   executable only when every baked constant is byte-identical.
 - **Persistent on-disk cache** (:func:`enable_persistent_cache`): JAX's
   compilation cache, keyed by XLA on the optimized HLO, so separate
-  *processes* (bench.py's per-case subprocesses, repeated CLI runs of
-  one suite) skip the XLA backend compile entirely.  ONE rule picks
+  *processes* (repeated CLI runs of one suite) skip the XLA backend compile entirely.  ONE rule picks
   the directory: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
   already points there and this module sets no other; otherwise
   ``<checkout>/.xla-cache``, resolved from this package's location —

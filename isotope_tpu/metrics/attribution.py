@@ -582,6 +582,67 @@ def merge_host(shards: Sequence[AttributionSummary]) -> AttributionSummary:
     return acc
 
 
+def merge_collective(attr: AttributionSummary, axes) -> AttributionSummary:
+    """The mesh merge of per-shard summaries (inside ``shard_map``):
+    ``psum`` for the O(H) / O(S * buckets) blame accumulators,
+    ``all_gather`` + ``top_k`` for the O(K * H) exemplar batch, so
+    every shard returns the same global top-K.  ``tail_cut`` is
+    identical on every shard and stays out of the psum."""
+    ex = attr.exemplars
+    with jax.named_scope("merge/attribution"):
+        psummed = jax.tree.map(
+            lambda x: jax.lax.psum(x, axes),
+            attr._replace(tail_cut=jnp.float32(0.0), exemplars=None),
+        )
+    merged = psummed._replace(tail_cut=attr.tail_cut)
+    if ex is not None:
+        k = ex.latency.shape[0]
+
+        @jax.named_scope("merge/exemplars")
+        def gather(x):
+            # one new leading axis of size mesh.size; fold it into
+            # the K axis so top_k sees every shard's candidates
+            y = jax.lax.all_gather(x, axes)
+            return y.reshape((-1,) + x.shape[1:])
+
+        cat = jax.tree.map(gather, ex)
+        _, keep = jax.lax.top_k(cat.latency, k)
+        merged = merged._replace(
+            exemplars=jax.tree.map(lambda a: a[keep], cat)
+        )
+    return merged
+
+
+def observer(tables: AttrTables, top_k: int, block: int,
+             tail_cut: Optional[jax.Array] = None,
+             packed: bool = False):
+    """The blame pass as a block-scan observer (sim/blockscan.py):
+    per-block summaries stack and sum, the top-K exemplar batch rides
+    the carry.  ``tail_cut`` (a traced scalar) arms the tail set."""
+    from isotope_tpu.sim.blockscan import Observer
+
+    k0 = min(top_k, block) if top_k > 0 else 0
+
+    def step(res, ex):
+        a, ex = attribute_block(
+            res, tables, tail_cut=tail_cut, top_k=top_k, ex_state=ex,
+            packed=packed,
+        )
+        return ex, a
+
+    return Observer(
+        # the exemplar carry needs concrete leaves before the scan
+        # starts: -inf latencies any real request displaces
+        init=lambda: (
+            empty_exemplars(k0, tables.num_hops) if k0 > 0 else None
+        ),
+        step=step,
+        reduce=reduce_stacked,
+        merge_collective=merge_collective,
+        merge_host=merge_host,
+    )
+
+
 # -- host-side tables -------------------------------------------------------
 
 

@@ -158,23 +158,6 @@ class MetricsCollector:
 
     # -- device-side collection (jittable) --------------------------------
 
-    def zeros(self) -> ServiceMetrics:
-        """An all-zero ServiceMetrics with this topology's shapes — the
-        identity of the ``+`` merge (the overlap pipeline's primer,
-        parallel/sharded.py)."""
-        S, E = self.compiled.num_services, len(self.edges)
-        nsb = len(SIZE_BUCKETS) + 1
-        return ServiceMetrics(
-            incoming_total=jnp.zeros(S),
-            outgoing_total=jnp.zeros(E),
-            outgoing_size_hist=jnp.zeros((E, nsb)),
-            outgoing_size_sum=jnp.zeros(E),
-            duration_hist=jnp.zeros((S, 2, _NB)),
-            duration_sum=jnp.zeros((S, 2)),
-            response_size_hist=jnp.zeros((S, 2, nsb)),
-            response_size_sum=jnp.zeros((S, 2)),
-        )
-
     def collect(self, res: SimResults) -> ServiceMetrics:
         # each accumulator traces under a scope of its own, so a device
         # profile can be read per accumulator (README: telemetry); the

@@ -393,6 +393,40 @@ def merge_host(shards: Sequence[TimelineSummary]) -> TimelineSummary:
     return acc._replace(window_s=np.asarray(shards[0].window_s))
 
 
+def merge_collective(tl: TimelineSummary, axes) -> TimelineSummary:
+    """The mesh merge of per-shard summaries (inside ``shard_map``): a
+    ``psum`` — windows align because all shards share the absolute
+    sim-time axis.  ``window_s`` is identical on every shard and stays
+    out of it (the attribution ``tail_cut`` idiom)."""
+    with jax.named_scope("merge/timeline"):
+        psummed = jax.tree.map(
+            lambda x: jax.lax.psum(x, axes),
+            tl._replace(window_s=jnp.float32(0.0)),
+        )
+    return psummed._replace(window_s=tl.window_s)
+
+
+def observer(spec: TimelineSpec, packed: bool = False):
+    """The flight recorder as a block-scan observer
+    (sim/blockscan.py).  It accumulates in the CARRY (not stacked ys):
+    device cost stays O(S * W) no matter how many blocks the run
+    scans."""
+    from isotope_tpu.sim.blockscan import Observer
+
+    def step(res, acc):
+        return accumulate(
+            acc, timeline_block(res, spec, packed=packed)
+        ), None
+
+    return Observer(
+        init=lambda: zeros_summary(spec, packed=packed),
+        step=step,
+        reduce=lambda ys, acc: acc,
+        merge_collective=merge_collective,
+        merge_host=merge_host,
+    )
+
+
 # -- host-side derivations ---------------------------------------------------
 
 

@@ -19,16 +19,14 @@ the only communication is the metric reduction — the design that makes
 
 Multi-host (DCN) awareness: a mesh with a ``slice`` axis reduces the
 ICI axes first and crosses DCN last, on already-scattered per-service
-tiles; ``SimParams.overlap=True`` additionally pipelines the merge
-collectives one block behind the compute (``_overlap_body``) so DCN
-latency hides behind the next block's event sweep.  An
-:class:`~isotope_tpu.parallel.mesh.EmulatedMesh` runs the whole thing
-shard-by-shard on one device — any host count, no pod required.
+tiles.  An :class:`~isotope_tpu.parallel.mesh.EmulatedMesh` runs the
+whole thing shard-by-shard on one device — any host count, no pod
+required.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,36 +42,19 @@ from isotope_tpu.resilience import faults
 from isotope_tpu.compiler.program import CompiledGraph
 from isotope_tpu.metrics.prometheus import MetricsCollector, ServiceMetrics
 from isotope_tpu.parallel.mesh import SLICE_AXIS, SVC_AXIS, EmulatedMesh
+from isotope_tpu.sim import blockscan
+from isotope_tpu.sim.blockscan import RunPlan
 from isotope_tpu.sim.config import OPEN_LOOP, LoadModel, SimParams
 from isotope_tpu.sim.engine import Simulator
 from isotope_tpu.sim.summary import (
     RunSummary,
     reduce_stacked,
     summarize,
-    summary_accumulate,
-    zeros_summary,
 )
 
 # back-compat alias: the sharded path now returns the same summary type
 # the single-device scan path produces
 ShardedSummary = RunSummary
-
-
-class _RunPlan(NamedTuple):
-    """Everything a run's physical execution shape depends on — shared
-    between the shard_map path and the single-device emulation so the
-    degradation ladder reproduces the exact same request streams."""
-
-    offered: float
-    gap: float
-    nominal_gap: float
-    conns_local: int
-    block: int
-    num_blocks: int
-    window: Tuple[float, float]
-    sat_conns: int
-    kind: str
-    trim: bool
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
@@ -186,8 +167,7 @@ class ShardedSimulator:
         # up to the return of the async call (the first call of a
         # program also traces and compiles in here)
         with telemetry.phase("summary.dispatch"):
-            fn = self._get(plan.block, plan.num_blocks, plan.kind,
-                           plan.conns_local, plan.trim, plan.sat_conns)
+            fn = self._get(plan)
             faults.check("sharded.compute")
             if self.dcn_axes:
                 # the dropped-DCN-collective chaos site: a mesh with a
@@ -203,12 +183,7 @@ class ShardedSimulator:
             telemetry.counter_inc(
                 "blocks_scanned", plan.num_blocks * self.n_shards
             )
-            out = fn(
-                key, jnp.float32(plan.offered), jnp.float32(plan.gap),
-                jnp.float32(plan.nominal_gap),
-                jnp.float32(plan.window[0]), jnp.float32(plan.window[1]),
-                vis, windows,
-            )
+            out = fn(*self._call_args(plan, key, vis, windows))
         if telemetry.detail_enabled():
             with telemetry.phase("sharded.gather"):
                 jax.block_until_ready(out.count)
@@ -218,72 +193,28 @@ class ShardedSimulator:
 
     def _plan_run(self, load, num_requests: int, key,
                   offered_qps=None, block_size: int = 65_536,
-                  trim: bool = False) -> _RunPlan:
-        """Resolve the physical run shape (see :class:`_RunPlan`)."""
+                  trim: bool = False) -> RunPlan:
+        """Resolve the physical run shape (``blockscan.plan_run``)
+        over this mesh's shards."""
         # every sharded entry point plans here: lb preconditions (no
         # saturated loads) + the lb.degraded_backend fault site
         self.sim._check_lb_load(load)
-        n_local = -(-num_requests // self.n_shards)
-        if load.kind == OPEN_LOOP:
-            offered = float(load.qps)
-            gap = 0.0
-            nominal_gap = 0.0
-            conns_local = 0
-            block = max(1, min(block_size, n_local))
-        else:
-            if load.connections % self.n_shards:
-                raise ValueError(
-                    f"closed-loop connections ({load.connections}) must "
-                    f"divide evenly over {self.n_shards} shards"
-                )
-            if offered_qps is None:
-                # saturated phased runs time-average per-phase rates
-                # over the REQUEST COUNT, so pass the real total (no
-                # pilot runs happen on that path); the pilot-based
-                # solver for paced loads keeps the small cap
-                n_solve = (
-                    num_requests
-                    if self.sim._saturated(load)
-                    else min(num_requests, 2048)
-                )
-                offered_qps = self.sim.solve_closed_rate(
-                    load, n_solve, key
-                )
-            offered = float(offered_qps)
-            gap = (
-                load.connections / load.qps
-                if load.qps is not None
-                else 0.0
-            )
-            nominal_gap = load.connections / offered
-            conns_local = max(load.connections // self.n_shards, 1)
-            # block_size is a soft HBM bound: when per-shard connections
-            # exceed it the block grows to ``conns_local`` requests
-            per = max(1, min(block_size, n_local) // conns_local)
-            block = per * conns_local
-        num_blocks = max(1, -(-n_local // block))
-        if trim:
-            from isotope_tpu.metrics.fortio import trim_window_bounds
-
-            window = trim_window_bounds(
-                num_blocks * block * self.n_shards, offered
-            )
-        else:
-            window = (0.0, float("inf"))
-        # saturated (-qps max): the finite-population wait law uses the
-        # TOTAL connection count — every shard's requests share the same
-        # service stations
-        sat_conns = (
-            load.connections if self.sim._saturated(load) else 0
+        # saturated phased runs time-average per-phase rates over the
+        # REQUEST COUNT, so the solver gets the real total (no pilot
+        # runs happen on that path); the pilot-based solver for paced
+        # loads keeps the small cap
+        n_solve = (
+            num_requests
+            if self.sim._saturated(load)
+            else min(num_requests, 2048)
         )
-        return _RunPlan(
-            offered=offered, gap=gap, nominal_gap=nominal_gap,
-            conns_local=conns_local, block=block, num_blocks=num_blocks,
-            window=window, sat_conns=sat_conns, kind=load.kind,
-            trim=trim,
+        return blockscan.plan_run(
+            self.sim, load, num_requests, key, shards=self.n_shards,
+            n_solve=n_solve, offered_qps=offered_qps,
+            block_size=block_size, trim=trim,
         )
 
-    def _args_put(self, plan: _RunPlan):
+    def _args_put(self, plan: RunPlan):
         """Per-run argument tables (visit fixed points, phase windows).
 
         args_put covers building + transferring them to the devices;
@@ -314,22 +245,49 @@ class ShardedSimulator:
                 f"one device"
             )
 
-    def _get(self, block: int, num_blocks: int, kind: str,
-             conns_local: int, trim: bool = False, sat_conns: int = 0):
-        cache_key = (block, num_blocks, kind, conns_local, trim, sat_conns)
+    @staticmethod
+    def _call_args(plan: RunPlan, key, vis, windows, *tail_cut):
+        """A planned run's traced arguments, for the mesh program and
+        (after the shard index) for its single-device replay."""
+        return (
+            key, jnp.float32(plan.offered), jnp.float32(plan.gap),
+            jnp.float32(plan.nominal_gap),
+            jnp.float32(plan.window[0]), jnp.float32(plan.window[1]),
+            vis, windows, *tail_cut,
+        )
+
+    @staticmethod
+    def _program_key(plan: RunPlan, attr, timeline) -> tuple:
+        """``(scan shape, cache-key tail)`` of a summary program: the
+        plain one keeps the key it always had, an observed one appends
+        its observers' static part."""
+        shape = (plan.block, plan.num_blocks, plan.kind,
+                 plan.conns_local, plan.trim, plan.sat_conns)
+        if attr is None and timeline is None:
+            return shape, shape
+        return shape, shape + (attr, timeline)
+
+    def _get(self, plan: RunPlan, attr=None, timeline=None):
+        """The jitted shard_map program of a planned run; ``attr`` /
+        ``timeline`` as in ``Simulator._get_summary``."""
+        shape, cache_key = self._program_key(plan, attr, timeline)
         if cache_key not in self._fns:
-            main = (
-                self._overlap_body
-                if self.sim.params.overlap
-                else self._body
-            )
-            body = partial(main, block, num_blocks, kind, conns_local,
-                           trim, sat_conns)
+            if attr is not None:
+                # eager: built inside the shard_map trace, the cached
+                # blame tables would hold tracers
+                self.sim._attribution_tables()
+            n_obs = (attr is not None) + (timeline is not None)
             mapped = _shard_map(
-                body,
+                partial(self._body, shape, attr, timeline),
                 mesh=self.mesh,
-                in_specs=tuple(P() for _ in range(8)),
-                out_specs=self._summary_out_specs(),
+                in_specs=tuple(
+                    P() for _ in range(8 + (attr is not None))
+                ),
+                # an observer's summary is replicated, leaf by leaf
+                out_specs=(
+                    (self._summary_out_specs(),) + (P(),) * n_obs
+                    if n_obs else self._summary_out_specs()
+                ),
             )
             mesh_sig = (
                 tuple(self.mesh.axis_names),
@@ -338,7 +296,8 @@ class ShardedSimulator:
             )
             self._fns[cache_key] = executable_cache.get_or_jit(
                 ("sharded", self.sim.signature, mesh_sig) + cache_key,
-                "sharded_summary", mapped,
+                "sharded_summary"
+                + blockscan.program_suffix(attr, timeline), mapped,
             )
         return self._fns[cache_key]
 
@@ -377,12 +336,9 @@ class ShardedSimulator:
 
     def _local_scan(
         self,
-        block: int,
-        num_blocks: int,
-        kind: str,
-        conns_local: int,
-        trim: bool,
-        sat_conns: int,
+        shape: tuple,
+        attr,
+        timeline,
         shard: jax.Array,
         key: jax.Array,
         offered_qps: jax.Array,
@@ -392,167 +348,49 @@ class ShardedSimulator:
         win_hi: jax.Array,
         visits_pc: jax.Array,
         phase_windows: jax.Array,
-    ) -> RunSummary:
-        """One shard's pre-collective block scan.
+        tail_cut=None,
+    ):
+        """One shard's pre-collective block scan: ``(RunSummary,
+        observed)``.
 
         Shared verbatim between the shard_map body and the single-device
-        emulation (``run_emulated``): the shard's RNG streams depend only
+        emulation (``_replay``): the shard's RNG streams depend only
         on ``shard``/``key``, so the degraded path replays bit-identical
         per-shard computations.
         """
         # disjoint fold domains: the rate solver's pilots consumed
         # fold_in(key, 0..iters) on the same base key
         local_key = jax.random.fold_in(key, 500_000 + shard)
-        c = max(conns_local, 1)
-        per = block // c
-
-        def block_body(carry, b):
-            t0, conn_t0, req_off = carry
-            kb = jax.random.fold_in(local_key, 1_000_000 + b)
-            res, t_end, conn_end = self.sim._simulate_core(
-                block,
-                kind,
-                conns_local,
-                kb,
-                offered_qps,
-                pace_gap,
-                # each shard generates 1/shards of the open-loop stream
-                offered_qps / self.n_shards,
-                nominal_gap,
-                t0,
-                conn_t0,
-                req_off,
-                sat_conns=sat_conns,
-                visits_pc=visits_pc,
-                phase_windows=phase_windows,
-            )
-            return (t_end, conn_end, req_off + per), summarize(
-                res, self.collector,
-                window=(win_lo, win_hi) if trim else None,
-            )
-
-        carry0 = (
-            jnp.float32(0.0),
-            jnp.zeros((c,), jnp.float32),
-            jnp.float32(0.0),
+        return blockscan.block_scan(
+            self.sim, self.collector, shape, local_key, offered_qps,
+            pace_gap, offered_qps, nominal_gap, win_lo, win_hi,
+            visits_pc, phase_windows,
+            self.sim._observers(shape[0], attr, timeline, tail_cut),
+            shards=self.n_shards,
         )
-        _, parts = jax.lax.scan(block_body, carry0, jnp.arange(num_blocks))
-        return reduce_stacked(parts)
 
-    def _body(
-        self,
-        block: int,
-        num_blocks: int,
-        kind: str,
-        conns_local: int,
-        trim: bool,
-        sat_conns: int,
-        key: jax.Array,
-        offered_qps: jax.Array,
-        pace_gap: jax.Array,
-        nominal_gap: jax.Array,
-        win_lo: jax.Array,
-        win_hi: jax.Array,
-        visits_pc: jax.Array,
-        phase_windows: jax.Array,
-    ) -> RunSummary:
+    def _body(self, shape: tuple, attr, timeline, *args):
+        """The shard_map body: the local scan, then the summary's
+        collective merge and each observer's."""
         both = tuple(self.mesh.axis_names)
         shard = jnp.int32(0)
         for a in self.mesh.axis_names:
             shard = shard * self.mesh.shape[a] + jax.lax.axis_index(a)
-        local = self._local_scan(
-            block, num_blocks, kind, conns_local, trim, sat_conns,
-            shard, key, offered_qps, pace_gap, nominal_gap,
-            win_lo, win_hi, visits_pc, phase_windows,
+        local, observed = self._local_scan(
+            shape, attr, timeline, shard, *args
         )
-        return self._merge_summary_collective(local, both)
-
-    def _overlap_body(
-        self,
-        block: int,
-        num_blocks: int,
-        kind: str,
-        conns_local: int,
-        trim: bool,
-        sat_conns: int,
-        key: jax.Array,
-        offered_qps: jax.Array,
-        pace_gap: jax.Array,
-        nominal_gap: jax.Array,
-        win_lo: jax.Array,
-        win_hi: jax.Array,
-        visits_pc: jax.Array,
-        phase_windows: jax.Array,
-    ) -> RunSummary:
-        """``_body`` with the merge collectives pipelined into the scan.
-
-        Double-buffered carry: block *k*'s summary rides the carry as
-        ``pending`` and its psum/psum_scatter merge is issued at the
-        TOP of step *k+1*, before that step's event sweep — the
-        collective's result is only consumed by the cheap
-        ``summary_accumulate`` fold, so the scheduler has a full
-        block's compute to hide the (DCN) merge latency behind.  Step 0
-        merges a zero primer (one extra tiny collective round per run);
-        the last block's merge happens after the scan, un-overlapped.
-
-        Identical RNG streams and per-block summaries to ``_body`` —
-        only the reduction ORDER differs (per-block cross-shard merge,
-        then across blocks, instead of blocks-then-shards), so
-        integer-valued fields match exactly and float sums to
-        reduction-order f32 noise (pinned by tests/test_multihost.py).
-        """
-        both = tuple(self.mesh.axis_names)
-        shard = jnp.int32(0)
-        for a in self.mesh.axis_names:
-            shard = shard * self.mesh.shape[a] + jax.lax.axis_index(a)
-        local_key = jax.random.fold_in(key, 500_000 + shard)
-        c = max(conns_local, 1)
-        per = block // c
-        S = self.compiled.num_services
-
-        def block_body(carry, b):
-            (t0, conn_t0, req_off), pending, acc = carry
-            acc = summary_accumulate(
-                acc, self._merge_summary_collective(pending, both)
-            )
-            kb = jax.random.fold_in(local_key, 1_000_000 + b)
-            res, t_end, conn_end = self.sim._simulate_core(
-                block, kind, conns_local, kb, offered_qps, pace_gap,
-                offered_qps / self.n_shards, nominal_gap, t0, conn_t0,
-                req_off,
-                sat_conns=sat_conns,
-                visits_pc=visits_pc,
-                phase_windows=phase_windows,
-            )
-            s = summarize(
-                res, self.collector,
-                window=(win_lo, win_hi) if trim else None,
-            )
-            return ((t_end, conn_end, req_off + per), s, acc), None
-
-        carry0 = (
-            (
-                jnp.float32(0.0),
-                jnp.zeros((c,), jnp.float32),
-                jnp.float32(0.0),
-            ),
-            # the pre-merge primer carries full-S metric shapes; the
-            # accumulator holds the post-scatter 1/svc tiles
-            zeros_summary(self.collector, S),
-            zeros_summary(self.collector, S,
-                          svc_rows=self.s_pad // self.n_svc),
-        )
-        (_, pending, acc), _ = jax.lax.scan(
-            block_body, carry0, jnp.arange(num_blocks)
-        )
-        return summary_accumulate(
-            acc, self._merge_summary_collective(pending, both)
+        merged = self._merge_summary_collective(local, both)
+        if not observed:
+            return merged
+        observers = self.sim._observers(shape[0], attr, timeline)
+        return (merged,) + tuple(
+            o.merge_collective(x, both)
+            for o, x in zip(observers, observed)
         )
 
     def _merge_summary_collective(self, local: RunSummary,
                                   both) -> RunSummary:
-        """The mesh metric reduction over one shard's RunSummary
-        (shared by the plain, overlap, and attributed bodies).
+        """The mesh metric reduction over one shard's RunSummary.
 
         DCN-aware ordering: the ICI axes (``data``/``svc`` — inside one
         slice/host) reduce first, the ``slice`` axis last; the
@@ -1505,21 +1343,10 @@ class ShardedSimulator:
         plan = self._plan_run(load, num_requests, key, offered_qps,
                               block_size, trim)
         telemetry.counter_inc("sharded_attributed_runs")
-        # build the blame tables EAGERLY: constants created inside the
-        # shard_map trace would be cached as tracers and leak
-        self.sim._attribution_tables()
-        fn = self._get_attr(plan, tail)
-        vis, windows = self._args_put(plan)
-        faults.check("sharded.compute")
-        out = fn(
-            key, jnp.float32(plan.offered), jnp.float32(plan.gap),
-            jnp.float32(plan.nominal_gap),
-            jnp.float32(plan.window[0]), jnp.float32(plan.window[1]),
+        return self._run_observed(
+            plan, key, "tail" if tail else "mean", None,
             jnp.float32(tail_cut if tail else np.inf),
-            vis, windows,
         )
-        faults.check("sharded.gather")
-        return out
 
     def run_attributed_emulated(
         self,
@@ -1544,208 +1371,16 @@ class ShardedSimulator:
             tail_cut = self.sim.estimate_tail_cut(
                 load, num_requests, key, block_size=block_size
             )
-        from isotope_tpu.metrics import attribution
-
         plan = self._plan_run(load, num_requests, key, offered_qps,
                               block_size, trim)
-        self.sim._attribution_tables()  # eager — see run_attributed
-        fn = self._get_local_attr_fn(plan, tail)
-        vis, windows = self._args_put(plan)
-        shards = []
-        with telemetry.phase("sharded.emulated"):
-            for s in range(self.n_shards):
-                out = fn(
-                    jnp.int32(s), key,
-                    jnp.float32(plan.offered), jnp.float32(plan.gap),
-                    jnp.float32(plan.nominal_gap),
-                    jnp.float32(plan.window[0]),
-                    jnp.float32(plan.window[1]),
-                    jnp.float32(tail_cut if tail else np.inf),
-                    vis, windows,
-                )
-                jax.block_until_ready(out[0].count)
-                shards.append(out)
-        summary = self._merge_shard_summaries([s for s, _ in shards])
-        return summary, attribution.merge_host([a for _, a in shards])
-
-    def _local_scan_attr(
-        self,
-        block: int,
-        num_blocks: int,
-        kind: str,
-        conns_local: int,
-        trim: bool,
-        sat_conns: int,
-        tail: bool,
-        shard: jax.Array,
-        key: jax.Array,
-        offered_qps: jax.Array,
-        pace_gap: jax.Array,
-        nominal_gap: jax.Array,
-        win_lo: jax.Array,
-        win_hi: jax.Array,
-        tail_cut: jax.Array,
-        visits_pc: jax.Array,
-        phase_windows: jax.Array,
-    ) -> Tuple[RunSummary, attribution.AttributionSummary]:
-        """One shard's pre-collective attributed block scan (the
-        ``_local_scan`` twin; identical RNG stream layout, so the
-        RunSummary half matches the unattributed path bit-for-bit)."""
-        # lazy: attribution-off paths never import the blame module
-        from isotope_tpu.metrics import attribution
-
-        tables = self.sim._attribution_tables()
-        top_k = self.sim.params.attribution_top_k
-        local_key = jax.random.fold_in(key, 500_000 + shard)
-        c = max(conns_local, 1)
-        per = block // c
-
-        def block_body(carry, b):
-            (t0, conn_t0, req_off), ex = carry
-            kb = jax.random.fold_in(local_key, 1_000_000 + b)
-            res, t_end, conn_end = self.sim._simulate_core(
-                block, kind, conns_local, kb, offered_qps, pace_gap,
-                offered_qps / self.n_shards, nominal_gap, t0, conn_t0,
-                req_off,
-                sat_conns=sat_conns,
-                visits_pc=visits_pc,
-                phase_windows=phase_windows,
-            )
-            s = summarize(
-                res, self.collector,
-                window=(win_lo, win_hi) if trim else None,
-            )
-            a, ex = attribution.attribute_block(
-                res, tables,
-                tail_cut=tail_cut if tail else None,
-                top_k=top_k, ex_state=ex,
-                packed=self.sim.params.packed_carries,
-            )
-            return ((t_end, conn_end, req_off + per), ex), (s, a)
-
-        k0 = min(top_k, block) if top_k > 0 else 0
-        H = self.compiled.num_hops
-        ex0 = (
-            attribution.empty_exemplars(k0, H)
-            if k0 > 0
-            else None
-        )
-        carry0 = (
-            (
-                jnp.float32(0.0),
-                jnp.zeros((c,), jnp.float32),
-                jnp.float32(0.0),
-            ),
-            ex0,
-        )
-        (_, ex_final), (parts, aparts) = jax.lax.scan(
-            block_body, carry0, jnp.arange(num_blocks)
-        )
-        return (
-            reduce_stacked(parts),
-            attribution.reduce_stacked(aparts, ex_final),
-        )
-
-    def _attr_body(
-        self,
-        block: int,
-        num_blocks: int,
-        kind: str,
-        conns_local: int,
-        trim: bool,
-        sat_conns: int,
-        tail: bool,
-        key: jax.Array,
-        offered_qps: jax.Array,
-        pace_gap: jax.Array,
-        nominal_gap: jax.Array,
-        win_lo: jax.Array,
-        win_hi: jax.Array,
-        tail_cut: jax.Array,
-        visits_pc: jax.Array,
-        phase_windows: jax.Array,
-    ):
-        both = tuple(self.mesh.axis_names)
-        shard = jnp.int32(0)
-        for a in self.mesh.axis_names:
-            shard = shard * self.mesh.shape[a] + jax.lax.axis_index(a)
-        summary, attr = self._local_scan_attr(
-            block, num_blocks, kind, conns_local, trim, sat_conns,
-            tail, shard, key, offered_qps, pace_gap, nominal_gap,
-            win_lo, win_hi, tail_cut, visits_pc, phase_windows,
-        )
-        merged_summary = self._merge_summary_collective(summary, both)
-        ex = attr.exemplars
-        with jax.named_scope("merge/attribution"):
-            psummed = jax.tree.map(
-                lambda x: jax.lax.psum(x, both),
-                attr._replace(tail_cut=jnp.float32(0.0), exemplars=None),
-            )
-        merged_attr = psummed._replace(tail_cut=attr.tail_cut)
-        if ex is not None:
-            k = ex.latency.shape[0]
-
-            @jax.named_scope("merge/exemplars")
-            def gather(x):
-                # one new leading axis of size mesh.size; fold it into
-                # the K axis so top_k sees every shard's candidates
-                y = jax.lax.all_gather(x, both)
-                return y.reshape((-1,) + x.shape[1:])
-
-            cat = jax.tree.map(gather, ex)
-            _, keep = jax.lax.top_k(cat.latency, k)
-            merged_attr = merged_attr._replace(
-                exemplars=jax.tree.map(lambda a: a[keep], cat)
-            )
-        return merged_summary, merged_attr
-
-    def _get_attr(self, plan: _RunPlan, tail: bool):
-        cache_key = (plan.block, plan.num_blocks, plan.kind,
-                     plan.conns_local, plan.trim, plan.sat_conns, tail)
-        key = ("sharded-attr",) + cache_key
-        if key not in self._fns:
-            from isotope_tpu.metrics import attribution
-
-            body = partial(self._attr_body, *cache_key)
-            ex_spec = (
-                attribution.ExemplarBatch(*([P()] * 7))
-                if self.sim.params.attribution_top_k > 0
-                else None
-            )
-            attr_spec = attribution.AttributionSummary(
-                *([P()] * 18), exemplars=ex_spec
-            )
-            mapped = _shard_map(
-                body,
-                mesh=self.mesh,
-                in_specs=tuple(P() for _ in range(9)),
-                out_specs=(self._summary_out_specs(), attr_spec),
-            )
-            mesh_sig = (
-                tuple(self.mesh.axis_names),
-                tuple(int(self.mesh.shape[a])
-                      for a in self.mesh.axis_names),
-                tuple(d.id for d in self.mesh.devices.flat),
-            )
-            self._fns[key] = executable_cache.get_or_jit(
-                ("sharded-attr", self.sim.signature, mesh_sig)
-                + cache_key,
-                "sharded_attr", mapped,
-            )
-        return self._fns[key]
-
-    def _get_local_attr_fn(self, plan: _RunPlan, tail: bool):
-        cache_key = (plan.block, plan.num_blocks, plan.kind,
-                     plan.conns_local, plan.trim, plan.sat_conns, tail)
-        full_key = ("sharded-attr-local", self.sim.signature,
-                    self.n_shards) + cache_key
-        return executable_cache.get_or_jit(
-            full_key, "local_attr", partial(self._local_scan_attr, *cache_key),
+        return self._replay(
+            plan, key, "tail" if tail else "mean", None,
+            jnp.float32(tail_cut if tail else np.inf),
         )
 
     # -- timeline runs (metrics/timeline.py) ----------------------------
 
-    def _timeline_plan(self, plan: _RunPlan, window_s):
+    def _timeline_plan(self, plan: RunPlan, window_s):
         """The static window grid for a sharded run: every shard bins
         into the SAME absolute sim-time grid (shard clocks all start at
         t=0), sized from the TOTAL request count and offered rate."""
@@ -1778,17 +1413,7 @@ class ShardedSimulator:
                               block_size, trim)
         tl_plan = self._timeline_plan(plan, window_s)
         telemetry.counter_inc("sharded_timeline_runs")
-        fn = self._get_tl(plan, tl_plan)
-        vis, windows = self._args_put(plan)
-        faults.check("sharded.compute")
-        out = fn(
-            key, jnp.float32(plan.offered), jnp.float32(plan.gap),
-            jnp.float32(plan.nominal_gap),
-            jnp.float32(plan.window[0]), jnp.float32(plan.window[1]),
-            vis, windows,
-        )
-        faults.check("sharded.gather")
-        return out
+        return self._run_observed(plan, key, None, tl_plan)
 
     def run_timeline_emulated(
         self,
@@ -1808,180 +1433,10 @@ class ShardedSimulator:
             raise ValueError(
                 "timeline runs need SimParams(timeline=True)"
             )
-        from isotope_tpu.metrics import timeline as timeline_mod
-
         plan = self._plan_run(load, num_requests, key, offered_qps,
                               block_size, trim)
-        tl_plan = self._timeline_plan(plan, window_s)
-        fn = self._get_local_tl_fn(plan, tl_plan)
-        vis, windows = self._args_put(plan)
-        shards = []
-        with telemetry.phase("sharded.emulated"):
-            for s in range(self.n_shards):
-                out = fn(
-                    jnp.int32(s), key,
-                    jnp.float32(plan.offered), jnp.float32(plan.gap),
-                    jnp.float32(plan.nominal_gap),
-                    jnp.float32(plan.window[0]),
-                    jnp.float32(plan.window[1]),
-                    vis, windows,
-                )
-                jax.block_until_ready(out[0].count)
-                shards.append(out)
-        summary = self._merge_shard_summaries([s for s, _ in shards])
-        return summary, timeline_mod.merge_host(
-            [t for _, t in shards]
-        )
-
-    def _local_scan_tl(
-        self,
-        block: int,
-        num_blocks: int,
-        kind: str,
-        conns_local: int,
-        trim: bool,
-        sat_conns: int,
-        tl_plan: Tuple[int, float],
-        shard: jax.Array,
-        key: jax.Array,
-        offered_qps: jax.Array,
-        pace_gap: jax.Array,
-        nominal_gap: jax.Array,
-        win_lo: jax.Array,
-        win_hi: jax.Array,
-        visits_pc: jax.Array,
-        phase_windows: jax.Array,
-    ):
-        """One shard's pre-collective timeline block scan (the
-        ``_local_scan`` twin; identical RNG stream layout, so the
-        RunSummary half matches the unrecorded path bit-for-bit)."""
-        from isotope_tpu.metrics import timeline as timeline_mod
-
-        spec = timeline_mod.build_spec(
-            self.compiled, tl_plan[0], tl_plan[1]
-        )
-        local_key = jax.random.fold_in(key, 500_000 + shard)
-        c = max(conns_local, 1)
-        per = block // c
-
-        def block_body(carry, b):
-            (t0, conn_t0, req_off), tl_acc = carry
-            kb = jax.random.fold_in(local_key, 1_000_000 + b)
-            res, t_end, conn_end = self.sim._simulate_core(
-                block, kind, conns_local, kb, offered_qps, pace_gap,
-                offered_qps / self.n_shards, nominal_gap, t0, conn_t0,
-                req_off,
-                sat_conns=sat_conns,
-                visits_pc=visits_pc,
-                phase_windows=phase_windows,
-            )
-            s = summarize(
-                res, self.collector,
-                window=(win_lo, win_hi) if trim else None,
-            )
-            # carry accumulation (not stacked ys): one O(S * W)
-            # recorder state per shard, independent of num_blocks
-            tl_acc = timeline_mod.accumulate(
-                tl_acc,
-                timeline_mod.timeline_block(
-                    res, spec, packed=self.sim.params.packed_carries
-                ),
-            )
-            return ((t_end, conn_end, req_off + per), tl_acc), s
-
-        carry0 = (
-            (
-                jnp.float32(0.0),
-                jnp.zeros((c,), jnp.float32),
-                jnp.float32(0.0),
-            ),
-            timeline_mod.zeros_summary(
-                spec, packed=self.sim.params.packed_carries
-            ),
-        )
-        (_, tl_final), parts = jax.lax.scan(
-            block_body, carry0, jnp.arange(num_blocks)
-        )
-        return reduce_stacked(parts), tl_final
-
-    def _tl_body(
-        self,
-        block: int,
-        num_blocks: int,
-        kind: str,
-        conns_local: int,
-        trim: bool,
-        sat_conns: int,
-        tl_plan: Tuple[int, float],
-        key: jax.Array,
-        offered_qps: jax.Array,
-        pace_gap: jax.Array,
-        nominal_gap: jax.Array,
-        win_lo: jax.Array,
-        win_hi: jax.Array,
-        visits_pc: jax.Array,
-        phase_windows: jax.Array,
-    ):
-        both = tuple(self.mesh.axis_names)
-        shard = jnp.int32(0)
-        for a in self.mesh.axis_names:
-            shard = shard * self.mesh.shape[a] + jax.lax.axis_index(a)
-        summary, tl = self._local_scan_tl(
-            block, num_blocks, kind, conns_local, trim, sat_conns,
-            tl_plan, shard, key, offered_qps, pace_gap, nominal_gap,
-            win_lo, win_hi, visits_pc, phase_windows,
-        )
-        merged_summary = self._merge_summary_collective(summary, both)
-        # window_s is identical on every shard — exclude it from the
-        # psum (the attribution tail_cut idiom)
-        with jax.named_scope("merge/timeline"):
-            psummed = jax.tree.map(
-                lambda x: jax.lax.psum(x, both),
-                tl._replace(window_s=jnp.float32(0.0)),
-            )
-        return merged_summary, psummed._replace(window_s=tl.window_s)
-
-    def _get_tl(self, plan: _RunPlan, tl_plan: Tuple[int, float]):
-        cache_key = (plan.block, plan.num_blocks, plan.kind,
-                     plan.conns_local, plan.trim, plan.sat_conns,
-                     tl_plan)
-        key = ("sharded-tl",) + cache_key
-        if key not in self._fns:
-            from isotope_tpu.metrics import timeline as timeline_mod
-
-            body = partial(self._tl_body, *cache_key)
-            n_fields = len(timeline_mod.TimelineSummary._fields)
-            tl_spec = timeline_mod.TimelineSummary(
-                *([P()] * n_fields)
-            )
-            mapped = _shard_map(
-                body,
-                mesh=self.mesh,
-                in_specs=tuple(P() for _ in range(8)),
-                out_specs=(self._summary_out_specs(), tl_spec),
-            )
-            mesh_sig = (
-                tuple(self.mesh.axis_names),
-                tuple(int(self.mesh.shape[a])
-                      for a in self.mesh.axis_names),
-                tuple(d.id for d in self.mesh.devices.flat),
-            )
-            self._fns[key] = executable_cache.get_or_jit(
-                ("sharded-tl", self.sim.signature, mesh_sig)
-                + cache_key,
-                "sharded_timeline", mapped,
-            )
-        return self._fns[key]
-
-    def _get_local_tl_fn(self, plan: _RunPlan,
-                         tl_plan: Tuple[int, float]):
-        cache_key = (plan.block, plan.num_blocks, plan.kind,
-                     plan.conns_local, plan.trim, plan.sat_conns,
-                     tl_plan)
-        full_key = ("sharded-tl-local", self.sim.signature,
-                    self.n_shards) + cache_key
-        return executable_cache.get_or_jit(
-            full_key, "local_timeline", partial(self._local_scan_tl, *cache_key),
+        return self._replay(
+            plan, key, None, self._timeline_plan(plan, window_s)
         )
 
     # -- protected co-sim runs (sim/policies.py + sim/rollout.py) -------
@@ -2446,33 +1901,10 @@ class ShardedSimulator:
         if with_pol:
             out = out + (pol_final,)
         if attr is not None:
-            # blame accumulators merge exactly like run_attributed:
-            # psum for the dense vectors, all_gather + top_k for the
-            # exemplar batch (every shard returns the global top-K)
-            local_attr = attribution.reduce_stacked(aparts, ex_final)
-            ex = local_attr.exemplars
-            with jax.named_scope("merge/attribution"):
-                psummed = jax.tree.map(
-                    lambda x: jax.lax.psum(x, both),
-                    local_attr._replace(
-                        tail_cut=jnp.float32(0.0), exemplars=None
-                    ),
-                )
-            merged_attr = psummed._replace(
-                tail_cut=local_attr.tail_cut
+            # blame accumulators merge exactly like run_attributed
+            merged_attr = attribution.merge_collective(
+                attribution.reduce_stacked(aparts, ex_final), both
             )
-            if ex is not None:
-                k = ex.latency.shape[0]
-
-                def gather(x):
-                    y = jax.lax.all_gather(x, both)
-                    return y.reshape((-1,) + x.shape[1:])
-
-                cat = jax.tree.map(gather, ex)
-                _, keep = jax.lax.top_k(cat.latency, k)
-                merged_attr = merged_attr._replace(
-                    exemplars=jax.tree.map(lambda a: a[keep], cat)
-                )
             out = out + (merged_attr,)
         return out
 
@@ -2687,12 +2119,12 @@ class ShardedSimulator:
             ),)
         return out
 
-    def _prot_cache_key(self, plan: _RunPlan, tl_plan, attr,
+    def _prot_cache_key(self, plan: RunPlan, tl_plan, attr,
                         roll: bool):
         return (plan.block, plan.num_blocks, plan.kind,
                 plan.conns_local, plan.trim, tl_plan, attr, roll)
 
-    def _get_prot(self, plan: _RunPlan, tl_plan: Tuple[int, float],
+    def _get_prot(self, plan: RunPlan, tl_plan: Tuple[int, float],
                   attr, roll: bool):
         cache_key = self._prot_cache_key(plan, tl_plan, attr, roll)
         key = ("sharded-prot",) + cache_key
@@ -2746,7 +2178,7 @@ class ShardedSimulator:
             )
         return self._fns[key]
 
-    def _get_local_prot_fn(self, plan: _RunPlan,
+    def _get_local_prot_fn(self, plan: RunPlan,
                            tl_plan: Tuple[int, float], attr,
                            roll: bool):
         cache_key = self._prot_cache_key(plan, tl_plan, attr, roll)
@@ -2787,41 +2219,63 @@ class ShardedSimulator:
 
         Results match the shard_map path to f32 reduction-order
         precision (<= 1 ULP on every field, measured bit-equal on CPU;
-        pinned by tests/test_resilience.py and tests/test_multihost.py).
-        The host merge always replays the overlap=off reduction order
-        (blocks within a shard, then shards): with ``overlap=True`` the
-        device path's per-block collective order differs by f32
-        reduction order only.
+        pinned by tests/test_resilience.py and tests/test_multihost.py):
+        the host merge replays the device's reduction order, blocks
+        within a shard, then shards.
         """
         plan = self._plan_run(load, num_requests, key, offered_qps,
                               block_size, trim)
         telemetry.counter_inc("sharded_emulated_runs")
         telemetry.gauge_set("shard_count", self.n_shards)
-        fn = self._get_local_fn(plan)
+        return self._replay(plan, key)
+
+    def _run_observed(self, plan: RunPlan, key, attr, timeline,
+                      *tail_cut):
+        """Dispatch a planned run's observed mesh program:
+        ``(RunSummary, *observed)``."""
+        fn = self._get(plan, attr, timeline)
+        vis, windows = self._args_put(plan)
+        faults.check("sharded.compute")
+        out = fn(*self._call_args(plan, key, vis, windows, *tail_cut))
+        faults.check("sharded.gather")
+        return out
+
+    def _replay(self, plan: RunPlan, key, attr=None, timeline=None,
+                *tail_cut):
+        """A planned run's mesh program replayed shard by shard on one
+        device, the collectives merged on host: the summary's by
+        ``_merge_shard_summaries``, each observer's by its
+        ``merge_host``.  Returns what the mesh program returns."""
+        fn = self._get_local_fn(plan, attr, timeline)
         vis, windows = self._args_put(plan)
         shards = []
         with telemetry.phase("sharded.emulated"):
             for s in range(self.n_shards):
-                out = fn(
-                    jnp.int32(s), key,
-                    jnp.float32(plan.offered), jnp.float32(plan.gap),
-                    jnp.float32(plan.nominal_gap),
-                    jnp.float32(plan.window[0]),
-                    jnp.float32(plan.window[1]),
-                    vis, windows,
-                )
+                out = fn(jnp.int32(s), *self._call_args(
+                    plan, key, vis, windows, *tail_cut
+                ))
                 # serialize: live memory stays bounded by ONE shard
-                jax.block_until_ready(out.count)
+                jax.block_until_ready(out[0].count)
                 shards.append(out)
-        return self._merge_shard_summaries(shards)
+        summary = self._merge_shard_summaries([s for s, _ in shards])
+        observers = self.sim._observers(plan.block, attr, timeline)
+        if not observers:
+            return summary
+        return (summary,) + tuple(
+            o.merge_host([obs[i] for _, obs in shards])
+            for i, o in enumerate(observers)
+        )
 
-    def _get_local_fn(self, plan: _RunPlan):
-        cache_key = (plan.block, plan.num_blocks, plan.kind,
-                     plan.conns_local, plan.trim, plan.sat_conns)
+    def _get_local_fn(self, plan: RunPlan, attr=None, timeline=None):
+        shape, cache_key = self._program_key(plan, attr, timeline)
+        if attr is not None:
+            self.sim._attribution_tables()  # eager — see _get
         full_key = ("sharded-local", self.sim.signature,
                     self.n_shards) + cache_key
         return executable_cache.get_or_jit(
-            full_key, "local_summary", partial(self._local_scan, *cache_key),
+            full_key,
+            "local_summary" + blockscan.program_suffix(attr, timeline),
+            partial(self._local_scan, shape, attr, timeline),
         )
 
     def _merge_shard_summaries(self, shards) -> RunSummary:

@@ -137,9 +137,6 @@ class ExperimentConfig:
     # "DATAxSVC[xSLICE]", or "data=4,svc=2,slice=1".  Overrides the
     # legacy mesh_data/mesh_svc pair when set.
     mesh_spec: Optional[str] = None
-    # collective/compute overlap on sharded runs (SimParams.overlap):
-    # merge collectives pipeline one block behind the event sweeps
-    overlap: bool = False
     labels: str = ""
     chaos: Tuple[ChaosEvent, ...] = ()
     churn: Tuple[TrafficSplit, ...] = ()
@@ -237,7 +234,6 @@ class ExperimentConfig:
             # the policy/rollout co-sims observe through the recorder
             timeline=self.timeline or self.policies or self.rollouts,
             timeline_window_s=self.timeline_window_s,
-            overlap=self.overlap,
             ensemble=max(int(self.ensemble), 0),
         )
 
@@ -521,7 +517,6 @@ def load_toml(path) -> ExperimentConfig:
         mesh_data=int(sim.get("mesh_data", 0)),
         mesh_svc=int(sim.get("mesh_svc", 1)),
         mesh_spec=sim.get("mesh"),
-        overlap=bool(sim.get("overlap", False)),
         labels=doc.get("labels", ""),
         chaos=tuple(chaos),
         churn=tuple(churn),

@@ -824,8 +824,7 @@ def _protected_rung_specs(is_sharded: bool, block: int):
     NOTE a half-block protected run is a DIFFERENT measurement: the
     control loops actuate at block boundaries, so halving the block
     halves the actuation lag.  That is exactly why ``degraded_to`` is
-    recorded on the result (and why bench_regress fails a capture
-    that degrades a previously-clean case)."""
+    recorded on the result."""
     half = max(256, block // 2)
     if is_sharded:
         return [
@@ -1757,9 +1756,7 @@ def run_experiment(
                     )
                 if degraded_to is not None:
                     # degradation is run METADATA: a sweep row that
-                    # came off a fallback rung must say so (and
-                    # bench_regress fails a capture that degrades a
-                    # previously-clean case)
+                    # came off a fallback rung must say so
                     flat["degraded_to"] = degraded_to
                 if pol_doc is not None:
                     # the row came from PROTECTED physics — a
@@ -1768,24 +1765,23 @@ def run_experiment(
                     flat["_policies"] = True
                     telemetry.set_meta("policies", "on")
                 if roll_doc is not None:
-                    # likewise for the rollout controller: bench
-                    # and bench_regress key on the marker so a
-                    # rollout-enabled case is never compared
-                    # against an open-loop twin
+                    # likewise for the rollout controller: the
+                    # marker keeps a rollout-enabled row from being
+                    # compared against an open-loop twin
                     flat["_rollout"] = True
                     telemetry.set_meta("rollouts", "on")
                 if lb_doc is not None:
                     # lb laws change the wait physics of every run
-                    # kind — the marker keeps bench_regress from
-                    # comparing an lb row against a fifo twin
+                    # kind — the marker keeps an lb row from being
+                    # compared against a fifo twin
                     flat["_lb"] = True
                     telemetry.set_meta("lb", "on")
                 if config.ingest:
                     # the row replays FITTED telemetry, not a
                     # hand-written topology — different
-                    # provenance; bench_regress keys on the
-                    # marker so an ingested replay is never
-                    # compared against a hand-written twin
+                    # provenance; the marker keeps an ingested
+                    # replay from being compared against a
+                    # hand-written twin
                     flat["_ingest"] = str(
                         config.ingest.get("label", "ingested")
                     )
@@ -1902,9 +1898,8 @@ def run_experiment(
                                 block_size=block,
                             )
                         search_doc = srch.to_doc(label)
-                        # the marker keeps bench_regress from
-                        # comparing a search-carrying row
-                        # against a plain twin
+                        # the marker keeps a search-carrying row
+                        # from being compared against a plain twin
                         flat["_search"] = (
                             search_spec_cfg.members
                         )

@@ -129,20 +129,9 @@ class SimParams:
     # falls back to the pure sparse encoding everywhere.
     sparse_tiling: bool = True
     sparse_tile_pmax: int = 64
-    # Pallas census kernel (native/census_pallas.py): fuse the per-step
-    # census / WaitGroup-max join (max with the sleep floor, step mask,
-    # busy row-sum, exclusive step prefix — today a chain of XLA ops)
-    # into one hand-written kernel.  None and False are both OFF, on
-    # every backend: the op-by-op XLA path.  True is an explicit
-    # request — interpreter mode off-TPU (the equivalence tests); on a
-    # TPU it compiles through Mosaic, which refuses the kernel as
-    # written (no ``cumsum`` lowering; P sits on the lane axis — see
-    # ROADMAP S9/D2), and the compiler's error propagates uncaught.
-    pallas_census: Optional[bool] = None
-    # Pack the census/blame carries where the <= 1 ULP pins allow:
+    # Pack the blame carries where the <= 1 ULP pins allow:
     # attribution hop counters / blame-histogram censuses accumulate as
-    # int32 (exact where f32 loses integers past 2^24) and the census
-    # kernel's step mask rides as bf16 (0/1 exact).  Latency/blame
+    # int32 (exact where f32 loses integers past 2^24).  Latency/blame
     # accumulators stay f32.  Attribution off is byte-identical either
     # way (the packing only touches attributed programs).  BOUND: any
     # single attributed run must keep every counter under 2^31 events
@@ -200,20 +189,6 @@ class SimParams:
     # hard cap on the window count; the planner widens windows (with a
     # warning) instead of letting the O(S * W) carries OOM the device
     timeline_max_windows: int = 256
-    # Collective/compute overlap (parallel/sharded.py): when True, the
-    # sharded runner issues each block's summary-merge collectives
-    # INSIDE the scan, one block late behind a double-buffered carry —
-    # block k's psum/psum_scatter results are consumed while block k+1
-    # computes, so DCN merge latency hides behind the next block's
-    # event sweep.  Off (default) keeps the historical single
-    # post-scan merge byte-identical; on matches off exactly on
-    # integer-valued fields and to reduction-order f32 noise on float
-    # sums (tests/test_multihost.py).  SCOPE: the plain summary path
-    # (ShardedSimulator.run) only — the attributed/timeline diagnostic
-    # passes keep their single post-scan merge (their O(K*H)/O(S*W)
-    # leaves merge once), and single-device Simulator runs ignore it
-    # (there is no collective to overlap).
-    overlap: bool = False
     # Scenario ensembles (sim/ensemble.py): the default Monte Carlo
     # fleet size of ``Simulator.run_ensemble`` when no explicit
     # EnsembleSpec is passed — N scenario variants (seeds, and

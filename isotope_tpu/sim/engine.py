@@ -1380,17 +1380,6 @@ class Simulator:
         self._plan_shapes = tuple(shapes)
         self._plan = tuple(plan)
         self._plan_sig = buckets.plan_signature(plan)
-        # -- Pallas census kernel flag (native/census_pallas.py) ------------
-        # None resolves to OFF on every backend: Mosaic refuses the
-        # kernel as written (ROADMAP S9/D2), so only an explicit True
-        # requests it — and on a TPU that raises the compiler's error.
-        self._pallas_census = bool(params.pallas_census)
-        self._census_mod = None
-        if self._pallas_census:
-            from isotope_tpu.native import census_pallas
-
-            self._census_mod = census_pallas
-
         # -- AOT shape signature (compiler/cache.py) ------------------------
         # Everything a traced entry point bakes in: the bucket plan, the
         # compiled graph's shape, and a content digest of every closed-
@@ -1589,10 +1578,6 @@ class Simulator:
         self._search_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
         self._rate_cache: Dict[tuple, float] = {}
         telemetry.counter_inc("simulators_built")
-        # which census implementation this engine's programs call
-        telemetry.set_meta(
-            "census", "pallas" if self._pallas_census else "xla"
-        )
 
     def _phase_reach_multipliers(self, svc_down_np: np.ndarray) -> np.ndarray:
         """(P, H) static reach multipliers from outage-driven script
@@ -2158,55 +2143,52 @@ class Simulator:
         the actual end isn't known until the scan finishes; the relative
         error is O(1/sqrt(N)) of the arrival process.
         """
-        if load.kind == OPEN_LOOP:
-            offered = float(load.qps)
-            pace = 0.0
-            nominal = 0.0
-            conns = 0
-            block = max(1, min(block_size, num_requests))
-        else:
-            conns = load.connections
-            offered = self.solve_closed_rate(load, num_requests, key,
-                                             fixed_point_iters)
-            pace = conns / load.qps if load.qps is not None else 0.0
-            nominal = conns / offered
-            # block_size is a soft HBM bound: each connection needs at
-            # least one request per block, so when connections > block_size
-            # the block grows to ``connections`` requests
-            per = max(1, min(block_size, num_requests) // conns)
-            block = per * conns
-        num_blocks = max(1, -(-num_requests // block))
-        if trim:
-            # lazy: metrics.fortio imports this module for its types
-            from isotope_tpu.metrics.fortio import trim_window_bounds
+        from isotope_tpu.sim import blockscan
 
-            window = trim_window_bounds(num_blocks * block, offered)
-        else:
-            window = (0.0, np.inf)
-        sat = self._saturated(load)
+        plan = blockscan.plan_run(
+            self, load, num_requests, key, block_size=block_size,
+            trim=trim, fixed_point_iters=fixed_point_iters,
+        )
         # up to the return of the async call (the first call of a
         # program also traces and compiles in here)
         with telemetry.phase("summary.dispatch"):
-            fn = self._get_summary(block, num_blocks, load.kind, conns,
-                                   collector, trim, sat=sat)
-            faults.check("engine.run")
-            self._check_lb_load(load)
-            telemetry.gauge_set("engine_block_requests", block)
-            telemetry.gauge_set("engine_num_blocks", num_blocks)
-            telemetry.counter_inc("requests_simulated", num_blocks * block)
+            fn = self._prepare_summary(load, plan, collector)
+            rows = plan.num_blocks * plan.block
+            telemetry.counter_inc("requests_simulated", rows)
             telemetry.counter_inc(
-                "hop_events_simulated",
-                num_blocks * block * self.compiled.num_hops,
+                "hop_events_simulated", rows * self.compiled.num_hops
             )
-            telemetry.counter_inc("blocks_scanned", num_blocks)
-            with self._detail_ctx():
-                return fn(
-                    key, jnp.float32(offered), jnp.float32(pace),
-                    jnp.float32(offered), jnp.float32(nominal),
-                    jnp.float32(window[0]), jnp.float32(window[1]),
-                    self._vis_arg(offered),
-                    self._windows_arg(offered, sat),
-                )
+            telemetry.counter_inc("blocks_scanned", plan.num_blocks)
+            return self._call_summary(fn, plan, key)
+
+    def _prepare_summary(self, load: LoadModel, plan, collector,
+                         attr: Optional[str] = None,
+                         timeline: Optional[Tuple[int, float]] = None):
+        """The block-scan program of a planned run
+        (sim/blockscan.py ``RunPlan``), checked and gauged."""
+        fn = self._get_summary(
+            plan.block, plan.num_blocks, plan.kind, plan.conns_local,
+            collector, plan.trim, sat=plan.sat_conns > 0, attr=attr,
+            timeline=timeline,
+        )
+        faults.check("engine.run")
+        self._check_lb_load(load)
+        telemetry.gauge_set("engine_block_requests", plan.block)
+        telemetry.gauge_set("engine_num_blocks", plan.num_blocks)
+        return fn
+
+    def _call_summary(self, fn, plan, key, *tail_cut):
+        with self._detail_ctx():
+            return fn(
+                key, jnp.float32(plan.offered), jnp.float32(plan.gap),
+                jnp.float32(plan.offered),
+                jnp.float32(plan.nominal_gap),
+                jnp.float32(plan.window[0]),
+                jnp.float32(plan.window[1]),
+                self._vis_arg(plan.offered),
+                self._windows_arg(plan.offered, plan.sat_conns > 0),
+                *tail_cut,
+            )
 
     # -- scenario ensembles (sim/ensemble.py) ---------------------------
 
@@ -3391,48 +3373,19 @@ class Simulator:
             raise ValueError(
                 "timeline runs need SimParams(timeline=True)"
             )
-        if load.kind == OPEN_LOOP:
-            offered = float(load.qps)
-            pace = 0.0
-            nominal = 0.0
-            conns = 0
-            block = max(1, min(block_size, num_requests))
-        else:
-            conns = load.connections
-            offered = self.solve_closed_rate(load, num_requests, key,
-                                             fixed_point_iters)
-            pace = conns / load.qps if load.qps is not None else 0.0
-            nominal = conns / offered
-            per = max(1, min(block_size, num_requests) // conns)
-            block = per * conns
-        num_blocks = max(1, -(-num_requests // block))
-        if trim:
-            from isotope_tpu.metrics.fortio import trim_window_bounds
+        from isotope_tpu.sim import blockscan
 
-            window = trim_window_bounds(num_blocks * block, offered)
-        else:
-            window = (0.0, np.inf)
-        sat = self._saturated(load)
+        plan = blockscan.plan_run(
+            self, load, num_requests, key, block_size=block_size,
+            trim=trim, fixed_point_iters=fixed_point_iters,
+        )
         tl_plan = self.plan_timeline_windows(
-            num_blocks * block, offered, window_s
+            plan.num_blocks * plan.block, plan.offered, window_s
         )
-        fn = self._get_summary(
-            block, num_blocks, load.kind, conns, collector, trim,
-            sat=sat, timeline=tl_plan,
-        )
-        faults.check("engine.run")
-        self._check_lb_load(load)
-        telemetry.gauge_set("engine_block_requests", block)
-        telemetry.gauge_set("engine_num_blocks", num_blocks)
+        fn = self._prepare_summary(load, plan, collector,
+                                   timeline=tl_plan)
         telemetry.counter_inc("timeline_runs")
-        with self._detail_ctx():
-            return fn(
-                key, jnp.float32(offered), jnp.float32(pace),
-                jnp.float32(offered), jnp.float32(nominal),
-                jnp.float32(window[0]), jnp.float32(window[1]),
-                self._vis_arg(offered),
-                self._windows_arg(offered, sat),
-            )
+        return self._call_summary(fn, plan, key)
 
     def run_policies(
         self,
@@ -4338,46 +4291,19 @@ class Simulator:
             tail_cut = self.estimate_tail_cut(
                 load, num_requests, key, block_size=block_size
             )
-        if load.kind == OPEN_LOOP:
-            offered = float(load.qps)
-            pace = 0.0
-            nominal = 0.0
-            conns = 0
-            block = max(1, min(block_size, num_requests))
-        else:
-            conns = load.connections
-            offered = self.solve_closed_rate(load, num_requests, key,
-                                             fixed_point_iters)
-            pace = conns / load.qps if load.qps is not None else 0.0
-            nominal = conns / offered
-            per = max(1, min(block_size, num_requests) // conns)
-            block = per * conns
-        num_blocks = max(1, -(-num_requests // block))
-        if trim:
-            from isotope_tpu.metrics.fortio import trim_window_bounds
+        from isotope_tpu.sim import blockscan
 
-            window = trim_window_bounds(num_blocks * block, offered)
-        else:
-            window = (0.0, np.inf)
-        sat = self._saturated(load)
-        fn = self._get_summary(
-            block, num_blocks, load.kind, conns, collector, trim,
-            sat=sat, attr="tail" if tail else "mean",
+        plan = blockscan.plan_run(
+            self, load, num_requests, key, block_size=block_size,
+            trim=trim, fixed_point_iters=fixed_point_iters,
         )
-        faults.check("engine.run")
-        self._check_lb_load(load)
-        telemetry.gauge_set("engine_block_requests", block)
-        telemetry.gauge_set("engine_num_blocks", num_blocks)
+        fn = self._prepare_summary(
+            load, plan, collector, attr="tail" if tail else "mean"
+        )
         telemetry.counter_inc("attributed_runs")
-        with self._detail_ctx():
-            return fn(
-                key, jnp.float32(offered), jnp.float32(pace),
-                jnp.float32(offered), jnp.float32(nominal),
-                jnp.float32(window[0]), jnp.float32(window[1]),
-                jnp.float32(tail_cut if tail else np.inf),
-                self._vis_arg(offered),
-                self._windows_arg(offered, sat),
-            )
+        return self._call_summary(
+            fn, plan, key, jnp.float32(tail_cut if tail else np.inf)
+        )
 
     def trace_entry_args(self, n: int, kind: str, connections: int = 0):
         """``(fn, abstract_args)`` for trace-only analysis.
@@ -4447,206 +4373,76 @@ class Simulator:
                      connections: int, collector, trim: bool = False,
                      sat: bool = False, attr: Optional[str] = None,
                      timeline: Optional[Tuple[int, float]] = None):
-        """Jitted scan-over-blocks program producing a RunSummary (and,
-        with ``attr`` set, an AttributionSummary alongside it).
+        """Jitted scan-over-blocks program (sim/blockscan.py) producing
+        a RunSummary and, after it, what the run's observers reduce.
 
-        ``attr=None`` keeps the historical scan program — the traced
-        signature and body are untouched, so attribution-off runs stay
-        byte-identical.  ``attr in ("mean", "tail")`` threads the blame
-        reduction through the same block scan: per-block blame vectors
-        stack and sum, the top-K exemplar state rides the carry, and
-        ``"tail"`` additionally weights a second accumulator set by
-        ``client_latency >= tail_cut`` (a traced scalar argument).
-
-        ``timeline=(num_windows, window_s)`` threads the flight
-        recorder (metrics/timeline.py) through the same scan instead:
-        per-block O(S * W) windowed series stack and sum next to the
-        RunSummary — mutually exclusive with ``attr``."""
-        from isotope_tpu.sim import summary as summary_mod
-
-        if attr is not None and timeline is not None:
-            raise ValueError(
-                "one scan reduces either blame or the timeline, "
-                "not both"
-            )
+        ``attr in ("mean", "tail")`` threads the blame reduction
+        through the block scan (an AttributionSummary; ``"tail"``
+        weights a second accumulator set by ``client_latency >=
+        tail_cut``, a traced scalar passed last);
+        ``timeline=(num_windows, window_s)`` the flight recorder (a
+        TimelineSummary).  With neither the program is the plain scan,
+        so observer-off runs stay byte-identical."""
         cache_key = (block, num_blocks, kind, connections,
                      collector is not None, trim, sat, attr, timeline)
         if cache_key not in self._summary_fns:
-            c = max(connections, 1)
-            per = block // c
+            from isotope_tpu.sim import blockscan
+
             if attr is not None:
-                from isotope_tpu.metrics import attribution
+                # eager: built inside the trace, the cached tables
+                # would hold tracers
+                self._attribution_tables()
+            shape = (block, num_blocks, kind, connections, trim,
+                     connections if sat else 0)
 
-                tables = self._attribution_tables()
-                top_k = self.params.attribution_top_k
-            if timeline is not None:
-                from isotope_tpu.metrics import timeline as timeline_mod
-
-                tspec = timeline_mod.build_spec(
-                    self.compiled, timeline[0], timeline[1]
+            def scanfn(key, offered_qps, pace_gap, arrival_qps,
+                       nominal_gap, win_lo, win_hi, visits_pc,
+                       phase_windows, tail_cut=None):
+                telemetry.record_trace(
+                    ("summary", self.signature[3]) + cache_key,
+                    tracing=isinstance(key, jax.core.Tracer),
+                    requests=block, hops=self.compiled.num_hops,
                 )
-
-            if timeline is not None:
-                def scanfn(key, offered_qps, pace_gap, arrival_qps,
-                           nominal_gap, win_lo, win_hi, visits_pc,
-                           phase_windows):
-                    telemetry.record_trace(
-                        ("summary", self.signature[3]) + cache_key,
-                        tracing=isinstance(key, jax.core.Tracer),
-                        requests=block, hops=self.compiled.num_hops,
-                    )
-
-                    def body(carry, b):
-                        (t0, conn_t0, req_off), tl_acc = carry
-                        kb = jax.random.fold_in(key, 1_000_000 + b)
-                        res, t_end, conn_end = self._simulate_core(
-                            block, kind, connections, kb, offered_qps,
-                            pace_gap, arrival_qps, nominal_gap, t0,
-                            conn_t0, req_off,
-                            sat_conns=connections if sat else 0,
-                            visits_pc=visits_pc,
-                            phase_windows=phase_windows,
-                        )
-                        s = summary_mod.summarize(
-                            res, collector,
-                            window=(win_lo, win_hi) if trim else None,
-                        )
-                        # the recorder accumulates in the CARRY (not
-                        # stacked ys): device cost stays O(S * W) no
-                        # matter how many blocks the run scans
-                        tl_acc = timeline_mod.accumulate(
-                            tl_acc,
-                            timeline_mod.timeline_block(
-                                res, tspec,
-                                packed=self.params.packed_carries,
-                            ),
-                        )
-                        return (
-                            (t_end, conn_end, req_off + per), tl_acc
-                        ), s
-
-                    carry0 = (
-                        (
-                            jnp.float32(0.0),
-                            jnp.zeros((c,), jnp.float32),
-                            jnp.float32(0.0),
-                        ),
-                        timeline_mod.zeros_summary(
-                            tspec, packed=self.params.packed_carries
-                        ),
-                    )
-                    (_, tl_final), parts = jax.lax.scan(
-                        body, carry0, jnp.arange(num_blocks)
-                    )
-                    return summary_mod.reduce_stacked(parts), tl_final
-            elif attr is None:
-                def scanfn(key, offered_qps, pace_gap, arrival_qps,
-                           nominal_gap, win_lo, win_hi, visits_pc,
-                           phase_windows):
-                    telemetry.record_trace(
-                        ("summary", self.signature[3]) + cache_key,
-                        tracing=isinstance(key, jax.core.Tracer),
-                        requests=block, hops=self.compiled.num_hops,
-                    )
-
-                    def body(carry, b):
-                        t0, conn_t0, req_off = carry
-                        kb = jax.random.fold_in(key, 1_000_000 + b)
-                        res, t_end, conn_end = self._simulate_core(
-                            block, kind, connections, kb, offered_qps,
-                            pace_gap, arrival_qps, nominal_gap, t0,
-                            conn_t0, req_off,
-                            sat_conns=connections if sat else 0,
-                            visits_pc=visits_pc,
-                            phase_windows=phase_windows,
-                        )
-                        s = summary_mod.summarize(
-                            res, collector,
-                            window=(win_lo, win_hi) if trim else None,
-                        )
-                        return (t_end, conn_end, req_off + per), s
-
-                    carry0 = (
-                        jnp.float32(0.0),
-                        jnp.zeros((c,), jnp.float32),
-                        jnp.float32(0.0),
-                    )
-                    _, parts = jax.lax.scan(
-                        body, carry0, jnp.arange(num_blocks)
-                    )
-                    return summary_mod.reduce_stacked(parts)
-            else:
-                def scanfn(key, offered_qps, pace_gap, arrival_qps,
-                           nominal_gap, win_lo, win_hi, tail_cut,
-                           visits_pc, phase_windows):
-                    telemetry.record_trace(
-                        ("summary", self.signature[3]) + cache_key,
-                        tracing=isinstance(key, jax.core.Tracer),
-                        requests=block, hops=self.compiled.num_hops,
-                    )
-
-                    def body(carry, b):
-                        (t0, conn_t0, req_off), ex = carry
-                        kb = jax.random.fold_in(key, 1_000_000 + b)
-                        res, t_end, conn_end = self._simulate_core(
-                            block, kind, connections, kb, offered_qps,
-                            pace_gap, arrival_qps, nominal_gap, t0,
-                            conn_t0, req_off,
-                            sat_conns=connections if sat else 0,
-                            visits_pc=visits_pc,
-                            phase_windows=phase_windows,
-                        )
-                        s = summary_mod.summarize(
-                            res, collector,
-                            window=(win_lo, win_hi) if trim else None,
-                        )
-                        a, ex = attribution.attribute_block(
-                            res, tables,
-                            tail_cut=(
-                                tail_cut if attr == "tail" else None
-                            ),
-                            top_k=top_k, ex_state=ex,
-                            packed=self.params.packed_carries,
-                        )
-                        carry_out = (
-                            (t_end, conn_end, req_off + per), ex
-                        )
-                        return carry_out, (s, a)
-
-                    # the exemplar carry needs concrete leaves before
-                    # the scan starts: seed it from a zero-latency
-                    # dummy block shaped like the real ones
-                    k0 = min(top_k, block) if top_k > 0 else 0
-                    H = self.compiled.num_hops
-                    ex0 = (
-                        attribution.empty_exemplars(k0, H)
-                        if k0 > 0
-                        else None
-                    )
-                    carry0 = (
-                        (
-                            jnp.float32(0.0),
-                            jnp.zeros((c,), jnp.float32),
-                            jnp.float32(0.0),
-                        ),
-                        ex0,
-                    )
-                    (_, ex_final), (parts, aparts) = jax.lax.scan(
-                        body, carry0, jnp.arange(num_blocks)
-                    )
-                    return (
-                        summary_mod.reduce_stacked(parts),
-                        attribution.reduce_stacked(aparts, ex_final),
-                    )
+                summary, observed = blockscan.block_scan(
+                    self, collector, shape, key, offered_qps, pace_gap,
+                    arrival_qps, nominal_gap, win_lo, win_hi,
+                    visits_pc, phase_windows,
+                    self._observers(block, attr, timeline, tail_cut),
+                )
+                return (summary, *observed) if observed else summary
 
             self._summary_fns[cache_key] = executable_cache.get_or_jit(
                 ("summary", self.signature) + cache_key,
                 f"summary_{kind}"
-                + ("_timeline" if timeline is not None
-                   else "_attr" if attr is not None else ""),
+                + blockscan.program_suffix(attr, timeline),
                 scanfn,
             )
         return self._summary_fns[cache_key]
+
+    def _observers(self, block: int, attr: Optional[str],
+                   timeline: Optional[Tuple[int, float]],
+                   tail_cut=None) -> tuple:
+        """The block scan's observers for a program's static choice of
+        ``attr`` and ``timeline`` (see :meth:`_get_summary`).  Built
+        inside the traced function: ``tail_cut`` is its traced scalar."""
+        observers = []
+        if attr is not None:
+            from isotope_tpu.metrics import attribution
+
+            observers.append(attribution.observer(
+                self._attribution_tables(),
+                self.params.attribution_top_k, block,
+                tail_cut=tail_cut if attr == "tail" else None,
+                packed=self.params.packed_carries,
+            ))
+        if timeline is not None:
+            from isotope_tpu.metrics import timeline as timeline_mod
+
+            observers.append(timeline_mod.observer(
+                timeline_mod.build_spec(self.compiled, *timeline),
+                packed=self.params.packed_carries,
+            ))
+        return tuple(observers)
 
     def _sample_service_time(self, key: jax.Array, shape) -> jax.Array:
         """Per-hop CPU time draws with mean ``cpu_time_s``.
@@ -5474,7 +5270,6 @@ class Simulator:
             n=n, wait=wait, svc_time=svc_time, err_coin=err_coin,
             u_send=u_send, down=down, tax=tax, churn_w=churn_w,
             track_err=self._track_err,
-            pallas_census=self._pallas_census,
             retry_coin=retry_coin,
         )
         bucket_ys: Dict[int, dict] = {}
@@ -5522,7 +5317,6 @@ class Simulator:
                 sl = slice(lvl.offset, lvl.offset + lvl.size)
                 P = lvl.pmax
                 fail_step = None
-                dense_excl = None  # census-kernel exclusive step prefix
                 if lvl.num_children > 0:
                     nxt = self._levels[d + 1]
                     csl = slice(nxt.offset, nxt.offset + nxt.size)
@@ -5730,15 +5524,6 @@ class Simulator:
                                     ).sum(-1),
                                     (n, T),
                                 )
-                            elif (
-                                self._census_mod is not None
-                                and self._census_mod.supported(T, W)
-                            ):
-                                busy_t, excl = self._census_mod.census(
-                                    tile.step_base, tile.step_mask, agg,
-                                    fail_t, None,
-                                )
-                                prefix = excl if need_off else None
                             else:
                                 step_dur_t = (
                                     jnp.maximum(tile.step_base, agg)
@@ -5844,29 +5629,10 @@ class Simulator:
                                     .at[:, lvl.call_seg // P]
                                     .min(fail_contrib)
                                 )
-                        if (
-                            self._census_mod is not None
-                            and self._census_mod.supported(lvl.size, P)
-                        ):
-                            # fused census kernel (native/census_pallas.py):
-                            # max + mask + fail/err truncation + row-sum +
-                            # exclusive prefix in one pass; the masked
-                            # (N, size, P) step grid never round-trips HBM
-                            busy, dense_excl = self._census_mod.census(
-                                lvl.step_base, lvl.step_mask, agg,
-                                fail_step,
-                                (
-                                    err_coin[:, sl]
-                                    if err_coin is not None
-                                    else None
-                                ),
-                            )
-                            step_dur = None
-                        else:
-                            step_dur = (
-                                jnp.maximum(lvl.step_base, agg)
-                                * lvl.step_mask
-                            )
+                        step_dur = (
+                            jnp.maximum(lvl.step_base, agg)
+                            * lvl.step_mask
+                        )
                 else:
                     # call-free level: busy time is fully static
                     busy = jnp.broadcast_to(lvl.leaf_busy, (n, lvl.size))
@@ -5902,15 +5668,6 @@ class Simulator:
                 if lvl.num_children > 0 and step_dur is not None:
                     prefix = jnp.cumsum(step_dur, axis=-1) - step_dur
                     off = prefix.reshape(n, -1)[:, lvl.child_seg]
-                    if att_off is not None:
-                        off = off + (
-                            used_lvls[d] * att_off[:, : lvl.num_children]
-                        )
-                    off_lvls[d] = off
-                elif lvl.num_children > 0 and dense_excl is not None:
-                    # census-kernel path: the fused prefix already carries
-                    # the fail/err truncation the masked grid would
-                    off = dense_excl.reshape(n, -1)[:, lvl.child_seg]
                     if att_off is not None:
                         off = off + (
                             used_lvls[d] * att_off[:, : lvl.num_children]

@@ -76,9 +76,6 @@ class SweepCtx(NamedTuple):
     tax: Optional[jax.Array]         # (N,) f32
     churn_w: Optional[jax.Array]     # (N, E+1) f32
     track_err: bool                  # any hop can 500 / transport-fail
-    # serve the per-step census join from the fused Pallas kernel
-    # (native/census_pallas.py) instead of the XLA op chain
-    pallas_census: bool = False
     # retry-budget gate (sim/policies.py): attempt >= 1 runs only when
     # its budget coin admits it.  None exactly when no budget can
     # throttle (the byte-identical default) — with it set, protected
@@ -281,12 +278,6 @@ def up_sweep(
     n, B = ctx.n, b.plan.bound_hops
     P, A = b.plan.bound_steps, b.plan.bound_attempts
     track_err = ctx.track_err
-    census_mod = None
-    if ctx.pallas_census:
-        from isotope_tpu.native import census_pallas
-
-        if census_pallas.supported(B, P):
-            census_mod = census_pallas
     # static specializations, mirroring the unrolled path's sentinels:
     # no call in the bucket can transport-fail unless a finite timeout
     # or a chaos outage exists, and a single-attempt bucket's retry
@@ -447,26 +438,19 @@ def up_sweep(
                 .at[:, x["call_hop"]]
                 .min(fail_contrib)
             )
-        if census_mod is not None:
-            # fused census kernel: max + mask + fail/err truncation +
-            # row-sum + exclusive prefix in one pass
-            busy, prefix = census_mod.census(
-                x["step_base"], x["step_mask"], agg, fail_step, err_sl,
+        step_dur = jnp.maximum(x["step_base"], agg) * x["step_mask"]
+        if fail_step is not None:
+            executed = (
+                jnp.arange(P, dtype=jnp.int32)
+                <= fail_step[:, :, None]
             )
-        else:
-            step_dur = jnp.maximum(x["step_base"], agg) * x["step_mask"]
-            if fail_step is not None:
-                executed = (
-                    jnp.arange(P, dtype=jnp.int32)
-                    <= fail_step[:, :, None]
-                )
-                if err_sl is not None:
-                    executed = executed & ~err_sl[:, :, None]
-                step_dur = step_dur * executed
-            elif err_sl is not None:
-                step_dur = step_dur * ~err_sl[:, :, None]
-            busy = step_dur.sum(-1)
-            prefix = jnp.cumsum(step_dur, axis=-1) - step_dur
+            if err_sl is not None:
+                executed = executed & ~err_sl[:, :, None]
+            step_dur = step_dur * executed
+        elif err_sl is not None:
+            step_dur = step_dur * ~err_sl[:, :, None]
+        busy = step_dur.sum(-1)
+        prefix = jnp.cumsum(step_dur, axis=-1) - step_dur
         lat = wait_sl + svc_sl + busy
         off = prefix.reshape(n, -1)[:, x["child_seg"]]
         if att_off is not None:

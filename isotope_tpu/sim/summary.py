@@ -134,65 +134,14 @@ def summarize(
     )
 
 
-def zeros_summary(
-    collector: Optional[MetricsCollector],
-    num_services: int,
-    svc_rows: Optional[int] = None,
-) -> RunSummary:
-    """The identity element of :func:`summary_accumulate`.
-
-    Primes the collective/compute overlap pipeline (parallel/
-    sharded.py): sums start at 0, mins at +inf, maxes at -inf, so
-    accumulating any real block summary over this leaves the block
-    unchanged.  ``svc_rows`` overrides the leading dimension of the
-    svc-sharded per-service histograms (after ``psum_scatter`` each
-    shard holds an ``s_pad / svc``-row tile, not the full ``S``).
-    """
-    from isotope_tpu.metrics.histogram import NUM_BUCKETS
-
-    metrics = None
-    if collector is not None:
-        metrics = collector.zeros()
-        if svc_rows is not None:
-            metrics = metrics._replace(
-                duration_hist=jnp.zeros(
-                    (svc_rows,) + metrics.duration_hist.shape[1:]
-                ),
-                response_size_hist=jnp.zeros(
-                    (svc_rows,) + metrics.response_size_hist.shape[1:]
-                ),
-            )
-    z = jnp.float32(0.0)
-    return RunSummary(
-        count=z,
-        error_count=z,
-        hop_events=z,
-        latency_sum=z,
-        latency_m2=z,
-        latency_min=jnp.float32(np.inf),
-        latency_max=jnp.float32(-np.inf),
-        latency_hist=jnp.zeros((NUM_BUCKETS,)),
-        end_max=z,
-        win_lo=z,
-        win_hi=z,
-        win_count=z,
-        win_error_count=z,
-        win_latency_hist=jnp.zeros((NUM_BUCKETS,)),
-        metrics=metrics,
-        utilization=jnp.zeros((num_services,)),
-        unstable=jnp.zeros((num_services,), bool),
-    )
-
-
 def summary_accumulate(acc: RunSummary, part: RunSummary) -> RunSummary:
     """Streaming two-summary merge (jit-friendly; no leading axis).
 
     The Chan/Welford pairwise form of :func:`reduce_stacked`'s block
-    reduction — the overlap pipeline folds each block's
-    collective-merged summary into a carried accumulator instead of
-    stacking ``num_blocks`` copies.  Mathematically identical to the
-    stacked reduction; float fields may differ by reduction order
-    (<= a few ULP — pinned by tests/test_multihost.py).
+    reduction, for a caller that folds summaries into an accumulator
+    instead of stacking them (sim/search.py).  Mathematically
+    identical to the stacked reduction; float fields may differ by
+    reduction order (<= a few ULP).
     """
     n = acc.count + part.count
     mean_a = acc.latency_sum / jnp.maximum(acc.count, 1.0)

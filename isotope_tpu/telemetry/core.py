@@ -583,13 +583,12 @@ def summary_block() -> Dict[str, Any]:
         ),
         "peak_device_bytes": peak,
     }
-    # key PRESENT only when the run actually degraded: bench_regress
-    # keys its degraded-on-a-previously-clean-case gate on presence
+    # key PRESENT only when the run actually degraded: a reader tells
+    # a clean run from a degraded one by presence
     if _STATE.meta.get("degraded_to"):
         blk["degraded_to"] = _STATE.meta["degraded_to"]
     # vet keys PRESENT only when a vet pass actually ran this record:
-    # bench_regress's opt-in new-vet-errors gate skips captures (and
-    # baselines) that never vetted instead of reading absence as zero
+    # absence means "never vetted", never zero errors
     if c.get("vet_runs_total"):
         blk["vet_runs"] = int(c["vet_runs_total"])
         blk["vet_errors"] = int(c.get("vet_errors_total", 0.0))

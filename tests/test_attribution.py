@@ -104,8 +104,6 @@ def _split_results(res, cut):
     return part(slice(None, cut)), part(slice(cut, None))
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_blocked_accumulation_equals_single_block(attr_sim):
     res = attr_sim.run(LOAD, 512, KEY)
     tables = attr_sim._attribution_tables()
@@ -217,8 +215,6 @@ def test_exemplar_trace_shapes(attr_sim, tree13):
 # -- sharded psum merge ------------------------------------------------------
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_sharded_psum_equals_single_device(tree13):
     from isotope_tpu.parallel import ShardedSimulator, make_mesh
 

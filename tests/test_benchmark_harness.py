@@ -1,0 +1,43 @@
+"""Tier-1 collects the benchmark's own tests (``benchmark/tests``): the
+judge of every PR is itself held to its pins where the program's tests
+run.  All of them but one:
+
+- ``test_scope_reader.py::test_new_entries_find_their_readers_and_kinds``
+  is not collected: it pins ``workloads[-1]`` to ``svc1000_mesh4`` and
+  has been red since PR 29 appended ``svc10k_served``.  The repair is a
+  ``benchmark`` PR's (``ROADMAP.md`` S8(d)); that PR removes the ``del``
+  below.
+
+``tests/conftest.py`` gives this process eight virtual CPU devices, and
+a cell of one chip is refused on eight: the in-process runs here are
+held to one device (``$ISOTOPE_MESH`` = 1x1, the served path's own
+switch) and the harness is told of that one."""
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import run  # noqa: E402
+from benchmark.tests.test_checks import *  # noqa: E402,F401,F403
+from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
+from benchmark.tests.test_deadline import *  # noqa: E402,F401,F403
+from benchmark.tests.test_host_spans import *  # noqa: E402,F401,F403
+from benchmark.tests.test_reference import *  # noqa: E402,F401,F403
+from benchmark.tests.test_run import *  # noqa: E402,F401,F403
+from benchmark.tests.test_scope_reader import *  # noqa: E402,F401,F403
+from benchmark.tests.test_stats import *  # noqa: E402,F401,F403
+from benchmark.tests.test_trace_reduce import *  # noqa: E402,F401,F403
+from benchmark.tests.test_yardstick import *  # noqa: E402,F401,F403
+
+del test_new_entries_find_their_readers_and_kinds  # noqa: F821
+
+
+@pytest.fixture(autouse=True)
+def one_device(monkeypatch):
+    real = run.device_doc
+    monkeypatch.setenv("ISOTOPE_MESH", "1x1")
+    monkeypatch.setattr(run, "device_doc", lambda: dict(real(), count=1))

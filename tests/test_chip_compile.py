@@ -20,7 +20,6 @@ from jax.sharding import SingleDeviceSharding
 from isotope_tpu.compiler import compile_graph
 from isotope_tpu.metrics.prometheus import MetricsCollector
 from isotope_tpu.models.graph import ServiceGraph
-from isotope_tpu.native import census_pallas
 from isotope_tpu.sim import SimParams, Simulator
 
 TOPOLOGIES = os.path.join(
@@ -70,12 +69,11 @@ def one_chip(topo):
 def test_cli_summary_program_compiles_for_v5e(one_chip, name, hops, block):
     """The program ``isotope-tpu simulate <graph> --qps 1000 --duration
     1000s`` runs — the block scan behind ``run_summary`` with the
-    collector and the trim window, XLA census — fits one chip."""
+    collector and the trim window — fits one chip."""
     compiled = compile_graph(
         ServiceGraph.from_yaml_file(os.path.join(TOPOLOGIES, name))
     )
     sim = Simulator(compiled, SimParams())
-    assert sim._pallas_census is False
     assert (compiled.num_hops, sim.default_block_size()) == (hops, block)
     per = block // CONNECTIONS
     blk = per * CONNECTIONS
@@ -95,20 +93,3 @@ def test_cli_summary_program_compiles_for_v5e(one_chip, name, hops, block):
     assert 0 < mem.temp_size_in_bytes < HBM_BYTES
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES
-
-
-def test_census_kernel_verdict_is_pinned(one_chip):
-    """Mosaic refuses the census kernel as written (ROADMAP S9/D2).
-    The day JAX or the kernel changes, this says so."""
-    n, b, p = 4096, 121, 4
-
-    def sds(shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-
-    def kernel(base, mask, agg):
-        return census_pallas.census(base, mask, agg, interpret=False)
-
-    with pytest.raises(NotImplementedError, match="cumsum"):
-        jax.jit(kernel).lower(
-            sds((b, p)), sds((b, p)), sds((n, b, p))
-        ).compile()

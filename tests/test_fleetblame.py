@@ -34,6 +34,8 @@ from isotope_tpu.sim.config import ChaosEvent
 from isotope_tpu.sim.engine import Simulator
 from isotope_tpu.sim.ensemble import EnsembleSpec
 
+from _twins import assert_attribution_twins, assert_ulp_equal
+
 YAML = """
 defaults:
   responseSize: 1 KiB
@@ -93,7 +95,13 @@ def obs4(asim):
     )
 
 
-# -- off == byte-identical ---------------------------------------------
+# -- off == on, as jit twins --------------------------------------------
+#
+# The observed and the plain fleet, and a fleet member and its solo
+# run, are differently compiled programs of one arithmetic: under
+# ``jax.disable_jit()`` every leaf below is bit-equal (PR 32's probe),
+# under jit XLA fuses the float reductions differently.  So they are
+# held to the twin rule (``_twins``), not to bits.
 
 
 def test_observability_off_is_byte_identical(asim, obs4):
@@ -101,7 +109,7 @@ def test_observability_off_is_byte_identical(asim, obs4):
         OPEN, N, KEY, EnsembleSpec.of(4), block_size=BLOCK
     )
     assert base.attributions is None and base.timelines is None
-    assert _leaves_equal(base.summaries, obs4.summaries)
+    assert_ulp_equal(base.summaries, obs4.summaries)
 
 
 def test_attribution_needs_armed_params(compiled):
@@ -119,12 +127,17 @@ def test_attribution_needs_armed_params(compiled):
 def test_member_k_blame_bit_equals_solo_open(asim, obs4):
     k = 2
     mkey = jax.random.fold_in(KEY, EnsembleSpec.of(4).seeds[k])
-    _, solo = asim.run_attributed(OPEN, N, mkey, block_size=BLOCK)
-    assert _leaves_equal(solo, obs4.member_attribution(k))
+    summ, solo = asim.run_attributed(OPEN, N, mkey, block_size=BLOCK)
+    member = obs4.member_attribution(k)
+    # 2 ULP: self_blame sums w * (lat - wait) - D, a difference whose
+    # operands are larger than it, so one ULP of theirs is up to two
+    # of the sum's (1 of 5 hops reads 1.9e-7 relative); the other
+    # float leaves read <= 1
+    assert_attribution_twins(solo, member, summ.latency_sum, maxulp=2)
     _, solo_tl = asim.run_timeline(
         OPEN, N, mkey, block_size=BLOCK, window_s=WIN
     )
-    assert _leaves_equal(solo_tl, obs4.member_timeline(k))
+    assert_ulp_equal(solo_tl, obs4.member_timeline(k))
 
 
 @pytest.mark.slow
@@ -305,10 +318,15 @@ def test_explainer_names_planted_member_hop_and_onset(planted):
     m = [e for e in doc["member_blame"] if e["member"] == worst][0]
     # the hop: worker queueing is where the lost capacity bites
     assert m["gap_ranking"][0]["service"] == "worker"
-    # the onset: the kill lands at 0.3s; 0.1s windows -> window ~3
+    # the onset: the kill lands at 0.3s; 0.1s windows -> window 3 at
+    # the earliest.  Member 2's worker in-flight departure, in robust
+    # sigmas a window: -0.6 0.1 -2.2 | 3.3 3.9 3.6 9.1 17.8 5.5 31.3.
+    # Windows 3-5 sit just under ONSET_MARGIN = 4 (with four members
+    # the MAD is the mean of the 2nd and 3rd deviation, and a healthy
+    # member's dip widens it); the queue's build-up crosses at 6
     assert m["onset"] is not None
     assert m["onset"]["service"] == "worker"
-    assert 2 <= m["onset"]["window"] <= 5
+    assert 3 <= m["onset"]["window"] <= 6
     assert m["onset"]["time_s"] == pytest.approx(
         m["onset"]["window"] * 0.1
     )
@@ -397,7 +415,6 @@ def test_vet_m006_fires_on_over_capacity_observed_fleet(monkeypatch):
 # -- runner + explain subcommand ---------------------------------------
 
 
-@pytest.mark.slow
 def test_runner_fleet_blame_artifacts_and_explain(tmp_path):
     from isotope_tpu.commands.explain_cmd import run_explain_cmd
     from isotope_tpu.runner.config import (
@@ -437,7 +454,7 @@ def test_runner_fleet_blame_artifacts_and_explain(tmp_path):
     )
     tl = json.loads((out / f"{res.label}.timeline.json").read_text())
     assert tl["worst_member"] is True and tl["member"] == worst
-    # the worst member's fleet blame replays bit-equal solo
+    # the worst member's fleet blame replays solo, as a jit twin
     seed_key = jax.random.PRNGKey(cfg.seed)
     mkey = jax.random.fold_in(
         jax.random.fold_in(seed_key, 0),
@@ -449,12 +466,13 @@ def test_runner_fleet_blame_artifacts_and_explain(tmp_path):
     )
     load = LoadModel(kind="open", qps=500.0, connections=8,
                      duration_s=2.0)
-    _, solo = sim.run_attributed(
+    summ, solo = sim.run_attributed(
         load, 256, mkey, block_size=sim.default_block_size(),
         trim=True,
     )
-    assert _leaves_equal(
-        solo, res.ensemble_summary.member_attribution(worst)
+    assert_attribution_twins(
+        solo, res.ensemble_summary.member_attribution(worst),
+        summ.latency_sum, maxulp=2,
     )
 
     # explain renders the why-report from the artifacts alone

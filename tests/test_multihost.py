@@ -1,7 +1,6 @@
 """Multi-host scale-out (ISSUE 8): the emulated multi-host twin, the
 DCN-aware merge, collective/compute overlap, and the DCN chaos path —
 all on the 8-device virtual CPU mesh (conftest)."""
-import dataclasses
 
 import jax
 import jax.tree_util as jtu
@@ -187,105 +186,6 @@ def test_hierarchical_merge_matches_flat_statistics(compiled):
     )
 
 
-# -- collective/compute overlap --------------------------------------------
-
-
-@pytest.mark.parametrize("spec", [
-    MeshSpec(data=4, svc=2),
-    MeshSpec(data=2, svc=2, slices=2),
-])
-@pytest.mark.parametrize("load,trim", [(OPEN, False), (OPEN, True),
-                                       (CLOSED, False)])
-@pytest.mark.slow
-def test_overlap_equivalence(compiled, spec, load, trim):
-    """ISSUE satellite: overlap on == off — exact on integer-valued
-    fields, f32 reduction-order noise on float sums (the pipelined
-    merge reduces shards-within-block before blocks; off reduces
-    blocks-within-shard first)."""
-    n = 8192
-    off = ShardedSimulator(compiled, build_mesh(spec)).run(
-        load, n, KEY, block_size=1024, trim=trim
-    )
-    on = ShardedSimulator(
-        compiled, build_mesh(spec), params=SimParams(overlap=True)
-    ).run(load, n, KEY, block_size=1024, trim=trim)
-    for f in ("count", "error_count", "hop_events", "win_count",
-              "win_error_count", "win_lo", "win_hi"):
-        assert float(getattr(on, f)) == float(getattr(off, f)), f
-    for f in ("latency_hist", "win_latency_hist"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(on, f)), np.asarray(getattr(off, f)), f
-        )
-    # order-sensitive float reductions: reassociation only
-    for f in ("latency_sum", "latency_m2"):
-        np.testing.assert_allclose(
-            float(getattr(on, f)), float(getattr(off, f)),
-            rtol=1e-5, err_msg=f,
-        )
-    for f in ("latency_min", "latency_max", "end_max"):
-        assert float(getattr(on, f)) == float(getattr(off, f)), f
-    _assert_close(on.metrics, off.metrics, max_ulp=4.0)
-    np.testing.assert_array_equal(
-        np.asarray(on.utilization), np.asarray(off.utilization)
-    )
-
-
-@pytest.mark.slow
-@pytest.mark.slow
-def test_overlap_equivalence_eager(compiled):
-    """The satellite's eager pin: under jax.disable_jit the overlap
-    body executes its collectives op-by-op and must still reproduce
-    the off path's integer fields exactly."""
-    n = 2048
-    spec = MeshSpec(data=2, svc=2, slices=2)
-    with jax.disable_jit():
-        off = ShardedSimulator(compiled, build_mesh(spec)).run(
-            OPEN, n, KEY, block_size=512
-        )
-        on = ShardedSimulator(
-            compiled, build_mesh(spec), params=SimParams(overlap=True)
-        ).run(OPEN, n, KEY, block_size=512)
-    assert float(on.count) == float(off.count)
-    assert float(on.hop_events) == float(off.hop_events)
-    np.testing.assert_array_equal(
-        np.asarray(on.latency_hist), np.asarray(off.latency_hist)
-    )
-    np.testing.assert_allclose(
-        float(on.latency_sum), float(off.latency_sum), rtol=1e-6
-    )
-
-
-def test_overlap_off_default_unchanged(compiled):
-    """overlap=False (the default) must stay byte-identical to an
-    explicitly-off run — the pre-PR single-merge path."""
-    a = ShardedSimulator(compiled, make_mesh(4, 2)).run(
-        OPEN, 4096, KEY, block_size=1024
-    )
-    b = ShardedSimulator(
-        compiled, make_mesh(4, 2), params=SimParams(overlap=False)
-    ).run(OPEN, 4096, KEY, block_size=1024)
-    _assert_close(a, b, max_ulp=0.0)
-
-
-def test_overlap_twin_matches_device_within_reduction_noise(compiled):
-    """The emulated twin replays the off-order host merge; with
-    overlap on, the device path differs by reduction order only."""
-    spec = MeshSpec(data=2, svc=2, slices=2)
-    sharded = ShardedSimulator(
-        compiled, build_mesh(spec), params=SimParams(overlap=True)
-    )
-    dev = sharded.run(OPEN, 8192, KEY, block_size=1024)
-    jax.block_until_ready(dev.count)
-    twin = sharded.run_emulated(OPEN, 8192, KEY, block_size=1024)
-    assert float(dev.count) == float(twin.count)
-    np.testing.assert_array_equal(
-        np.asarray(dev.latency_hist), np.asarray(twin.latency_hist)
-    )
-    np.testing.assert_allclose(
-        float(dev.latency_sum), float(twin.latency_sum), rtol=1e-5
-    )
-
-
 # -- DCN chaos + taxonomy --------------------------------------------------
 
 
@@ -395,18 +295,3 @@ def test_runner_bad_mesh_spec_fails_before_simulating(tmp_path):
 
     with pytest.raises(ValueError, match=r"mesh"):
         run_experiment(_config(YAML, tmp_path, mesh_spec="nope=1"))
-
-
-def test_runner_overlap_config_round_trip(tmp_path):
-    from isotope_tpu.runner.run import run_experiment
-
-    cfg = _config(YAML, tmp_path, mesh_spec="2x2", overlap=True)
-    assert cfg.sim_params().overlap
-    (res,) = run_experiment(cfg)
-    assert not res.failed
-    off = run_experiment(
-        dataclasses.replace(cfg, overlap=False)
-    )[0]
-    assert res.fortio_json["DurationHistogram"]["Count"] == (
-        off.fortio_json["DurationHistogram"]["Count"]
-    )

@@ -1,11 +1,10 @@
-"""int32/bf16 carry packing (SimParams.packed_carries).
+"""int32 carry packing (SimParams.packed_carries).
 
 The attribution sweep's COUNT-valued carries — request/tail counts,
 per-hop crit/error counters, blame-histogram censuses — accumulate as
 int32 when packed; crit weights are exact 0/1 products so the packing
 is EXACT (not merely <= 1 ULP), and every seconds-valued accumulator
-stays f32.  The bf16 half of the packing lives in the census kernel's
-step mask (tests/test_census_pallas.py pins its exactness).
+stays f32.
 """
 import jax
 import numpy as np

@@ -14,6 +14,8 @@ from isotope_tpu.sim import policies as pol_mod
 from isotope_tpu.sim.config import ChaosEvent, LoadModel, SimParams
 from isotope_tpu.sim.engine import Simulator
 
+from _twins import assert_ulp_equal
+
 KEY = jax.random.PRNGKey(0)
 MU = 13_000.0
 
@@ -164,19 +166,6 @@ def test_policies_default_keeps_bucketed_plan():
     assert any(isinstance(p, ScanBucketPlan) for p in sim._plan)
 
 
-def _assert_ulp_equal(a, b, maxulp=1):
-    """Exact on integer/bool leaves, <= ``maxulp`` on float leaves —
-    the jit-twin tolerance the levelscan/overlap pins use (XLA may
-    contract the policy path's extra neutral multiplies into FMAs,
-    shifting intermediate rounding by 1 ULP)."""
-    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        x, y = np.asarray(x), np.asarray(y)
-        if np.issubdtype(x.dtype, np.floating):
-            np.testing.assert_array_max_ulp(x, y, maxulp=maxulp)
-        else:
-            assert np.array_equal(x, y)
-
-
 @pytest.mark.slow
 def test_neutral_policies_match_unpoliced_run():
     """A policy set that never actuates (huge caps, budget slack, HPA
@@ -201,8 +190,8 @@ policies:
     s_tl, tl_plain = sim.run_timeline(
         load, 4_096, KEY, block_size=1_024, window_s=0.5
     )
-    _assert_ulp_equal(s_pol, s_tl)
-    _assert_ulp_equal(tl_pol, tl_plain)
+    assert_ulp_equal(s_pol, s_tl)
+    assert_ulp_equal(tl_pol, tl_plain)
     # and the actuation series shows no action
     assert float(np.asarray(pol.trips).sum()) == 0
     assert float(np.asarray(pol.scale_events).sum()) == 0

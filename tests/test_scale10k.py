@@ -1,5 +1,6 @@
 """BASELINE configs[3]: the 10,000-service realistic path compiles and
-runs (CPU-sized request counts; the TPU rate is measured by bench.py)."""
+runs (CPU-sized request counts; the TPU rate is the `svc10k_served`
+cell's, `PERF.md`)."""
 import jax
 import pytest
 

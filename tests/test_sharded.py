@@ -179,8 +179,6 @@ def test_svc_axis_required():
         ShardedSimulator(compile_graph(ServiceGraph.from_yaml(YAML)), bad)
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_sharded_full_feature_agreement(compiled):
     # VERDICT r3 weak-6: nothing exercised closed-loop + chaos + churn
     # (+ the phased mTLS tax) through the sharded path.  The sharded

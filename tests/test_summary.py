@@ -122,8 +122,6 @@ def test_closed_loop_max_qps_chaos_phases_are_hit():
     assert float(res.client_error.mean()) > 0.5
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_metrics_accumulate_across_blocks():
     sim = _sim()
     collector = MetricsCollector(sim.compiled)

@@ -318,7 +318,7 @@ def test_degraded_to_meta_lands_in_snapshot_and_summary():
     assert blk["degraded_to"] == "single-device"
     assert blk["degradations_total"] == 1
     assert blk["retries_total"] == 2
-    # clean runs carry NO degraded_to key (bench_regress keys on it)
+    # clean runs carry NO degraded_to key
     telemetry.reset()
     assert "degraded_to" not in telemetry.summary_block()
 

@@ -232,8 +232,6 @@ def test_blocked_accumulation_equals_single_block(tree13, tl_sim):
         )
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_sharded_psum_equals_emulated(tree13):
     from isotope_tpu.parallel import ShardedSimulator, make_mesh
 

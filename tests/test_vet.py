@@ -327,7 +327,7 @@ def test_runner_gate_preselects_rung_and_records_degraded(
     (res,) = run_experiment(config, vet="on")
     assert not res.failed
     # the memory verdict started the ladder degraded — recorded exactly
-    # like a ladder descent (bench_regress keys on degraded_to)
+    # like a ladder descent
     assert res.degraded_to == "cpu-eager"
 
 
@@ -526,32 +526,9 @@ def test_vet_counters_render_as_first_class_series():
     text = telemetry.prometheus_text()
     assert "isotope_engine_vet_runs_total" in text
     # a record that never vetted must NOT carry the keys (presence is
-    # how bench_regress distinguishes "clean" from "never ran")
+    # what tells "clean" from "never ran")
     telemetry.reset()
     assert "vet_errors" not in telemetry.summary_block()
-
-
-def test_bench_regress_vet_gate(monkeypatch):
-    import tools.bench_regress as br
-
-    prev = {"value": 1.0, "extra": {
-        "svc1000": 2.0,
-        "svc1000_telemetry": {"vet_errors": 0, "vet_runs": 1},
-    }}
-    new_bad = {"value": 1.0, "extra": {
-        "svc1000": 2.0,
-        "svc1000_telemetry": {"vet_errors": 2, "vet_runs": 1},
-    }}
-    monkeypatch.delenv("BENCH_REGRESS_VET_GATE", raising=False)
-    assert br.vet_failures(prev, new_bad) == []      # gate disarmed
-    monkeypatch.setenv("BENCH_REGRESS_VET_GATE", "1")
-    assert br.vet_failures(prev, new_bad) == ["svc1000.vet_errors"]
-    assert br.vet_failures(prev, prev) == []         # unchanged: clean
-    # baseline without vet data: skipped, never read as zero
-    no_vet = {"value": 1.0, "extra": {
-        "svc1000": 2.0, "svc1000_telemetry": {},
-    }}
-    assert br.vet_failures(no_vet, new_bad) == []
 
 
 # -- fault-injection eager validation (satellite) ---------------------------

@@ -37,8 +37,7 @@ import sys
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
 #: directories scanned by the fallback linter (and passed to ruff)
-TARGETS = ("isotope_tpu", "tests", "tools", "bench.py",
-           "__graft_entry__.py")
+TARGETS = ("isotope_tpu", "tests", "tools", "__graft_entry__.py")
 
 
 def _files():

@@ -9,13 +9,9 @@ Drives the whole PR-8 scale-out surface on one machine:
 2. **shard_map == twin** — the same (2, 2, 2) multislice program on
    the 8-device virtual CPU mesh vs its emulated replay, every summary
    field within 1 f32 ULP (measured bit-equal on CPU);
-3. **overlap == off** — collective/compute overlap
-   (``SimParams.overlap``) must match the single post-scan merge
-   exactly on integer-valued fields and to f32 reduction order on
-   float sums;
-4. **layout search** — ``--mesh auto`` (parallel/layout.py) must score
+3. **layout search** — ``--mesh auto`` (parallel/layout.py) must score
    no worse than the hand-picked ``{'slice': 2, 'data': 2, 'svc': 2}``;
-5. **DCN chaos** — a transient injected at the
+4. **DCN chaos** — a transient injected at the
    ``sharded.dcn_collective`` site must classify transient and be
    retried by the supervisor to a bit-identical result.
 """
@@ -43,7 +39,7 @@ def main() -> int:
     )
     from isotope_tpu.resilience import execution_rungs, faults, run_ladder
     from isotope_tpu.resilience.supervisor import ResiliencePolicy
-    from isotope_tpu.sim import LoadModel, SimParams
+    from isotope_tpu.sim import LoadModel
 
     yaml = """
 services:
@@ -101,25 +97,12 @@ services:
     )
     assert worst <= 1.0, worst
 
-    # 3. overlap on == off
-    on = ShardedSimulator(
-        compiled, build_mesh(spec222), params=SimParams(overlap=True)
-    ).run(load, n, key, block_size=1024)
-    for f in ("count", "error_count", "hop_events", "win_count"):
-        assert float(getattr(on, f)) == float(getattr(dev, f)), f
-    np.testing.assert_array_equal(
-        np.asarray(on.latency_hist), np.asarray(dev.latency_hist)
-    )
-    np.testing.assert_allclose(
-        float(on.latency_sum), float(dev.latency_sum), rtol=1e-6
-    )
-
-    # 4. layout search beats (or ties) the hand-picked mesh
+    # 3. layout search beats (or ties) the hand-picked mesh
     auto = layout.choose_layout(8, compiled.num_services, max_slices=2)
     hand = layout.score_layout(spec222, compiled.num_services)
     assert auto.score_s <= hand.score_s, (auto.score_s, hand.score_s)
 
-    # 5. injected DCN-collective transient retries to identical results
+    # 4. injected DCN-collective transient retries to identical results
     telemetry.reset()
     faults.install("transient:sharded.dcn_collective:1")
     try:
@@ -139,7 +122,7 @@ services:
     print(
         "multihost-smoke: 16-shard emulated twin reconciles "
         f"({int(s16.count)} reqs), shard_map==twin within "
-        f"{worst:.1f} ULP, overlap==off, auto mesh "
+        f"{worst:.1f} ULP, auto mesh "
         f"{auto.spec.describe()} ({auto.score_s:.3g}s) <= hand "
         f"{hand.score_s:.3g}s, DCN transient retried "
         f"({int(telemetry.counter_get('retries_total'))}x)"

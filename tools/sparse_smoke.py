@@ -37,9 +37,6 @@ def main() -> int:
         "dense": SimParams(),
         "tiled": SimParams(sparse_level_elems=1),
         "sparse": SimParams(sparse_level_elems=1, sparse_tiling=False),
-        "tiled+pallas": SimParams(
-            sparse_level_elems=1, pallas_census=True
-        ),
     }
     sums = {}
     for name, params in engines.items():
