@@ -30,6 +30,21 @@ from isotope_tpu.models.size import ByteSize
 from isotope_tpu.models.svctype import ServiceType
 
 
+# libyaml scans and parses the text where the installed PyYAML carries
+# it: 4-8 x faster at every size, and the parser is nearly all of a
+# served call's ``graph.decode``.  The constructor and resolver are
+# SafeLoader's under both, so documents and error classes are equal.
+_LOADER = getattr(yaml, "CSafeLoader", None) or yaml.SafeLoader
+
+
+def parses_with_libyaml() -> bool:
+    return _LOADER is not yaml.SafeLoader
+
+
+def _load(stream):
+    return yaml.load(stream, Loader=_LOADER)
+
+
 class RequestToUndefinedServiceError(ValueError):
     def __init__(self, service_name: str):
         self.service_name = service_name
@@ -112,12 +127,12 @@ class ServiceGraph:
 
     @classmethod
     def from_yaml(cls, text: str) -> "ServiceGraph":
-        return cls.decode(yaml.safe_load(text))
+        return cls.decode(_load(text))
 
     @classmethod
     def from_yaml_file(cls, path) -> "ServiceGraph":
         with open(path) as f:
-            return cls.decode(yaml.safe_load(f))
+            return cls.decode(_load(f))
 
     # -- encode ------------------------------------------------------------
 

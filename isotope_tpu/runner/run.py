@@ -41,7 +41,7 @@ from isotope_tpu.metrics.fortio import (
     write_json,
 )
 from isotope_tpu.metrics.prometheus import MetricsCollector
-from isotope_tpu.models.graph import ServiceGraph
+from isotope_tpu.models.graph import ServiceGraph, parses_with_libyaml
 from isotope_tpu.parallel import (
     MeshSpec,
     ShardedSimulator,
@@ -193,6 +193,12 @@ class _LazyTopology:
             with telemetry.phase("graph.decode"):
                 graph = ServiceGraph.from_yaml_file(self.path)
             telemetry.counter_inc("graphs_decoded")
+            # equal to graphs_decoded where libyaml did the parse, 0
+            # where the installed PyYAML lacks it (the slow loader)
+            libyaml = parses_with_libyaml()
+            telemetry.counter_inc("graphs_decoded_libyaml", int(libyaml))
+            telemetry.set_meta("yaml_parser",
+                               "libyaml" if libyaml else "python")
             self._graph = graph
             self._compiled = compile_graph(graph, entry=self.config.entry)
             self._entry_resp = float(

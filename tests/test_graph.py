@@ -3,8 +3,15 @@
 Coverage mirrors the reference's graph/unmarshal_test.go end-to-end fixture
 (defaults inheritance) and validation.go error cases.
 """
-import pytest
+import functools
+import glob
+import importlib.util
+import sys
 
+import pytest
+import yaml
+
+from isotope_tpu.models import graph as graph_mod
 from isotope_tpu.models.graph import (
     NestedConcurrentCommandError,
     RequestToUndefinedServiceError,
@@ -186,3 +193,86 @@ def test_strict_int_fields():
     ):
         with pytest.raises(ValueError):
             ServiceGraph.from_yaml(doc)
+
+
+# -- the YAML loader: libyaml where PyYAML has it, SafeLoader where not ----
+
+TOPOLOGIES = sorted(
+    glob.glob("examples/topologies/*.yaml")
+    + glob.glob("benchmark/topologies/*.yaml")
+)
+
+MALFORMED = {
+    "unclosed_flow_sequence": "services: [a, b\n",
+    "tab_indentation": "services:\n\t- name: a\n",
+    "two_mappings_on_a_line": "services: a: b\n",
+    "python_object_tag": "services: !!python/object:os.system {}\n",
+    "not_a_mapping": "- a\n- b\n",
+}
+
+
+@pytest.fixture(scope="module")
+def fallback_mod():
+    """models/graph.py imported afresh by a PyYAML without libyaml: the
+    choice the module makes at import is what is under test."""
+    spec = importlib.util.spec_from_file_location(
+        "_graph_without_libyaml", graph_mod.__file__
+    )
+    mod = importlib.util.module_from_spec(spec)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.delattr(yaml, "CSafeLoader", raising=False)
+        patch.setitem(sys.modules, spec.name, mod)  # dataclasses look it up
+        spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(params=["chosen", "fallback"])
+def loader_mod(request):
+    if request.param == "chosen":
+        return graph_mod
+    return request.getfixturevalue("fallback_mod")
+
+
+@functools.cache
+def _safe_loader_reading(path):
+    with open(path) as f:
+        return ServiceGraph.decode(yaml.load(f, Loader=yaml.SafeLoader))
+
+
+def test_loader_choice(fallback_mod):
+    assert graph_mod.parses_with_libyaml() == yaml.__with_libyaml__
+    if yaml.__with_libyaml__:
+        assert graph_mod._LOADER is yaml.CSafeLoader
+    assert fallback_mod._LOADER is yaml.SafeLoader
+    assert not fallback_mod.parses_with_libyaml()
+
+
+@pytest.mark.parametrize("path", TOPOLOGIES)
+def test_topology_decodes_alike_under_both_loaders(loader_mod, path):
+    want = _safe_loader_reading(path)
+    got = loader_mod.ServiceGraph.from_yaml_file(path)
+    assert got.encode() == want.encode()
+    if loader_mod is graph_mod:
+        assert got == want  # the dataclasses themselves, not only encode()
+
+
+@pytest.mark.parametrize("case", sorted(MALFORMED))
+def test_malformed_text_raises_alike_under_both_loaders(loader_mod, case):
+    text = MALFORMED[case]
+    with pytest.raises((yaml.YAMLError, ValueError)) as want:
+        ServiceGraph.decode(yaml.load(text, Loader=yaml.SafeLoader))
+    with pytest.raises((yaml.YAMLError, ValueError)) as got:
+        loader_mod.ServiceGraph.from_yaml(text)
+    assert type(got.value) is type(want.value)
+    # parser messages differ in wording; the place they point at does not
+    mark, want_mark = (getattr(e.value, "problem_mark", None)
+                       for e in (got, want))
+    assert (mark is None) == (want_mark is None)
+    if mark is not None:
+        assert (mark.line, mark.column) == (want_mark.line, want_mark.column)
+
+
+def test_duplicate_key_resolves_alike_under_both_loaders(loader_mod):
+    text = "services:\n- name: a\n  numReplicas: 2\n  numReplicas: 3\n"
+    assert loader_mod.ServiceGraph.from_yaml(text).services[0].num_replicas \
+        == 3

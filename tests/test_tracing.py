@@ -13,8 +13,10 @@ import sys
 
 import jax
 import pytest
+import yaml
 
 from isotope_tpu import cli, telemetry
+from isotope_tpu.models import graph as graph_mod
 from isotope_tpu.telemetry import core
 
 TOPOLOGY = "examples/topologies/canonical.yaml"   # 4 services, 6 hops
@@ -120,11 +122,29 @@ def test_served_call_counters(served):
     assert snap.counters["hop_events_simulated"] == count * HOPS
     assert snap.counters["runs_served"] == 1
     assert snap.counters["graphs_decoded"] == 1
+    # ... by libyaml where the installed PyYAML carries it
+    assert snap.counters["graphs_decoded_libyaml"] == \
+        int(yaml.__with_libyaml__)
+    assert snap.meta["yaml_parser"] == \
+        ("libyaml" if yaml.__with_libyaml__ else "python")
     assert snap.counters["closed_rate_pilot_runs"] >= 1
     assert snap.counters["artifact_bytes_written"] > 1000
     # a paced call never reaches sim/closed.py's census
     assert "closed_rate_census_sweeps" not in snap.counters
     assert "closed_rate.census" not in snap.phases
+
+
+def test_fallback_loader_serves_the_same_call(served, tmp_path, monkeypatch):
+    """A PyYAML without libyaml: the counter reads 0, the artifacts are
+    the same bytes."""
+    monkeypatch.setattr(graph_mod, "_LOADER", yaml.SafeLoader)
+    telemetry.reset()
+    rc, _, prom = serve(tmp_path, "fallback")
+    snap = telemetry.snapshot()
+    assert rc == 0 and prom == served[2]
+    assert snap.counters["graphs_decoded"] == 1
+    assert snap.counters["graphs_decoded_libyaml"] == 0
+    assert snap.meta["yaml_parser"] == "python"
 
 
 def test_sharded_call_accrues_its_phases(served, tmp_path):
