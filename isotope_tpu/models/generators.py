@@ -165,9 +165,15 @@ def realistic_topology(
     num_replicas: int = 1,
     seed: int = 0,
     name_prefix: str = "mock-",
+    callee_error_rate: Optional[str] = None,
 ) -> dict:
     """Scale-free topology; node 0 is the entrypoint, children are called
     sequentially (one call step each, create_realistic_topology.py:176-187).
+
+    ``callee_error_rate`` (e.g. ``"0.01%"``, the value upstream's README
+    documents the field with) sets ``errorRate`` on every service but
+    the entrypoint, which stays the ingress a client's request cannot
+    fail at; the reference's script writes none.
     """
     if archetype not in ARCHETYPES:
         raise ValueError(
@@ -185,6 +191,8 @@ def realistic_topology(
         svc: dict = {"name": f"{name_prefix}{i}"}
         if i == 0:
             svc["isEntrypoint"] = True
+        elif callee_error_rate is not None:
+            svc["errorRate"] = callee_error_rate
         if children[i]:
             svc["script"] = [
                 {"call": f"{name_prefix}{c}"} for c in children[i]

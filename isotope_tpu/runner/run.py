@@ -27,6 +27,7 @@ import sys
 from typing import List, Optional, Sequence
 
 import jax
+import numpy as np
 
 from isotope_tpu import telemetry
 from isotope_tpu.compiler import compile_graph
@@ -1934,6 +1935,24 @@ def run_experiment(
                 # full exposition: the five service series plus the
                 # sim-side resource series the alarm queries read
                 prom_text = topo.collector.full_text(summary)
+                if summary.metrics is not None:
+                    # what the requests did, beside the computed
+                    # columns of hop_events_simulated: a 500 skips
+                    # its script, so the hops under it never execute
+                    # (host integers off the summary already read
+                    # back, once a run)
+                    telemetry.counter_inc(
+                        "hop_events_executed",
+                        int(np.asarray(
+                            summary.metrics.incoming_total, np.float64
+                        ).sum()),
+                    )
+                    telemetry.counter_inc(
+                        "responses_500",
+                        int(np.asarray(
+                            summary.metrics.duration_hist, np.float64
+                        )[:, 1].sum()),
+                    )
                 run_telem = None
                 if telemetry.emitting():
                     # one scrape sees workload AND engine: append

@@ -65,6 +65,8 @@ def one_chip(topo):
 @pytest.mark.parametrize("name, hops, block", [
     ("1000-svc_2000-end.yaml", 1000, 33_554),
     ("tree-111-services.yaml", 111, 302_292),
+    # the error coins and the live code masks (PR 34)
+    ("realistic-multitier-100-errors.yaml", 100, 335_544),
 ])
 def test_cli_summary_program_compiles_for_v5e(one_chip, name, hops, block):
     """The program ``isotope-tpu simulate <graph> --qps 1000 --duration

@@ -120,6 +120,9 @@ def test_served_call_counters(served):
     count = doc["DurationHistogram"]["Count"]
     assert snap.counters["requests_simulated"] == count
     assert snap.counters["hop_events_simulated"] == count * HOPS
+    # no errorRate in this graph: every computed column executed
+    assert snap.counters["hop_events_executed"] == count * HOPS
+    assert snap.counters["responses_500"] == 0
     assert snap.counters["runs_served"] == 1
     assert snap.counters["graphs_decoded"] == 1
     # ... by libyaml where the installed PyYAML carries it

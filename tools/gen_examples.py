@@ -78,6 +78,17 @@ def main() -> None:
             ),
         )
 
+    # the multitier archetype at a hundred services, every callee
+    # failing at the rate upstream's README documents `errorRate` with:
+    # the benchmark's `powerlaw100` (benchmark/configs/powerlaw100.json)
+    dump(
+        "realistic-multitier-100-errors.yaml",
+        generators.realistic_topology(
+            num_services=100, archetype="multitier", seed=0,
+            callee_error_rate="0.01%",
+        ),
+    )
+
     # Zipf out-degree skew with heterogeneous sleeps/error rates: the
     # ingest self-closure fixture (tools/ingest_smoke.py simulates it,
     # exports the exposition, and re-fits it back)
