@@ -52,6 +52,10 @@ CLIENT_NAME = "fortio-client"
 
 _NB = len(DURATION_BUCKETS) + 1  # +overflow (+Inf)
 
+# Where a histogram row's label goes in its family's template; no
+# number prints it.
+_ROW_LABEL = "\0"
+
 
 def escape_label_value(value: str) -> str:
     """Prometheus text-format label-value escaping (backslash, quote,
@@ -271,9 +275,14 @@ class MetricsCollector:
             return self.resource_text(
                 None, summary.utilization, float(summary.end_max)
             )
-        return self.to_text(summary.metrics) + self.resource_text(
-            summary.metrics, summary.utilization, float(summary.end_max)
-        )
+        m = jax.device_get(summary.metrics)  # one readback for both texts
+        out, rows, general = self._render(m)
+        telemetry.counter_inc("exposition_rows_rendered", rows)
+        telemetry.counter_inc("exposition_rows_general", general)
+        out.append(self.resource_text(
+            m, summary.utilization, float(summary.end_max)
+        ))
+        return "".join(out)
 
     def resource_text(self, m: ServiceMetrics, utilization,
                       duration_s: float) -> str:
@@ -346,100 +355,106 @@ class MetricsCollector:
 
     def to_text(self, m: ServiceMetrics) -> str:
         """Render the Prometheus text exposition format."""
+        return "".join(self._render(jax.device_get(m))[0])
+
+    def _render(self, m: ServiceMetrics) -> Tuple[List[str], int, int]:
+        """``to_text`` of host arrays as chunks that each end in a
+        newline, with the histogram rows rendered and how many of them
+        took the per-value path (`_histogram`)."""
         names = self.compiled.services.names
-
-        def ename(i: int) -> str:
-            return CLIENT_NAME if i < 0 else names[i]
-
-        out: List[str] = []
-
-        out.append(
+        by_edge = [
+            f'service="{CLIENT_NAME if s < 0 else names[s]}",'
+            f'destination_service="{names[d]}"'
+            for s, d in self.edges
+        ]
+        by_code = [
+            f'service="{name}",code="{code}"'
+            for name in names for code in ("200", "500")
+        ]
+        out: List[str] = [
             "# HELP service_incoming_requests_total Number of requests sent"
-            " to this service."
-        )
-        out.append("# TYPE service_incoming_requests_total counter")
-        inc = np.asarray(m.incoming_total)
-        for s, name in enumerate(names):
-            out.append(
-                f'service_incoming_requests_total{{service="{name}"}}'
-                f" {inc[s]:.10g}"
-            )
-
+            " to this service.\n"
+            "# TYPE service_incoming_requests_total counter\n"
+        ]
+        out += [
+            f'service_incoming_requests_total{{service="{name}"}} {v:.10g}\n'
+            for name, v in zip(names, np.asarray(m.incoming_total).tolist())
+        ]
         out.append(
             "# HELP service_outgoing_requests_total Number of requests sent"
-            " from this service."
+            " from this service.\n"
+            "# TYPE service_outgoing_requests_total counter\n"
         )
-        out.append("# TYPE service_outgoing_requests_total counter")
-        outc = np.asarray(m.outgoing_total)
-        for e, (src, dst) in enumerate(self.edges):
-            out.append(
-                "service_outgoing_requests_total{"
-                f'service="{ename(src)}",destination_service="{ename(dst)}"'
-                f"}} {outc[e]:.10g}"
+        out += [
+            f"service_outgoing_requests_total{{{label}}} {v:.10g}\n"
+            for label, v in zip(
+                by_edge, np.asarray(m.outgoing_total).tolist()
             )
-
-        self._histogram(
+        ]
+        rows = len(by_edge) + 2 * len(by_code)
+        general = self._histogram(
             out,
             "service_outgoing_request_size",
             "Size in bytes of requests sent from this service.",
             SIZE_BUCKETS,
-            np.asarray(m.outgoing_size_hist),
-            np.asarray(m.outgoing_size_sum),
-            [
-                (
-                    f'service="{ename(src)}",'
-                    f'destination_service="{ename(dst)}"'
-                )
-                for src, dst in self.edges
-            ],
+            m.outgoing_size_hist,
+            m.outgoing_size_sum,
+            by_edge,
         )
-
-        dur = np.asarray(m.duration_hist)
-        dur_sum = np.asarray(m.duration_sum)
-        labels, rows, sums = self._by_code(names, dur, dur_sum)
-        self._histogram(
+        general += self._histogram(
             out,
             "service_request_duration_seconds",
             "Duration in seconds it took to serve requests to this service.",
             DURATION_BUCKETS,
-            rows,
-            sums,
-            labels,
+            np.asarray(m.duration_hist).reshape(len(by_code), -1),
+            np.asarray(m.duration_sum).reshape(-1),
+            by_code,
         )
-
-        resp = np.asarray(m.response_size_hist)
-        resp_sum = np.asarray(m.response_size_sum)
-        labels, rows, sums = self._by_code(names, resp, resp_sum)
-        self._histogram(
+        general += self._histogram(
             out,
             "service_response_size",
             "Size in bytes of responses sent from this service.",
             SIZE_BUCKETS,
-            rows,
-            sums,
-            labels,
+            np.asarray(m.response_size_hist).reshape(len(by_code), -1),
+            np.asarray(m.response_size_sum).reshape(-1),
+            by_code,
         )
-        return "\n".join(out) + "\n"
+        return out, rows, general
 
     @staticmethod
-    def _by_code(names, hist, sums):
-        labels, rows, row_sums = [], [], []
-        for s, name in enumerate(names):
-            for ci, code in enumerate(("200", "500")):
-                labels.append(f'service="{name}",code="{code}"')
-                rows.append(hist[s, ci])
-                row_sums.append(sums[s, ci])
-        return labels, np.asarray(rows), np.asarray(row_sums)
-
-    @staticmethod
-    def _histogram(out, name, help_text, buckets, rows, sums, labels):
-        out.append(f"# HELP {name} {help_text}")
-        out.append(f"# TYPE {name} histogram")
-        rows = np.asarray(rows)
-        for row, s, label in zip(rows, np.asarray(sums), labels):
-            cum = np.cumsum(row)
-            for le, c in zip(buckets, cum[:-1]):
-                out.append(f'{name}_bucket{{{label},le="{le:g}"}} {c:.10g}')
-            out.append(f'{name}_bucket{{{label},le="+Inf"}} {cum[-1]:.10g}')
-            out.append(f"{name}_sum{{{label}}} {s:.10g}")
-            out.append(f"{name}_count{{{label}}} {cum[-1]:.10g}")
+    def _histogram(out, name, help_text, buckets, rows, sums, labels) -> int:
+        """Append one histogram family a ROW at a time: every row fills
+        the family's one template (its lines with a slot a value and
+        ``_ROW_LABEL`` where the row's label goes), the numbers
+        converted an array at a time.  Cumulative counts that are all
+        whole, unsigned and under 1e10 print as the integers ``.10g``
+        would print; any other family keeps ``.10g`` a value, and its
+        rows not all zero are the count returned.  The label goes in
+        after the numbers, so no name can meet the ``%`` operator."""
+        out.append(f"# HELP {name} {help_text}\n# TYPE {name} histogram\n")
+        cum = np.cumsum(np.asarray(rows), axis=1)  # in the rows' dtype
+        sums = np.asarray(sums)
+        whole = bool(
+            ((cum == np.floor(cum)) & (cum < 1e10) & ~np.signbit(cum)).all()
+        )
+        slot = "%d" if whole else "%.10g"
+        stem = f"{name}_bucket{{{_ROW_LABEL},le="
+        template = (
+            "".join(f'{stem}"{le:g}"}} {slot}\n' for le in buckets)
+            + f'{stem}"+Inf"}} {slot}\n'
+            + f"{name}_sum{{{_ROW_LABEL}}} %.10g\n"
+            + f"{name}_count{{{_ROW_LABEL}}} {slot}\n"
+        )
+        blank = template % ((0,) * (len(buckets) + 3))
+        vals = np.concatenate([cum, sums[:, None]], axis=1)
+        zero = ~(vals.astype(bool) | np.signbit(vals)).any(axis=1)
+        counts = (cum.astype(np.int64) if whole else cum).tolist()
+        out += [
+            (blank if z else template % (*c, s, c[-1])).replace(
+                _ROW_LABEL, label
+            )
+            for label, z, c, s in zip(
+                labels, zero.tolist(), counts, sums.tolist()
+            )
+        ]
+        return 0 if whole else len(labels) - int(zero.sum())

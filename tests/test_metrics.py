@@ -1,16 +1,19 @@
 """Metrics layer tests: Prometheus series parity + Fortio schema."""
 import json
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from isotope_tpu import telemetry
 from isotope_tpu.compiler import compile_graph
 from isotope_tpu.metrics import (
     DURATION_BUCKETS,
     MetricsCollector,
     SIZE_BUCKETS,
+    ServiceMetrics,
     convert_data,
     fortio_result,
     trim_window_summary,
@@ -404,3 +407,272 @@ def test_collect_scatters_hop_rows_not_hop_events(run):
     assert sizes, "collect no longer scatters: rewrite this guard"
     assert max(sizes) <= compiled.num_hops * 2 * (len(DURATION_BUCKETS) + 1)
     assert res.hop_sent.size > max(sizes)
+
+
+# -- exposition: the row-template renderer against the per-line one ----------
+# The plain reference is the renderer as it stood before rows were
+# rendered from a family's template: one f-string a line, one
+# np.cumsum a row.  It imports the bucket constants and nothing else.
+
+
+def _reference_histogram(out, name, help_text, buckets, rows, sums, labels):
+    out.append(f"# HELP {name} {help_text}")
+    out.append(f"# TYPE {name} histogram")
+    rows = np.asarray(rows)
+    for row, s, label in zip(rows, np.asarray(sums), labels):
+        cum = np.cumsum(row)
+        for le, c in zip(buckets, cum[:-1]):
+            out.append(f'{name}_bucket{{{label},le="{le:g}"}} {c:.10g}')
+        out.append(f'{name}_bucket{{{label},le="+Inf"}} {cum[-1]:.10g}')
+        out.append(f"{name}_sum{{{label}}} {s:.10g}")
+        out.append(f"{name}_count{{{label}}} {cum[-1]:.10g}")
+
+
+def _reference_by_code(names, hist, sums):
+    labels, rows, row_sums = [], [], []
+    for s, name in enumerate(names):
+        for ci, code in enumerate(("200", "500")):
+            labels.append(f'service="{name}",code="{code}"')
+            rows.append(hist[s, ci])
+            row_sums.append(sums[s, ci])
+    return labels, np.asarray(rows), np.asarray(row_sums)
+
+
+def _reference_text(names, edges, m):
+    def ename(i):
+        return "fortio-client" if i < 0 else names[i]
+
+    out = [
+        "# HELP service_incoming_requests_total Number of requests sent"
+        " to this service.",
+        "# TYPE service_incoming_requests_total counter",
+    ]
+    inc = np.asarray(m.incoming_total)
+    for s, name in enumerate(names):
+        out.append(
+            f'service_incoming_requests_total{{service="{name}"}}'
+            f" {inc[s]:.10g}"
+        )
+    out.append(
+        "# HELP service_outgoing_requests_total Number of requests sent"
+        " from this service."
+    )
+    out.append("# TYPE service_outgoing_requests_total counter")
+    outc = np.asarray(m.outgoing_total)
+    for e, (src, dst) in enumerate(edges):
+        out.append(
+            "service_outgoing_requests_total{"
+            f'service="{ename(src)}",destination_service="{ename(dst)}"'
+            f"}} {outc[e]:.10g}"
+        )
+    _reference_histogram(
+        out, "service_outgoing_request_size",
+        "Size in bytes of requests sent from this service.", SIZE_BUCKETS,
+        np.asarray(m.outgoing_size_hist), np.asarray(m.outgoing_size_sum),
+        [
+            f'service="{ename(src)}",destination_service="{ename(dst)}"'
+            for src, dst in edges
+        ],
+    )
+    labels, rows, sums = _reference_by_code(
+        names, np.asarray(m.duration_hist), np.asarray(m.duration_sum)
+    )
+    _reference_histogram(
+        out, "service_request_duration_seconds",
+        "Duration in seconds it took to serve requests to this service.",
+        DURATION_BUCKETS, rows, sums, labels,
+    )
+    labels, rows, sums = _reference_by_code(
+        names, np.asarray(m.response_size_hist),
+        np.asarray(m.response_size_sum),
+    )
+    _reference_histogram(
+        out, "service_response_size",
+        "Size in bytes of responses sent from this service.", SIZE_BUCKETS,
+        rows, sums, labels,
+    )
+    return "\n".join(out) + "\n"
+
+
+_ODD_NAMES_YAML = r"""
+services:
+- name: "100%d{svc}\"q\""
+  isEntrypoint: true
+  script:
+  - call: "%s%%"
+  - call: "nul\0in{0}"
+- name: "%s%%"
+- name: "nul\0in{0}"
+"""
+
+_ONE_SERVICE_YAML = """
+services:
+- name: alone
+  isEntrypoint: true
+"""
+
+
+def _whole_metrics(collector, live_500, dtype=np.float32):
+    """Whole counts of a served call's size (268,288 requests a service,
+    spread over the buckets), the 500 rows all zero or live."""
+    rng = np.random.default_rng(35)
+    S, E = collector.compiled.num_services, len(collector.edges)
+    nd, ns = len(DURATION_BUCKETS) + 1, len(SIZE_BUCKETS) + 1
+
+    def hist(shape, total):
+        h = rng.multinomial(total, np.full(shape[-1], 1 / shape[-1]),
+                            size=shape[:-1])
+        return h.astype(dtype)
+
+    dur = np.zeros((S, 2, nd), dtype)
+    resp = np.zeros((S, 2, ns), dtype)
+    dsum = np.zeros((S, 2), dtype)
+    rsum = np.zeros((S, 2), dtype)
+    dur[:, 0] = hist((S, nd), 268_288)
+    resp[:, 0, 3] = 268_288
+    dsum[:, 0] = rng.uniform(300.0, 900.0, S)       # fractional sums
+    rsum[:, 0] = 268_288 * 512.0                     # whole sums
+    if live_500:
+        dur[:, 1] = hist((S, nd), 24)
+        resp[:, 1, 3] = 24
+        dsum[:, 1] = rng.uniform(0.01, 0.2, S)
+        rsum[:, 1] = 24 * 512.0
+    out_hist = hist((E, ns), 268_288)
+    return ServiceMetrics(
+        incoming_total=np.full(S, 268_288, dtype),
+        outgoing_total=out_hist.sum(1),
+        outgoing_size_hist=out_hist,
+        outgoing_size_sum=out_hist.sum(1) * dtype(128),
+        duration_hist=dur,
+        duration_sum=dsum,
+        response_size_hist=resp,
+        response_size_sum=rsum,
+    )
+
+
+def _set(field, index, value):
+    def apply(m):
+        getattr(m, field)[index] = value
+    return apply
+
+
+def _over_2p24(m):
+    # float32 running sums stop counting by one at 2**24: the text is
+    # the rounded cumulative count, as the per-row cumsum printed it
+    m.duration_hist[0, 0, :6] = [2.0 ** 24, 1, 1, 3, 5, 2.0 ** 25]
+    m.outgoing_size_hist[0, :4] = [2.0 ** 24 - 1, 1, 1, 1]
+
+
+def _sums(m):
+    m.duration_sum[0] = [0.0, 3.0]            # zero beside live counts
+    m.duration_hist[1, 1] = 0
+    m.duration_sum[1, 1] = 0.125              # a sum beside zero counts
+    m.response_size_sum[0, 1] = np.nan        # ... and one that is no number
+    m.response_size_hist[0, 1] = 0
+    m.outgoing_size_sum[0] = 1e12             # whole, in exponent notation
+
+
+def _negative_zero(m):
+    m.duration_hist[0, 1] = -0.0              # prints "-0", so not blank
+    m.response_size_hist[0, 1] = 0
+    m.response_size_sum[0, 1] = -0.0
+
+
+# yaml, live 500 rows, dtype, edit of the metrics, rows on the per-value
+# path: those of a family with a count that is no whole number under
+# 1e10, less its rows that are all zero.  YAML and _ODD_NAMES_YAML have
+# three services and three edges: 3 + 6 + 6 rows.
+_EXPOSITION_CASES = {
+    "whole_counts_zero_500_rows": (YAML, False, np.float32, None, 0),
+    "whole_counts_live_500_rows": (YAML, True, np.float32, None, 0),
+    "whole_counts_float64": (YAML, True, np.float64, None, 0),
+    "fractional_count": (
+        YAML, True, np.float32, _set("duration_hist", (0, 0, 3), 0.5), 6),
+    "count_of_1e10": (
+        YAML, True, np.float32, _set("response_size_hist", (1, 0, 2), 1e10),
+        6),
+    "nan_count": (
+        YAML, False, np.float32,
+        _set("outgoing_size_hist", (0, 1), np.nan), 3),
+    "inf_count": (
+        YAML, False, np.float32,
+        _set("duration_hist", (2, 0, 9), np.inf), 3),
+    "negative_count": (
+        YAML, False, np.float32, _set("duration_hist", (1, 1, 0), -3.0), 4),
+    "negative_zero": (YAML, False, np.float32, _negative_zero, 4),
+    "count_above_2p24": (YAML, True, np.float32, _over_2p24, 0),
+    "sums_zero_whole_fractional_nan": (YAML, True, np.float32, _sums, 0),
+    "names_with_percent_brace_quote_nul": (
+        _ODD_NAMES_YAML, True, np.float32, None, 0),
+    "names_on_the_per_value_path": (
+        _ODD_NAMES_YAML, True, np.float32,
+        _set("duration_hist", (0, 0, 3), 0.5), 6),
+    "one_service": (_ONE_SERVICE_YAML, True, np.float32, None, 0),
+    "one_service_zero_500_rows": (
+        _ONE_SERVICE_YAML, False, np.float32, None, 0),
+}
+
+
+def _summary_of(collector, m):
+    S = collector.compiled.num_services
+    return types.SimpleNamespace(
+        metrics=m, utilization=np.linspace(0.1, 0.7, S), end_max=240.5
+    )
+
+
+def _exposition_counters():
+    return (
+        telemetry.counter_get("exposition_rows_rendered"),
+        telemetry.counter_get("exposition_rows_general"),
+    )
+
+
+@pytest.mark.parametrize("case", sorted(_EXPOSITION_CASES))
+def test_exposition_is_the_per_line_renderers_bytes(case):
+    yaml_text, live_500, dtype, edit, per_value_rows = (
+        _EXPOSITION_CASES[case]
+    )
+    collector = MetricsCollector(
+        compile_graph(ServiceGraph.from_yaml(yaml_text))
+    )
+    names = collector.compiled.services.names
+    m = _whole_metrics(collector, live_500, dtype)
+    if edit is not None:
+        edit(m)
+    want = _reference_text(names, collector.edges, m)
+    before = _exposition_counters()
+    assert collector.to_text(m) == want
+    if dtype is np.float32:  # device arrays render as their host copies
+        assert collector.to_text(
+            type(m)(*(jnp.asarray(a) for a in m))
+        ) == want
+    # a render alone counts nothing; full_text counts what it rendered
+    assert _exposition_counters() == before
+    summary = _summary_of(collector, m)
+    assert collector.full_text(summary) == want + collector.resource_text(
+        m, summary.utilization, summary.end_max
+    )
+    rendered, general = (
+        a - b for a, b in zip(_exposition_counters(), before)
+    )
+    assert rendered == len(collector.edges) + 4 * len(names)
+    assert general == per_value_rows
+
+
+def test_full_text_without_collector_metrics_is_the_resource_text():
+    collector = MetricsCollector(compile_graph(ServiceGraph.from_yaml(YAML)))
+    summary = _summary_of(collector, None)
+    before = _exposition_counters()
+    assert collector.full_text(summary) == collector.resource_text(
+        None, summary.utilization, summary.end_max
+    )
+    assert _exposition_counters() == before
+
+
+def test_exposition_of_a_simulated_run_matches_the_reference(run):
+    compiled, res = run
+    collector = MetricsCollector(compiled)
+    m = collector.collect(res)
+    assert collector.to_text(m) == _reference_text(
+        compiled.services.names, collector.edges, m
+    )
