@@ -644,6 +644,7 @@ def lint_compiled(compiled, params=None) -> List[Finding]:
         sparse = False
         tiles = None
         residual_slots = 0
+        tile_real_elems = 0
         if lvl.num_calls:
             n_slots = len(np.unique(lvl.call_seg))
             widths = lvl.step_is_real[:, :pmax].sum(1)
@@ -656,6 +657,7 @@ def lint_compiled(compiled, params=None) -> List[Finding]:
             sparse = enc != "dense"
             if enc == "tiled":
                 tiles = tile_plan.shapes()
+                tile_real_elems = tile_plan.real_elems
                 res_widths = widths[tile_plan.residual]
                 # EXACT residual slot count (call-bearing steps of the
                 # residual hops) — the engine's tiled.residual.n_slots,
@@ -703,6 +705,7 @@ def lint_compiled(compiled, params=None) -> List[Finding]:
             calls=lvl.num_calls, attempts=lvl.max_attempts,
             sparse=sparse, offset=offset, tiles=tiles,
             residual_slots=residual_slots,
+            tile_real_elems=tile_real_elems,
         ))
         offset += lvl.num_hops
     plan = buckets.plan_segments(

@@ -72,6 +72,8 @@ class LevelShape:
     # (tiled levels execute as one unrolled segment)
     tiles: Optional[Tuple[Tuple[int, int], ...]] = None
     residual_slots: int = 0
+    # real (hop, step) cells among the tiles' padded ones
+    tile_real_elems: int = 0
 
     @property
     def leaf(self) -> bool:
@@ -369,11 +371,14 @@ class TilePlan:
     ``tiles`` holds (width, hop-index-array) bins — each becomes a
     dense (size x width) sub-grid padded to the bin's widest script —
     and ``residual`` the hop indices that stay on the true sparse
-    call-slot encoding (scripts wider than the tile cap).
+    call-slot encoding (scripts wider than the tile cap);
+    ``real_elems`` counts the real steps of the tiled hops, the part of
+    ``tiled_elems`` that is not padding.
     """
 
     tiles: Tuple[Tuple[int, np.ndarray], ...]
     residual: np.ndarray
+    real_elems: int
 
     @property
     def tiled_elems(self) -> int:
@@ -419,7 +424,8 @@ def plan_tiles(
             end += 1
         tiles.append((wmax, np.sort(order[start:end])))
         start = end
-    return TilePlan(tiles=tuple(tiles), residual=np.sort(residual))
+    return TilePlan(tiles=tuple(tiles), residual=np.sort(residual),
+                    real_elems=int(widths[tileable].sum()))
 
 
 def level_encoding(
@@ -500,10 +506,39 @@ def plan_stats(shapes: Sequence[LevelShape],
     }
 
 
+def encoding_stats(shapes: Sequence[LevelShape]) -> dict:
+    """What the levels that left the dense (hops x steps) grid were
+    planned as (:func:`level_encoding`), summed over one plan: the
+    tiled levels, their hops, their tiles' padded and real (hop, step)
+    cells, the slots on the sparse call-slot sweep (tiled levels'
+    residuals and pure-sparse levels) and the dense grids avoided (the
+    element-slots VET-C006 prints)."""
+    tiled = [s for s in shapes if s.tiles is not None]
+    return {
+        "levels_tiled": len(tiled),
+        "hops_in_tiled_levels": sum(s.size for s in tiled),
+        "tile_padded_elems": sum(
+            t_size * t_w for s in tiled for t_size, t_w in s.tiles
+        ),
+        "tile_real_elems": sum(s.tile_real_elems for s in tiled),
+        "sparse_residual_slots": sum(
+            s.residual_slots for s in shapes if s.sparse
+        ),
+        "dense_grid_elems_avoided": sum(
+            s.size * s.pmax for s in shapes if s.sparse
+        ),
+    }
+
+
 def _record_plan(shapes: Sequence[LevelShape],
                  segs: Sequence[Segment]) -> None:
     """Fold one plan's stats into the engine telemetry registry."""
     st = plan_stats(shapes, segs)
+    # absent where every level is dense, so a plan without a tiled or
+    # sparse level leaves the registry as it was
+    for name, value in encoding_stats(shapes).items():
+        if value:
+            telemetry.counter_inc(name, value)
     telemetry.counter_inc("bucket_plans")
     telemetry.counter_inc("buckets_formed", st["num_buckets"])
     # the same count and the hops the buckets sweep, under the names

@@ -548,6 +548,86 @@ def _sparse_level_sweep(
     return busy, fail_step, off
 
 
+def _tile_sweep(
+    tile: _Tile,
+    n: int,
+    P: int,
+    dur_call: jax.Array,                 # (n, K) the LEVEL's calls
+    final_transport: Optional[jax.Array],  # (n, K) or None
+    err_lvl: Optional[jax.Array],        # (n, L) the level's 500 coins
+):
+    """The dense step-grid sweep over one tile of a tiled level.
+
+    Returns ``(busy, fail_step, off)`` over the tile's hops and
+    children, as :func:`_sparse_level_sweep` does over the residual's:
+    ``fail_step`` is ``None`` when no transport failure can occur and
+    ``off`` is ``None`` when the tile's hops have no children.  The ops
+    are the dense grid's on exactly the tile's rows.
+    """
+    T, W = len(tile.hops), tile.width
+    transportable = final_transport is not None
+    need_off = tile.child_sel.size > 0
+    if tile.call_sel.size:
+        dc = dur_call[:, tile.call_sel]
+        if tile.uniform_calls is not None:
+            agg = dc.reshape(n, T, W, tile.uniform_calls).max(-1)
+        else:
+            agg = (
+                jnp.zeros((n, T * W))
+                .at[:, tile.call_seg]
+                .max(dc)
+                .reshape(n, T, W)
+            )
+    else:
+        agg = None
+    fail_t = None
+    if transportable:
+        if tile.call_sel.size:
+            ft = final_transport[:, tile.call_sel]
+            fail_contrib = jnp.where(ft, tile.call_step, P).astype(
+                jnp.int32
+            )
+            if tile.uniform_calls is not None:
+                fail_t = fail_contrib.reshape(
+                    n, T, W * tile.uniform_calls
+                ).min(-1)
+            else:
+                fail_t = (
+                    jnp.full((n, T), P, jnp.int32)
+                    .at[:, tile.call_pos]
+                    .min(fail_contrib)
+                )
+        else:
+            # call-free rows cannot transport-fail
+            fail_t = jnp.full((n, T), P, jnp.int32)
+    prefix = None
+    if agg is None:
+        # the dense grid's agg is all-zero here
+        busy_t = jnp.broadcast_to(
+            (jnp.maximum(tile.step_base, 0.0) * tile.step_mask).sum(-1),
+            (n, T),
+        )
+    else:
+        step_dur_t = jnp.maximum(tile.step_base, agg) * tile.step_mask
+        if fail_t is not None:
+            step_dur_t = step_dur_t * (
+                jnp.arange(W, dtype=jnp.int32) <= fail_t[:, :, None]
+            )
+        busy_t = step_dur_t.sum(-1)
+        if need_off:
+            prefix = jnp.cumsum(step_dur_t, axis=-1) - step_dur_t
+    off_t = None
+    if need_off:
+        off_t = prefix.reshape(n, -1)[
+            :, tile.child_pos * W + tile.child_step
+        ]
+        if err_lvl is not None:
+            # dense zeroes the grid before the prefix for a 500ing
+            # parent — match
+            off_t = off_t * ~err_lvl[:, tile.hops][:, tile.child_pos]
+    return busy_t, fail_t, off_t
+
+
 # one definition serves both executors: the scan twin's bit-for-bit
 # contract requires the attempt-outcome ops to stay in exact lockstep
 _call_outcome = levelscan.call_outcome
@@ -1263,6 +1343,9 @@ class Simulator:
                     if tiled is not None and tiled.residual is not None
                     else (sparse.n_slots if sparse is not None else 0)
                 ),
+                tile_real_elems=(
+                    tile_plan.real_elems if tiled is not None else 0
+                ),
             )
             if params.bucketed_scan and not (meta["sparse"]
                                              or meta["leaf"]):
@@ -1357,6 +1440,7 @@ class Simulator:
                 calls=m["K"], attempts=m["A"], sparse=m["sparse"],
                 offset=m["offset"], tiles=m.get("tiles"),
                 residual_slots=m.get("residual_slots", 0),
+                tile_real_elems=m.get("tile_real_elems", 0),
             )
             for m in np_meta
         ]
@@ -5445,17 +5529,18 @@ class Simulator:
                         # busy times are packed segment sums, pure-sleep
                         # steps are static (_sparse_level_sweep — shared
                         # with the tiled encoding's residual part).
-                        busy, fail_step, off = _sparse_level_sweep(
-                            lvl.sparse, n, P, lvl.size, dur_call,
-                            final_transport,
-                            (
-                                err_coin[:, sl]
-                                if err_coin is not None
-                                else None
-                            ),
-                            lvl.child_parent_local,
-                            lvl.child_step,
-                        )
+                        with jax.named_scope("residual"):
+                            busy, fail_step, off = _sparse_level_sweep(
+                                lvl.sparse, n, P, lvl.size, dur_call,
+                                final_transport,
+                                (
+                                    err_coin[:, sl]
+                                    if err_coin is not None
+                                    else None
+                                ),
+                                lvl.child_parent_local,
+                                lvl.child_step,
+                            )
                         if att_off is not None:
                             off = off + used_lvls[d] * att_off[:, :C]
                         off_lvls[d] = off
@@ -5477,100 +5562,36 @@ class Simulator:
                         fail_parts: List[jax.Array] = []
                         off_parts: List[jax.Array] = []
                         for tile in tl.tiles:
-                            T, W = len(tile.hops), tile.width
-                            need_off = tile.child_sel.size > 0
-                            if tile.call_sel.size:
-                                dc = dur_call[:, tile.call_sel]
-                                if tile.uniform_calls is not None:
-                                    agg = dc.reshape(
-                                        n, T, W, tile.uniform_calls
-                                    ).max(-1)
-                                else:
-                                    agg = (
-                                        jnp.zeros((n, T * W))
-                                        .at[:, tile.call_seg]
-                                        .max(dc)
-                                        .reshape(n, T, W)
-                                    )
-                            else:
-                                agg = None
-                            fail_t = None
-                            if transportable:
-                                if tile.call_sel.size:
-                                    ft = final_transport[:, tile.call_sel]
-                                    fail_contrib = jnp.where(
-                                        ft, tile.call_step, P
-                                    ).astype(jnp.int32)
-                                    if tile.uniform_calls is not None:
-                                        fail_t = fail_contrib.reshape(
-                                            n, T, W * tile.uniform_calls
-                                        ).min(-1)
-                                    else:
-                                        fail_t = (
-                                            jnp.full((n, T), P, jnp.int32)
-                                            .at[:, tile.call_pos]
-                                            .min(fail_contrib)
-                                        )
-                                else:
-                                    # call-free rows cannot transport-fail
-                                    fail_t = jnp.full((n, T), P, jnp.int32)
-                            prefix = None
-                            if agg is None:
-                                # the dense grid's agg is all-zero here
-                                busy_t = jnp.broadcast_to(
-                                    (
-                                        jnp.maximum(tile.step_base, 0.0)
-                                        * tile.step_mask
-                                    ).sum(-1),
-                                    (n, T),
+                            with jax.named_scope(
+                                f"tile[{len(tile.hops)}x{tile.width}]"
+                            ):
+                                busy_t, fail_t, off_t = _tile_sweep(
+                                    tile, n, P, dur_call,
+                                    final_transport, err_lvl,
                                 )
-                            else:
-                                step_dur_t = (
-                                    jnp.maximum(tile.step_base, agg)
-                                    * tile.step_mask
-                                )
-                                if fail_t is not None:
-                                    step_dur_t = step_dur_t * (
-                                        jnp.arange(W, dtype=jnp.int32)
-                                        <= fail_t[:, :, None]
-                                    )
-                                busy_t = step_dur_t.sum(-1)
-                                if need_off:
-                                    prefix = (
-                                        jnp.cumsum(step_dur_t, axis=-1)
-                                        - step_dur_t
-                                    )
                             busy_parts.append(busy_t)
                             if transportable:
                                 fail_parts.append(fail_t)
-                            if need_off:
-                                off_t = prefix.reshape(n, -1)[
-                                    :, tile.child_pos * W + tile.child_step
-                                ]
-                                if err_lvl is not None:
-                                    # dense zeroes the grid before the
-                                    # prefix for a 500ing parent — match
-                                    off_t = off_t * ~err_lvl[
-                                        :, tile.hops
-                                    ][:, tile.child_pos]
+                            if off_t is not None:
                                 off_parts.append(off_t)
                         if tl.residual is not None:
-                            busy_r, fail_r, off_r = _sparse_level_sweep(
-                                tl.residual, n, P, len(tl.res_hops),
-                                dur_call[:, tl.res_call_sel],
-                                (
-                                    final_transport[:, tl.res_call_sel]
-                                    if transportable
-                                    else None
-                                ),
-                                (
-                                    err_lvl[:, tl.res_hops]
-                                    if err_lvl is not None
-                                    else None
-                                ),
-                                tl.res_child_pos,
-                                tl.res_child_step,
-                            )
+                            with jax.named_scope("residual"):
+                                busy_r, fail_r, off_r = _sparse_level_sweep(
+                                    tl.residual, n, P, len(tl.res_hops),
+                                    dur_call[:, tl.res_call_sel],
+                                    (
+                                        final_transport[:, tl.res_call_sel]
+                                        if transportable
+                                        else None
+                                    ),
+                                    (
+                                        err_lvl[:, tl.res_hops]
+                                        if err_lvl is not None
+                                        else None
+                                    ),
+                                    tl.res_child_pos,
+                                    tl.res_child_step,
+                                )
                             busy_parts.append(busy_r)
                             if transportable:
                                 # a call-free residual cannot fail: carry
@@ -5584,19 +5605,20 @@ class Simulator:
                                 )
                             if tl.res_child_sel.size:
                                 off_parts.append(off_r)
-                        busy = jnp.concatenate(busy_parts, axis=1)[
-                            :, tl.hop_inv
-                        ]
-                        fail_step = (
-                            jnp.concatenate(fail_parts, axis=1)[
+                        with jax.named_scope("reassemble"):
+                            busy = jnp.concatenate(busy_parts, axis=1)[
                                 :, tl.hop_inv
                             ]
-                            if transportable
-                            else None
-                        )
-                        off = jnp.concatenate(off_parts, axis=1)[
-                            :, tl.child_inv
-                        ]
+                            fail_step = (
+                                jnp.concatenate(fail_parts, axis=1)[
+                                    :, tl.hop_inv
+                                ]
+                                if transportable
+                                else None
+                            )
+                            off = jnp.concatenate(off_parts, axis=1)[
+                                :, tl.child_inv
+                            ]
                         if att_off is not None:
                             off = off + used_lvls[d] * att_off[:, :C]
                         off_lvls[d] = off

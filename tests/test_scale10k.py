@@ -41,9 +41,9 @@ def test_10k_simulates_through_scan_path(compiled10k):
     assert not bool(s.unstable.any())
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_star10k_with_timeouts_keeps_sparse_encoding():
+    # host work only (two Simulator builds, ~11 s on the CPU); the
+    # served run of this graph is tests/test_star10k.py
     # BASELINE configs[3] names retries/timeouts on the 10k graph; the
     # star archetype's skewed hub level is exactly where the non-dense
     # step encodings matter (a dense grid block-starves it), and until
@@ -75,7 +75,6 @@ def test_star10k_with_timeouts_keeps_sparse_encoding():
     assert any(lvl.sparse is not None for lvl in sim_sp._levels)
 
 
-@pytest.mark.slow
 @pytest.mark.slow
 def test_100k_generates_and_compiles_host_side():
     # BASELINE configs[4]: generation is O(n log n) (Fenwick sampler)

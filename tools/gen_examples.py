@@ -89,6 +89,16 @@ def main() -> None:
         ),
     )
 
+    # the star archetype at the north star's 10,000 services: the
+    # benchmark's `star10k` (benchmark/configs/star10k.json), whose
+    # benchmark/topologies/ copy is this file byte for byte
+    dump(
+        "star-10000.yaml",
+        generators.realistic_topology(
+            num_services=10_000, archetype="star", seed=0
+        ),
+    )
+
     # Zipf out-degree skew with heterogeneous sleeps/error rates: the
     # ingest self-closure fixture (tools/ingest_smoke.py simulates it,
     # exports the exposition, and re-fits it back)
