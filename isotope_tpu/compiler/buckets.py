@@ -50,6 +50,20 @@ SEGMENT_OVERHEAD_ELEMS = 1 << 24
 #: fan-out bins keep tiles VPU-shaped; retune on a real capture.
 DEFAULT_TILE_PMAX = 64
 
+#: the graph size at which ``SimParams.sparse_level_elems`` (elements a
+#: REQUEST) is the floor as stated; under it the floor shrinks with the
+#: hops (level_encoding).  The device holds a level's grid a BLOCK at a
+#: time and the block is sized from the hops
+#: (Simulator.default_block_size: block x hops = one 33,554,432-element,
+#: 128 MiB float32 event tensor), so a dense step tensor is (grid /
+#: hops) event tensors whatever the graph's size.  At the default
+#: 262,144 the floor is 262,144 / 32,768 = 8 x hops: 8 event tensors =
+#: 2^28 elements = 1 GiB a float32 step tensor, 1/16 of a v5e's HBM.
+#: Between the vendored graphs' two populations: svc10k's widest level
+#: is 2.66 x hops (stays dense, in its scan bucket), star10k's level 2
+#: 17.6 x (2.35 GB a tensor at 99.8 % padding: leaves).
+SPARSE_LEVEL_REF_HOPS = 32_768
+
 #: critical-path DP lookback cap: buckets longer than this are not
 #: considered (keeps planning O(levels * cap); a >64-level scan body
 #: already amortizes its dispatch overhead to nothing)
@@ -434,6 +448,7 @@ def level_encoding(
     n_slots: int,
     widths: np.ndarray,
     *,
+    num_hops: int,
     sparse_level_elems: int,
     tiling: bool = True,
     tile_pmax: int = DEFAULT_TILE_PMAX,
@@ -445,12 +460,22 @@ def level_encoding(
     decision point shared by the engine's lowering and the vet linter,
     so the static analysis always reports the executor's real choice.
     A level leaves the dense grid when the grid is > 4x its real call
-    slots (or past ``sparse_level_elems``); it then tiles when the
-    dense-blocked plan halves the grid, else keeps the true sparse
-    encoding (tiny fully-skewed levels, e.g. one hub hop).
+    slots AND past the size floor.  The floor is what the program will
+    hold on the device, not elements a request: ``sparse_level_elems``
+    for a graph of ``SPARSE_LEVEL_REF_HOPS`` hops or more, and the
+    share ``num_hops / SPARSE_LEVEL_REF_HOPS`` of it for a smaller one
+    - 8 x ``num_hops`` at the default, i.e. a step tensor of 8 event
+    tensors under ``default_block_size``'s budget.  So
+    ``sparse_level_elems=1`` sends every skewed level off the grid and
+    ``10**9`` keeps any level a graph can have on it.  A level that
+    leaves tiles when the dense-blocked plan halves the grid, else
+    keeps the true sparse encoding (tiny fully-skewed levels, e.g. one
+    hub hop).
     """
     dense_elems = size * pmax
-    if dense_elems <= max(4 * n_slots, sparse_level_elems):
+    floor = (sparse_level_elems * min(num_hops, SPARSE_LEVEL_REF_HOPS)
+             // SPARSE_LEVEL_REF_HOPS)
+    if dense_elems <= max(4 * n_slots, floor):
         return "dense", None
     if not tiling:
         return "sparse", None

@@ -113,10 +113,16 @@ class SimParams:
     # engine), and a singleton's own wait has no within-group
     # correlation to transfer in the first place.
     hierarchical_copula_gamma: float = 0.9
-    # Dense-grid element threshold above which a skewed level (grid
-    # > 4x its real call-step count) leaves the dense step grid — the
-    # star-10k mitigation.  Lower it to force the non-dense path on
-    # small graphs (tests).
+    # Dense-grid size, in elements a REQUEST, above which a skewed level
+    # (grid > 4x its real call-step count) leaves the dense step grid —
+    # the star-10k mitigation.  It is the floor as stated for a graph
+    # of 32,768 hops or more; a smaller graph runs a larger block
+    # (default_block_size: block x hops is fixed), so its floor is
+    # that share of it — 8 x the graph's hops at this default, a step
+    # tensor of 8 event tensors = 1 GiB float32 a block
+    # (compiler/buckets.level_encoding, SPARSE_LEVEL_REF_HOPS).  1
+    # forces the non-dense path on any skewed level, 10**9 the dense
+    # grid (tests).
     sparse_level_elems: int = 262_144
     # Dense-blocked sparse levels (engine._TiledSteps): a level past
     # the sparse threshold is partitioned into fixed-width dense tiles

@@ -1313,6 +1313,7 @@ class Simulator:
                 widths = lvl.step_is_real[:, :pmax].sum(1)
                 enc, tile_plan = buckets.level_encoding(
                     lvl.num_hops, pmax, n_slots, widths,
+                    num_hops=compiled.num_hops,
                     sparse_level_elems=params.sparse_level_elems,
                     tiling=params.sparse_tiling,
                     tile_pmax=params.sparse_tile_pmax,

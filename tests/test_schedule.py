@@ -253,3 +253,52 @@ services:
         trace=False,
     )
     assert not [f for f in rep2.findings if f.rule == "VET-C006"]
+
+
+def test_vet_accounts_for_the_vendored_stars_tiled_levels(monkeypatch):
+    """``isotope-tpu vet`` on the benchmark's ``star10k``: the linter
+    plans from the shapes the engine lowers (one decision,
+    ``buckets.level_encoding``, the graph's hops passed by both), so
+    levels 1 and 2 are tiled in its account too, and VET-C006 names
+    the one level with hubs on the residual sparse path."""
+    import os
+
+    from isotope_tpu.analysis import topo_lint, vet_simulator
+    from isotope_tpu.compiler import buckets, compile_graph
+    from isotope_tpu.models.graph import ServiceGraph
+    from isotope_tpu.sim import LoadModel, SimParams, Simulator
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "topologies", "star-10000.yaml")
+    g = ServiceGraph.from_yaml_file(path)
+    compiled = compile_graph(g)
+    sim = Simulator(compiled, SimParams())
+
+    planned = []
+    plan_segments = buckets.plan_segments
+
+    def spy(shapes, **kw):
+        planned.append(tuple(shapes))
+        return plan_segments(shapes, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr(buckets, "plan_segments", spy)
+        findings = topo_lint.lint_compiled(compiled, SimParams())
+    assert planned == [sim._plan_shapes]
+    assert [d for d, s in enumerate(planned[0]) if s.tiles] == [1, 2]
+    c006 = [f for f in findings if f.rule == "VET-C006"]
+    # level 2 has no script past the tile cap: nothing of it is sparse
+    assert [f.path for f in c006] == ["levels[1]"]
+    assert "8 of 5021 hop(s) at depth 1" in c006[0].message
+    assert "(3878 slot(s))" in c006[0].message
+    assert "11131557 element-slots" in c006[0].message
+
+    report = vet_simulator(
+        sim, LoadModel(kind="open", qps=10.0), graph=g, trace=False)
+    rows = {r["position"]: r["kind"]
+            for r in report.meta["bucket_schedule"]}
+    assert rows == {0: "unrolled", 1: "tiled", 2: "tiled",
+                    3: "unrolled", 4: "leaf"}
+    assert [f.path for f in report.findings
+            if f.rule == "VET-C006"] == ["levels[1]"]

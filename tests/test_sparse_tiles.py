@@ -9,6 +9,7 @@ dense contract.  The tiling decision itself lives in
 compiler/buckets.level_encoding and is shared with the vet linter.
 """
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -370,23 +371,131 @@ def test_level_encoding_decision_points():
     # tight grid: stays dense
     enc, _ = level_encoding(
         4, 2, 8, np.asarray([2, 2, 2, 2]),
-        sparse_level_elems=262_144,
+        num_hops=16, sparse_level_elems=262_144,
     )
     assert enc == "dense"
     # skewed + tiling on: tiles
     enc, plan = level_encoding(
-        1000, 500, 1499, widths, sparse_level_elems=1,
+        1000, 500, 1499, widths, num_hops=2500, sparse_level_elems=1,
     )
     assert enc == "tiled" and plan is not None
     assert len(plan.residual) == 1
     # tiling off: the true sparse encoding
     enc, plan = level_encoding(
-        1000, 500, 1499, widths, sparse_level_elems=1, tiling=False,
+        1000, 500, 1499, widths, num_hops=2500, sparse_level_elems=1,
+        tiling=False,
     )
     assert enc == "sparse" and plan is None
     # a single wide mostly-sleep hop: every hop is past the tile cap,
     # tiling saves nothing — the true sparse encoding keeps the level
     enc, plan = level_encoding(
-        1, 500, 10, np.asarray([500]), sparse_level_elems=1,
+        1, 500, 10, np.asarray([500]), num_hops=11, sparse_level_elems=1,
     )
     assert enc == "sparse" and plan is None
+
+
+# the two populations of the vendored graphs (PERF.md, PR 38), as
+# (hops of the level, widest script, call slots, script widths): the
+# widest level of the 10,000-service multitier mesh is 2.66 x the
+# graph's hops, level 2 of the 10,000-service star 17.6 x
+_MULTITIER_L8 = (1402, 19, 1164,
+                 np.asarray([0] * 600 + [1] * 700 + [4] * 80 + [12] * 21
+                            + [19]))
+_STAR_L2 = (4641, 38, 330,
+            np.asarray([0] * 4400 + [1] * 197 + [4] * 20 + [11] * 19
+                       + [26] * 4 + [38]))
+
+
+@pytest.mark.parametrize("level, kw, want", [
+    # default floor at 10,000 hops: 8 x hops = 80,000 elements
+    (_MULTITIER_L8, {}, "dense"),                     # 26,638
+    (_STAR_L2, {}, "tiled"),                          # 176,358
+    (_STAR_L2, {"tiling": False}, "sparse"),
+    # the knob still forces each way
+    (_MULTITIER_L8, {"sparse_level_elems": 1}, "tiled"),
+    (_STAR_L2, {"sparse_level_elems": 1}, "tiled"),
+    (_STAR_L2, {"sparse_level_elems": 10**9}, "dense"),
+    (_MULTITIER_L8, {"sparse_level_elems": 10**9}, "dense"),
+    # ... and is the floor as stated from SPARSE_LEVEL_REF_HOPS hops up:
+    # the same level in a graph of 40,000 hops is 4.4 x them
+    (_STAR_L2, {"num_hops": 40_000}, "dense"),
+    (_STAR_L2, {"num_hops": 40_000, "sparse_level_elems": 176_357},
+     "tiled"),
+    # the floor is 8 x hops to the element: 22,044 hops put it at
+    # 176,352, 22,045 at 176,360
+    (_STAR_L2, {"num_hops": 22_044}, "tiled"),
+    (_STAR_L2, {"num_hops": 22_045}, "dense"),
+    # a grid within 4 x its call slots stays whatever the floor
+    ((100, 4, 100, np.asarray([4] * 100)),
+     {"num_hops": 10, "sparse_level_elems": 1}, "dense"),
+])
+def test_level_encoding_reads_the_grid_against_the_graphs_hops(
+        level, kw, want):
+    size, pmax, n_slots, widths = level
+    assert len(widths) == size and widths.max() == pmax
+    kw = {"num_hops": 10_000,
+          "sparse_level_elems": SimParams().sparse_level_elems, **kw}
+    enc, plan = level_encoding(size, pmax, n_slots, widths, **kw)
+    assert enc == want
+    assert (plan is not None) == (want == "tiled")
+    if want == "tiled":
+        # no script past the tile cap, and the tiles are a small part
+        # of the grid (the real levels' plans: tests/test_star10k.py)
+        assert len(plan.residual) == 0
+        assert plan.tiled_elems * 10 < size * pmax
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's six vendored graphs by default SimParams: the plans
+# the cells were measured on (PERF.md, PR 38).  The signatures are the
+# parent's (c90da70), taken before the rule read the graph's hops
+
+_TOPOLOGIES = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmark", "topologies")
+
+
+def _unrolled(*depths):
+    return tuple(("unrolled", d) for d in depths)
+
+
+#: file -> (levels off the dense grid, the plan's signature)
+_VENDORED = {
+    "1000-svc_2000-end.yaml": ((), _unrolled(0, 1, 2, 3, 4)),
+    "canonical.yaml": ((), (("scan", 0, 1, 3, 2, 3, 1), ("unrolled", 2))),
+    "tree-111-services.yaml": ((), _unrolled(0, 1, 2)),
+    "multitier-10000.yaml": ((), (
+        *_unrolled(0, 1, 2), ("scan", 3, 8, 1458, 19, 1458, 1),
+        ("scan", 9, 10, 1164, 14, 860, 1), ("scan", 11, 13, 582, 11, 423, 1),
+        *_unrolled(14, 15, 16, 17, 18))),
+    "realistic-multitier-100-errors.yaml": ((), (
+        ("unrolled", 0), ("scan", 1, 5, 21, 6, 21, 1),
+        *_unrolled(6, 7, 8, 9))),
+    # the one graph with levels off the grid; its signature did not
+    # change either (a tiled level is an unrolled segment)
+    "star-10000.yaml": ((1, 2), _unrolled(0, 1, 2, 3, 4)),
+}
+
+
+def test_every_vendored_graph_has_its_plan_pinned():
+    assert sorted(_VENDORED) == sorted(
+        f for f in os.listdir(_TOPOLOGIES) if f.endswith(".yaml"))
+
+
+@pytest.mark.parametrize("name", sorted(_VENDORED))
+def test_vendored_graphs_keep_their_plans(name):
+    tiled_levels, signature = _VENDORED[name]
+    compiled = compile_graph(ServiceGraph.from_yaml_file(
+        os.path.join(_TOPOLOGIES, name)))
+    sim = Simulator(compiled, SimParams())
+    assert tuple(d for d, s in enumerate(sim._plan_shapes)
+                 if s.sparse) == tiled_levels
+    assert tuple(d for d, lvl in enumerate(sim._levels)
+                 if lvl.tiled is not None) == tiled_levels
+    assert all(lvl.sparse is None for lvl in sim._levels)
+    assert sim._plan_sig == signature
+    # how far each graph is from the floor of 8 x its hops: the widest
+    # call-bearing dense level, in hops of the graph
+    widest = max(s.size * s.pmax for s in sim._plan_shapes
+                 if s.calls and not s.sparse) / compiled.num_hops
+    assert widest < (1.0 if tiled_levels else 2.7)

@@ -1,11 +1,12 @@
 """The 10,000-service hub-and-spoke mesh on the served path (``star10k``,
 ``benchmark/configs/star10k.json``): the vendored topology, the plan the
-engine makes of it - the one deployment whose widest level leaves the
-dense step grid for five tiles and a sparse residual - the whole graph's
-quiet run against the plain walk, a smaller star that still tiles
-through the CLI's artifacts, the tiled sweep against the dense grid on
-the same seed, and the scopes and counters the cell's per-layer metrics
-read."""
+engine makes of it - the one deployment whose levels leave the dense
+step grid: level 1 for five tiles and a sparse residual, level 2 (since
+PR 38) for five tiles - the whole graph's quiet run against the plain
+walk, a smaller star that still tiles through the CLI's artifacts, the
+tiled sweep against the dense grid on the same seed (a star whose level
+1 tiles, and one whose levels 1 and 2 do), and the scopes and counters
+the cell's per-layer metrics read."""
 import json
 import os
 import re
@@ -36,11 +37,19 @@ KEY = jax.random.PRNGKey(36)
 VENDORED = os.path.join(ROOT, "benchmark", "topologies", "star-10000.yaml")
 MODEL = {"cpu_time_s": 1 / 13000, "base_latency_s": 250e-6,
          "bytes_per_second": 1.25e9}
-#: the smallest star of the generator's seed 0 (in steps of 200) whose
-#: level 1 tiles WITH a residual by default ``SimParams``: 880 hops x
-#: 364 steps; at 1,200 services the 759 x 315 grid is still under
-#: ``sparse_level_elems`` and stays dense
+#: a star of the generator's seed 0 whose level 1 tiles WITH a residual
+#: by default ``SimParams``: 880 hops x 364 steps.  It was the smallest
+#: such (in steps of 200) while the floor was ``sparse_level_elems``
+#: elements a request; read against the graph's hops (PR 38: 8 x 1,400
+#: here) every star of the family from 400 services up does, and the
+#: 1,200-service one (759 x 315) tiles too.  Its level 2 (514 x 3) is
+#: dense under either rule
 SMALL = 1400
+#: (services, seed) of a star shaped like the cell's: level 1 (1,259 x
+#: 544) five tiles and a 544-slot residual, level 2 (677 hops x 28 steps
+#: for 63 calls: 9.5 x the graph's hops) four tiles and no residual by
+#: default ``SimParams`` - dense at the parent, whose floor was 262,144
+TWO_LEVELS = (2000, 5)
 ENCODING_COUNTERS = (
     "levels_tiled", "hops_in_tiled_levels", "tile_padded_elems",
     "tile_real_elems", "sparse_residual_slots", "dense_grid_elems_avoided")
@@ -59,9 +68,9 @@ def _generate(path, services: int) -> None:
                      "-o", str(path)]) == 0
 
 
-def _star(services: int):
+def _star(services: int, seed: int = 0):
     return compile_graph(ServiceGraph.decode(
-        realistic_topology(services, archetype="star", seed=0)))
+        realistic_topology(services, archetype="star", seed=seed)))
 
 
 def _simulate(topo, prom, load, seed: int, requests: int, capsys) -> dict:
@@ -94,14 +103,15 @@ def test_vendored_topology_is_the_generators_output(tmp_path):
 
 
 def test_the_plan_is_the_one_the_cell_was_measured_on(star10k):
-    """ISSUE 36's table: a change of plan is a diff someone reads."""
+    """ISSUE 36's table and ISSUE 38's level 2: a change of plan is a
+    diff someone reads."""
     compiled, sim, _ = star10k
     assert (compiled.num_services, compiled.num_hops) == (10_000, 10_000)
     shapes = sim._plan_shapes
     assert [(s.size, s.pmax, s.calls) for s in shapes] == [
         (1, 5021, 5021), (5021, 2217, 4641), (4641, 38, 330),
         (330, 3, 7), (7, 1, 0)]
-    assert [s.sparse for s in shapes] == [False, True, False, False, False]
+    assert [s.sparse for s in shapes] == [False, True, True, False, False]
     assert shapes[1].tiles == (
         (4950, 1), (23, 3), (16, 8), (10, 15), (14, 51))
     assert shapes[1].residual_slots == 3878
@@ -109,8 +119,18 @@ def test_the_plan_is_the_one_the_cell_was_measured_on(star10k):
     assert len(tl.res_hops) == 8            # the hubs past sparse_tile_pmax
     assert sorted(tl.hop_inv) == list(range(5021))
     assert sorted(tl.child_inv) == list(range(4641))
-    assert all(lvl.tiled is None and lvl.sparse is None
-               for d, lvl in enumerate(sim._levels) if d != 1)
+    # level 2: 176,358 cells for 330 calls, 17.6 x the graph's hops -
+    # past the floor of 8 x; 5,028 tile cells, no script past the cap
+    assert shapes[2].tiles == (
+        (4597, 1), (20, 4), (19, 11), (4, 26), (1, 38))
+    assert shapes[2].residual_slots == 0
+    tl = sim._levels[2].tiled
+    assert tl.residual is None and tl.res_hops is None
+    assert sorted(tl.hop_inv) == list(range(4641))
+    assert sorted(tl.child_inv) == list(range(330))
+    assert all(lvl.sparse is None for lvl in sim._levels)
+    assert all(lvl.tiled is None
+               for d, lvl in enumerate(sim._levels) if d not in (1, 2))
     # no scan bucket: five unrolled levels
     assert not any(isinstance(s, ScanBucket) for s in sim._segments)
     stats = buckets.plan_stats(shapes, sim._plan)
@@ -126,12 +146,17 @@ def test_encoding_counters_move_by_what_the_plan_says(star10k):
     every ``engine.build`` records its tiled and sparse levels."""
     _, sim, moved = star10k
     assert moved == buckets.encoding_stats(sim._plan_shapes) == {
-        "levels_tiled": 1, "hops_in_tiled_levels": 5021,
-        "tile_padded_elems": 4950 + 23 * 3 + 16 * 8 + 10 * 15 + 14 * 51,
-        "tile_real_elems": 763, "sparse_residual_slots": 3878,
-        "dense_grid_elems_avoided": 5021 * 2217}
-    # 4,641 call steps at level 1: the tiles' and the residual's
-    assert moved["tile_real_elems"] + moved["sparse_residual_slots"] == 4641
+        "levels_tiled": 2, "hops_in_tiled_levels": 5021 + 4641,
+        "tile_padded_elems": (
+            4950 + 23 * 3 + 16 * 8 + 10 * 15 + 14 * 51            # 6,011
+            + 4597 + 20 * 4 + 19 * 11 + 4 * 26 + 1 * 38),         # 5,028
+        "tile_real_elems": 763 + 330, "sparse_residual_slots": 3878,
+        "dense_grid_elems_avoided": 5021 * 2217 + 4641 * 38}
+    assert moved["tile_padded_elems"] == 11_039
+    # 4,641 call steps at level 1, the tiles' and the residual's, and
+    # 330 at level 2, all in its tiles
+    assert moved["tile_real_elems"] + moved["sparse_residual_slots"] == (
+        4641 + 330)
 
 
 def test_a_plan_of_dense_levels_moves_no_encoding_counter():
@@ -198,20 +223,31 @@ def test_a_star_that_tiles_against_the_walk_through_the_cli(
     assert not checks.failed(compared)
 
 
-@pytest.fixture(scope="module")
-def tiled_and_dense():
-    compiled = _star(SMALL)
+@pytest.fixture(scope="module", params=[
+    pytest.param((SMALL, 0, (1,)), id="level1"),
+    pytest.param((*TWO_LEVELS, (1, 2)), id="levels1and2")])
+def tiled_and_dense(request):
+    """A star by default ``SimParams`` and the same graph forced dense:
+    ``request.param`` = (services, seed, the levels that tile)."""
+    services, seed, levels = request.param
+    compiled = _star(services, seed)
     tiled = Simulator(compiled, SimParams())
     dense = Simulator(compiled, SimParams(sparse_level_elems=10**9))
-    lvl = tiled._levels[1]
-    assert lvl.tiled is not None and lvl.tiled.residual is not None
+    assert tuple(d for d, lvl in enumerate(tiled._levels)
+                 if lvl.tiled is not None) == levels
+    assert tiled._levels[1].tiled.residual is not None
+    if 2 in levels:
+        # the cell's level 2: tiles alone, no script past the cap
+        assert tiled._levels[2].tiled.residual is None
+        assert len(tiled._plan_shapes[2].tiles) > 1
+    assert all(l.sparse is None for l in tiled._levels)
     assert all(l.tiled is None and l.sparse is None for l in dense._levels)
     return compiled, tiled, dense
 
 
 def test_tiled_sweep_gives_the_dense_grids_summary(tiled_and_dense):
-    """Same seed, same blocks: the level that left the grid (tiles +
-    residual + re-assembly) and the same level kept dense by a raised
+    """Same seed, same blocks: the levels that left the grid (tiles +
+    residual + re-assembly) and the same levels kept dense by a raised
     ``sparse_level_elems`` collect the same summary - whole numbers
     exactly, float32 sums as two fusions of the same terms do."""
     compiled, tiled, dense = tiled_and_dense
@@ -252,7 +288,8 @@ def test_tiles_are_the_dense_grid_in_eager(tiled_and_dense):
     args = (KEY, qps, jnp.float32(0.0), qps)
     got = tiled._simulate(64, OPEN_LOOP, 0, False, *args)
     want = dense._simulate(64, OPEN_LOOP, 0, False, *args)
-    lvl = tiled._levels[1]
+    # the deepest tiled level: what it calls is dense on both sides
+    lvl = [l for l in tiled._levels if l.tiled is not None][-1]
     spokes = np.concatenate([
         lvl.offset + np.asarray(tile.hops)
         for tile in lvl.tiled.tiles if tile.width == 1])
@@ -284,8 +321,14 @@ def test_tiled_levels_trace_under_scopes_of_their_own(tiled_and_dense):
         text = jax.jit(fn).lower(*args).as_text(debug_info=True)
         return {m.group(0) for m in part.finditer(text)}
 
-    tiles = tiled._plan_shapes[1].tiles
-    assert parts(tiled) == {
-        *(f"engine/up/lvl[1]/tile[{t}x{w}]" for t, w in tiles),
-        "engine/up/lvl[1]/residual", "engine/up/lvl[1]/reassemble"}
+    want = set()
+    for d, lvl in enumerate(tiled._levels):
+        if lvl.tiled is None:
+            continue
+        want |= {f"engine/up/lvl[{d}]/tile[{t}x{w}]"
+                 for t, w in tiled._plan_shapes[d].tiles}
+        want.add(f"engine/up/lvl[{d}]/reassemble")
+        if lvl.tiled.residual is not None:
+            want.add(f"engine/up/lvl[{d}]/residual")
+    assert parts(tiled) == want
     assert parts(dense) == set()

@@ -267,6 +267,68 @@ def test_hlo_scopes_reach_into_a_loop_the_compiler_made():
     assert scopes["copy.1"] == ""
 
 
+_BARE_CUMSUM_HLO = """\
+HloModule jit_summary_closed_ab12cd, entry_computation_layout={()->f32[8]}
+
+%region_1.2 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0), metadata={op_name="reduce_window_sum"}
+  %b = f32[] parameter(1), metadata={op_name="reduce_window_sum"}
+  ROOT %add.9 = f32[] add(%a, %b), metadata={op_name="reduce_window_sum"}
+}
+
+%fused_computation.5 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %zero = f32[] constant(0), metadata={op_name="jit(f)/while/body/closed_call"}
+  ROOT %reduce-window.1 = f32[8]{0} reduce-window(%p, %zero), window={size=8 pad=7_0}, to_apply=%region_1.2
+}
+
+%fused_computation.6 (p: f32[8], q: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %q = f32[8]{0} parameter(1)
+  ROOT %sub.1 = f32[8]{0} subtract(%p, %q), metadata={op_name="jit(f)/while/body/closed_call/engine/up/lvl[1]/residual/sub"}
+}
+
+%body (w: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %w = (s32[], f32[8]{0}) parameter(0)
+  %x = f32[8]{0} get-tuple-element(%w), index=1
+  %fusion.350 = f32[8]{0} fusion(%x), kind=kOutput, calls=%fused_computation.5
+  %copy.88 = f32[8]{0} copy(%fusion.350)
+  %fusion.351 = f32[8]{0} fusion(%copy.88, %x), kind=kLoop, calls=%fused_computation.6
+  %dynamic-update-slice.3 = f32[8]{0} dynamic-update-slice(%x, %fusion.351, %w)
+  ROOT %tuple.1 = (s32[], f32[8]{0}) tuple(%w, %dynamic-update-slice.3)
+}
+
+%cond (w: (s32[], f32[8])) -> pred[] {
+  %w = (s32[], f32[8]{0}) parameter(0)
+  ROOT %compare.1 = pred[] compare(%w, %w), direction=LT
+}
+
+ENTRY %main.1 () -> f32[8] {
+  %c = f32[8]{0} constant(0)
+  %while.9 = (s32[], f32[8]{0}) while(%c), condition=%cond, body=%body, metadata={op_name="jit(f)/while"}
+  ROOT %gte = f32[8]{0} get-tuple-element(%while.9), index=1
+}
+"""
+
+
+def test_hlo_scopes_follow_a_bare_result_to_what_reads_it():
+    """jax lowers ``cumsum`` on a TPU to a ``reduce_window_sum`` whose
+    ``op_name`` has lost the scope path, so the fusion round it, and a
+    copy of its result, carry nothing, in the block loop whose
+    ``while`` has no scope either (star10k's sparse residual: its
+    prefix sums were a tenth of the busy time once the dense level 2
+    went, and the scope metrics are left out past a tenth).  They take
+    the scope of the first scoped instruction down their users; a
+    result that only the loop's carry reads stays bare."""
+    scopes = core.hlo_scopes(_BARE_CUMSUM_HLO)
+    residual = "engine/up/lvl[1]/residual/sub"
+    assert scopes["fusion.351"] == residual
+    assert scopes["fusion.350"] == residual    # bare fusion, bare callee
+    assert scopes["copy.88"] == residual       # one step from its reader
+    assert scopes["dynamic-update-slice.3"] == ""
+    assert scopes["while.9"] == ""
+
+
 def test_program_scopes_leaves_the_registry_as_found(served):
     before = telemetry.snapshot()
     telemetry.program_scopes()
