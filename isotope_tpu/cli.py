@@ -17,6 +17,7 @@ import sys
 from isotope_tpu import telemetry
 
 
+@telemetry.phase("cli.parser_build")
 def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(
         prog="isotope-tpu",
@@ -30,17 +31,21 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    with telemetry.phase("cli.parse"):
-        parser = build_parser()
-        args = parser.parse_args(argv)
-    if not getattr(args, "func", None):
-        parser.print_help()
-        return 2
-    try:
-        return args.func(args) or 0
-    except (OSError, ValueError) as e:
-        print(f"error: {e}", file=sys.stderr)
-        return 1
+    # the root span of a served call: every phase the call opens on
+    # this thread lies under it, so their self times sum to its seconds
+    with telemetry.phase("cli.main"):
+        with telemetry.phase("cli.parse"):
+            parser = build_parser()
+            with telemetry.phase("cli.parse_args"):
+                args = parser.parse_args(argv)
+        if not getattr(args, "func", None):
+            parser.print_help()
+            return 2
+        try:
+            return args.func(args) or 0
+        except (OSError, ValueError) as e:
+            print(f"error: {e}", file=sys.stderr)
+            return 1
 
 
 if __name__ == "__main__":

@@ -564,7 +564,6 @@ def _record_plan(shapes: Sequence[LevelShape],
     for name, value in encoding_stats(shapes).items():
         if value:
             telemetry.counter_inc(name, value)
-    telemetry.counter_inc("bucket_plans")
     telemetry.counter_inc("buckets_formed", st["num_buckets"])
     # the same count and the hops the buckets sweep, under the names
     # the benchmark's per-layer readers use
@@ -574,10 +573,6 @@ def _record_plan(shapes: Sequence[LevelShape],
     telemetry.counter_inc("levels_unrolled", st["levels_unrolled"])
     telemetry.counter_inc("bucket_padded_elems", st["padded_elems"])
     telemetry.counter_inc("bucket_real_elems", st["real_elems"])
-    telemetry.counter_inc(
-        "bucket_padded_rows",
-        sum(b["padded_rows"] for b in st["buckets"]),
-    )
     telemetry.gauge_set(
         "bucket_padding_waste_fraction", st["padding_waste_fraction"]
     )

@@ -196,7 +196,6 @@ def enable_persistent_cache(request: Optional[str] = None) -> Optional[str]:
     # persistent-cache hit/miss counts come from jax's own monitoring
     # events — subscribe before anything compiles through the cache
     telemetry.install_jax_hooks()
-    telemetry.counter_inc("persistent_cache_enables")
     import jax
 
     os.makedirs(path, exist_ok=True)
@@ -228,6 +227,7 @@ def array_digest(*chunks) -> str:
     import numpy as np
 
     h = hashlib.sha256()
+    hashed = 0
     for c in chunks:
         if c is None:
             continue
@@ -240,8 +240,12 @@ def array_digest(*chunks) -> str:
             h.update(str(a.dtype).encode())
             h.update(str(a.shape).encode())
             h.update(np.ascontiguousarray(a).tobytes())
+            hashed += a.nbytes
         else:
-            h.update(repr(c).encode())
+            text = repr(c).encode()
+            h.update(text)
+            hashed += len(text)
+    telemetry.counter_inc("signature_bytes_hashed", hashed)
     return h.hexdigest()
 
 

@@ -14,6 +14,7 @@ from typing import List
 
 import yaml
 
+from isotope_tpu import telemetry
 from isotope_tpu.models.errors import config_path
 from isotope_tpu.models.pct import Percentage
 from isotope_tpu.models.script import (
@@ -41,8 +42,11 @@ def parses_with_libyaml() -> bool:
     return _LOADER is not yaml.SafeLoader
 
 
-def _load(stream):
-    return yaml.load(stream, Loader=_LOADER)
+@telemetry.phase("graph.decode.yaml")
+def _load(text: str):
+    """The document of a topology's text: the scanner and parser
+    (libyaml's where present) and PyYAML's constructor."""
+    return yaml.load(text, Loader=_LOADER)
 
 
 class RequestToUndefinedServiceError(ValueError):
@@ -90,6 +94,7 @@ class ServiceGraph:
     # -- decode ------------------------------------------------------------
 
     @classmethod
+    @telemetry.phase("graph.decode.model")
     def decode(cls, doc: dict) -> "ServiceGraph":
         if not isinstance(doc, dict):
             raise ValueError(f"service graph must be a mapping: {doc!r}")
@@ -131,8 +136,9 @@ class ServiceGraph:
 
     @classmethod
     def from_yaml_file(cls, path) -> "ServiceGraph":
-        with open(path) as f:
-            return cls.decode(_load(f))
+        with telemetry.phase("graph.decode.read"), open(path) as f:
+            text = f.read()
+        return cls.from_yaml(text)
 
     # -- encode ------------------------------------------------------------
 

@@ -201,13 +201,21 @@ class _LazyTopology:
             telemetry.set_meta("yaml_parser",
                                "libyaml" if libyaml else "python")
             self._graph = graph
-            self._compiled = compile_graph(graph, entry=self.config.entry)
+            # compile.unroll's parent: what is left of it is the level
+            # loop behind compile_graph's step-grid gauges
+            with telemetry.phase("compile.graph"):
+                self._compiled = compile_graph(
+                    graph, entry=self.config.entry
+                )
             self._entry_resp = float(
                 self._compiled.services.response_size[
                     self._compiled.entry_service
                 ]
             )
-            self._collector = MetricsCollector(self._compiled)
+            # the hop -> edge map (a Python loop over the hops) and
+            # five index vectors put on the device
+            with telemetry.phase("collector.build"):
+                self._collector = MetricsCollector(self._compiled)
         return self._compiled
 
     @property
@@ -1160,7 +1168,10 @@ def run_experiment(
     if policy is None:
         policy = ResiliencePolicy.from_env()
     results: List[RunResult] = []
-    key = jax.random.PRNGKey(config.seed)
+    # run.key: the experiment's PRNG key and, a run, its fold: eager
+    # ops, one dispatch to the device each
+    with telemetry.phase("run.key"):
+        key = jax.random.PRNGKey(config.seed)
     # "auto" | MeshSpec | None — parse/env errors surface here, before
     # anything simulates; "auto" resolves per topology (the layout
     # search needs the compiled service count)
@@ -1213,14 +1224,15 @@ def run_experiment(
         # kill left behind, guarantees appends start on a fresh line,
         # and a kill during the rewrite itself cannot lose the old file
         tmp_path = out / "checkpoint.jsonl.tmp"
-        with open(tmp_path, "w") as tmp:
-            tmp.write(json.dumps({"config": fingerprint}) + "\n")
-            for rec in done_records:
-                tmp.write(json.dumps(rec) + "\n")
-            tmp.flush()
-            os.fsync(tmp.fileno())
-        os.replace(tmp_path, ckpt_path)
-        ckpt_file = open(ckpt_path, "a")
+        with telemetry.phase("artifacts.checkpoint"):  # an fsync a sweep
+            with open(tmp_path, "w") as tmp:
+                tmp.write(json.dumps({"config": fingerprint}) + "\n")
+                for rec in done_records:
+                    tmp.write(json.dumps(rec) + "\n")
+                tmp.flush()
+                os.fsync(tmp.fileno())
+            os.replace(tmp_path, ckpt_path)
+            ckpt_file = open(ckpt_path, "a")
         for rec in done_records:
             done[rec["label"]] = rec
 
@@ -1253,7 +1265,8 @@ def run_experiment(
             with telemetry.phase("run.case", label=label,
                                  run_index=run_index):
                 telemetry.counter_inc("runs_served")
-                run_key = jax.random.fold_in(key, run_index)
+                with telemetry.phase("run.key"):
+                    run_key = jax.random.fold_in(key, run_index)
                 if profile_dir is not None:
                     prof_ctx = jax.profiler.trace(
                         str(pathlib.Path(profile_dir) / label)

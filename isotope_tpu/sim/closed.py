@@ -504,6 +504,7 @@ def compress_census(pi_row: np.ndarray, scv: float) -> np.ndarray:
     return out / s if s > 0 else pi_row
 
 
+@telemetry.phase("closed_rate.tables_from_pi")
 def tables_from_pi(
     pi: np.ndarray,
     replicas: np.ndarray,

@@ -636,6 +636,21 @@ _call_outcome = levelscan.call_outcome
 _FOLD_MEMBER_KEYS = None
 
 
+def _table_bytes(tables) -> int:
+    """Bytes of the device arrays a level's tables hold, its tiles' and
+    its residual's included: what a level built on the host and handed
+    to the device (the ``level_table_bytes`` counter)."""
+    if isinstance(tables, jax.Array):
+        return tables.nbytes
+    if isinstance(tables, tuple):
+        return sum(map(_table_bytes, tables))
+    if dataclasses.is_dataclass(tables):
+        return _table_bytes(tuple(
+            getattr(tables, f.name) for f in dataclasses.fields(tables)
+        ))
+    return 0
+
+
 def _seg_label(seg) -> str:
     """A segment's name in detail-mode fences and device scopes."""
     if isinstance(seg, levelscan.ScanBucket):
@@ -677,6 +692,48 @@ class Simulator:
     ):
         telemetry.install_jax_hooks()
         faults.check("engine.build")
+        # the constructor's sections, one span each (``engine.build``'s
+        # own self time is what is left between them)
+        net_out, net_back = self._build_load(
+            compiled, params, chaos, churn, mtls, policies, rollouts, lb
+        )
+        levels: List[_Level] = []
+        np_meta: List[dict] = []  # host-side shapes for bucket planning
+        offset = 0
+        for depth, lvl in enumerate(compiled.levels):
+            with telemetry.phase(
+                "engine.build.level", depth=depth, hops=lvl.num_hops
+            ):
+                level, meta = self._build_level(
+                    lvl, offset, net_out, net_back, bool(churn)
+                )
+                telemetry.counter_inc(
+                    "level_table_bytes", _table_bytes(level)
+                )
+            levels.append(level)
+            np_meta.append(meta)
+            offset += lvl.num_hops
+        self._levels: Tuple[_Level, ...] = tuple(levels)
+        self._build_plan(np_meta, chaos, policies, rollouts, lb)
+        self._build_signature(chaos, mtls, policies, rollouts, lb)
+        self._build_copula()
+        # (the finite-population law handles chaos/churn phases with
+        # per-row tables; only the phased mTLS tax keeps a run on the
+        # open-loop fallback — see _saturated)
+        self._fns: Dict[Tuple[int, str, bool], "jax.stages.Wrapped"] = {}
+        self._summary_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
+        self._ensemble_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
+        self._search_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
+        self._rate_cache: Dict[tuple, float] = {}
+        telemetry.counter_inc("simulators_built")
+
+    @telemetry.phase("engine.build.load")
+    def _build_load(self, compiled, params, chaos, churn, mtls, policies,
+                    rollouts, lb):
+        """Everything the level tables are built from: policies, load
+        balancing, chaos / churn phases, offered load and visits, the
+        closed-network inputs, which coins can land both ways.  Returns
+        the hops' one-way wire times ``(net_out, net_back)``."""
         self.compiled = compiled
         self.params = params
         # auto-mTLS switching: a time-phased extra one-way latency on
@@ -1257,160 +1314,166 @@ class Simulator:
             rollouts is not None and rollouts.any_error_override
         )
 
-        levels: List[_Level] = []
-        np_meta: List[dict] = []  # host-side shapes for bucket planning
-        offset = 0
-        for lvl in compiled.levels:
-            cids = lvl.child_ids
-            # Per-level step width: the compiler encodes segments with the
-            # GLOBAL max_steps stride, but a level only needs the widest
-            # script among ITS services — on skewed graphs (one huge
-            # fan-out service, thousands of leaves) the global width
-            # wastes multiples of the step-tensor footprint.
-            pmax = max(int(lvl.step_is_real.sum(1).max(initial=0)), 1)
-            parent_local = lvl.child_seg // compiled.max_steps
-            child_step = lvl.child_seg % compiled.max_steps
-            call_local = lvl.call_seg // compiled.max_steps
-            call_step = lvl.call_seg % compiled.max_steps
-            n_calls = len(lvl.call_seg)
-            ident = (
-                lvl.att_child.shape[0] == 1
-                and n_calls == len(cids)
-                and bool(lvl.att_valid.all())
-                and np.array_equal(
-                    lvl.att_child[0], np.arange(n_calls, dtype=np.int32)
-                )
-            )
-            call_seg_p = call_local * pmax + call_step
-            slots = lvl.num_hops * pmax  # > 0: every level has >= 1 hop
-            uniform: Optional[int] = None
-            if n_calls > 0 and n_calls % slots == 0:
-                c = n_calls // slots
-                if np.array_equal(
-                    call_seg_p, np.repeat(np.arange(slots), c)
-                ):
-                    uniform = c
+        return net_out, net_back
 
-            # -- non-dense step encodings for skewed wide levels -------
-            # A level whose dense (hops x Pmax) grid is pathological
-            # (engine docstring) leaves the dense path.  The default
-            # mitigation is the DENSE-BLOCKED tiling (_TiledSteps):
-            # hops binned by script-width class run the dense grid ops
-            # on fixed-width tiles, and only scripts wider than the
-            # tile cap keep the true sparse call-slot encoding
-            # (_SparseSteps) as a residual.  The decision is shared
-            # with the vet linter (compiler/buckets.level_encoding).
-            sparse: Optional[_SparseSteps] = None
-            tiled: Optional[_TiledSteps] = None
-            leaf_busy: Optional[jax.Array] = None
-            sleep_real = lvl.step_is_real.astype(np.float64) * (
-                lvl.step_base
+    def _build_level(self, lvl, offset: int, net_out, net_back,
+                     churn: bool) -> Tuple[_Level, dict]:
+        """One depth level's device constants and the host-side shapes
+        (``meta``) the bucket plan reads: the step tables in the
+        encoding ``buckets.level_encoding`` picks, put on the device."""
+        compiled, params = self.compiled, self.params
+        cids = lvl.child_ids
+        # Per-level step width: the compiler encodes segments with the
+        # GLOBAL max_steps stride, but a level only needs the widest
+        # script among ITS services — on skewed graphs (one huge
+        # fan-out service, thousands of leaves) the global width
+        # wastes multiples of the step-tensor footprint.
+        pmax = max(int(lvl.step_is_real.sum(1).max(initial=0)), 1)
+        parent_local = lvl.child_seg // compiled.max_steps
+        child_step = lvl.child_seg % compiled.max_steps
+        call_local = lvl.call_seg // compiled.max_steps
+        call_step = lvl.call_seg % compiled.max_steps
+        n_calls = len(lvl.call_seg)
+        ident = (
+            lvl.att_child.shape[0] == 1
+            and n_calls == len(cids)
+            and bool(lvl.att_valid.all())
+            and np.array_equal(
+                lvl.att_child[0], np.arange(n_calls, dtype=np.int32)
             )
-            if n_calls == 0:
-                leaf_busy = jnp.asarray(sleep_real.sum(1), jnp.float32)
-            else:
-                n_slots = len(np.unique(call_seg_p))
-                widths = lvl.step_is_real[:, :pmax].sum(1)
-                enc, tile_plan = buckets.level_encoding(
-                    lvl.num_hops, pmax, n_slots, widths,
-                    num_hops=compiled.num_hops,
-                    sparse_level_elems=params.sparse_level_elems,
-                    tiling=params.sparse_tiling,
-                    tile_pmax=params.sparse_tile_pmax,
-                )
-                if enc == "tiled":
-                    tiled = _build_tiled_steps(
-                        tile_plan, pmax, lvl.step_is_real,
-                        lvl.step_base, sleep_real, call_seg_p,
-                        parent_local, child_step,
-                    )
-                elif enc == "sparse":
-                    sparse = _sparse_tables(
-                        lvl.num_hops, pmax, sleep_real, lvl.step_base,
-                        call_seg_p, parent_local, child_step,
-                    )
-            meta = dict(
-                size=lvl.num_hops, pmax=pmax, C=len(cids), K=n_calls,
-                A=lvl.att_child.shape[0], offset=offset,
-                sparse=sparse is not None or tiled is not None,
-                leaf=n_calls == 0,
-                tiles=(
-                    tuple((len(t.hops), t.width) for t in tiled.tiles)
-                    if tiled is not None
-                    else None
-                ),
-                residual_slots=(
-                    tiled.residual.n_slots
-                    if tiled is not None and tiled.residual is not None
-                    else (sparse.n_slots if sparse is not None else 0)
-                ),
-                tile_real_elems=(
-                    tile_plan.real_elems if tiled is not None else 0
-                ),
-            )
-            if params.bucketed_scan and not (meta["sparse"]
-                                             or meta["leaf"]):
-                # dense host copies only for scan-ELIGIBLE levels — a
-                # sparse level's (size x pmax) grid is exactly what the
-                # sparse encoding exists to avoid materializing
-                meta.update(
-                    step_mask=lvl.step_is_real[:, :pmax]
-                    .astype(np.float32),
-                    step_base=np.asarray(
-                        lvl.step_base[:, :pmax], np.float32
-                    ),
-                    parent_local=parent_local, child_step=child_step,
-                    child_rtt=(net_out[cids] + net_back[cids]),
-                    child_net_out=net_out[cids],
-                    child_send_prob=compiled.hop_send_prob[cids],
-                    child_churn_entry=(
-                        self._hop_churn_entry[cids] if churn else None
-                    ),
-                    call_local=call_local, call_step=call_step,
-                    call_timeout=lvl.call_timeout,
-                    att_child=lvl.att_child, att_valid=lvl.att_valid,
-                )
-            np_meta.append(meta)
-            levels.append(
-                _Level(
-                    offset=offset,
-                    size=lvl.num_hops,
-                    pmax=pmax,
-                    step_mask=jnp.asarray(
-                        lvl.step_is_real[:, :pmax], jnp.float32
-                    ),
-                    step_base=jnp.asarray(lvl.step_base[:, :pmax]),
-                    child_seg=jnp.asarray(parent_local * pmax + child_step),
-                    child_parent_local=jnp.asarray(parent_local),
-                    child_step=jnp.asarray(child_step),
-                    child_rtt=jnp.asarray(
-                        (net_out[cids] + net_back[cids]), jnp.float32
-                    ),
-                    child_net_out=jnp.asarray(net_out[cids], jnp.float32),
-                    child_send_prob=jnp.asarray(
-                        compiled.hop_send_prob[cids]
-                    ),
-                    call_seg=jnp.asarray(call_seg_p),
-                    call_step=jnp.asarray(call_step),
-                    call_timeout=jnp.asarray(lvl.call_timeout),
-                    att_child=lvl.att_child,
-                    att_valid=lvl.att_valid,
-                    child_churn_entry=(
-                        self._hop_churn_entry[cids] if churn else None
-                    ),
-                    ident_attempts=ident,
-                    finite_timeout=bool(
-                        np.isfinite(lvl.call_timeout).any()
-                    ),
-                    uniform_calls=uniform,
-                    sparse=sparse,
-                    tiled=tiled,
-                    leaf_busy=leaf_busy,
-                )
-            )
-            offset += lvl.num_hops
-        self._levels: Tuple[_Level, ...] = tuple(levels)
+        )
+        call_seg_p = call_local * pmax + call_step
+        slots = lvl.num_hops * pmax  # > 0: every level has >= 1 hop
+        uniform: Optional[int] = None
+        if n_calls > 0 and n_calls % slots == 0:
+            c = n_calls // slots
+            if np.array_equal(
+                call_seg_p, np.repeat(np.arange(slots), c)
+            ):
+                uniform = c
 
+        # -- non-dense step encodings for skewed wide levels -------
+        # A level whose dense (hops x Pmax) grid is pathological
+        # (engine docstring) leaves the dense path.  The default
+        # mitigation is the DENSE-BLOCKED tiling (_TiledSteps):
+        # hops binned by script-width class run the dense grid ops
+        # on fixed-width tiles, and only scripts wider than the
+        # tile cap keep the true sparse call-slot encoding
+        # (_SparseSteps) as a residual.  The decision is shared
+        # with the vet linter (compiler/buckets.level_encoding).
+        sparse: Optional[_SparseSteps] = None
+        tiled: Optional[_TiledSteps] = None
+        leaf_busy: Optional[jax.Array] = None
+        sleep_real = lvl.step_is_real.astype(np.float64) * (
+            lvl.step_base
+        )
+        if n_calls == 0:
+            leaf_busy = jnp.asarray(sleep_real.sum(1), jnp.float32)
+        else:
+            n_slots = len(np.unique(call_seg_p))
+            widths = lvl.step_is_real[:, :pmax].sum(1)
+            enc, tile_plan = buckets.level_encoding(
+                lvl.num_hops, pmax, n_slots, widths,
+                num_hops=compiled.num_hops,
+                sparse_level_elems=params.sparse_level_elems,
+                tiling=params.sparse_tiling,
+                tile_pmax=params.sparse_tile_pmax,
+            )
+            if enc == "tiled":
+                tiled = _build_tiled_steps(
+                    tile_plan, pmax, lvl.step_is_real,
+                    lvl.step_base, sleep_real, call_seg_p,
+                    parent_local, child_step,
+                )
+            elif enc == "sparse":
+                sparse = _sparse_tables(
+                    lvl.num_hops, pmax, sleep_real, lvl.step_base,
+                    call_seg_p, parent_local, child_step,
+                )
+        meta = dict(
+            size=lvl.num_hops, pmax=pmax, C=len(cids), K=n_calls,
+            A=lvl.att_child.shape[0], offset=offset,
+            sparse=sparse is not None or tiled is not None,
+            leaf=n_calls == 0,
+            tiles=(
+                tuple((len(t.hops), t.width) for t in tiled.tiles)
+                if tiled is not None
+                else None
+            ),
+            residual_slots=(
+                tiled.residual.n_slots
+                if tiled is not None and tiled.residual is not None
+                else (sparse.n_slots if sparse is not None else 0)
+            ),
+            tile_real_elems=(
+                tile_plan.real_elems if tiled is not None else 0
+            ),
+        )
+        if params.bucketed_scan and not (meta["sparse"]
+                                         or meta["leaf"]):
+            # dense host copies only for scan-ELIGIBLE levels — a
+            # sparse level's (size x pmax) grid is exactly what the
+            # sparse encoding exists to avoid materializing
+            meta.update(
+                step_mask=lvl.step_is_real[:, :pmax]
+                .astype(np.float32),
+                step_base=np.asarray(
+                    lvl.step_base[:, :pmax], np.float32
+                ),
+                parent_local=parent_local, child_step=child_step,
+                child_rtt=(net_out[cids] + net_back[cids]),
+                child_net_out=net_out[cids],
+                child_send_prob=compiled.hop_send_prob[cids],
+                child_churn_entry=(
+                    self._hop_churn_entry[cids] if churn else None
+                ),
+                call_local=call_local, call_step=call_step,
+                call_timeout=lvl.call_timeout,
+                att_child=lvl.att_child, att_valid=lvl.att_valid,
+            )
+        return (
+            _Level(
+                offset=offset,
+                size=lvl.num_hops,
+                pmax=pmax,
+                step_mask=jnp.asarray(
+                    lvl.step_is_real[:, :pmax], jnp.float32
+                ),
+                step_base=jnp.asarray(lvl.step_base[:, :pmax]),
+                child_seg=jnp.asarray(parent_local * pmax + child_step),
+                child_parent_local=jnp.asarray(parent_local),
+                child_step=jnp.asarray(child_step),
+                child_rtt=jnp.asarray(
+                    (net_out[cids] + net_back[cids]), jnp.float32
+                ),
+                child_net_out=jnp.asarray(net_out[cids], jnp.float32),
+                child_send_prob=jnp.asarray(
+                    compiled.hop_send_prob[cids]
+                ),
+                call_seg=jnp.asarray(call_seg_p),
+                call_step=jnp.asarray(call_step),
+                call_timeout=jnp.asarray(lvl.call_timeout),
+                att_child=lvl.att_child,
+                att_valid=lvl.att_valid,
+                child_churn_entry=(
+                    self._hop_churn_entry[cids] if churn else None
+                ),
+                ident_attempts=ident,
+                finite_timeout=bool(
+                    np.isfinite(lvl.call_timeout).any()
+                ),
+                uniform_calls=uniform,
+                sparse=sparse,
+                tiled=tiled,
+                leaf_busy=leaf_busy,
+            ),
+            meta,
+        )
+
+    @telemetry.phase("engine.build.plan")
+    def _build_plan(self, np_meta: List[dict], chaos, policies, rollouts,
+                    lb) -> None:
+        compiled, params = self.compiled, self.params
         # -- bucketed level-scan plan (compiler/buckets.py) -----------------
         # Consecutive close-shaped levels collapse into lax.scan buckets
         # (sim/levelscan.py): the sweep body is traced once per bucket,
@@ -1465,6 +1528,11 @@ class Simulator:
         self._plan_shapes = tuple(shapes)
         self._plan = tuple(plan)
         self._plan_sig = buckets.plan_signature(plan)
+
+    @telemetry.phase("engine.build.signature")
+    def _build_signature(self, chaos, mtls, policies, rollouts, lb) -> None:
+        compiled, params = self.compiled, self.params
+        t = compiled.services
         # -- AOT shape signature (compiler/cache.py) ------------------------
         # Everything a traced entry point bakes in: the bucket plan, the
         # compiled graph's shape, and a content digest of every closed-
@@ -1510,6 +1578,9 @@ class Simulator:
             ),
         )
 
+    @telemetry.phase("engine.build.copula")
+    def _build_copula(self) -> None:
+        compiled, params = self.compiled, self.params
         # -- sibling copula: static hop -> group id map ---------------------
         # Concurrent sibling hops (children spawned by the same parent
         # step, retry attempts included) share correlated wait draws.
@@ -1654,15 +1725,6 @@ class Simulator:
         self._retry_w = np.where(
             in_rg, np.sqrt(params.retry_copula_r), 0.0
         ).astype(np.float32)
-        # (the finite-population law handles chaos/churn phases with
-        # per-row tables; only the phased mTLS tax keeps a run on the
-        # open-loop fallback — see _saturated)
-        self._fns: Dict[Tuple[int, str, bool], "jax.stages.Wrapped"] = {}
-        self._summary_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
-        self._ensemble_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
-        self._search_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
-        self._rate_cache: Dict[tuple, float] = {}
-        telemetry.counter_inc("simulators_built")
 
     def _phase_reach_multipliers(self, svc_down_np: np.ndarray) -> np.ndarray:
         """(P, H) static reach multipliers from outage-driven script
@@ -1874,6 +1936,7 @@ class Simulator:
         return (throughput, p0_h, coef[:, hs], e_h, c_center, scale_h)
 
     @staticmethod
+    @telemetry.phase("closed_rate.center_terms")
     def _center_terms(sigma, var_d, hs):
         """Population-copula centering terms from census sigmas.
 

@@ -20,7 +20,11 @@ report through:
   of the same name, so under a profiler session every phase lies in
   the trace's host plane on the clock the device planes use (outside a
   session: an inactive TraceMe).  It is a context manager and a
-  decorator.
+  decorator.  Phases nest: each thread keeps a stack of its open
+  phases, so a phase knows the phase that opened it (``phase_parents``)
+  and its self time (``phase_self``: its seconds minus what its direct
+  children cover).  A phase with children is a container; what its
+  ``<name>.self`` reads is host time no leaf names yet.
 - **Device scopes, on demand** (:func:`program_scopes`): the engine
   traces under ``jax.named_scope`` (SCOPE_ROOTS); the map from a
   compiled program's instructions to those scopes is computed only
@@ -53,11 +57,15 @@ import contextlib
 import dataclasses
 import json
 import re
+import threading
 import time
 import weakref
 from typing import Any, Dict, Iterator, List, Optional, Set
 
 SCHEMA = "isotope-engine-telemetry/v1"
+
+#: ``snapshot().phases`` carries a container's self time as ``<name>.self``
+_SELF = ".self"
 
 #: jax duration events -> phase names (the trace/lower/compile split)
 _JAX_EVENT_PHASES = {
@@ -84,6 +92,10 @@ class _State:
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
         self.phases: Dict[str, float] = {}
+        # seconds of a phase its direct children did not cover, and the
+        # names it was opened under ("" = no phase open: a root)
+        self.phase_self: Dict[str, float] = {}
+        self.phase_parents: Dict[str, Set[str]] = {}
         self.meta: Dict[str, Any] = {}  # run annotations (degraded_to, ...)
         self.emit = False          # artifact emission requested (--telemetry)
         self.detail = False        # segment fencing armed (--telemetry=detail)
@@ -93,6 +105,29 @@ class _State:
 
 _STATE = _State()
 _HOOKS_INSTALLED = False
+
+
+class _Frame:
+    """One open phase: its name, when it opened (or the registry was
+    last reset under it) and the seconds its direct children took."""
+
+    __slots__ = ("name", "t0", "children")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.t0 = time.perf_counter()
+        self.children = 0.0
+
+
+class _Open(threading.local):
+    """The calling thread's open phases, outermost first.  The deadline
+    watchdog's thread opens none."""
+
+    def __init__(self) -> None:
+        self.frames: List[_Frame] = []
+
+
+_OPEN = _Open()
 
 
 # -- mode switches ---------------------------------------------------------
@@ -121,10 +156,18 @@ def reset() -> None:
     """Clear every counter/gauge/phase (tests, per-bench-case isolation).
 
     Leaves the emit/detail switches and installed jax hooks in place.
+    A phase open on the calling thread (the runner resets inside
+    ``cli.main``) starts again from here: what it records on exit is
+    its seconds, and its children's, since the reset.
     """
     _STATE.counters.clear()
     _STATE.gauges.clear()
     _STATE.phases.clear()
+    _STATE.phase_self.clear()
+    _STATE.phase_parents.clear()
+    now = time.perf_counter()
+    for frame in _OPEN.frames:
+        frame.t0, frame.children = now, 0.0
     _STATE.meta.clear()
     _STATE.trace_keys.clear()
     _STATE.last_fence_t = None
@@ -177,7 +220,13 @@ def gauge_get(name: str, **labels: Any) -> Optional[float]:
 
 
 def phase_add(name: str, seconds: float) -> None:
+    """Credit ``seconds`` to the named phase, measured by the caller
+    (``time_first_call``, the jax hooks, the detail-mode fences).  It
+    has no parent, and where a phase is open it is NOT subtracted from
+    that phase's self time: a compile event overlaps the host phase it
+    fires in by design, so both keep their seconds."""
     _STATE.phases[name] = _STATE.phases.get(name, 0.0) + seconds
+    _STATE.phase_self[name] = _STATE.phase_self.get(name, 0.0) + seconds
 
 
 def phase_seconds(name: str) -> float:
@@ -199,9 +248,14 @@ def _trace_annotation(name: str, attrs: Dict[str, Any]):
 def phase(name: str, **attrs: Any) -> Iterator[None]:
     """Accumulating wall-clock phase timer AND profiler span.
 
-    Re-entering the same name sums; nested phases time independently,
-    so an enclosing phase's seconds include its children's (each name
-    is its own accumulator — there is no implicit hierarchy).
+    Re-entering the same name sums.  Phases nest by the calling
+    thread's stack of open phases: on exit a phase adds its seconds to
+    ``phases[name]`` (inclusive: its children's too) and, less what its
+    direct children took, to ``phase_self[name]``; the name it was
+    opened under goes to ``phase_parents[name]`` (``""`` for a root).
+    So the self times of everything opened under one root sum to that
+    root's seconds, and a container's ``<name>.self`` in a snapshot is
+    the host time none of its children names.
 
     Under a ``jax.profiler`` session (``sweep --profile``, ``telemetry
     --xla-trace``) the phase also lands in the trace's host plane as an
@@ -209,12 +263,28 @@ def phase(name: str, **attrs: Any) -> Iterator[None]:
     planes use — so device idle gaps can be set against the host phase
     open in them.  Outside a session that costs one inactive TraceMe.
     """
-    t0 = time.perf_counter()
+    frames = _OPEN.frames
+    parent = frames[-1] if frames else None
+    frame = _Frame(name)
+    frames.append(frame)
     try:
         with _trace_annotation(name, attrs):
             yield
     finally:
-        phase_add(name, time.perf_counter() - t0)
+        seconds = time.perf_counter() - frame.t0
+        # its own frame, wherever it lies: a phase held across a
+        # generator's ``yield`` can close after the phase it was opened
+        # under, and then must not take that one's place on the stack
+        frames.remove(frame)
+        if parent is not None:
+            parent.children += seconds
+        _STATE.phases[name] = _STATE.phases.get(name, 0.0) + seconds
+        _STATE.phase_self[name] = (
+            _STATE.phase_self.get(name, 0.0) + seconds - frame.children
+        )
+        _STATE.phase_parents.setdefault(name, set()).add(
+            parent.name if parent is not None else ""
+        )
 
 
 def _under_disable_jit() -> bool:
@@ -415,6 +485,8 @@ def program_scopes() -> Dict[str, Dict[str, str]]:
     (:func:`hlo_scopes`).  The registry is left as found.
     """
     saved = (dict(_STATE.counters), dict(_STATE.phases),
+             dict(_STATE.phase_self),
+             {k: set(v) for k, v in _STATE.phase_parents.items()},
              dict(_STATE.gauges), set(_STATE.trace_keys))
     out: Dict[str, Dict[str, str]] = {}
     try:
@@ -430,8 +502,9 @@ def program_scopes() -> Dict[str, Dict[str, str]]:
                 )
     finally:
         for live, was in zip(
-            (_STATE.counters, _STATE.phases, _STATE.gauges,
-             _STATE.trace_keys), saved,
+            (_STATE.counters, _STATE.phases, _STATE.phase_self,
+             _STATE.phase_parents, _STATE.gauges, _STATE.trace_keys),
+            saved,
         ):
             live.clear()
             live.update(was)
@@ -643,12 +716,20 @@ class RunTelemetry:
     gauges: Dict[str, float]
     meta: Dict[str, Any]
     schema: str = SCHEMA
+    # additive since the phases nest: a record written before has
+    # neither (nor a ``<name>.self`` key in ``phases``) and still reads
+    phase_self: Dict[str, float] = dataclasses.field(default_factory=dict)
+    phase_parents: Dict[str, List[str]] = dataclasses.field(
+        default_factory=dict
+    )
 
     def to_dict(self) -> dict:
         return {
             "schema": self.schema,
             "label": self.label,
             "phases": self.phases,
+            "phase_self": self.phase_self,
+            "phase_parents": self.phase_parents,
             "counters": self.counters,
             "gauges": self.gauges,
             "meta": self.meta,
@@ -663,6 +744,10 @@ class RunTelemetry:
             gauges=dict(d.get("gauges", {})),
             meta=dict(d.get("meta", {})),
             schema=d.get("schema", SCHEMA),
+            phase_self=dict(d.get("phase_self", {})),
+            phase_parents={
+                k: list(v) for k, v in d.get("phase_parents", {}).items()
+            },
         )
 
     def to_json_line(self) -> str:
@@ -686,7 +771,11 @@ class RunTelemetry:
             f.write(lead + self.to_json_line() + "\n")
 
     def prometheus_text(self) -> str:
-        return _render_prometheus(self.phases, self.counters, self.gauges)
+        return _render_prometheus(
+            {k: v for k, v in self.phases.items()
+             if not (k.endswith(_SELF) and k[:-len(_SELF)] in self.phases)},
+            self.phase_self, self.counters, self.gauges,
+        )
 
 
 def snapshot(label: Optional[str] = None) -> RunTelemetry:
@@ -702,29 +791,46 @@ def snapshot(label: Optional[str] = None) -> RunTelemetry:
     except Exception:  # pragma: no cover - converter-only env
         pass
     meta.update(_STATE.meta)  # run annotations (degraded_to, ...)
+    phase_self = {
+        k: round(v, 6) for k, v in sorted(_STATE.phase_self.items())
+    }
+    phases = {k: round(v, 6) for k, v in _STATE.phases.items()}
+    # a container's self time beside its seconds, under a name a reader
+    # of ``phases`` can ask for; a leaf's self time is its seconds
+    containers = set().union(*_STATE.phase_parents.values()) - {""}
+    phases.update((name + _SELF, phase_self[name])
+                  for name in containers if name in phase_self)
     return RunTelemetry(
         label=label,
-        phases={k: round(v, 6) for k, v in sorted(_STATE.phases.items())},
+        phases=dict(sorted(phases.items())),
         counters=dict(sorted(_STATE.counters.items())),
         gauges={k: float(v) for k, v in sorted(_STATE.gauges.items())},
         meta=meta,
+        phase_self=phase_self,
+        phase_parents={
+            k: sorted(v) for k, v in sorted(_STATE.phase_parents.items())
+        },
     )
 
 
 # -- Prometheus exposition -------------------------------------------------
 
-def _render_prometheus(phases, counters, gauges) -> str:
+def _render_prometheus(phases, phase_self, counters, gauges) -> str:
     out: List[str] = []
-    out.append(
-        "# HELP isotope_engine_phase_seconds_total Wall seconds spent in"
-        " each engine phase."
-    )
-    out.append("# TYPE isotope_engine_phase_seconds_total counter")
-    for name, v in sorted(phases.items()):
-        out.append(
-            f'isotope_engine_phase_seconds_total{{phase="{name}"}}'
-            f" {v:.10g}"
-        )
+    for family, what, seconds in (
+        ("phase_seconds_total",
+         "Wall seconds spent in each engine phase, its children's"
+         " included.", phases),
+        ("phase_self_seconds_total",
+         "Wall seconds of each engine phase that no child phase"
+         " covers.", phase_self),
+    ):
+        out.append(f"# HELP isotope_engine_{family} {what}")
+        out.append(f"# TYPE isotope_engine_{family} counter")
+        for name, v in sorted(seconds.items()):
+            out.append(
+                f'isotope_engine_{family}{{phase="{name}"}} {v:.10g}'
+            )
     out.append(
         "# HELP isotope_engine_events_total Engine event counters"
         " (cache hits/misses, buckets formed, traces, fences)."
@@ -765,7 +871,7 @@ def _render_prometheus(phases, counters, gauges) -> str:
 def prometheus_text() -> str:
     """Render the live registry as ``isotope_engine_*`` series."""
     return _render_prometheus(
-        _STATE.phases, _STATE.counters, _STATE.gauges
+        _STATE.phases, _STATE.phase_self, _STATE.counters, _STATE.gauges
     )
 
 
@@ -817,8 +923,8 @@ def validate_jsonl(path) -> int:
                 raise ValueError(
                     f"{path}:{i}: missing/invalid {section!r} section"
                 )
-        for section in ("phases", "counters", "gauges"):
-            for k, v in doc[section].items():
+        for section in ("phases", "phase_self", "counters", "gauges"):
+            for k, v in doc.get(section, {}).items():
                 if not isinstance(k, str) or not isinstance(
                     v, (int, float)
                 ):

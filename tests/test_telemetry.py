@@ -66,7 +66,7 @@ def test_phase_timers_nest_and_sum():
     assert telemetry.phase_seconds("outer") >= telemetry.phase_seconds(
         "inner"
     )
-    # phases are independent accumulators, not a consuming hierarchy
+    # an inclusive accumulator a name: a child takes nothing from it
     with telemetry.phase("outer"):
         pass
     assert telemetry.phase_seconds("outer") >= 0.04
@@ -78,6 +78,200 @@ def test_phase_records_on_exception():
             time.sleep(0.01)
             raise RuntimeError()
     assert telemetry.phase_seconds("boom") >= 0.01
+
+
+# -- parent and self time --------------------------------------------------
+
+class _Clock:
+    """``time`` for telemetry/core.py with a clock the test moves."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+    def time(self):
+        return 0.0
+
+    def tick(self, seconds):
+        self.now += seconds
+
+
+def _nested(tick):
+    with telemetry.phase("outer"):
+        tick(1)
+        with telemetry.phase("inner"):
+            tick(2)
+            with telemetry.phase("leaf"):
+                tick(4)
+        tick(8)
+    return (
+        {"outer": 15, "inner": 6, "leaf": 4},
+        {"outer": 9, "inner": 2, "leaf": 4},
+        {"outer": [""], "inner": ["outer"], "leaf": ["inner"]},
+    )
+
+
+def _siblings(tick):
+    with telemetry.phase("outer"):
+        with telemetry.phase("a"):
+            tick(1)
+        with telemetry.phase("b"):
+            tick(2)
+        tick(4)
+    return (
+        {"outer": 7, "a": 1, "b": 2}, {"outer": 4, "a": 1, "b": 2},
+        {"outer": [""], "a": ["outer"], "b": ["outer"]},
+    )
+
+
+def _re_entered(tick):
+    with telemetry.phase("outer"):
+        with telemetry.phase("inner"):
+            tick(1)
+        with telemetry.phase("inner"):
+            tick(2)
+    with telemetry.phase("inner"):      # and as a root: both parents
+        tick(4)
+    return (
+        {"outer": 3, "inner": 7}, {"outer": 0, "inner": 7},
+        {"outer": [""], "inner": ["", "outer"]},
+    )
+
+
+def _exception_in_a_child(tick):
+    with telemetry.phase("outer"):
+        with pytest.raises(RuntimeError):
+            with telemetry.phase("inner"):
+                with telemetry.phase("leaf"):
+                    tick(1)
+                    raise RuntimeError()
+        tick(2)
+        with telemetry.phase("after"):   # the stack was popped: a child
+            tick(4)                      # of outer, not of leaf
+    return (
+        {"outer": 7, "inner": 1, "leaf": 1, "after": 4},
+        {"outer": 2, "inner": 0, "leaf": 1, "after": 4},
+        {"outer": [""], "inner": ["outer"], "leaf": ["inner"],
+         "after": ["outer"]},
+    )
+
+
+def _reset_under_an_open_phase(tick):
+    with telemetry.phase("outer"):
+        tick(1)
+        with telemetry.phase("gone"):
+            tick(2)
+        with telemetry.phase("inner"):
+            tick(4)
+            telemetry.reset()            # runner/run.py, --telemetry on
+            tick(8)
+            with telemetry.phase("leaf"):
+                tick(16)
+        tick(32)
+    # every open phase starts again at the reset
+    return (
+        {"outer": 56, "inner": 24, "leaf": 16},
+        {"outer": 32, "inner": 8, "leaf": 16},
+        {"outer": [""], "inner": ["outer"], "leaf": ["inner"]},
+    )
+
+
+def _decorator_form(tick):
+    @telemetry.phase("work")
+    def work():
+        tick(1)
+
+    with telemetry.phase("outer"):
+        work()
+        work()
+        tick(2)
+    return (
+        {"outer": 4, "work": 2}, {"outer": 2, "work": 2},
+        {"outer": [""], "work": ["outer"]},
+    )
+
+
+def _phase_add_under_an_open_phase(tick):
+    with telemetry.phase("outer"):
+        tick(1)
+        # a compile event overlaps the host phase it fires in: credited
+        # to its own name, not taken from the open phase
+        telemetry.phase_add("compile.probe", 4)
+        with telemetry.phase("inner"):
+            tick(2)
+    return (
+        {"outer": 3, "inner": 2, "compile.probe": 4},
+        {"outer": 1, "inner": 2, "compile.probe": 4},
+        {"outer": [""], "inner": ["outer"]},
+    )
+
+
+def _held_across_a_generators_yield(tick):
+    def steps():
+        with telemetry.phase("held"):
+            tick(1)
+            yield
+            tick(4)
+
+    it = steps()
+    with telemetry.phase("outer"):
+        next(it)                         # "held" stays open, suspended
+        tick(2)
+    # outer closed first and took its OWN frame off the stack, not the
+    # top one: "held" is still the open phase, so "later" is its child,
+    # and it closes as the child of outer it was opened as
+    with telemetry.phase("later"):
+        tick(8)
+    assert next(it, None) is None
+    return (
+        {"outer": 3, "held": 15, "later": 8},
+        {"outer": 3, "held": 7, "later": 8},
+        {"outer": [""], "held": ["outer"], "later": ["held"]},
+    )
+
+
+@pytest.mark.parametrize("scenario", [
+    _nested, _siblings, _re_entered, _exception_in_a_child,
+    _reset_under_an_open_phase, _decorator_form,
+    _phase_add_under_an_open_phase, _held_across_a_generators_yield,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_phase_parent_and_self_time(scenario, monkeypatch):
+    from isotope_tpu.telemetry import core
+
+    clock = _Clock()
+    monkeypatch.setattr(core, "time", clock)
+    phases, phase_self, parents = scenario(clock.tick)
+    assert not core._OPEN.frames         # every frame popped
+    snap = telemetry.snapshot()
+    assert snap.phase_self == phase_self
+    assert snap.phase_parents == parents
+    # the inclusive seconds as before, and a container's self time
+    # under <name>.self; a leaf has no such key
+    containers = {p for ps in parents.values() for p in ps} - {""}
+    assert snap.phases == {
+        **phases, **{f"{c}.self": phase_self[c] for c in containers}
+    }
+
+
+def test_a_thread_keeps_its_own_stack():
+    """A phase opened on another thread is a root there: it neither
+    names the main thread's open phase its parent nor takes from it."""
+    import threading
+
+    def work():
+        with telemetry.phase("on_thread"):
+            time.sleep(0.01)
+
+    with telemetry.phase("outer"):
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    snap = telemetry.snapshot()
+    assert snap.phase_parents == {"outer": [""], "on_thread": [""]}
+    assert snap.phase_self["outer"] == snap.phases["outer"] >= 0.01
+    assert "outer.self" not in snap.phases
 
 
 # -- counters across the jit boundary --------------------------------------
@@ -250,6 +444,33 @@ def test_prometheus_exposition_parses():
     )
 
 
+def test_prometheus_exposition_carries_the_self_times():
+    with telemetry.phase("probe.outer"):
+        with telemetry.phase("probe.inner"):
+            time.sleep(0.01)
+    rec = telemetry.snapshot()
+    assert "probe.outer.self" in rec.phases
+    # the live registry's text and a record's: the same series
+    for text in (telemetry.prometheus_text(), rec.prometheus_text()):
+        for line in text.splitlines():
+            if line and not line.startswith("#"):
+                assert PROM_LINE.match(line), f"unparseable line: {line!r}"
+        assert text.count("# TYPE isotope_engine_phase_self_seconds_total"
+                          " counter") == 1
+        for name in ("probe.outer", "probe.inner"):
+            for family in ("phase_seconds_total",
+                           "phase_self_seconds_total"):
+                assert f'isotope_engine_{family}{{phase="{name}"}} ' in text
+        # the .self keys are the benchmark readers' names for a self
+        # time: not a phase of the inclusive series
+        assert '.self"}' not in text
+    own = float(re.search(
+        r'phase_self_seconds_total\{phase="probe.outer"\} (\S+)',
+        rec.prometheus_text()).group(1))
+    assert own == pytest.approx(rec.phase_self["probe.outer"], abs=1e-6)
+    assert own < rec.phases["probe.inner"]
+
+
 # -- JSONL round trip ------------------------------------------------------
 
 def test_run_telemetry_jsonl_round_trip(tmp_path):
@@ -264,6 +485,33 @@ def test_run_telemetry_jsonl_round_trip(tmp_path):
     rec.append_jsonl(path)
     rec.append_jsonl(path)
     assert telemetry.validate_jsonl(path) == 2
+
+
+def test_jsonl_round_trip_keeps_parents_and_self_times(tmp_path):
+    with telemetry.phase("outer"):
+        with telemetry.phase("inner"):
+            time.sleep(0.001)
+    rec = telemetry.snapshot(label="nested")
+    assert rec.phase_parents == {"inner": ["outer"], "outer": [""]}
+    assert rec.phases["outer.self"] == rec.phase_self["outer"]
+    assert "inner.self" not in rec.phases
+    back = telemetry.RunTelemetry.from_dict(json.loads(rec.to_json_line()))
+    assert back == rec
+    path = tmp_path / "telemetry.jsonl"
+    rec.append_jsonl(path)
+    # a record written before phases nested has neither section: it
+    # validates and reads, with nothing where the new sections are
+    old = json.loads(rec.to_json_line())
+    del old["phase_self"], old["phase_parents"], old["phases"]["outer.self"]
+    with open(path, "a") as f:
+        f.write(json.dumps(old) + "\n")
+    assert telemetry.validate_jsonl(path) == 2
+    new, was = telemetry.iter_jsonl(path)
+    assert new == rec
+    assert was.phases == {"outer": rec.phases["outer"],
+                          "inner": rec.phases["inner"]}
+    assert was.phase_self == {} and was.phase_parents == {}
+    assert "phase_self_seconds_total{" not in was.prometheus_text()
 
 
 def test_jsonl_tolerates_crash_torn_final_line(tmp_path):

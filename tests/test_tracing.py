@@ -165,11 +165,18 @@ def test_sharded_call_accrues_its_phases(served, tmp_path):
     assert snap.counters["hop_events_simulated"] == count * HOPS
 
 
-def test_saturated_solve_is_the_mva_child(served, tmp_path):
+@pytest.fixture(scope="module")
+def saturated(served, tmp_path_factory):
+    """The registry after one ``--qps max`` call on a clean registry."""
     telemetry.reset()
-    rc, _, _ = serve(tmp_path, "qpsmax", qps="max")
-    snap = telemetry.snapshot()
+    rc, _, _ = serve(tmp_path_factory.mktemp("saturated"), "qpsmax",
+                     qps="max")
     assert rc == 0
+    return telemetry.snapshot()
+
+
+def test_saturated_solve_is_the_mva_child(saturated):
+    snap = saturated
     assert 0 < snap.phases["closed_rate.mva"] <= \
         snap.phases["closed_rate.solve"]
     assert "closed_rate.pilot" not in snap.phases
@@ -178,6 +185,194 @@ def test_saturated_solve_is_the_mva_child(served, tmp_path):
     assert snap.counters["closed_rate_census_sweeps"] >= 1
     assert 0 < snap.phases["closed_rate.census"] <= \
         snap.phases["closed_rate.mva"]
+
+
+def test_saturated_solve_names_its_fits(saturated):
+    """What is inside ``closed_rate.mva``: every ``tables_from_pi`` a
+    span, the centering terms, the census, the probes."""
+    snap = saturated
+    for child in ("closed_rate.tables_from_pi", "closed_rate.center_terms",
+                  "closed_rate.census", "closed_rate.sat_probe"):
+        assert snap.phase_parents[child] == ["closed_rate.mva"], child
+        assert snap.phases[child] > 0
+    assert snap.phase_parents["closed_rate.mva"] == ["closed_rate.solve"]
+    # what the four leave of the phase has a name of its own
+    assert snap.phases["closed_rate.mva.self"] == pytest.approx(
+        snap.phases["closed_rate.mva"] - sum(
+            snap.phases[child] for child, parents
+            in snap.phase_parents.items()
+            if parents == ["closed_rate.mva"]), abs=1e-4)
+
+
+# -- (b') parents, self times and the leaves inside the host phases --------
+
+#: every phase of the paced call, and the phase that opens it
+PARENTS = {
+    "cli.main": "",
+    "cli.parse": "cli.main", "cli.config": "cli.main",
+    "run.case": "cli.main", "artifacts.write": "cli.main",
+    "cli.parser_build": "cli.parse", "cli.parse_args": "cli.parse",
+    "graph.decode": "run.case", "compile.graph": "run.case",
+    "compile.unroll": "compile.graph", "collector.build": "run.case",
+    "engine.build": "run.case", "closed_rate.solve": "run.case",
+    "summary.dispatch": "run.case", "summary.wait": "run.case",
+    "artifacts.fortio": "run.case", "artifacts.window": "run.case",
+    "artifacts.exposition": "run.case",
+    "graph.decode.read": "graph.decode",
+    "graph.decode.yaml": "graph.decode",
+    "graph.decode.model": "graph.decode",
+    "engine.build.load": "engine.build",
+    "engine.build.level": "engine.build",
+    "engine.build.plan": "engine.build",
+    "engine.build.signature": "engine.build",
+    "engine.build.copula": "engine.build",
+    "closed_rate.pilot": "closed_rate.solve",
+}
+CONTAINERS = set(PARENTS.values()) - {""}
+#: the experiment's PRNG key under ``cli.main``, a run's fold of it
+#: under ``run.case``: one name, both parents
+TWO_PARENTS = {"run.key": ["cli.main", "run.case"]}
+
+
+def test_served_call_opens_each_phase_under_its_parent(served):
+    snap = served[0]
+    assert snap.phase_parents == {
+        **{name: [parent] for name, parent in PARENTS.items()},
+        **TWO_PARENTS}
+    # a container's self time has a name a reader of ``phases`` can ask
+    # for; a leaf's self time is its seconds
+    assert {n[:-len(".self")] for n in snap.phases
+            if n.endswith(".self")} == CONTAINERS
+    for name in PARENTS:
+        assert snap.phases[name] > 0, name
+        if name not in CONTAINERS:
+            assert snap.phase_self[name] == snap.phases[name], name
+
+
+def test_self_times_of_a_call_sum_to_its_root_span(served):
+    snap = served[0]
+    # phase_add's names (the jax hooks' compile.*) have no parent: they
+    # overlap the host phase they fired in
+    assert {"compile.trace", "compile.backend"} <= \
+        set(snap.phase_self) - set(snap.phase_parents)
+    own = sum(snap.phase_self[name] for name in snap.phase_parents)
+    assert own == pytest.approx(snap.phases["cli.main"], abs=1e-4)
+    # the five sections cover the constructor
+    assert snap.phases["engine.build.self"] < \
+        0.1 * snap.phases["engine.build"]
+    assert snap.phases["cli.parse.self"] < 0.1 * snap.phases["cli.parse"]
+
+
+def test_served_call_counts_at_the_new_boundaries(served):
+    snap = served[0]
+    assert snap.counters["signature_bytes_hashed"] > 0
+    assert snap.counters["level_table_bytes"] > 0
+    # the Simulator and, on the tests' 8 virtual devices, the one the
+    # ShardedSimulator builds for itself (ROADMAP S6)
+    assert snap.counters["simulators_built"] == 2
+
+
+def test_new_layer_metrics_read_these_spans_and_counters(served, saturated):
+    """The benchmark's ten data-only readers (``benchmark/layer_metrics``)
+    against the registry of a served call: each finds what it names;
+    on a registry without the names, as the parent's, a phase metric
+    returns nothing and does not raise."""
+    from benchmark.harness import readers
+
+    def ctx(snap, calls=2):
+        tel = {"phases": snap.phases, "counters": snap.counters}
+        return {"calls": calls, "telemetry": {"setup": tel, "window": tel}}
+
+    snap = served[0]
+    want = {
+        "host_unattributed_ms": 500.0 * (
+            snap.phases["cli.main.self"] + snap.phases["run.case.self"]),
+        "setup_calls_s": snap.phases["cli.main"],       # a run, not a call
+        "cli_parser_build_ms": 500.0 * snap.phases["cli.parser_build"],
+        "yaml_parse_ms": 500.0 * snap.phases["graph.decode.yaml"],
+        "engine_levels_ms": 500.0 * snap.phases["engine.build.level"],
+        "engine_plan_ms": 500.0 * snap.phases["engine.build.plan"],
+        "engine_signature_ms": 500.0 * snap.phases["engine.build.signature"],
+        "engine_table_mb_per_call":
+            0.5e-6 * snap.counters["level_table_bytes"],
+        "signature_mb_hashed_per_call":
+            0.5e-6 * snap.counters["signature_bytes_hashed"],
+    }
+    for name, value in want.items():
+        assert value > 0
+        assert readers.read_metric(name, ctx(snap)) == pytest.approx(value)
+    assert readers.read_metric("closed_tables_fit_ms", ctx(saturated)) == \
+        pytest.approx(
+            500.0 * saturated.phases["closed_rate.tables_from_pi"])
+    before = telemetry.RunTelemetry(
+        label=None, phases={"engine.build": 1.0, "cli.parse": 1.0},
+        counters={"runs_served": 2.0}, gauges={}, meta={})
+    for name in list(want) + ["closed_tables_fit_ms"]:
+        value = readers.read_metric(name, ctx(before))
+        assert value is None or (name.endswith("_per_call") and value == 0)
+
+
+def _entries(monkeypatch):
+    """Every phase entry from here on, by name."""
+    names = []
+    real = core._trace_annotation
+    monkeypatch.setattr(
+        core, "_trace_annotation",
+        lambda name, attrs: names.append(name) or real(name, attrs))
+    return names
+
+
+def test_no_span_inside_the_block_loop(served, tmp_path, monkeypatch):
+    """A warm call opens as many phases over 8 blocks as over 1: one a
+    level of the build is the finest span there is."""
+    from isotope_tpu.sim import Simulator
+
+    names = _entries(monkeypatch)
+    rc, _, _ = serve(tmp_path, "one", seed=12)
+    one = list(names)
+    # three levels a build, the Simulator's and the ShardedSimulator's
+    assert rc == 0 and one.count("engine.build.level") == 6
+    monkeypatch.setattr(
+        Simulator, "default_block_size", lambda self, budget_elems=0: 256)
+    serve(tmp_path, "cold", seed=13)       # compiles the 8-block scan
+    blocks = telemetry.counter_get("blocks_scanned")
+    del names[:]
+    rc, _, _ = serve(tmp_path, "eight", seed=14)
+    assert rc == 0
+    assert telemetry.counter_get("blocks_scanned") - blocks == 8
+    assert sorted(names) == sorted(one)
+
+
+def test_host_spans_reach_neither_a_program_nor_a_cache_key(monkeypatch):
+    """The served program's text and ``Simulator.signature``, built and
+    lowered inside an open phase and outside one."""
+    import jax.numpy as jnp
+
+    from isotope_tpu.compiler import compile_graph
+    from isotope_tpu.compiler.cache import executable_cache
+    from isotope_tpu.metrics.prometheus import MetricsCollector
+    from isotope_tpu.sim import Simulator
+
+    # each Simulator lowers its own closure, not a cached twin's
+    monkeypatch.setattr(executable_cache, "get_or_jit",
+                        lambda key, name, fun, **kw: jax.jit(fun))
+    compiled = compile_graph(graph_mod.ServiceGraph.from_yaml_file(TOPOLOGY))
+
+    def build():
+        sim = Simulator(compiled)
+        fn = sim._get_summary(512, 4, "closed", 4,
+                              MetricsCollector(compiled), True)
+        _, a = sim.trace_entry_args(512, "closed", 4)
+        scalar = jax.ShapeDtypeStruct((), jnp.float32)
+        return sim.signature, fn.lower(
+            *a[:5], scalar, scalar, *a[5:]).as_text()
+
+    outside = build()
+    with telemetry.phase("probe.outer", label="x"):
+        with telemetry.phase("probe.inner"):
+            inside = build()
+    assert inside[0] == outside[0]
+    assert inside[1] == outside[1] and "probe." not in inside[1]
 
 
 # -- (c) device scopes and their map ---------------------------------------
