@@ -638,7 +638,7 @@ def lint_compiled(compiled, params=None) -> List[Finding]:
     shapes = []
     offset = 0
     for d, lvl in enumerate(compiled.levels):
-        pmax = max(int(lvl.step_is_real.sum(1).max(initial=0)), 1)
+        pmax = max(lvl.pmax, 1)
         import numpy as np
 
         sparse = False
@@ -647,7 +647,7 @@ def lint_compiled(compiled, params=None) -> List[Finding]:
         tile_real_elems = 0
         if lvl.num_calls:
             n_slots = len(np.unique(lvl.call_seg))
-            widths = lvl.step_is_real[:, :pmax].sum(1)
+            widths = lvl.step_widths()
             enc, tile_plan = buckets.level_encoding(
                 lvl.num_hops, pmax, n_slots, widths,
                 num_hops=compiled.num_hops,

@@ -484,8 +484,8 @@ def compile_graph(
     grid_elems = 0
     skew = 1.0
     for lvl in compiled.levels:
-        widths = lvl.step_is_real.sum(1)
-        pmax = int(widths.max(initial=0))
+        widths = lvl.step_widths()
+        pmax = lvl.pmax
         if pmax <= 0:
             continue
         grid_elems = max(grid_elems, lvl.num_hops * pmax)
@@ -559,8 +559,10 @@ def _compile_graph(
     frontier = [0]  # global hop ids at the current depth
     while frontier:
         level_services = [hop_service[h] for h in frontier]
-        step_is_real = np.zeros((len(frontier), max_steps), bool)
-        step_base = np.zeros((len(frontier), max_steps), np.float32)
+        # the level's steps, packed: one entry a real step
+        step_hop: List[int] = []
+        step_at: List[int] = []
+        step_sleep: List[float] = []
         child_ids: List[int] = []
         child_seg: List[int] = []
         call_seg: List[int] = []
@@ -572,8 +574,9 @@ def _compile_graph(
             prog = programs[hop_service[h]]
             parent_err = float(table.error_rate[hop_service[h]])
             for step_idx, step in enumerate(prog):
-                step_is_real[local, step_idx] = True
-                step_base[local, step_idx] = step.base
+                step_hop.append(local)
+                step_at.append(step_idx)
+                step_sleep.append(step.base)
                 for call in step.calls:
                     # Each retry attempt is its own hop (with its own
                     # subtree); its static reach discounts by the target's
@@ -618,8 +621,12 @@ def _compile_graph(
             HopLevel(
                 hop_ids=np.asarray(frontier, np.int32),
                 service=np.asarray(level_services, np.int32),
-                step_is_real=step_is_real,
-                step_base=step_base,
+                step_hop=np.asarray(step_hop, np.int32),
+                step_idx=np.asarray(step_at, np.int32),
+                step_sleep=np.asarray(step_sleep, np.float32),
+                pmax=max(
+                    (len(programs[s]) for s in level_services), default=0
+                ),
                 child_ids=np.asarray(child_ids, np.int32),
                 child_seg=np.asarray(child_seg, np.int32),
                 call_seg=np.asarray(call_seg, np.int32),

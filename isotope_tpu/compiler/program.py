@@ -17,9 +17,11 @@ Everything here is plain NumPy; the engine moves it on-device once.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import numpy as np
+
+from isotope_tpu import telemetry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,11 +74,18 @@ class ServiceTable:
 class HopLevel:
     """All hops at one depth of the unrolled call tree.
 
-    ``Pmax`` is the graph-wide maximum script length; every hop's script is
-    padded to it.  Step slots hold either a fixed base duration (sleep
+    The level's steps travel PACKED: one entry a real step, in the
+    compiler's emission order (hop-major, step ascending — a hop's
+    script occupies steps ``0..width-1``, so its entries are one
+    contiguous run).  A step holds either a fixed base duration (sleep
     commands — including the max over sleeps inside a concurrent group,
     which run in parallel with the group's calls,
-    srv/executable.go:148-179) or a join over child hops.
+    srv/executable.go:148-179) or a join over child hops (base 0).
+    ``pmax`` is the level's OWN width, the widest script among its
+    hops; a dense ``(rows x width)`` step table exists only where a
+    reader asks ``dense_steps`` for one.  ``Pmax`` below is the
+    graph-wide maximum script length (``CompiledGraph.max_steps``): the
+    stride of the flat ``child_seg`` / ``call_seg`` slots, nothing else.
 
     Child hops (depth+1, in that level's local order) are grouped two
     ways:
@@ -94,8 +103,11 @@ class HopLevel:
 
     hop_ids: np.ndarray        # (L,) int32 — global hop ids, level-local order
     service: np.ndarray        # (L,) int32
-    step_is_real: np.ndarray   # (L, Pmax) bool — slot holds an actual step
-    step_base: np.ndarray      # (L, Pmax) f32 — sleep seconds (0 for calls)
+    # -- packed steps (S = real steps of the level) ------------------------
+    step_hop: np.ndarray       # (S,) int32 — level-local hop owning the step
+    step_idx: np.ndarray       # (S,) int32 — step index in the hop's script
+    step_sleep: np.ndarray     # (S,) f32 — sleep seconds (0 for calls)
+    pmax: int                  # widest script among the level's hops
     child_ids: np.ndarray      # (C,) int32 — global hop ids at depth+1
     child_seg: np.ndarray      # (C,) int32 — parent_local * Pmax + step
     # -- call tables (K = number of call sites at this level) -------------
@@ -121,6 +133,52 @@ class HopLevel:
     def max_attempts(self) -> int:
         return self.att_child.shape[0]
 
+    def step_widths(self) -> np.ndarray:
+        """(L,) script length of each hop."""
+        return np.bincount(self.step_hop, minlength=self.num_hops)
+
+    def sleep_at(self, hop: np.ndarray, step: np.ndarray) -> np.ndarray:
+        """Sleep base (f32) of the real steps ``(hop, step)``: a hop's
+        steps are one contiguous run of the packed entries."""
+        return self.step_sleep[np.searchsorted(self.step_hop, hop) + step]
+
+    def sleep_totals(self) -> np.ndarray:
+        """(L,) f32 sum of each hop's sleep bases — a segment sum over
+        the packed steps, in float32 as a row sum of the dense f32 grid
+        would be."""
+        total = np.zeros(self.num_hops, np.float32)
+        scripted = np.flatnonzero(self.step_widths())
+        if len(scripted):
+            total[scripted] = np.add.reduceat(
+                self.step_sleep, np.searchsorted(self.step_hop, scripted)
+            )
+        return total
+
+    def dense_steps(
+        self, rows: Optional[np.ndarray], width: int
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Materialise ``(step_is_real, step_base)`` for a set of rows.
+
+        ``rows`` are distinct level-local hops (``None``: every hop, in
+        order); the pair is ``(len(rows), width)`` bool / f32, a row
+        holding its hop's steps below ``width`` and padding after them.
+        The ONLY place the host makes a dense step table: the
+        ``dense_step_cells_built`` counter reads every cell of it.
+        """
+        hop, idx, n_rows = self.step_hop, self.step_idx, self.num_hops
+        if rows is not None:
+            row_of = np.full(self.num_hops, -1, np.int64)
+            row_of[rows] = np.arange(len(rows))
+            hop, n_rows = row_of[hop], len(rows)
+        keep = (hop >= 0) & (idx < width)
+        hop, idx = hop[keep], idx[keep]
+        telemetry.counter_inc("dense_step_cells_built", n_rows * width)
+        is_real = np.zeros((n_rows, width), bool)
+        base = np.zeros((n_rows, width), np.float32)
+        is_real[hop, idx] = True
+        base[hop, idx] = self.step_sleep[keep]
+        return is_real, base
+
 
 @dataclasses.dataclass(frozen=True)
 class CompiledGraph:
@@ -142,7 +200,7 @@ class CompiledGraph:
     hop_reach: np.ndarray      # (H,) f64
 
     levels: Tuple[HopLevel, ...]
-    max_steps: int             # Pmax
+    max_steps: int             # Pmax — the child_seg / call_seg stride
 
     @property
     def num_hops(self) -> int:
@@ -161,8 +219,9 @@ class CompiledGraph:
 
         Two compiled graphs with equal signatures produce identically
         *shaped* tensor programs (same level sizes, call/attempt
-        tables, step width) — the coarse half of the AOT executable
-        cache key (compiler/cache.py); value equality is established
+        tables, slot stride) — the coarse half of the AOT executable
+        cache key (compiler/cache.py); value equality — the levels'
+        packed steps and step widths among it — is established
         separately by the engine's constant digest.
         """
         return (

@@ -238,10 +238,9 @@ def build_tables(compiled: CompiledGraph, net) -> AttrTables:
         slot_of_call = np.searchsorted(slot_segs, lvl.call_seg).astype(
             np.int32
         )
-        slot_base = lvl.step_base[
-            slot_segs // compiled.max_steps,
-            slot_segs % compiled.max_steps,
-        ].astype(np.float32)
+        slot_base = lvl.sleep_at(
+            slot_segs // compiled.max_steps, slot_segs % compiled.max_steps
+        )
         timeout = lvl.call_timeout[call_of_child].astype(np.float32)
         nxt = compiled.levels[d + 1]
         levels.append(

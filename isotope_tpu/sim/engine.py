@@ -119,9 +119,12 @@ class _Level:
 
     offset: int                 # start of this level's slice in hop order
     size: int
-    pmax: int
-    step_mask: jax.Array        # (L, Pmax) f32 — 1 where a real step
-    step_base: jax.Array        # (L, Pmax) f32
+    pmax: int                   # the level's own step width (>= 1)
+    # the dense (L, pmax) step grid — only a level that RUNS on it holds
+    # one; a tiled, sparse or leaf level carries None (its tiles, slot
+    # tables or ``leaf_busy`` hold what its sweep reads)
+    step_mask: Optional[jax.Array]  # (L, pmax) f32 — 1 where a real step
+    step_base: Optional[jax.Array]  # (L, pmax) f32
     child_seg: jax.Array        # (C,) i32 — parent_local * Pmax + step
     child_parent_local: jax.Array  # (C,) i32
     child_step: jax.Array       # (C,) i32 — step index within the parent
@@ -265,8 +268,8 @@ class _TiledSteps:
 def _sparse_tables(
     num_hops: int,
     pmax: int,
-    sleep_real: np.ndarray,      # (L, >=pmax) f64 — step_is_real * base
-    step_base: np.ndarray,       # (L, >=pmax)
+    step_is_real: np.ndarray,    # (L, pmax) bool
+    step_base: np.ndarray,       # (L, pmax) f32
     call_seg_p: np.ndarray,      # (K,) parent_local * pmax + step
     parent_local: np.ndarray,    # (C,)
     child_step: np.ndarray,      # (C,)
@@ -290,7 +293,8 @@ def _sparse_tables(
         seg_last[h] = i
     has_call_step = np.zeros((num_hops, pmax), bool)
     has_call_step[slot_hop, slot_step] = True
-    sleep_only = sleep_real[:, :pmax] * ~has_call_step
+    sleep_real = step_is_real.astype(np.float64) * step_base
+    sleep_only = sleep_real * ~has_call_step
     sleep_prefix = np.cumsum(sleep_only, 1) - sleep_only
     child_sleep_prefix = sleep_prefix[parent_local, child_step]
     child_slot_np = np.searchsorted(
@@ -330,14 +334,14 @@ def _sparse_tables(
 def _build_tiled_steps(
     plan,                        # buckets.TilePlan
     pmax: int,
-    step_is_real: np.ndarray,    # (L, >=pmax) bool
-    step_base: np.ndarray,       # (L, >=pmax)
-    sleep_real: np.ndarray,      # (L, >=pmax) f64
+    lvl,                         # compiler.program.HopLevel
     call_seg_p: np.ndarray,      # (K,)
     parent_local: np.ndarray,    # (C,)
     child_step: np.ndarray,      # (C,)
 ) -> _TiledSteps:
-    """Lower one level's tile plan into device constants."""
+    """Lower one level's tile plan into device constants.  Each tile's
+    ``(T x W)`` step rows and the residual's ``(R x pmax)`` come
+    straight from the level's packed steps."""
     call_parent = call_seg_p // pmax
     call_step_all = call_seg_p % pmax
     tiles: List[_Tile] = []
@@ -378,13 +382,12 @@ def _build_tiled_steps(
                 call_seg_t, np.repeat(np.arange(slots_t), c)
             ):
                 uniform = c
+        tile_is_real, tile_base = lvl.dense_steps(hop_idx, w)
         tiles.append(_Tile(
             hops=hop_idx,
             width=w,
-            step_mask=jnp.asarray(
-                step_is_real[hop_idx][:, :w], jnp.float32
-            ),
-            step_base=jnp.asarray(step_base[hop_idx][:, :w]),
+            step_mask=jnp.asarray(tile_is_real, jnp.float32),
+            step_base=jnp.asarray(tile_base),
             call_sel=call_sel,
             call_pos=jnp.asarray(call_pos, jnp.int32),
             call_step=jnp.asarray(cstep, jnp.int32),
@@ -412,8 +415,7 @@ def _build_tiled_steps(
         )
         child_step_r = child_step[res_child_sel]
         residual = _sparse_tables(
-            len(res_hops), pmax,
-            sleep_real[res_hops], step_base[res_hops],
+            len(res_hops), pmax, *lvl.dense_steps(res_hops, pmax),
             call_seg_r, parent_r, child_step_r,
         )
         res_child_pos = jnp.asarray(parent_r, jnp.int32)
@@ -1269,9 +1271,7 @@ class Simulator:
         reach_f = compiled.hop_reach * fj
         hop_sleep = np.zeros(compiled.num_hops)
         for lvl in compiled.levels:
-            hop_sleep[lvl.hop_ids] = (
-                lvl.step_base * lvl.step_is_real
-            ).sum(1)
+            hop_sleep[lvl.hop_ids] = lvl.sleep_totals()
         # per-hop delay weight: wire round trip + own sleeps; the delay
         # station's Z and the cycle visit ratios follow per phase row as
         # sums over reach_fj * mult_pc (phased saturated closed loop)
@@ -1320,15 +1320,22 @@ class Simulator:
                      churn: bool) -> Tuple[_Level, dict]:
         """One depth level's device constants and the host-side shapes
         (``meta``) the bucket plan reads: the step tables in the
-        encoding ``buckets.level_encoding`` picks, put on the device."""
+        encoding ``buckets.level_encoding`` picks, put on the device.
+        The level's steps arrive packed (``HopLevel``); a dense table
+        is materialised (``HopLevel.dense_steps``) only for the rows the
+        encoding reads - a dense level's ``(L x pmax)`` pair, a tile's
+        ``(T x W)``, a residual's or sparse level's rows for their
+        static sleep tables, a leaf's for its row sums."""
         compiled, params = self.compiled, self.params
         cids = lvl.child_ids
         # Per-level step width: the compiler encodes segments with the
         # GLOBAL max_steps stride, but a level only needs the widest
         # script among ITS services — on skewed graphs (one huge
         # fan-out service, thousands of leaves) the global width
-        # wastes multiples of the step-tensor footprint.
-        pmax = max(int(lvl.step_is_real.sum(1).max(initial=0)), 1)
+        # wastes multiples of the step-tensor footprint.  The steps
+        # arrive packed (HopLevel); a dense table is made below only
+        # for the rows, and at the width, the level's encoding reads.
+        pmax = max(lvl.pmax, 1)
         parent_local = lvl.child_seg // compiled.max_steps
         child_step = lvl.child_seg % compiled.max_steps
         call_local = lvl.call_seg // compiled.max_steps
@@ -1364,14 +1371,15 @@ class Simulator:
         sparse: Optional[_SparseSteps] = None
         tiled: Optional[_TiledSteps] = None
         leaf_busy: Optional[jax.Array] = None
-        sleep_real = lvl.step_is_real.astype(np.float64) * (
-            lvl.step_base
-        )
+        step_mask = step_base = None  # host (L, pmax) f32: dense only
         if n_calls == 0:
-            leaf_busy = jnp.asarray(sleep_real.sum(1), jnp.float32)
+            is_real, base = lvl.dense_steps(None, pmax)
+            leaf_busy = jnp.asarray(
+                (is_real.astype(np.float64) * base).sum(1), jnp.float32
+            )
         else:
             n_slots = len(np.unique(call_seg_p))
-            widths = lvl.step_is_real[:, :pmax].sum(1)
+            widths = lvl.step_widths()
             enc, tile_plan = buckets.level_encoding(
                 lvl.num_hops, pmax, n_slots, widths,
                 num_hops=compiled.num_hops,
@@ -1381,15 +1389,19 @@ class Simulator:
             )
             if enc == "tiled":
                 tiled = _build_tiled_steps(
-                    tile_plan, pmax, lvl.step_is_real,
-                    lvl.step_base, sleep_real, call_seg_p,
+                    tile_plan, pmax, lvl, call_seg_p,
                     parent_local, child_step,
                 )
             elif enc == "sparse":
                 sparse = _sparse_tables(
-                    lvl.num_hops, pmax, sleep_real, lvl.step_base,
+                    lvl.num_hops, pmax, *lvl.dense_steps(None, pmax),
                     call_seg_p, parent_local, child_step,
                 )
+            else:
+                # the level runs on the dense grid (unrolled, or in a
+                # scan bucket): its (L x pmax) pair, made once
+                is_real, step_base = lvl.dense_steps(None, pmax)
+                step_mask = is_real.astype(np.float32)
         meta = dict(
             size=lvl.num_hops, pmax=pmax, C=len(cids), K=n_calls,
             A=lvl.att_child.shape[0], offset=offset,
@@ -1415,11 +1427,7 @@ class Simulator:
             # sparse level's (size x pmax) grid is exactly what the
             # sparse encoding exists to avoid materializing
             meta.update(
-                step_mask=lvl.step_is_real[:, :pmax]
-                .astype(np.float32),
-                step_base=np.asarray(
-                    lvl.step_base[:, :pmax], np.float32
-                ),
+                step_mask=step_mask, step_base=step_base,
                 parent_local=parent_local, child_step=child_step,
                 child_rtt=(net_out[cids] + net_back[cids]),
                 child_net_out=net_out[cids],
@@ -1436,10 +1444,12 @@ class Simulator:
                 offset=offset,
                 size=lvl.num_hops,
                 pmax=pmax,
-                step_mask=jnp.asarray(
-                    lvl.step_is_real[:, :pmax], jnp.float32
+                step_mask=(
+                    None if step_mask is None else jnp.asarray(step_mask)
                 ),
-                step_base=jnp.asarray(lvl.step_base[:, :pmax]),
+                step_base=(
+                    None if step_base is None else jnp.asarray(step_base)
+                ),
                 child_seg=jnp.asarray(parent_local * pmax + child_step),
                 child_parent_local=jnp.asarray(parent_local),
                 child_step=jnp.asarray(child_step),
@@ -1570,9 +1580,12 @@ class Simulator:
                     a
                     for l in compiled.levels
                     for a in (
-                        l.step_is_real, l.step_base, l.child_ids,
-                        l.child_seg, l.call_seg, l.call_timeout,
-                        l.att_child, l.att_valid,
+                        # the packed steps and the level's width: which
+                        # steps exist, whose they are, their order and
+                        # sleep base — whatever encoding the level runs
+                        l.step_hop, l.step_idx, l.step_sleep, l.pmax,
+                        l.child_ids, l.child_seg, l.call_seg,
+                        l.call_timeout, l.att_child, l.att_valid,
                     )
                 ],
             ),

@@ -201,12 +201,15 @@ class RetryFeedback:
             else:
                 g0 = np.zeros(0, np.int64)
                 att_global = np.zeros((1, 0), np.int64)
+            # the dense grid at the level's own width (the call tables'
+            # ``call_seg`` keep the graph-wide stride ``ms``)
+            step_real, step_base = lvl.dense_steps(None, max(lvl.pmax, 1))
             self._levels.append(
                 _LevelCalls(
                     hop_ids=lvl.hop_ids.astype(np.int64),
                     svc=hs[lvl.hop_ids].astype(np.int64),
-                    step_base=lvl.step_base.astype(np.float64),
-                    step_real=lvl.step_is_real.astype(bool),
+                    step_base=step_base.astype(np.float64),
+                    step_real=step_real,
                     parent_local=(lvl.call_seg // ms).astype(np.int64),
                     step=(lvl.call_seg % ms).astype(np.int64),
                     timeout=lvl.call_timeout.astype(np.float64),

@@ -105,6 +105,51 @@ def test_signature_stable_across_runs():
     assert s.signature == sig
 
 
+def _two_scripts(left, right) -> str:
+    """An entry calling ``left``, ``right`` and ``leaf``: two scripted
+    hops at one level, their scripts the steps given."""
+    def script(steps):
+        return "".join(f"  - {step}\n" for step in steps)
+
+    return ("services:\n- name: entry\n  isEntrypoint: true\n  script:\n"
+            "  - call: left\n  - call: right\n  - call: leaf\n"
+            "- name: left\n  script:\n" + script(left)
+            + "- name: right\n  script:\n" + script(right)
+            + "- name: leaf\n")
+
+
+STEPS = (["sleep: 1ms", "sleep: 2ms"], ["sleep: 1ms"])
+
+
+@pytest.mark.parametrize("other, same", [
+    pytest.param(None, True, id="built_twice"),
+    pytest.param(STEPS, True, id="decoded_afresh"),
+    pytest.param((["sleep: 1ms", "sleep: 3ms"], ["sleep: 1ms"]), False,
+                 id="one_sleep_base"),
+    pytest.param((["sleep: 1ms", "call: leaf"], ["sleep: 1ms"]), False,
+                 id="one_step_made_a_call"),
+    pytest.param((["sleep: 2ms", "sleep: 1ms"], ["sleep: 1ms"]), False,
+                 id="two_steps_of_one_script_swapped"),
+    pytest.param((["sleep: 1ms"], ["sleep: 1ms", "sleep: 2ms"]), False,
+                 id="another_hop_of_the_level_owns_the_step"),
+])
+def test_signature_tells_a_levels_steps_apart(other, same):
+    """What the signature digests of a level's steps is their packed
+    form (``compiler.program.HopLevel``): one signature for one graph,
+    however often it is decoded and built; another for a graph whose
+    steps differ in a constant the traced program bakes in - whose a
+    step is, where in the script, its sleep base - whatever encoding
+    the level runs."""
+    compiled = compile_graph(ServiceGraph.from_yaml(_two_scripts(*STEPS)))
+    base = Simulator(compiled)
+    if other is not None:
+        compiled = compile_graph(
+            ServiceGraph.from_yaml(_two_scripts(*other)))
+        # every case keeps the levels' hop counts: the steps tell apart
+        assert [lvl.num_hops for lvl in compiled.levels][:2] == [1, 3]
+    assert (Simulator(compiled).signature == base.signature) == same
+
+
 def test_array_digest_discriminates():
     a = np.arange(6, dtype=np.float32)
     assert array_digest(a) == array_digest(a.copy())

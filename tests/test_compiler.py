@@ -120,11 +120,57 @@ services:
     )
     c = compile_graph(g)
     root = c.levels[0]
-    assert root.step_is_real[0, :2].all()
+    # the root's two steps, packed: one entry a real step
+    assert (root.pmax, c.max_steps) == (2, 2)
+    assert list(root.step_hop) == [0, 0] and list(root.step_idx) == [0, 1]
     # step 0: plain sleep; step 1: concurrent group keeps max(5ms, 7ms)
-    np.testing.assert_allclose(root.step_base[0, :2], [0.010, 0.007])
+    np.testing.assert_allclose(root.step_sleep, [0.010, 0.007])
+    is_real, base = root.dense_steps(None, 2)
+    assert is_real.all() and is_real.shape == (1, 2)
+    np.testing.assert_array_equal(base[0], root.step_sleep)
+    # the leaf has no script: a level of no steps and width 0
+    assert (c.levels[1].pmax, len(c.levels[1].step_hop)) == (0, 0)
     # the group's call is a child anchored at step 1
     assert list(c.hop_step) == [-1, 1]
+
+
+def test_dense_steps_materialises_the_rows_asked_for():
+    """The one maker of a dense step table on the host: the rows asked
+    for, in the order asked, cut to the width asked - and every cell of
+    it counted."""
+    from isotope_tpu import telemetry
+
+    g = ServiceGraph.from_yaml(
+        """
+services:
+- name: entry
+  isEntrypoint: true
+  script:
+  - [{call: a}, {call: b}, {call: c}]
+- name: a
+  script: [{sleep: 1ms}, {sleep: 2ms}, {sleep: 3ms}]
+- name: b
+- name: c
+  script: [{sleep: 4ms}]
+"""
+    )
+    lvl = compile_graph(g).levels[1]
+    assert (lvl.num_hops, lvl.pmax) == (3, 3)
+    assert list(lvl.step_widths()) == [3, 0, 1]
+    before = telemetry.counter_get("dense_step_cells_built")
+    is_real, base = lvl.dense_steps(None, 3)
+    assert is_real.tolist() == [[True] * 3, [False] * 3,
+                                [True, False, False]]
+    np.testing.assert_array_equal(
+        base, np.asarray([[.001, .002, .003], [0, 0, 0], [.004, 0, 0]],
+                         np.float32))
+    assert base.dtype == np.float32 and is_real.dtype == bool
+    # rows out of order, cut below the widest script
+    is_real, base = lvl.dense_steps(np.asarray([2, 0]), 2)
+    assert is_real.tolist() == [[True, False], [True, True]]
+    np.testing.assert_array_equal(
+        base, np.asarray([[.004, 0], [.001, .002]], np.float32))
+    assert telemetry.counter_get("dense_step_cells_built") - before == 9 + 4
 
 
 def test_cycle_rejected():

@@ -17,12 +17,15 @@ import numpy as np
 import pytest
 
 from isotope_tpu.compiler import compile_graph
+from isotope_tpu.compiler.program import hop_wire_times
 from isotope_tpu.compiler.buckets import (
     DEFAULT_TILE_PMAX,
     level_encoding,
     plan_tiles,
 )
+from isotope_tpu.models.generators import realistic_topology
 from isotope_tpu.models.graph import ServiceGraph
+from isotope_tpu.models.script import ConcurrentCommand, SleepCommand
 from isotope_tpu.sim import LoadModel, SimParams, Simulator
 from isotope_tpu.sim.config import OPEN_LOOP, ChaosEvent
 
@@ -55,6 +58,39 @@ services:
 - name: w2
   script: [{sleep: 1ms}]
 - name: w3
+"""
+
+
+# a pure-sleep script wider than a tile cap of 3 among short siblings,
+# beside a hub whose first call times out
+CALLFREE_WIDE = """
+services:
+- name: entry
+  isEntrypoint: true
+  script:
+  - [{call: hub}, {call: slow}, {call: s0}, {call: s1}, {call: s2},
+     {call: s3}, {call: s4}]
+- name: hub
+  script:
+  - sleep: 1ms
+  - call: {service: w0, timeout: 3ms}
+  - call: w1
+- name: slow
+  script:
+  - sleep: 1ms
+  - sleep: 1ms
+  - sleep: 1ms
+  - sleep: 1ms
+  - sleep: 1ms
+  - sleep: 1ms
+- name: s0
+- name: s1
+- name: s2
+- name: s3
+- name: s4
+- name: w0
+  script: [{sleep: 5ms}]
+- name: w1
 """
 
 
@@ -246,36 +282,7 @@ def test_callfree_wide_hop_in_residual():
     residual with ZERO call slots; with a firing timeout elsewhere in
     the level (transport machinery armed level-wide) the static-busy
     guard must hold and match the dense grid."""
-    yaml_text = """
-services:
-- name: entry
-  isEntrypoint: true
-  script:
-  - [{call: hub}, {call: slow}, {call: s0}, {call: s1}, {call: s2},
-     {call: s3}, {call: s4}]
-- name: hub
-  script:
-  - sleep: 1ms
-  - call: {service: w0, timeout: 3ms}
-  - call: w1
-- name: slow
-  script:
-  - sleep: 1ms
-  - sleep: 1ms
-  - sleep: 1ms
-  - sleep: 1ms
-  - sleep: 1ms
-  - sleep: 1ms
-- name: s0
-- name: s1
-- name: s2
-- name: s3
-- name: s4
-- name: w0
-  script: [{sleep: 5ms}]
-- name: w1
-"""
-    dense, tiled, sparse = _sims(yaml_text, tile_pmax=3)
+    dense, tiled, sparse = _sims(CALLFREE_WIDE, tile_pmax=3)
     tl = [lvl.tiled for lvl in tiled._levels if lvl.tiled is not None]
     assert tl and tl[0].residual is not None
     assert tl[0].residual.n_slots == 0  # the pure-sleep 'slow' hop
@@ -343,6 +350,133 @@ def test_attribution_oblivious_to_tiling():
         rtol=1e-5, atol=1e-9,
     )
     assert float(at.residual_abs) / float(at.count) < 1e-6
+
+
+# ---------------------------------------------------------------------------
+# the build reads a level's PACKED steps (compiler.program.HopLevel): what
+# it hands the device is what a plain (hops x max_steps) grid would give
+
+
+def _plain_grid(graph, compiled, depth):
+    """One level's ``(hops x max_steps)`` step grid, bool and float32,
+    written from the graph's scripts a cell at a time."""
+    lvl = compiled.levels[depth]
+    is_real = np.zeros((lvl.num_hops, compiled.max_steps), bool)
+    base = np.zeros((lvl.num_hops, compiled.max_steps), np.float32)
+    for row, svc in enumerate(lvl.service):
+        for i, cmd in enumerate(graph.services[svc].script):
+            is_real[row, i] = True
+            if isinstance(cmd, SleepCommand):
+                base[row, i] = cmd.seconds
+            elif isinstance(cmd, ConcurrentCommand):
+                base[row, i] = max(
+                    (c.seconds for c in cmd if isinstance(c, SleepCommand)),
+                    default=0.0)
+    return is_real, base
+
+
+def _same_bytes(got, want, what):
+    got, want = np.asarray(got), np.asarray(want)
+    assert (got.dtype, got.shape) == (want.dtype, want.shape), what
+    assert got.tobytes() == want.tobytes(), what
+
+
+def _slot_tables_from_grid(sp, is_real, base, pmax, parent, step, what):
+    """A ``_SparseSteps``' static sleep tables against the rows of the
+    plain grid it covers (``parent`` / ``step``: its children's)."""
+    slot_hop, slot_step = np.asarray(sp.slot_hop), np.asarray(sp.slot_step)
+    sleep = (is_real.astype(np.float64) * base)[:, :pmax]
+    calls = np.zeros(sleep.shape, bool)
+    calls[slot_hop, slot_step] = True
+    sleep_only = sleep * ~calls
+    prefix = np.cumsum(sleep_only, 1) - sleep_only
+    for name, want in (
+        ("slot_base", base[slot_hop, slot_step]),
+        ("sleep_total", sleep_only.sum(1)),
+        ("slot_sleep_prefix", prefix[slot_hop, slot_step]),
+        ("child_sleep_prefix", prefix[parent, step]),
+    ):
+        _same_bytes(getattr(sp, name), want.astype(np.float32),
+                    f"{what}.{name}")
+
+
+@pytest.mark.parametrize("graph, kw, encodings", [
+    pytest.param(SKEWED, {}, "dense", id="skewed_dense"),
+    pytest.param(SKEWED, {"sparse_level_elems": 1}, "tiled",
+                 id="skewed_tiled"),
+    pytest.param(SKEWED, {"sparse_level_elems": 1, "sparse_tile_pmax": 4},
+                 "tiled+residual", id="skewed_residual"),
+    pytest.param(SKEWED, {"sparse_level_elems": 1, "sparse_tiling": False},
+                 "sparse", id="skewed_sparse"),
+    pytest.param(CALLFREE_WIDE,
+                 {"sparse_level_elems": 1, "sparse_tile_pmax": 3},
+                 "tiled+residual", id="callfree_wide_residual"),
+    pytest.param(400, {}, "tiled+residual", id="star400"),
+])
+def test_step_tables_are_the_plain_grids(graph, kw, encodings):
+    """Every tile's ``(T x W)`` pair, a residual's and a sparse level's
+    slot tables, ``leaf_busy`` and a dense level's ``(L x pmax)`` pair,
+    byte for byte what a plain grid at the graph-wide stride gives -
+    and no dense pair where the level's sweep reads none."""
+    g = (ServiceGraph.decode(realistic_topology(graph, archetype="star",
+                                                seed=0))
+         if isinstance(graph, int) else ServiceGraph.from_yaml(graph))
+    compiled = compile_graph(g)
+    sim = Simulator(compiled, SimParams(**kw))
+    seen = set()
+    hop_sleep = np.zeros(compiled.num_hops)
+    for d, lvl in enumerate(sim._levels):
+        is_real, base = _plain_grid(g, compiled, d)
+        hl = compiled.levels[d]
+        assert lvl.pmax == max(int(is_real.sum(1).max()), 1)
+        hop_sleep[hl.hop_ids] = (base * is_real).sum(1)
+        parent = hl.child_seg // compiled.max_steps
+        step = hl.child_seg % compiled.max_steps
+        if lvl.leaf_busy is not None:
+            seen.add("leaf")
+            _same_bytes(
+                lvl.leaf_busy,
+                (is_real.astype(np.float64) * base).sum(1)
+                .astype(np.float32), f"lvl[{d}].leaf_busy")
+        elif lvl.tiled is not None:
+            seen.add("tiled")
+            for tile in lvl.tiled.tiles:
+                what = f"lvl[{d}].tile[{len(tile.hops)}x{tile.width}]"
+                _same_bytes(
+                    tile.step_mask,
+                    is_real[tile.hops][:, :tile.width].astype(np.float32),
+                    what + ".step_mask")
+                _same_bytes(tile.step_base,
+                            base[tile.hops][:, :tile.width],
+                            what + ".step_base")
+            if lvl.tiled.residual is not None:
+                seen.add("residual")
+                rows = lvl.tiled.res_hops
+                _slot_tables_from_grid(
+                    lvl.tiled.residual, is_real[rows], base[rows],
+                    lvl.pmax, np.asarray(lvl.tiled.res_child_pos),
+                    np.asarray(lvl.tiled.res_child_step),
+                    f"lvl[{d}].residual")
+        elif lvl.sparse is not None:
+            seen.add("sparse")
+            _slot_tables_from_grid(lvl.sparse, is_real, base, lvl.pmax,
+                                   parent, step, f"lvl[{d}].sparse")
+        else:
+            seen.add("dense")
+            _same_bytes(lvl.step_mask,
+                        is_real[:, :lvl.pmax].astype(np.float32),
+                        f"lvl[{d}].step_mask")
+            _same_bytes(lvl.step_base, base[:, :lvl.pmax],
+                        f"lvl[{d}].step_base")
+            continue
+        assert lvl.step_mask is None and lvl.step_base is None, d
+    # the closed-loop model's per-hop delay weights: wire + own sleeps
+    net_out, net_back = hop_wire_times(compiled, sim.params.network)
+    _same_bytes(sim._hop_delay_w, net_out + net_back + hop_sleep,
+                "hop_delay_w")
+    assert set(encodings.split("+")) <= seen and "leaf" in seen
+    assert ("tiled" in seen) == ("tiled" in encodings)
+    assert ("sparse" in seen) == (encodings == "sparse")
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,9 @@ TWO_LEVELS = (2000, 5)
 ENCODING_COUNTERS = (
     "levels_tiled", "hops_in_tiled_levels", "tile_padded_elems",
     "tile_real_elems", "sparse_residual_slots", "dense_grid_elems_avoided")
+#: what one ``engine.build`` makes, hands to the device and hashes
+BUILD_COUNTERS = (
+    "dense_step_cells_built", "level_table_bytes", "signature_bytes_hashed")
 
 #: latency240's argv and its pre-check's (benchmark/traffic/latency240.json)
 #: without the compile cache, cut to ``--max-requests``
@@ -85,12 +88,13 @@ def _simulate(topo, prom, load, seed: int, requests: int, capsys) -> dict:
 @pytest.fixture(scope="module")
 def star10k():
     """The vendored graph compiled and its default ``Simulator``, with
-    what the six encoding counters moved by at that one build."""
+    what the six encoding counters and the three build counters moved
+    by at that one build."""
     compiled = compile_graph(ServiceGraph.from_yaml_file(VENDORED))
-    before = {n: telemetry.counter_get(n) for n in ENCODING_COUNTERS}
+    names = ENCODING_COUNTERS + BUILD_COUNTERS
+    before = {n: telemetry.counter_get(n) for n in names}
     sim = Simulator(compiled, SimParams())
-    moved = {n: telemetry.counter_get(n) - before[n]
-             for n in ENCODING_COUNTERS}
+    moved = {n: telemetry.counter_get(n) - before[n] for n in names}
     return compiled, sim, moved
 
 
@@ -145,6 +149,7 @@ def test_encoding_counters_move_by_what_the_plan_says(star10k):
     """What ``tile_padding_share`` (benchmark/layer_metrics) reads:
     every ``engine.build`` records its tiled and sparse levels."""
     _, sim, moved = star10k
+    moved = {n: moved[n] for n in ENCODING_COUNTERS}
     assert moved == buckets.encoding_stats(sim._plan_shapes) == {
         "levels_tiled": 2, "hops_in_tiled_levels": 5021 + 4641,
         "tile_padded_elems": (
@@ -157,6 +162,34 @@ def test_encoding_counters_move_by_what_the_plan_says(star10k):
     # 330 at level 2, all in its tiles
     assert moved["tile_real_elems"] + moved["sparse_residual_slots"] == (
         4641 + 330)
+
+
+def test_the_build_makes_the_plans_own_step_cells_and_no_grid(star10k):
+    """The level's steps travel packed (PR 40): the build makes a dense
+    step table only for the rows its plan reads - level 0's one row of
+    5,021, the ten tiles, the eight residual hubs at level 1's width,
+    levels 3 and 4 at theirs - where the (hops x max_steps) grids held
+    10,000 x 5,021 cells for the same 9,999 steps; the tiled levels hold
+    no dense table, so what goes to the device and what is hashed are
+    the steps' size, not the grids' (91.1 MB and 251.9 MB until then)."""
+    compiled, sim, moved = star10k
+    assert compiled.max_steps == 5021
+    assert [(lvl.pmax, len(lvl.step_hop)) for lvl in compiled.levels] == [
+        (5021, 5021), (2217, 4641), (38, 330), (3, 7), (0, 0)]
+    assert moved["dense_step_cells_built"] == (
+        5021                        # level 0, dense
+        + 6011 + 8 * 2217           # level 1: five tiles, the residual
+        + 5028                      # level 2: five tiles
+        + 330 * 3 + 7 * 1)          # level 3 dense, level 4's leaf rows
+    assert moved["dense_step_cells_built"] < 60_000 < 10_000 * 5021
+    assert moved["level_table_bytes"] < 2e6
+    assert moved["signature_bytes_hashed"] < 2e6
+    for d, lvl in enumerate(sim._levels):
+        dense = d in (0, 3)
+        assert (lvl.step_mask is not None) == dense, d
+        assert (lvl.step_base is not None) == dense, d
+    assert sim._levels[0].step_mask.shape == (1, 5021)
+    assert sim._levels[3].step_base.shape == (330, 3)
 
 
 def test_a_plan_of_dense_levels_moves_no_encoding_counter():
