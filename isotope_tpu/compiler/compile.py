@@ -474,6 +474,17 @@ def compile_graph(
     with telemetry.phase("compile.unroll"):
         compiled = _compile_graph(graph, entry, max_hops)
     telemetry.counter_inc("graphs_compiled")
+    # what a call's `retries` cost the plan: the hop columns that are a
+    # second or later attempt (each with a subtree of its own under it)
+    # and the call sites that have them; absent where no call retries
+    attempt_hops = int((compiled.hop_attempt > 0).sum())
+    if attempt_hops:
+        telemetry.counter_inc("attempt_hops_compiled", attempt_hops)
+        telemetry.counter_inc(
+            "retry_call_sites",
+            sum(int((lvl.att_valid.sum(0) > 1).sum())
+                for lvl in compiled.levels if lvl.num_calls),
+        )
     telemetry.gauge_set("last_graph_hops", compiled.num_hops)
     telemetry.gauge_set("last_graph_levels", len(compiled.levels))
     # step-grid skew: the widest level's dense (hops x pmax) element

@@ -1080,6 +1080,33 @@ def _splitting_pass(sim, sharded, use_sharded, topo, load, n,
         return None
 
 
+def _retries_fired(compiled, metrics, requests: int, chaos) -> Optional[int]:
+    """Executions beyond the calls' first attempts, off the summary's
+    totals: every execution less the client's requests less each
+    service's 200s x the calls its script makes.  ``None`` where no call
+    retries, and where the totals do not say because something other
+    than the caller's own 500 can skip or cut a first attempt (a send
+    probability, a timeout, an outage)."""
+    retried = compiled.hop_attempt > 0
+    if (
+        not retried.any()
+        or chaos
+        or (compiled.hop_send_prob < 1.0).any()
+        or any(np.isfinite(lvl.call_timeout).any() for lvl in compiled.levels)
+    ):
+        return None
+    first = ~retried & (compiled.hop_parent >= 0)
+    calls_of_hop = np.bincount(
+        compiled.hop_parent[first], minlength=compiled.num_hops
+    )
+    # every hop of a service runs the same script: one hop's calls
+    calls = np.zeros(metrics.incoming_total.shape[0], np.int64)
+    calls[compiled.hop_service] = calls_of_hop
+    ok = np.asarray(metrics.duration_hist, np.float64)[:, 0].sum(-1)
+    incoming = np.asarray(metrics.incoming_total, np.float64)
+    return int(incoming.sum() - requests - (ok * calls).sum())
+
+
 def _record_vet_memory_ratio() -> None:
     """Measured/estimated device-peak-bytes ratio gauge: pairs the
     VET-M cost-model estimate with the run's real high-water so
@@ -1966,6 +1993,12 @@ def run_experiment(
                             summary.metrics.duration_hist, np.float64
                         )[:, 1].sum()),
                     )
+                    fired = _retries_fired(
+                        topo.compiled, summary.metrics,
+                        int(summary.count), config.chaos,
+                    )
+                    if fired is not None:
+                        telemetry.counter_inc("retries_fired", fired)
                 run_telem = None
                 if telemetry.emitting():
                     # one scrape sees workload AND engine: append

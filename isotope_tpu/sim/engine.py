@@ -1699,6 +1699,8 @@ class Simulator:
             for i, f, c in rows:
                 mix[i, f] = c
             self._copula_mix = jnp.asarray(mix, jnp.float32)
+            # device bytes of the dense mix every block multiplies by
+            telemetry.counter_inc("copula_mix_bytes", self._copula_mix.nbytes)
             self._copula_rows = jnp.asarray(active_groups, jnp.int32)
             self._copula_dim = F
 
@@ -4792,58 +4794,61 @@ class Simulator:
         z_wait = None
         u_wait = None
         if any_copula:
-            r = (
-                self.params.sibling_copula_r
-                if self._copula_active
-                else 0.0
-            )
-            z_h = jax.random.normal(k_wait_u, (n, H))
-            z_wait = 0.0
-            w_own_sq = 1.0 - r
-            if self._copula_active:
-                # the saturated path skips the hierarchical mix, so it
-                # draws the flat (n, G) tensor — not the (n, F) factor
-                # space whose extra columns it would discard
-                dim = (
-                    self._copula_dim
-                    if self._copula_mix is not None and not sat_conns
-                    else self._num_sib_groups
+            # factor draw, hierarchical mix and the retry groups' draw:
+            # a scope of their own under the wait law's
+            with jax.named_scope("waits/copula"):
+                r = (
+                    self.params.sibling_copula_r
+                    if self._copula_active
+                    else 0.0
                 )
-                z_small = jax.random.normal(k_wait2, (n, dim))
-                if self._copula_mix is not None and not sat_conns:
-                    # hierarchical mix for the ACTIVE (concurrent)
-                    # groups only: Z_act = z @ mix.T combines each
-                    # group's ancestor factors (unit variance,
-                    # same-depth cousin corr r * gamma^L, zero across
-                    # depths); singleton groups keep their base
-                    # column.  OPEN LOOP ONLY: the saturated sampler's
-                    # composition (population centering + repairman
-                    # join) was calibrated with the flat copula, and
-                    # the mix collapses its join median (measured
-                    # tree13 -qps max p50 -3.7% -> -11.6% at gamma=0.8)
-                    z_act = jnp.matmul(
-                        z_small, self._copula_mix.T,
-                        precision=jax.lax.Precision.HIGHEST,
+                z_h = jax.random.normal(k_wait_u, (n, H))
+                z_wait = 0.0
+                w_own_sq = 1.0 - r
+                if self._copula_active:
+                    # the saturated path skips the hierarchical mix, so it
+                    # draws the flat (n, G) tensor — not the (n, F) factor
+                    # space whose extra columns it would discard
+                    dim = (
+                        self._copula_dim
+                        if self._copula_mix is not None and not sat_conns
+                        else self._num_sib_groups
                     )
-                    z_groups = (
-                        z_small[:, : self._num_sib_groups]
-                        .at[:, self._copula_rows]
-                        .set(z_act)
+                    z_small = jax.random.normal(k_wait2, (n, dim))
+                    if self._copula_mix is not None and not sat_conns:
+                        # hierarchical mix for the ACTIVE (concurrent)
+                        # groups only: Z_act = z @ mix.T combines each
+                        # group's ancestor factors (unit variance,
+                        # same-depth cousin corr r * gamma^L, zero across
+                        # depths); singleton groups keep their base
+                        # column.  OPEN LOOP ONLY: the saturated sampler's
+                        # composition (population centering + repairman
+                        # join) was calibrated with the flat copula, and
+                        # the mix collapses its join median (measured
+                        # tree13 -qps max p50 -3.7% -> -11.6% at gamma=0.8)
+                        z_act = jnp.matmul(
+                            z_small, self._copula_mix.T,
+                            precision=jax.lax.Precision.HIGHEST,
+                        )
+                        z_groups = (
+                            z_small[:, : self._num_sib_groups]
+                            .at[:, self._copula_rows]
+                            .set(z_act)
+                        )
+                    else:
+                        z_groups = z_small[:, : self._num_sib_groups]
+                    z_wait = z_wait + np.sqrt(r) * z_groups[:, self._sib_group]
+                if self._retry_active:
+                    z_call = jax.random.normal(
+                        k_wait3, (n, self._num_retry_groups + 1)
                     )
-                else:
-                    z_groups = z_small[:, : self._num_sib_groups]
-                z_wait = z_wait + np.sqrt(r) * z_groups[:, self._sib_group]
-            if self._retry_active:
-                z_call = jax.random.normal(
-                    k_wait3, (n, self._num_retry_groups + 1)
-                )
-                z_wait = z_wait + (
-                    self._retry_w * z_call[:, self._retry_group]
-                )
-                w_own_sq = w_own_sq - self._retry_w**2
-            z_wait = z_wait + np.sqrt(w_own_sq) * z_h
-            if not sat_conns:
-                u_wait = jax.scipy.special.ndtr(z_wait)
+                    z_wait = z_wait + (
+                        self._retry_w * z_call[:, self._retry_group]
+                    )
+                    w_own_sq = w_own_sq - self._retry_w**2
+                z_wait = z_wait + np.sqrt(w_own_sq) * z_h
+                if not sat_conns:
+                    u_wait = jax.scipy.special.ndtr(z_wait)
         elif sat_conns:
             z_wait = jax.random.normal(k_wait_u, (n, H))
         else:
@@ -5513,92 +5518,95 @@ class Simulator:
                             final_transport = transport_a
                         att_off = None
                     else:
-                        # general path: serial retry attempts.  dummy column C
-                        # absorbs invalid attempt slots
-                        pad = lambda x: jnp.pad(x, ((0, 0), (0, 1)))  # noqa: E731
-                        lat_child = pad(lat_lvls[d + 1])
-                        err_child = (
-                            pad(child_err.astype(jnp.float32)) > 0
-                            if child_err is not None
-                            else None
-                        )
-                        down_child = (
-                            pad(down[:, csl].astype(jnp.float32)) > 0
-                            if down is not None
-                            else None
-                        )
-                        rtt_child = jnp.pad(lvl.child_rtt, (0, 1))
+                        # the attempt loop under a scope of its own
+                        # (engine/up/lvl[d]/attempts)
+                        with jax.named_scope("attempts"):
+                            # general path: serial retry attempts.  dummy column C
+                            # absorbs invalid attempt slots
+                            pad = lambda x: jnp.pad(x, ((0, 0), (0, 1)))  # noqa: E731
+                            lat_child = pad(lat_lvls[d + 1])
+                            err_child = (
+                                pad(child_err.astype(jnp.float32)) > 0
+                                if child_err is not None
+                                else None
+                            )
+                            down_child = (
+                                pad(down[:, csl].astype(jnp.float32)) > 0
+                                if down is not None
+                                else None
+                            )
+                            rtt_child = jnp.pad(lvl.child_rtt, (0, 1))
 
-                        a0 = lvl.att_child[0]  # (K,) attempt-0 local child idx
-                        if self._need_send:
-                            prob = lvl.child_send_prob[a0]
-                            if self._churn:
-                                # current schedule weight scales the send prob
-                                prob = prob * churn_w[
-                                    :, lvl.child_churn_entry[a0]
-                                ]
-                            coin = u_send[:, csl][:, a0] < prob  # (N, K)
-                        else:
-                            coin = jnp.ones((n, lvl.num_calls), bool)
-                        transportable = (
-                            down_child is not None or lvl.finite_timeout
-                        )
-                        # retry-budget gate (sim/policies.py): attempt >= 1
-                        # runs only when its budget coin admits it — a
-                        # suppressed retry surfaces the PREVIOUS attempt's
-                        # failure to the caller (Envoy budget semantics)
-                        retry_gate = None
-                        if retry_coin is not None and lvl.max_attempts > 1:
-                            retry_gate = (
-                                pad(retry_coin[:, csl].astype(jnp.float32))
-                                > 0
-                            )  # (N, C + 1); pad col False is dead (invalid)
-                        dur_call = jnp.zeros((n, lvl.num_calls))
-                        final_transport = (
-                            jnp.zeros((n, lvl.num_calls), bool)
-                            if transportable
-                            else None
-                        )
-                        used = jnp.zeros((n, C + 1), bool)
-                        att_off = jnp.zeros((n, C + 1))
-                        used_a = coin
-                        for a in range(lvl.max_attempts):
-                            idx = lvl.att_child[a]       # (K,) in [0, C]
-                            valid = lvl.att_valid[a]     # (K,) static
-                            use = used_a & valid
-                            if retry_gate is not None and a > 0:
-                                use = use & retry_gate[:, idx]
-                            t = rtt_child[idx] + lat_child[:, idx]
-                            if tax is not None:
-                                t = t + 2.0 * tax[:, None]
-                            transport_a, dur_a = _call_outcome(
-                                t,
-                                lvl.call_timeout if lvl.finite_timeout else None,
-                                down_child[:, idx]
-                                if down_child is not None
-                                else None,
+                            a0 = lvl.att_child[0]  # (K,) attempt-0 local child idx
+                            if self._need_send:
+                                prob = lvl.child_send_prob[a0]
+                                if self._churn:
+                                    # current schedule weight scales the send prob
+                                    prob = prob * churn_w[
+                                        :, lvl.child_churn_entry[a0]
+                                    ]
+                                coin = u_send[:, csl][:, a0] < prob  # (N, K)
+                            else:
+                                coin = jnp.ones((n, lvl.num_calls), bool)
+                            transportable = (
+                                down_child is not None or lvl.finite_timeout
                             )
-                            failed_a = transport_a
-                            if err_child is not None:
-                                ec = err_child[:, idx]
-                                failed_a = (
-                                    ec if failed_a is None else failed_a | ec
+                            # retry-budget gate (sim/policies.py): attempt >= 1
+                            # runs only when its budget coin admits it — a
+                            # suppressed retry surfaces the PREVIOUS attempt's
+                            # failure to the caller (Envoy budget semantics)
+                            retry_gate = None
+                            if retry_coin is not None and lvl.max_attempts > 1:
+                                retry_gate = (
+                                    pad(retry_coin[:, csl].astype(jnp.float32))
+                                    > 0
+                                )  # (N, C + 1); pad col False is dead (invalid)
+                            dur_call = jnp.zeros((n, lvl.num_calls))
+                            final_transport = (
+                                jnp.zeros((n, lvl.num_calls), bool)
+                                if transportable
+                                else None
+                            )
+                            used = jnp.zeros((n, C + 1), bool)
+                            att_off = jnp.zeros((n, C + 1))
+                            used_a = coin
+                            for a in range(lvl.max_attempts):
+                                idx = lvl.att_child[a]       # (K,) in [0, C]
+                                valid = lvl.att_valid[a]     # (K,) static
+                                use = used_a & valid
+                                if retry_gate is not None and a > 0:
+                                    use = use & retry_gate[:, idx]
+                                t = rtt_child[idx] + lat_child[:, idx]
+                                if tax is not None:
+                                    t = t + 2.0 * tax[:, None]
+                                transport_a, dur_a = _call_outcome(
+                                    t,
+                                    lvl.call_timeout if lvl.finite_timeout else None,
+                                    down_child[:, idx]
+                                    if down_child is not None
+                                    else None,
                                 )
-                            att_off = att_off.at[:, idx].set(
-                                jnp.where(use, dur_call, 0.0)
-                            )
-                            used = used.at[:, idx].set(use)
-                            dur_call = dur_call + jnp.where(use, dur_a, 0.0)
-                            if final_transport is not None:
-                                final_transport = jnp.where(
-                                    use, transport_a, final_transport
+                                failed_a = transport_a
+                                if err_child is not None:
+                                    ec = err_child[:, idx]
+                                    failed_a = (
+                                        ec if failed_a is None else failed_a | ec
+                                    )
+                                att_off = att_off.at[:, idx].set(
+                                    jnp.where(use, dur_call, 0.0)
                                 )
-                            used_a = (
-                                use & failed_a
-                                if failed_a is not None
-                                else jnp.zeros_like(use)
-                            )
-                        used_lvls[d] = used[:, :C]
+                                used = used.at[:, idx].set(use)
+                                dur_call = dur_call + jnp.where(use, dur_a, 0.0)
+                                if final_transport is not None:
+                                    final_transport = jnp.where(
+                                        use, transport_a, final_transport
+                                    )
+                                used_a = (
+                                    use & failed_a
+                                    if failed_a is not None
+                                    else jnp.zeros_like(use)
+                                )
+                            used_lvls[d] = used[:, :C]
 
                     # -- aggregate calls into (parent, step) slots -------------
                     if lvl.sparse is not None:

@@ -359,66 +359,69 @@ def up_sweep(
                 final_transport = transport_a
             att_off = None
         else:
-            coin_a = (
-                coin
-                if coin is not None
-                else jnp.ones((n, a0.shape[0]), bool)
-            )
-            # retry-budget gate (sim/policies.py): the child slice's
-            # budget coins, padded like down_child — the bucket dummy
-            # column ``B`` is False (dead lane), matching the unrolled
-            # path's dead pad column
-            retry_gate = (
-                pad1(_dslice(seg_retry, x["choff"], B))
-                if seg_retry is not None
-                else None
-            )
-            dur_call = jnp.zeros((n, a0.shape[0]))
-            final_transport = (
-                jnp.zeros((n, a0.shape[0]), bool) if transportable
-                else None
-            )
-            used_b = jnp.zeros((n, B + 1), bool)
-            att_off = jnp.zeros((n, B + 1))
-            used_a = coin_a
-            for a in range(A):
-                idx = x["att_child"][a]
-                valid = x["att_valid"][a]
-                use = used_a & valid
-                if retry_gate is not None and a > 0:
-                    # a suppressed retry surfaces the PREVIOUS
-                    # attempt's failure to the caller (Envoy budget
-                    # semantics) — same op as the unrolled gate
-                    use = use & retry_gate[:, idx]
-                t = rtt_child[idx] + lat_child[:, idx]
-                if tax is not None:
-                    t = t + 2.0 * tax[:, None]
-                transport_a, dur_a = outcome(
-                    t, x,
-                    down_child[:, idx] if down_child is not None else None,
+            # the attempt loop under a scope of its own
+            # (engine/up/scan[d0-d1]/attempts)
+            with jax.named_scope("attempts"):
+                coin_a = (
+                    coin
+                    if coin is not None
+                    else jnp.ones((n, a0.shape[0]), bool)
                 )
-                failed_a = transport_a
-                if err_child is not None:
-                    failed_a = (
-                        err_child[:, idx]
-                        if failed_a is None
-                        else failed_a | err_child[:, idx]
+                # retry-budget gate (sim/policies.py): the child slice's
+                # budget coins, padded like down_child — the bucket dummy
+                # column ``B`` is False (dead lane), matching the unrolled
+                # path's dead pad column
+                retry_gate = (
+                    pad1(_dslice(seg_retry, x["choff"], B))
+                    if seg_retry is not None
+                    else None
+                )
+                dur_call = jnp.zeros((n, a0.shape[0]))
+                final_transport = (
+                    jnp.zeros((n, a0.shape[0]), bool) if transportable
+                    else None
+                )
+                used_b = jnp.zeros((n, B + 1), bool)
+                att_off = jnp.zeros((n, B + 1))
+                used_a = coin_a
+                for a in range(A):
+                    idx = x["att_child"][a]
+                    valid = x["att_valid"][a]
+                    use = used_a & valid
+                    if retry_gate is not None and a > 0:
+                        # a suppressed retry surfaces the PREVIOUS
+                        # attempt's failure to the caller (Envoy budget
+                        # semantics) — same op as the unrolled gate
+                        use = use & retry_gate[:, idx]
+                    t = rtt_child[idx] + lat_child[:, idx]
+                    if tax is not None:
+                        t = t + 2.0 * tax[:, None]
+                    transport_a, dur_a = outcome(
+                        t, x,
+                        down_child[:, idx] if down_child is not None else None,
                     )
-                att_off = att_off.at[:, idx].set(
-                    jnp.where(use, dur_call, 0.0)
-                )
-                used_b = used_b.at[:, idx].set(use)
-                dur_call = dur_call + jnp.where(use, dur_a, 0.0)
-                if final_transport is not None:
-                    final_transport = jnp.where(
-                        use, transport_a, final_transport
+                    failed_a = transport_a
+                    if err_child is not None:
+                        failed_a = (
+                            err_child[:, idx]
+                            if failed_a is None
+                            else failed_a | err_child[:, idx]
+                        )
+                    att_off = att_off.at[:, idx].set(
+                        jnp.where(use, dur_call, 0.0)
                     )
-                used_a = (
-                    use & failed_a
-                    if failed_a is not None
-                    else jnp.zeros_like(use)
-                )
-            used = used_b[:, :B]
+                    used_b = used_b.at[:, idx].set(use)
+                    dur_call = dur_call + jnp.where(use, dur_a, 0.0)
+                    if final_transport is not None:
+                        final_transport = jnp.where(
+                            use, transport_a, final_transport
+                        )
+                    used_a = (
+                        use & failed_a
+                        if failed_a is not None
+                        else jnp.zeros_like(use)
+                    )
+                used = used_b[:, :B]
         # -- aggregate calls into (hop, step) slots; padded calls carry
         # dur 0 / transport False, so max-with-0 and min-with-P are
         # identities on the real lanes

@@ -24,11 +24,14 @@ if ROOT not in sys.path:
 from benchmark import run  # noqa: E402
 from benchmark.tests.test_checks import *  # noqa: E402,F401,F403
 from benchmark.tests.test_checks_outcomes import *  # noqa: E402,F401,F403
+from benchmark.tests.test_checks_retries import *  # noqa: E402,F401,F403
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deadline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_host_spans import *  # noqa: E402,F401,F403
+from benchmark.tests.test_layer_metrics_retries import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference_outcomes import *  # noqa: E402,F401,F403
+from benchmark.tests.test_reference_retries import *  # noqa: E402,F401,F403
 from benchmark.tests.test_run import *  # noqa: E402,F401,F403
 from benchmark.tests.test_scope_reader import *  # noqa: E402,F401,F403
 from benchmark.tests.test_stats import *  # noqa: E402,F401,F403

@@ -605,6 +605,11 @@ _VENDORED = {
     "realistic-multitier-100-errors.yaml": ((), (
         ("unrolled", 0), ("scan", 1, 5, 21, 6, 21, 1),
         *_unrolled(6, 7, 8, 9))),
+    # PR 41: three attempts a call; the bucket's last field is the
+    # attempts it scans
+    "realistic-multitier-50-errors-retries2.yaml": ((), (
+        *_unrolled(0, 1, 2, 3, 4), ("scan", 5, 6, 2673, 1, 729, 3),
+        ("unrolled", 7))),
     # the one graph with levels off the grid; its signature did not
     # change either (a tiled level is an unrolled segment)
     "star-10000.yaml": ((1, 2), _unrolled(0, 1, 2, 3, 4)),

@@ -89,6 +89,22 @@ def main() -> None:
         ),
     )
 
+    # the multitier archetype at the fork's committed example's size,
+    # the same callee error rate, and Istio's mesh-wide default retry
+    # policy (2 retries) on every call: the benchmark's
+    # `multitier50_retry2` (benchmark/configs/multitier50_retry2.json),
+    # whose benchmark/topologies/ copy is this file byte for byte
+    dump(
+        "realistic-multitier-50-errors-retries2.yaml",
+        generators.with_call_policy(
+            generators.realistic_topology(
+                num_services=50, archetype="multitier", seed=0,
+                callee_error_rate="0.01%",
+            ),
+            retries=2,
+        ),
+    )
+
     # the star archetype at the north star's 10,000 services: the
     # benchmark's `star10k` (benchmark/configs/star10k.json), whose
     # benchmark/topologies/ copy is this file byte for byte
