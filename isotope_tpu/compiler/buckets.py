@@ -51,18 +51,36 @@ SEGMENT_OVERHEAD_ELEMS = 1 << 24
 DEFAULT_TILE_PMAX = 64
 
 #: the graph size at which ``SimParams.sparse_level_elems`` (elements a
-#: REQUEST) is the floor as stated; under it the floor shrinks with the
-#: hops (level_encoding).  The device holds a level's grid a BLOCK at a
-#: time and the block is sized from the hops
+#: REQUEST) is the dense -> sparse floor as stated; under it the floor
+#: shrinks with the hops (level_encoding).  The device holds a level's
+#: grid a BLOCK at a time and the block is sized from the hops
 #: (Simulator.default_block_size: block x hops = one 33,554,432-element,
 #: 128 MiB float32 event tensor), so a dense step tensor is (grid /
 #: hops) event tensors whatever the graph's size.  At the default
 #: 262,144 the floor is 262,144 / 32,768 = 8 x hops: 8 event tensors =
 #: 2^28 elements = 1 GiB a float32 step tensor, 1/16 of a v5e's HBM.
-#: Between the vendored graphs' two populations: svc10k's widest level
-#: is 2.66 x hops (stays dense, in its scan bucket), star10k's level 2
-#: 17.6 x (2.35 GB a tensor at 99.8 % padding: leaves).
+#: It is what a level whose tile plan does NOT halve its grid must pass
+#: to leave the dense grid for the pure sparse encoding (none of the
+#: vendored graphs' levels does).
 SPARSE_LEVEL_REF_HOPS = 32_768
+
+#: the dense -> TILED floor, as a share of the floor above: a skewed
+#: level whose tile plan at least halves its grid leaves the dense grid
+#: at 1/16 of it, 0.5 x the graph's hops at the default.  Placed where
+#: a tiled level breaks even on the chip (PERF.md, PR 42; v5e, 240,000
+#: requests): a dense level's sweep is bandwidth-bound on its padded
+#: (block x grid) step tensor, 1.8-2.6 ms a block per (1 x hops) of
+#: grid, a tiled level of 4-5 tiles ~0.34 ms a block with its
+#: re-assembly gathers, and costs the host ~12 ms a build (2.6 ms a
+#: tile: its tables' puts).  svc10k's levels 3-11 (0.54-2.66 x hops)
+#: tile: 4.6 -> 0.8 s of device a call.  The floor it is a share of
+#: stops shrinking with the graph at the same share of the knob, so
+#: the tiled floor is never under 262,144 / 16 / 16 = 1,024 cells a
+#: request: the device gives back ~84 ps a cell a request, so a
+#: smaller grid cannot repay a tiled level's host cost at the CLI's
+#: request counts (the 100-service mesh, tiled: -9.4 ms of device,
+#: +15.7 ms of engine.build a call).
+TILED_FLOOR_SHARE = 16
 
 #: critical-path DP lookback cap: buckets longer than this are not
 #: considered (keeps planning O(levels * cap); a >64-level scan body
@@ -92,6 +110,11 @@ class LevelShape:
     @property
     def leaf(self) -> bool:
         return self.calls == 0 or self.children == 0
+
+    @property
+    def tile_elems(self) -> int:
+        """Padded (hop, step) cells of the level's tiles (0: not tiled)."""
+        return sum(t_size * t_w for t_size, t_w in self.tiles or ())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,8 +195,8 @@ def segment_cp_cost(shapes: Sequence[LevelShape], seg: Segment) -> int:
         return SEGMENT_OVERHEAD_ELEMS + _bucket_cost(members, bounds)
     s = shapes[seg.d]
     if s.tiles is not None:
-        elems = sum(t_size * t_w for t_size, t_w in s.tiles)
-        elems += s.residual_slots + 3 * s.children + 2 * s.calls * s.attempts
+        elems = s.tile_elems + s.residual_slots
+        elems += 3 * s.children + 2 * s.calls * s.attempts
         return SEGMENT_OVERHEAD_ELEMS + elems
     return SEGMENT_OVERHEAD_ELEMS + _real_cost([s])
 
@@ -460,32 +483,40 @@ def level_encoding(
     decision point shared by the engine's lowering and the vet linter,
     so the static analysis always reports the executor's real choice.
     A level leaves the dense grid when the grid is > 4x its real call
-    slots AND past the size floor.  The floor is what the program will
+    slots AND past a size floor.  The floors are what the program will
     hold on the device, not elements a request: ``sparse_level_elems``
     for a graph of ``SPARSE_LEVEL_REF_HOPS`` hops or more, and the
     share ``num_hops / SPARSE_LEVEL_REF_HOPS`` of it for a smaller one
-    - 8 x ``num_hops`` at the default, i.e. a step tensor of 8 event
-    tensors under ``default_block_size``'s budget.  So
-    ``sparse_level_elems=1`` sends every skewed level off the grid and
-    ``10**9`` keeps any level a graph can have on it.  A level that
-    leaves tiles when the dense-blocked plan halves the grid, else
-    keeps the true sparse encoding (tiny fully-skewed levels, e.g. one
-    hub hop).
+    - 8 x ``num_hops`` at the default, a step tensor of 8 event
+    tensors under ``default_block_size``'s budget - is the floor of
+    the true sparse encoding; a level whose dense-blocked plan at
+    least halves its grid TILES from ``1 / TILED_FLOOR_SHARE`` of it
+    (0.5 x ``num_hops`` at the default: where a tiled level breaks
+    even on the chip), the floor held to at least that share of the
+    knob first (so never under 1,024 cells at the default: a small
+    graph's level cannot repay its tiles' tables).  A level whose
+    plan does not halve it (a width-1 grid, one hub hop) stays dense
+    up to the sparse floor.  So ``sparse_level_elems=1`` sends every
+    skewed level off the grid and ``10**9`` keeps any level a graph
+    can have on it.
     """
     dense_elems = size * pmax
     floor = (sparse_level_elems * min(num_hops, SPARSE_LEVEL_REF_HOPS)
              // SPARSE_LEVEL_REF_HOPS)
-    if dense_elems <= max(4 * n_slots, floor):
+    tiled_floor = (max(floor, sparse_level_elems // TILED_FLOOR_SHARE)
+                   // TILED_FLOOR_SHARE)
+    if dense_elems <= max(4 * n_slots, tiled_floor):
         return "dense", None
-    if not tiling:
-        return "sparse", None
-    plan = plan_tiles(widths, cap=tile_pmax, waste=waste)
-    # residual hops keep one slot per call-bearing step; approximate
-    # with their width sum for the decision (exact slots need call
-    # tables the caller may not have at hand)
-    res_elems = int(np.asarray(widths)[plan.residual].sum())
-    if plan.tiled_elems + res_elems <= dense_elems // 2 and plan.tiles:
-        return "tiled", plan
+    if tiling:
+        plan = plan_tiles(widths, cap=tile_pmax, waste=waste)
+        # residual hops keep one slot per call-bearing step; approximate
+        # with their width sum for the decision (exact slots need call
+        # tables the caller may not have at hand)
+        res_elems = int(np.asarray(widths)[plan.residual].sum())
+        if plan.tiles and plan.tiled_elems + res_elems <= dense_elems // 2:
+            return "tiled", plan
+    if dense_elems <= floor:
+        return "dense", None
     return "sparse", None
 
 
@@ -542,9 +573,7 @@ def encoding_stats(shapes: Sequence[LevelShape]) -> dict:
     return {
         "levels_tiled": len(tiled),
         "hops_in_tiled_levels": sum(s.size for s in tiled),
-        "tile_padded_elems": sum(
-            t_size * t_w for s in tiled for t_size, t_w in s.tiles
-        ),
+        "tile_padded_elems": sum(s.tile_elems for s in tiled),
         "tile_real_elems": sum(s.tile_real_elems for s in tiled),
         "sparse_residual_slots": sum(
             s.residual_slots for s in shapes if s.sparse
@@ -555,10 +584,35 @@ def encoding_stats(shapes: Sequence[LevelShape]) -> dict:
     }
 
 
+def step_cells_planned(shapes: Sequence[LevelShape],
+                       segs: Sequence[Segment]) -> int:
+    """The (hop, step) cells one request's up sweep computes under a
+    plan: a bucket's levels at its padded bounds, an unrolled dense
+    level at size x pmax, a tiled level at its tiles' padded cells
+    plus its residual's slots, a pure-sparse level at its slots; a
+    leaf level has no step grid (its busy time is a row sum made at
+    build)."""
+    cells = 0
+    for seg in segs:
+        if isinstance(seg, ScanBucketPlan):
+            cells += seg.num_levels * seg.bound_hops * seg.bound_steps
+            continue
+        s = shapes[seg.d]
+        if s.leaf:
+            continue
+        if s.sparse:
+            cells += s.tile_elems + s.residual_slots
+        else:
+            cells += s.size * s.pmax
+    return cells
+
+
 def _record_plan(shapes: Sequence[LevelShape],
                  segs: Sequence[Segment]) -> None:
     """Fold one plan's stats into the engine telemetry registry."""
     st = plan_stats(shapes, segs)
+    telemetry.counter_inc(
+        "step_cells_planned", step_cells_planned(shapes, segs))
     # absent where every level is dense, so a plan without a tiled or
     # sparse level leaves the registry as it was
     for name, value in encoding_stats(shapes).items():

@@ -115,14 +115,22 @@ class SimParams:
     hierarchical_copula_gamma: float = 0.9
     # Dense-grid size, in elements a REQUEST, above which a skewed level
     # (grid > 4x its real call-step count) leaves the dense step grid —
-    # the star-10k mitigation.  It is the floor as stated for a graph
-    # of 32,768 hops or more; a smaller graph runs a larger block
-    # (default_block_size: block x hops is fixed), so its floor is
-    # that share of it — 8 x the graph's hops at this default, a step
-    # tensor of 8 event tensors = 1 GiB float32 a block
-    # (compiler/buckets.level_encoding, SPARSE_LEVEL_REF_HOPS).  1
-    # forces the non-dense path on any skewed level, 10**9 the dense
-    # grid (tests).
+    # the star-10k mitigation.  It is the floor of the true SPARSE
+    # encoding as stated for a graph of 32,768 hops or more; a smaller
+    # graph runs a larger block (default_block_size: block x hops is
+    # fixed), so its floor is that share of it — 8 x the graph's hops
+    # at this default, a step tensor of 8 event tensors = 1 GiB float32
+    # a block (compiler/buckets.level_encoding, SPARSE_LEVEL_REF_HOPS).
+    # A level whose dense-blocked tile plan at least halves its grid
+    # TILES from 1/16 of that floor (buckets.TILED_FLOOR_SHARE): 0.5 x
+    # the graph's hops at this default, where a tiled level breaks even
+    # on a v5e (a dense level's sweep is bandwidth-bound on its padded
+    # step tensor: svc10k's nine middle levels, 4.6 s -> 0.8 s of
+    # device a call; PERF.md, PR 42), and never under 1/256 of this
+    # knob, 1,024 cells, below which a level's saving on the device
+    # does not repay its tiles' tables on the host (the 100-service
+    # mesh).  1 forces the non-dense path on any skewed level, 10**9
+    # the dense grid (tests).
     sparse_level_elems: int = 262_144
     # Dense-blocked sparse levels (engine._TiledSteps): a level past
     # the sparse threshold is partitioned into fixed-width dense tiles

@@ -62,6 +62,26 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
+def _lower_served(sim, compiled, requests, one_chip):
+    """``run_summary``'s program as the CLI's closed loop builds it -
+    the block scan with the collector and the trim window - lowered
+    for the described chip; its argument list is the single-block
+    entry's, plus the two trim-window bounds before the visit / phase
+    tables."""
+    blk = sim.default_block_size() // CONNECTIONS * CONNECTIONS
+    fn = sim._get_summary(
+        blk, -(-requests // blk), "closed", CONNECTIONS,
+        MetricsCollector(compiled), True, sat=False,
+    )
+    _, a = sim.trace_entry_args(blk, "closed", CONNECTIONS)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    args = [
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        for x in (*a[:5], scalar, scalar, *a[5:])
+    ]
+    return fn.lower(*args)
+
+
 @pytest.mark.parametrize("name, hops, block", [
     ("1000-svc_2000-end.yaml", 1000, 33_554),
     ("tree-111-services.yaml", 111, 302_292),
@@ -77,21 +97,36 @@ def test_cli_summary_program_compiles_for_v5e(one_chip, name, hops, block):
     )
     sim = Simulator(compiled, SimParams())
     assert (compiled.num_hops, sim.default_block_size()) == (hops, block)
-    per = block // CONNECTIONS
-    blk = per * CONNECTIONS
-    fn = sim._get_summary(
-        blk, -(-REQUESTS // blk), "closed", CONNECTIONS,
-        MetricsCollector(compiled), True, sat=False,
-    )
-    # run_summary's argument list: the single-block entry's, plus the
-    # two trim-window bounds before the visit / phase tables
-    _, a = sim.trace_entry_args(blk, "closed", CONNECTIONS)
-    scalar = jax.ShapeDtypeStruct((), jnp.float32)
-    args = [
-        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-        for x in (*a[:5], scalar, scalar, *a[5:])
-    ]
-    mem = fn.lower(*args).compile().memory_analysis()
+    mem = _lower_served(
+        sim, compiled, REQUESTS, one_chip).compile().memory_analysis()
     assert 0 < mem.temp_size_in_bytes < HBM_BYTES
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.slow
+def test_svc10k_served_program_compiles_for_v5e(one_chip, record_property):
+    """``svc10k_served``'s program (``latency240``: 240,000 requests in
+    73 blocks of 3,328 x 10,000 hops) with nine tiled levels (PR 42):
+    the compile is ~4 x the bucketed plan's (55 s against 13 s on this
+    sandbox's CPU, 69 s against ~35 s on the chip's host; PERF.md) and
+    its temporaries about half (2.56 against 4.76 GB: no (3,328 x
+    27,702) step grids).  Records both, and the lowered text's size,
+    for the next change of the encoding or block-size rule."""
+    compiled = compile_graph(ServiceGraph.from_yaml_file(
+        os.path.join(TOPOLOGIES, "multitier-10000.yaml")))
+    sim = Simulator(compiled, SimParams())
+    assert [d for d, lvl in enumerate(sim._levels)
+            if lvl.tiled is not None] == list(range(3, 12))
+    assert sim.default_block_size() // CONNECTIONS * CONNECTIONS == 3328
+    lowered = _lower_served(sim, compiled, 240_000, one_chip)
+    hlo_chars = len(lowered.as_text())
+    mem = lowered.compile().memory_analysis()
+    record_property("hlo_chars", hlo_chars)
+    record_property("temp_size_in_bytes", mem.temp_size_in_bytes)
+    # 1.39 M characters and 2.56 GB when written; the bucketed plan's
+    # were 2.88 M and 4.76 GB
+    assert hlo_chars < 2_000_000
+    assert 0 < mem.temp_size_in_bytes < 3.5e9
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES

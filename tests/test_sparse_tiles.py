@@ -528,21 +528,35 @@ def test_level_encoding_decision_points():
     assert enc == "sparse" and plan is None
 
 
-# the two populations of the vendored graphs (PERF.md, PR 38), as
-# (hops of the level, widest script, call slots, script widths): the
-# widest level of the 10,000-service multitier mesh is 2.66 x the
-# graph's hops, level 2 of the 10,000-service star 17.6 x
+# levels of the vendored graphs (PERF.md, PR 38 and PR 42), as (hops of
+# the level, widest script, call slots, script widths): the widest
+# level of the 10,000-service multitier mesh is 2.66 x the graph's
+# hops, its level 3 0.54 x, level 2 of the 10,000-service star 17.6 x;
+# level 5 of the 50-service mesh under two retries is a width-1 grid
+# no tile plan shrinks, level 3 of the 100-service mesh a 108-cell one
 _MULTITIER_L8 = (1402, 19, 1164,
                  np.asarray([0] * 600 + [1] * 700 + [4] * 80 + [12] * 21
                             + [19]))
+_MULTITIER_L3 = (339, 16, 668,
+                 np.asarray([0] * 100 + [1] * 102 + [3] * 75 + [8] * 46
+                            + [16] * 16))
 _STAR_L2 = (4641, 38, 330,
             np.asarray([0] * 4400 + [1] * 197 + [4] * 20 + [11] * 19
                        + [26] * 4 + [38]))
+_RETRY2_L5 = (2673, 1, 486, np.asarray([0] * 2187 + [1] * 486))
+_POWERLAW_L3 = (18, 6, 17, np.asarray([0] * 4 + [1] * 10 + [2] * 2 + [6] * 2))
+# 1,025 cells that tile to 225, and one hop fewer
+_SMALL_GRAPH_LEVEL = (205, 5, 50, np.asarray([0] * 150 + [1] * 50 + [5] * 5))
+_SMALL_GRAPH_LEVEL_LESS = (204, 5, 50,
+                           np.asarray([0] * 149 + [1] * 50 + [5] * 5))
 
 
 @pytest.mark.parametrize("level, kw, want", [
-    # default floor at 10,000 hops: 8 x hops = 80,000 elements
-    (_MULTITIER_L8, {}, "dense"),                     # 26,638
+    # default floors at 10,000 hops: a level whose tile plan halves it
+    # leaves the grid past 0.5 x hops = 5,000 elements (PR 42: where a
+    # tiled level breaks even on the chip; 8 x until then), any other
+    # past 8 x hops = 80,000
+    (_MULTITIER_L8, {}, "tiled"),                     # 26,638
     (_STAR_L2, {}, "tiled"),                          # 176,358
     (_STAR_L2, {"tiling": False}, "sparse"),
     # the knob still forces each way
@@ -550,18 +564,42 @@ _STAR_L2 = (4641, 38, 330,
     (_STAR_L2, {"sparse_level_elems": 1}, "tiled"),
     (_STAR_L2, {"sparse_level_elems": 10**9}, "dense"),
     (_MULTITIER_L8, {"sparse_level_elems": 10**9}, "dense"),
-    # ... and is the floor as stated from SPARSE_LEVEL_REF_HOPS hops up:
-    # the same level in a graph of 40,000 hops is 4.4 x them
-    (_STAR_L2, {"num_hops": 40_000}, "dense"),
+    # ... and is the sparse floor as stated from SPARSE_LEVEL_REF_HOPS
+    # hops up: the same level in a graph of 40,000 hops is 4.4 x them -
+    # tiled (past 262,144 / 16), not sparse
+    (_STAR_L2, {"num_hops": 40_000}, "tiled"),
     (_STAR_L2, {"num_hops": 40_000, "sparse_level_elems": 176_357},
      "tiled"),
-    # the floor is 8 x hops to the element: 22,044 hops put it at
-    # 176,352, 22,045 at 176,360
-    (_STAR_L2, {"num_hops": 22_044}, "tiled"),
-    (_STAR_L2, {"num_hops": 22_045}, "dense"),
+    # the tiled floor is 0.5 x hops to the element: 10,847 hops put it
+    # at 5,423, 10,848 at the level's own 5,424
+    (_MULTITIER_L3, {"num_hops": 10_847}, "tiled"),
+    (_MULTITIER_L3, {"num_hops": 10_848}, "dense"),
     # a grid within 4 x its call slots stays whatever the floor
     ((100, 4, 100, np.asarray([4] * 100)),
      {"num_hops": 10, "sparse_level_elems": 1}, "dense"),
+    # the lower floor governs dense -> tiled only: without tiling the
+    # floor is 8 x hops to the element, as it was (22,044 hops put it
+    # at 176,352, 22,045 at 176,360) ...
+    (_STAR_L2, {"num_hops": 22_044, "tiling": False}, "sparse"),
+    (_STAR_L2, {"num_hops": 22_045, "tiling": False}, "dense"),
+    (_STAR_L2, {"num_hops": 40_000, "tiling": False}, "dense"),
+    # ... and a level whose tile plan does not halve its grid stays
+    # dense under 8 x hops: the width-1 level of the retried mesh
+    # (2,673 cells, 5.5 x its slots, 7,456 hops) at the default, at a
+    # knob that puts the tiled floor at 0.25 x hops (1,864) and the
+    # sparse one at 4 x, and sparse only past the sparse floor itself
+    (_RETRY2_L5, {"num_hops": 7_456}, "dense"),
+    (_RETRY2_L5, {"num_hops": 7_456, "sparse_level_elems": 131_072},
+     "dense"),
+    (_RETRY2_L5, {"num_hops": 7_456, "sparse_level_elems": 8_192},
+     "sparse"),
+    # a small graph's floor stops at sparse_level_elems / 256 = 1,024
+    # cells a request: under it a level cannot repay its tiles' tables
+    # on the host (PERF.md, PR 42: the 100-service mesh on the chip)
+    (_POWERLAW_L3, {"num_hops": 100}, "dense"),       # 108 = 1.08 x hops
+    (_POWERLAW_L3, {"num_hops": 100, "sparse_level_elems": 1}, "tiled"),
+    (_SMALL_GRAPH_LEVEL, {"num_hops": 100}, "tiled"),        # 1,025
+    (_SMALL_GRAPH_LEVEL_LESS, {"num_hops": 100}, "dense"),   # 1,020
 ])
 def test_level_encoding_reads_the_grid_against_the_graphs_hops(
         level, kw, want):
@@ -573,16 +611,19 @@ def test_level_encoding_reads_the_grid_against_the_graphs_hops(
     assert enc == want
     assert (plan is not None) == (want == "tiled")
     if want == "tiled":
-        # no script past the tile cap, and the tiles are a small part
-        # of the grid (the real levels' plans: tests/test_star10k.py)
+        # no script past the tile cap, and the tiles are at most half
+        # the grid (the real levels' plans: tests/test_star10k.py,
+        # tests/test_svc10k.py)
         assert len(plan.residual) == 0
-        assert plan.tiled_elems * 10 < size * pmax
+        assert plan.tiled_elems * 2 <= size * pmax
 
 
 # ---------------------------------------------------------------------------
-# the benchmark's six vendored graphs by default SimParams: the plans
-# the cells were measured on (PERF.md, PR 38).  The signatures are the
-# parent's (c90da70), taken before the rule read the graph's hops
+# the benchmark's seven vendored graphs by default SimParams: the plans
+# the cells were measured on (PERF.md, PR 38, PR 41, PR 42).  Six
+# signatures are the parent's; the 10,000-service multitier mesh's
+# levels 3-11 left the grid in PR 42 (0.54-2.66 x its hops against a
+# tiled floor of 0.5 x), which leaves one scan bucket of its three
 
 _TOPOLOGIES = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -598,20 +639,22 @@ _VENDORED = {
     "1000-svc_2000-end.yaml": ((), _unrolled(0, 1, 2, 3, 4)),
     "canonical.yaml": ((), (("scan", 0, 1, 3, 2, 3, 1), ("unrolled", 2))),
     "tree-111-services.yaml": ((), _unrolled(0, 1, 2)),
-    "multitier-10000.yaml": ((), (
-        *_unrolled(0, 1, 2), ("scan", 3, 8, 1458, 19, 1458, 1),
-        ("scan", 9, 10, 1164, 14, 860, 1), ("scan", 11, 13, 582, 11, 423, 1),
+    "multitier-10000.yaml": (tuple(range(3, 12)), (
+        *_unrolled(*range(12)), ("scan", 12, 13, 423, 11, 275, 1),
         *_unrolled(14, 15, 16, 17, 18))),
+    # its levels 3 and 4 (108 and 102 cells, ~1 x its hops) are under
+    # the small-graph floor of 1,024 cells: the parent's plan
     "realistic-multitier-100-errors.yaml": ((), (
         ("unrolled", 0), ("scan", 1, 5, 21, 6, 21, 1),
         *_unrolled(6, 7, 8, 9))),
     # PR 41: three attempts a call; the bucket's last field is the
-    # attempts it scans
+    # attempts it scans.  Its level 5 (2,673 x 1) is 0.36 x its hops:
+    # under the tiled floor, and no tile plan would shrink it
     "realistic-multitier-50-errors-retries2.yaml": ((), (
         *_unrolled(0, 1, 2, 3, 4), ("scan", 5, 6, 2673, 1, 729, 3),
         ("unrolled", 7))),
-    # the one graph with levels off the grid; its signature did not
-    # change either (a tiled level is an unrolled segment)
+    # levels 1 and 2 off the grid since PR 36 / PR 38; level 3 (990
+    # cells, 0.1 x its hops) stays
     "star-10000.yaml": ((1, 2), _unrolled(0, 1, 2, 3, 4)),
 }
 
@@ -633,8 +676,10 @@ def test_vendored_graphs_keep_their_plans(name):
                  if lvl.tiled is not None) == tiled_levels
     assert all(lvl.sparse is None for lvl in sim._levels)
     assert sim._plan_sig == signature
-    # how far each graph is from the floor of 8 x its hops: the widest
-    # call-bearing dense level, in hops of the graph
+    # how far each graph is from the tiled floor of 0.5 x its hops:
+    # the widest call-bearing dense level, in hops of the graph (the
+    # star's level 0, 1 x 5,021, is its call slots; the 100-service
+    # mesh's 1.08 x is 108 cells, under the small-graph floor)
     widest = max(s.size * s.pmax for s in sim._plan_shapes
                  if s.calls and not s.sparse) / compiled.num_hops
-    assert widest < (1.0 if tiled_levels else 2.7)
+    assert widest < (0.51 if tiled_levels else 1.1)

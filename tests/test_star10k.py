@@ -40,15 +40,19 @@ MODEL = {"cpu_time_s": 1 / 13000, "base_latency_s": 250e-6,
 #: a star of the generator's seed 0 whose level 1 tiles WITH a residual
 #: by default ``SimParams``: 880 hops x 364 steps.  It was the smallest
 #: such (in steps of 200) while the floor was ``sparse_level_elems``
-#: elements a request; read against the graph's hops (PR 38: 8 x 1,400
-#: here) every star of the family from 400 services up does, and the
-#: 1,200-service one (759 x 315) tiles too.  Its level 2 (514 x 3) is
-#: dense under either rule
+#: elements a request; read against the graph's hops (PR 38) every star
+#: of the family from 400 services up does.  Its level 2 (514 x 3 for 4
+#: calls, 1.1 x its hops) was dense while the tiled floor was 8 x the
+#: hops and is two tiles (513 x 1, 1 x 3) since PR 42 put it at 0.5 x
 SMALL = 1400
+#: a smaller star of the same seed whose level 1 (527 x 195) tiles with
+#: a residual and whose level 2 (269 x 3 = 807 cells) stays dense: under
+#: 1,024 cells a request no level leaves the grid by default (PR 42)
+LEVEL1_ONLY = 800
 #: (services, seed) of a star shaped like the cell's: level 1 (1,259 x
 #: 544) five tiles and a 544-slot residual, level 2 (677 hops x 28 steps
 #: for 63 calls: 9.5 x the graph's hops) four tiles and no residual by
-#: default ``SimParams`` - dense at the parent, whose floor was 262,144
+#: default ``SimParams`` - dense until PR 38, whose floor was 262,144
 TWO_LEVELS = (2000, 5)
 ENCODING_COUNTERS = (
     "levels_tiled", "hops_in_tiled_levels", "tile_padded_elems",
@@ -124,7 +128,9 @@ def test_the_plan_is_the_one_the_cell_was_measured_on(star10k):
     assert sorted(tl.hop_inv) == list(range(5021))
     assert sorted(tl.child_inv) == list(range(4641))
     # level 2: 176,358 cells for 330 calls, 17.6 x the graph's hops -
-    # past the floor of 8 x; 5,028 tile cells, no script past the cap
+    # past the tiled floor (8 x in PR 38, 0.5 x since PR 42); 5,028
+    # tile cells, no script past the cap.  Level 3 (330 x 3 = 0.1 x
+    # the hops) and level 0 (1 x 5,021: its own call slots) stay dense
     assert shapes[2].tiles == (
         (4597, 1), (20, 4), (19, 11), (4, 26), (1, 38))
     assert shapes[2].residual_slots == 0
@@ -257,7 +263,7 @@ def test_a_star_that_tiles_against_the_walk_through_the_cli(
 
 
 @pytest.fixture(scope="module", params=[
-    pytest.param((SMALL, 0, (1,)), id="level1"),
+    pytest.param((LEVEL1_ONLY, 0, (1,)), id="level1"),
     pytest.param((*TWO_LEVELS, (1, 2)), id="levels1and2")])
 def tiled_and_dense(request):
     """A star by default ``SimParams`` and the same graph forced dense:
