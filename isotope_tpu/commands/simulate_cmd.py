@@ -595,7 +595,8 @@ def run_simulate(args) -> int:
         # (including the entrypoint override), same env-applied params,
         # same load grid (of one), same chaos
         compiled = compile_graph(
-            ServiceGraph.from_yaml_file(args.topology), entry=config.entry
+            ServiceGraph.from_yaml_file(args.topology), entry=config.entry,
+            leaf_attempts=not config.chaos,
         )
         sim = Simulator(
             compiled,

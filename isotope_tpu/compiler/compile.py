@@ -464,26 +464,54 @@ def compile_graph(
     graph: ServiceGraph,
     entry: Optional[str] = None,
     max_hops: int = 2_000_000,
+    *,
+    leaf_attempts: bool = True,
 ) -> CompiledGraph:
     """Compile ``graph`` for simulation, unrolling from ``entry``.
 
     ``entry`` defaults to the graph's first entrypoint service — the service
     the reference's Fortio client is pointed at
     (isotope/convert/pkg/kubernetes/fortio_client.go:28-78).
+
+    ``leaf_attempts=False`` gives every retry attempt a subtree of its
+    own whatever the calls' timeouts: what a run under a chaos schedule
+    needs (an outage below a callee fails each attempt with part of its
+    script run), and what the Simulator asks for by name where it is
+    handed one with a plan that has leaf attempts.
     """
     with telemetry.phase("compile.unroll"):
-        compiled = _compile_graph(graph, entry, max_hops)
+        compiled = _compile_graph(graph, entry, max_hops, leaf_attempts)
     telemetry.counter_inc("graphs_compiled")
+    # what the device computes a request for, whatever executes
+    telemetry.counter_inc("hop_columns_compiled", compiled.num_hops)
     # what a call's `retries` cost the plan: the hop columns that are a
-    # second or later attempt (each with a subtree of its own under it)
-    # and the call sites that have them; absent where no call retries
-    attempt_hops = int((compiled.hop_attempt > 0).sum())
+    # second or later attempt, the call sites that have them, the
+    # columns that are a failed attempt's leaf and those that lie under
+    # a second or later attempt (a finite timeout keeps each attempt a
+    # subtree of its own: the multiplication's size); absent where no
+    # call retries
+    attempt_hops = sum(
+        int(lvl.att_valid[1:].sum()) for lvl in compiled.levels
+    )
     if attempt_hops:
         telemetry.counter_inc("attempt_hops_compiled", attempt_hops)
         telemetry.counter_inc(
             "retry_call_sites",
             sum(int((lvl.att_valid.sum(0) > 1).sum())
                 for lvl in compiled.levels if lvl.num_calls),
+        )
+        telemetry.counter_inc(
+            "attempt_leaf_hops_compiled",
+            sum(int(lvl.att_valid[:, lvl.att_leaf].sum())
+                for lvl in compiled.levels),
+        )
+        retried = compiled.hop_attempt > 0
+        under = np.zeros(compiled.num_hops, bool)
+        for lvl in compiled.levels[1:]:
+            parent = compiled.hop_parent[lvl.hop_ids]
+            under[lvl.hop_ids] = under[parent] | retried[parent]
+        telemetry.counter_inc(
+            "attempt_subtree_hops_compiled", int(under.sum())
         )
     telemetry.gauge_set("last_graph_hops", compiled.num_hops)
     telemetry.gauge_set("last_graph_levels", len(compiled.levels))
@@ -511,6 +539,7 @@ def _compile_graph(
     graph: ServiceGraph,
     entry: Optional[str],
     max_hops: int,
+    leaf_attempts: bool = True,
 ) -> CompiledGraph:
     if not graph.services:
         raise NoEntrypointError()
@@ -556,15 +585,52 @@ def _compile_graph(
     _check_acyclic(entry_idx, programs, names)
     max_steps = max([len(p) for p in programs] + [1])
 
+    # a service whose own calls can time out can answer 500 with part
+    # of its script executed: an attempt on it is not a leaf
+    times_out = [
+        any(np.isfinite(c.timeout) for step in prog for c in step.calls)
+        for prog in programs
+    ]
+    # Nor is it where the engine has other ways to fail an attempt, or
+    # draws per hop what belongs to the attempt: a breaker's shed, panic
+    # routing and a canary's arm are coins of each hop (the subtree hop
+    # would draw again what the answering leaf drew), and a retry budget
+    # counts the attempts that were sent.  They come with the graph's
+    # ``policies`` / ``rollouts`` blocks, so such a graph keeps every
+    # attempt a subtree of its own; a chaos schedule comes with the run,
+    # whose caller says so (``compile_graph(leaf_attempts=False)``).
+    leaf_attempts = leaf_attempts and not (
+        getattr(graph, "policies", None) or getattr(graph, "rollouts", None)
+    )
+
     # -- BFS unroll --------------------------------------------------------
     hop_service: List[int] = [entry_idx]
     hop_parent: List[int] = [-1]
     hop_depth: List[int] = [0]
     hop_step: List[int] = [-1]
     hop_attempt: List[int] = [0]
+    hop_subtree: List[bool] = [False]
+    hop_scripted: List[bool] = [True]  # False: a failed attempt's leaf
     hop_send_prob: List[float] = [1.0]
     hop_request_size: List[float] = [0.0]
     hop_reach: List[float] = [1.0]
+
+    def add_hop(h, step_idx, call, attempt, reach, subtree=False,
+                scripted=True) -> int:
+        child = len(hop_service)
+        if child >= max_hops:
+            raise HopBudgetExceededError(max_hops)
+        hop_service.append(call.target)
+        hop_parent.append(h)
+        hop_depth.append(hop_depth[h] + 1)
+        hop_step.append(step_idx)
+        hop_attempt.append(attempt)
+        hop_subtree.append(subtree)
+        hop_scripted.append(scripted)
+        hop_send_prob.append(call.send_prob)
+        hop_request_size.append(call.size)
+        hop_reach.append(reach)
+        return child
 
     levels: List[HopLevel] = []
     frontier = [0]  # global hop ids at the current depth
@@ -574,52 +640,70 @@ def _compile_graph(
         step_hop: List[int] = []
         step_at: List[int] = []
         step_sleep: List[float] = []
+        pmax = 0
         child_ids: List[int] = []
         child_seg: List[int] = []
         call_seg: List[int] = []
         call_step: List[int] = []
         call_timeout: List[float] = []
         call_attempt_children: List[List[int]] = []  # local child indices
-        next_frontier: List[int] = []
+        call_sub_child: List[int] = []  # local child index, -1: none
         for local, h in enumerate(frontier):
+            if not hop_scripted[h]:
+                continue
             prog = programs[hop_service[h]]
-            parent_err = float(table.error_rate[hop_service[h]])
+            pmax = max(pmax, len(prog))
+            # a subtree hop is sent only under an attempt that answered 200
+            parent_err = (
+                0.0 if hop_subtree[h]
+                else float(table.error_rate[hop_service[h]])
+            )
             for step_idx, step in enumerate(prog):
                 step_hop.append(local)
                 step_at.append(step_idx)
                 step_sleep.append(step.base)
                 for call in step.calls:
-                    # Each retry attempt is its own hop (with its own
-                    # subtree); its static reach discounts by the target's
-                    # error rate — the statically-known part of "previous
-                    # attempt failed" — for offered-load estimation.
                     target_err = float(table.error_rate[call.target])
-                    call_seg.append(local * max_steps + step_idx)
+                    seg = local * max_steps + step_idx
+                    call_seg.append(seg)
                     call_step.append(step_idx)
                     call_timeout.append(call.timeout)
-                    att_locals: List[int] = []
-                    for a in range(call.attempts):
-                        child = len(hop_service)
-                        if child >= max_hops:
-                            raise HopBudgetExceededError(max_hops)
-                        hop_service.append(call.target)
-                        hop_parent.append(h)
-                        hop_depth.append(hop_depth[h] + 1)
-                        hop_step.append(step_idx)
-                        hop_attempt.append(a)
-                        hop_send_prob.append(call.send_prob)
-                        hop_request_size.append(call.size)
-                        hop_reach.append(
-                            hop_reach[h]
-                            * call.send_prob
-                            * (1.0 - parent_err)
-                            * target_err**a
-                        )
-                        att_locals.append(len(child_ids))
-                        child_ids.append(child)
-                        child_seg.append(local * max_steps + step_idx)
-                        next_frontier.append(child)
-                    call_attempt_children.append(att_locals)
+                    sent = hop_reach[h] * call.send_prob * (1.0 - parent_err)
+                    # Each retry attempt is its own hop; its static
+                    # reach discounts by the target's error rate — the
+                    # statically-known part of "previous attempt failed"
+                    # — for offered-load estimation.  Where only the
+                    # callee's own 500 can fail the call, a failed
+                    # attempt executed nothing below it and at most one
+                    # attempt succeeds: the attempts are LEAVES (attempt
+                    # a answered 500: reach p^(a+1)) and one more hop
+                    # carries the callee's subtree (some attempt
+                    # answered 200).  Elsewhere a timed-out attempt did
+                    # start the callee's script: every attempt keeps a
+                    # subtree of its own.
+                    leaf = int(
+                        leaf_attempts
+                        and call.attempts > 1
+                        and not np.isfinite(call.timeout)
+                        and not times_out[call.target]
+                    )
+                    call_sub_child.append(len(child_ids) if leaf else -1)
+                    if leaf:
+                        child_ids.append(add_hop(
+                            h, step_idx, call, 0,
+                            sent * (1.0 - target_err**call.attempts),
+                            subtree=True,
+                        ))
+                    call_attempt_children.append(list(range(
+                        len(child_ids), len(child_ids) + call.attempts
+                    )))
+                    child_ids.extend(
+                        add_hop(h, step_idx, call, a + leaf,
+                                sent * target_err ** (a + leaf),
+                                scripted=not leaf)
+                        for a in range(call.attempts)
+                    )
+                    child_seg.extend([seg] * (len(child_ids) - len(child_seg)))
         max_a = max((len(c) for c in call_attempt_children), default=1)
         n_calls = len(call_seg)
         att_child = np.full((max_a, n_calls), len(child_ids), np.int32)
@@ -628,6 +712,8 @@ def _compile_graph(
             for a, local_idx in enumerate(att_locals):
                 att_child[a, k] = local_idx
                 att_valid[a, k] = True
+        sub_child = np.asarray(call_sub_child, np.int32)
+        att_leaf = sub_child >= 0
         levels.append(
             HopLevel(
                 hop_ids=np.asarray(frontier, np.int32),
@@ -635,9 +721,7 @@ def _compile_graph(
                 step_hop=np.asarray(step_hop, np.int32),
                 step_idx=np.asarray(step_at, np.int32),
                 step_sleep=np.asarray(step_sleep, np.float32),
-                pmax=max(
-                    (len(programs[s]) for s in level_services), default=0
-                ),
+                pmax=pmax,
                 child_ids=np.asarray(child_ids, np.int32),
                 child_seg=np.asarray(child_seg, np.int32),
                 call_seg=np.asarray(call_seg, np.int32),
@@ -645,9 +729,13 @@ def _compile_graph(
                 call_timeout=np.asarray(call_timeout, np.float32),
                 att_child=att_child,
                 att_valid=att_valid,
+                att_leaf=att_leaf,
+                sub_child=np.where(
+                    att_leaf, sub_child, len(child_ids)
+                ).astype(np.int32),
             )
         )
-        frontier = next_frontier
+        frontier = child_ids
 
     return CompiledGraph(
         services=table,
@@ -657,6 +745,7 @@ def _compile_graph(
         hop_depth=np.asarray(hop_depth, np.int32),
         hop_step=np.asarray(hop_step, np.int32),
         hop_attempt=np.asarray(hop_attempt, np.int32),
+        hop_subtree=np.asarray(hop_subtree, bool),
         hop_send_prob=np.asarray(hop_send_prob, np.float32),
         hop_request_size=np.asarray(hop_request_size, np.float32),
         hop_reach=np.asarray(hop_reach, np.float64),

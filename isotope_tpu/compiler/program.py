@@ -94,7 +94,15 @@ class HopLevel:
       consecutive attempt hops; ``att_child[a, k]`` is the local child
       index of call k's attempt a (``att_valid`` masks shorter chains).
       Attempt durations sum serially; the call's outcome is the last
-      attempt's.
+      attempt's.  Where only the callee's own 500 can fail a call
+      (``att_leaf[k]``: no finite timeout on it or on the callee's own
+      calls, no ``policies`` / ``rollouts`` block on the graph, no chaos
+      schedule on the run) a failed attempt executed nothing below it
+      and at most one attempt succeeds, so the call's attempt hops are
+      LEAVES - row a is the attempt that answered 500, sent iff
+      attempts ``0..a`` all did - and one more child, ``sub_child[k]``,
+      carries the callee's subtree: it runs iff some attempt answered
+      200.
     - per **step**: ``call_seg`` maps each call to the flat
       ``parent_local * Pmax + step`` slot so a scatter-max computes the
       per-step join — the vectorized form of the reference's WaitGroup
@@ -116,6 +124,8 @@ class HopLevel:
     call_timeout: np.ndarray   # (K,) f32 — +inf when none
     att_child: np.ndarray      # (maxA, K) int32 — local child idx (or C)
     att_valid: np.ndarray      # (maxA, K) bool
+    att_leaf: np.ndarray       # (K,) bool — the call's attempts are leaves
+    sub_child: np.ndarray      # (K,) int32 — its subtree child (or C)
 
     @property
     def num_hops(self) -> int:
@@ -192,7 +202,13 @@ class CompiledGraph:
     hop_parent: np.ndarray     # (H,) int32 — -1 for the root
     hop_depth: np.ndarray      # (H,) int32
     hop_step: np.ndarray       # (H,) int32 — step index in parent's script
-    hop_attempt: np.ndarray    # (H,) int32 — retry attempt index (0 first)
+    # retry attempt index (0 first); under leaf attempts (HopLevel) the
+    # subtree hop is 0 and the leaf of failed attempt a is a + 1, so
+    # ``== 0`` is one hop a call and ``> 0`` the hops its retries add
+    hop_attempt: np.ndarray    # (H,) int32
+    # a leaf-attempt call's subtree hop: sent only under an attempt that
+    # answered 200, so its own error coin never lands
+    hop_subtree: np.ndarray    # (H,) bool
     hop_send_prob: np.ndarray  # (H,) f32 — this hop's own coin, [0, 1]
     hop_request_size: np.ndarray  # (H,) f32 — bytes sent to the hop
     # P(hop is reached) = prod over path of send_prob * (1 - parent error
@@ -205,6 +221,15 @@ class CompiledGraph:
     @property
     def num_hops(self) -> int:
         return len(self.hop_service)
+
+    def hop_error_rate(self, error_rate: Optional[np.ndarray] = None
+                       ) -> np.ndarray:
+        """(H,) each hop's own P(injected 500): its service's
+        ``error_rate`` (default: the table's), 0 on a subtree hop."""
+        if error_rate is None:
+            error_rate = self.services.error_rate
+        rate = np.asarray(error_rate)[self.hop_service]
+        return np.where(self.hop_subtree, rate.dtype.type(0), rate)
 
     @property
     def num_services(self) -> int:
@@ -235,6 +260,7 @@ class CompiledGraph:
                     lvl.num_children,
                     lvl.num_calls,
                     lvl.max_attempts,
+                    bool(lvl.att_leaf.any()),
                 )
                 for lvl in self.levels
             ),

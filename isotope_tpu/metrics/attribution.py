@@ -232,6 +232,10 @@ def build_tables(compiled: CompiledGraph, net) -> AttrTables:
             call_of_child[lvl.att_child[a][valid]] = np.arange(
                 K, dtype=np.int32
             )[valid]
+        # a leaf call's subtree child is the attempt that answered 200
+        call_of_child[lvl.sub_child[lvl.att_leaf]] = np.flatnonzero(
+            lvl.att_leaf
+        )
         # call-bearing steps only — the sparse-level fix applied
         # globally: no (L x Pmax) dense step grid is ever materialized
         slot_segs = np.unique(lvl.call_seg)

@@ -204,8 +204,11 @@ class _LazyTopology:
             # compile.unroll's parent: what is left of it is the level
             # loop behind compile_graph's step-grid gauges
             with telemetry.phase("compile.graph"):
+                # a chaos outage fails an attempt with part of its
+                # callee's script run: no leaf attempts under one
                 self._compiled = compile_graph(
-                    graph, entry=self.config.entry
+                    graph, entry=self.config.entry,
+                    leaf_attempts=not self.config.chaos,
                 )
             self._entry_resp = float(
                 self._compiled.services.response_size[
@@ -1099,9 +1102,10 @@ def _retries_fired(compiled, metrics, requests: int, chaos) -> Optional[int]:
     calls_of_hop = np.bincount(
         compiled.hop_parent[first], minlength=compiled.num_hops
     )
-    # every hop of a service runs the same script: one hop's calls
+    # every hop of a service that runs its script makes the same calls
+    # (a failed attempt's leaf makes none)
     calls = np.zeros(metrics.incoming_total.shape[0], np.int64)
-    calls[compiled.hop_service] = calls_of_hop
+    np.maximum.at(calls, compiled.hop_service, calls_of_hop)
     ok = np.asarray(metrics.duration_hist, np.float64)[:, 0].sum(-1)
     incoming = np.asarray(metrics.incoming_total, np.float64)
     return int(incoming.sum() - requests - (ok * calls).sum())

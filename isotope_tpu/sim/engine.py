@@ -138,6 +138,11 @@ class _Level:
     att_child: np.ndarray       # (maxA, K) i32 — static gather indices
     att_valid: np.ndarray       # (maxA, K) bool — static masks
     child_churn_entry: Optional[np.ndarray] = None  # (C,) i32 static
+    # leaf attempts (compiler.program.HopLevel): which calls' attempt
+    # hops are failed attempts' leaves, and the child that carries each
+    # one's subtree (C elsewhere); None where the level has none
+    att_leaf: Optional[np.ndarray] = None   # (K,) bool — static mask
+    sub_child: Optional[np.ndarray] = None  # (K,) i32 — static indices
     # -- static structure flags (trace-time specialization) ---------------
     # single-attempt levels where call k's only child is child k: the
     # attempt loop degenerates to elementwise ops (no scatters)
@@ -694,6 +699,19 @@ class Simulator:
     ):
         telemetry.install_jax_hooks()
         faults.check("engine.build")
+        if compiled.hop_subtree.any() and (
+            len(chaos) or policies is not None or rollouts is not None
+            or lb is not None
+        ):
+            # the leaf layout is exact only where a callee's own
+            # errorRate coin is the one way an attempt fails
+            # (compiler/compile.py _compile_graph)
+            raise ValueError(
+                "this plan's failed retry attempts are leaf hops, which "
+                "a chaos schedule, policies, rollouts or an lb law would "
+                "make inexact: compile it with "
+                "compile_graph(..., leaf_attempts=False)"
+            )
         # the constructor's sections, one span each (``engine.build``'s
         # own self time is what is left between them)
         net_out, net_back = self._build_load(
@@ -804,7 +822,7 @@ class Simulator:
                 jnp.float32,
             )
             self._canary_err_h = jnp.asarray(
-                rollouts.canary_error_rate[compiled.hop_service],
+                compiled.hop_error_rate(rollouts.canary_error_rate),
                 jnp.float32,
             )
             self._canary_reps_np = rollouts.canary_replicas.astype(
@@ -1231,7 +1249,8 @@ class Simulator:
         # Per-hop gathers are resolved at trace time (static indices).
         hs = compiled.hop_service
         self._hop_service = jnp.asarray(hs)
-        self._hop_err_rate = jnp.asarray(t.error_rate[hs])
+        hop_err = compiled.hop_error_rate()
+        self._hop_err_rate = jnp.asarray(hop_err)
         # cluster-aware wire times: cross-cluster edges pay the gateway
         # class, and the client -> entrypoint edge may traverse an
         # ingress gateway (compiler/program.py hop_wire_times)
@@ -1308,7 +1327,7 @@ class Simulator:
         self._need_send = bool(churn) or bool(
             (compiled.hop_send_prob[1:] < 1.0).any()
         )
-        self._need_err = bool((t.error_rate[hs] > 0.0).any()) or (
+        self._need_err = bool((hop_err > 0.0).any()) or (
             # a canary arm that can 500 needs the error coins drawn
             # even when the baseline is error-free (sim/rollout.py)
             rollouts is not None and rollouts.any_error_override
@@ -1438,7 +1457,9 @@ class Simulator:
                 call_local=call_local, call_step=call_step,
                 call_timeout=lvl.call_timeout,
                 att_child=lvl.att_child, att_valid=lvl.att_valid,
+                att_leaf=lvl.att_leaf, sub_child=lvl.sub_child,
             )
+        any_leaf = bool(lvl.att_leaf.any())
         return (
             _Level(
                 offset=offset,
@@ -1468,6 +1489,8 @@ class Simulator:
                 child_churn_entry=(
                     self._hop_churn_entry[cids] if churn else None
                 ),
+                att_leaf=lvl.att_leaf if any_leaf else None,
+                sub_child=lvl.sub_child if any_leaf else None,
                 ident_attempts=ident,
                 finite_timeout=bool(
                     np.isfinite(lvl.call_timeout).any()
@@ -1573,6 +1596,7 @@ class Simulator:
                 lb.signature() if lb is not None else "",
                 compiled.hop_service, compiled.hop_parent,
                 compiled.hop_step, compiled.hop_attempt,
+                compiled.hop_subtree,
                 compiled.hop_send_prob, compiled.hop_request_size,
                 compiled.hop_reach, t.replicas, t.error_rate,
                 t.response_size, t.cluster,
@@ -1586,6 +1610,7 @@ class Simulator:
                         l.step_hop, l.step_idx, l.step_sleep, l.pmax,
                         l.child_ids, l.child_seg, l.call_seg,
                         l.call_timeout, l.att_child, l.att_valid,
+                        l.att_leaf, l.sub_child,
                     )
                 ],
             ),
@@ -1719,9 +1744,11 @@ class Simulator:
                 continue
             att_counts = lvl.att_valid.sum(0)
             for k in np.nonzero(att_counts > 1)[0]:
-                gids = lvl.child_ids[
-                    lvl.att_child[lvl.att_valid[:, k], k]
-                ]
+                locs = lvl.att_child[lvl.att_valid[:, k], k]
+                if lvl.att_leaf[k]:
+                    # the attempt that answered 200 is the subtree hop
+                    locs = np.append(locs, lvl.sub_child[k])
+                gids = lvl.child_ids[locs]
                 rg[gids] = n_rg
                 in_rg[gids] = True
                 n_rg += 1
@@ -5417,6 +5444,9 @@ class Simulator:
         #     that step (fail_step), a 500 does not (executable.go:132-143),
         #   - which attempt hops would actually run (``used``), and each
         #     attempt's time offset inside its step (for start times).
+        #     Where the call's attempts are leaves (HopLevel.att_leaf) a
+        #     leaf runs iff its attempt was made AND answered 500, and the
+        #     call's subtree hop after them iff one answered 200.
         # ``None`` sentinels carry static knowledge through the sweep so
         # impossible branches vanish from the compiled program entirely:
         # err_lvls[d] is None when no hop can 500, fail_lvls[d] is None
@@ -5570,12 +5600,19 @@ class Simulator:
                             used = jnp.zeros((n, C + 1), bool)
                             att_off = jnp.zeros((n, C + 1))
                             used_a = coin
+                            # leaf attempts: some attempt answered 200
+                            leaf = lvl.att_leaf          # (K,) static
+                            answered = (
+                                None if leaf is None
+                                else jnp.zeros((n, lvl.num_calls), bool)
+                            )
                             for a in range(lvl.max_attempts):
                                 idx = lvl.att_child[a]       # (K,) in [0, C]
                                 valid = lvl.att_valid[a]     # (K,) static
                                 use = used_a & valid
                                 if retry_gate is not None and a > 0:
                                     use = use & retry_gate[:, idx]
+                                tried = use
                                 t = rtt_child[idx] + lat_child[:, idx]
                                 if tax is not None:
                                     t = t + 2.0 * tax[:, None]
@@ -5592,6 +5629,10 @@ class Simulator:
                                     failed_a = (
                                         ec if failed_a is None else failed_a | ec
                                     )
+                                if leaf is not None:
+                                    use, answered = levelscan.leaf_attempt(
+                                        tried, leaf, failed_a, answered
+                                    )
                                 att_off = att_off.at[:, idx].set(
                                     jnp.where(use, dur_call, 0.0)
                                 )
@@ -5599,12 +5640,20 @@ class Simulator:
                                 dur_call = dur_call + jnp.where(use, dur_a, 0.0)
                                 if final_transport is not None:
                                     final_transport = jnp.where(
-                                        use, transport_a, final_transport
+                                        tried, transport_a, final_transport
                                     )
                                 used_a = (
-                                    use & failed_a
+                                    tried & failed_a
                                     if failed_a is not None
                                     else jnp.zeros_like(use)
+                                )
+                            if leaf is not None:
+                                att_off, used, dur_call = (
+                                    levelscan.subtree_attempt(
+                                        answered, lvl.sub_child, rtt_child,
+                                        lat_child, tax, att_off, used,
+                                        dur_call,
+                                    )
                                 )
                             used_lvls[d] = used[:, :C]
 

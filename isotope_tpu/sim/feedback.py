@@ -109,9 +109,12 @@ class _LevelCalls:
     target: np.ndarray           # (K,) service index
     send_prob: np.ndarray        # (K,)
     rtt: np.ndarray              # (K,) request+response wire time
-    first_child: np.ndarray      # (K,) global hop id of attempt 0
+    # the hop whose subtree a successful attempt runs: attempt 0's, or
+    # a leaf-attempt call's subtree hop (compiler.program.HopLevel)
+    first_child: np.ndarray      # (K,) global hop id
     att_global: np.ndarray       # (maxA, K) global hop ids (garbage where
     att_valid: np.ndarray        # (maxA, K) bool              ... invalid)
+    leaf: np.ndarray             # (K,) bool: the attempt hops are leaves
 
 
 class RetryFeedback:
@@ -171,6 +174,8 @@ class RetryFeedback:
 
         t = compiled.services
         self._err = t.error_rate.astype(np.float64)
+        # a hop's own P(500): its service's, 0 on a subtree hop
+        self._hop_err = compiled.hop_error_rate().astype(np.float64)
         hs = compiled.hop_service
         net_out, net_back = hop_wire_times(compiled, params.network)
         if mtls is not None:
@@ -192,7 +197,9 @@ class RetryFeedback:
         for lvl in compiled.levels:
             K = len(lvl.call_seg)
             if K:
-                first_local = lvl.att_child[0]
+                first_local = np.where(
+                    lvl.att_leaf, lvl.sub_child, lvl.att_child[0]
+                )
                 g0 = lvl.child_ids[first_local]
                 att_global = lvl.child_ids[
                     np.clip(lvl.att_child, 0, max(len(lvl.child_ids) - 1, 0))
@@ -220,6 +227,7 @@ class RetryFeedback:
                     first_child=g0.astype(np.int64),
                     att_global=att_global.astype(np.int64),
                     att_valid=lvl.att_valid.astype(bool),
+                    leaf=lvl.att_leaf,
                 )
             )
         self._cache: dict = {}
@@ -257,11 +265,12 @@ class RetryFeedback:
                 continue
             base = (
                 reach[lc.hop_ids[lc.parent_local]]
-                * (1.0 - self._err[lc.svc[lc.parent_local]])
+                * (1.0 - self._hop_err[lc.hop_ids[lc.parent_local]])
                 * lc.send_prob
                 * own[lc.first_child]
             )
             base = np.where(down[lc.target], 0.0, base)
+            reach[lc.first_child] = base
             for a in range(lc.att_global.shape[0]):
                 valid = lc.att_valid[a]
                 if valid.any():
@@ -411,7 +420,7 @@ class RetryFeedback:
                     )
                     # the reach recursion continues attempts at the
                     # BUDGETED rate q, not raw pf
-                    lvl_pf[d] = q
+                    lvl_pf[d] = (q, pf)
                     lvl_surv[d], lvl_send[d] = surv, send_eff
                     step_dur = np.maximum(
                         lc.step_base, slot_max.reshape(L, P)
@@ -421,7 +430,7 @@ class RetryFeedback:
                     lvl_surv[d] = surv
                     step_dur = lc.step_base * lc.step_real
                 busy = (surv * step_dur).sum(1)
-                pe_h = self._err[lc.svc]
+                pe_h = self._hop_err[lc.hop_ids]
                 mean_run[lc.hop_ids] = (
                     ew[lc.svc] + cpu + (1.0 - pe_h) * busy
                 )
@@ -438,18 +447,25 @@ class RetryFeedback:
                 # carries, compiler/compile.py)
                 base = (
                     reach[lc.hop_ids[lc.parent_local]]
-                    * (1.0 - self._err[lc.svc[lc.parent_local]])
+                    * (1.0 - self._hop_err[lc.hop_ids[lc.parent_local]])
                     * lvl_surv[d][lc.parent_local, lc.step]
                     * lvl_send[d]
                 )
                 base = np.where(down[lc.target], 0.0, base)
-                pf = lvl_pf[d]
+                q, pf = lvl_pf[d]
                 r_a = base
+                answered = np.zeros(K)
                 for a in range(lc.att_global.shape[0]):
                     valid = lc.att_valid[a]
                     if valid.any():
-                        reach[lc.att_global[a][valid]] = r_a[valid]
-                    r_a = r_a * pf
+                        # a leaf is attempt a where it failed; the
+                        # attempts that answered are the subtree hop's
+                        reach[lc.att_global[a][valid]] = np.where(
+                            lc.leaf, r_a * pf, r_a
+                        )[valid]
+                        answered = answered + valid * r_a * (1.0 - pf)
+                    r_a = r_a * q
+                reach[lc.first_child[lc.leaf]] = answered[lc.leaf]
             new = np.bincount(
                 compiled.hop_service, weights=reach, minlength=S
             )

@@ -58,6 +58,35 @@ def call_outcome(t, timeout, down_child):
     return transport, dur
 
 
+def leaf_attempt(tried, leaf, failed_a, answered):
+    """One attempt of the calls whose attempts are leaves
+    (``compiler.program.HopLevel.att_leaf``): a leaf is the attempt
+    only where it answered 500 - the one that answered 200 is the
+    subtree hop's.  ``tried`` is where the attempt was made; returns
+    (where the attempt's own hop ran, ``answered`` with this attempt's
+    200s).  Shared by both executors, like :func:`call_outcome`."""
+    ok = tried & leaf
+    if failed_a is not None:
+        ok = ok & ~failed_a
+    return tried & ~ok, answered | ok
+
+
+def subtree_attempt(answered, idx, rtt_child, lat_child, tax,
+                    att_off, used, dur_call):
+    """The subtree hops ``idx`` of the leaf-attempt calls: each runs
+    after its call's attempts that answered 500, where one answered 200
+    (no timeout on such a call, and a down callee answered nothing).
+    Returns the updated (att_off, used, dur_call)."""
+    t = rtt_child[idx] + lat_child[:, idx]
+    if tax is not None:
+        t = t + 2.0 * tax[:, None]
+    return (
+        att_off.at[:, idx].set(jnp.where(answered, dur_call, 0.0)),
+        used.at[:, idx].set(answered),
+        dur_call + jnp.where(answered, t, 0.0),
+    )
+
+
 class SweepCtx(NamedTuple):
     """Per-run tensors the sweep bodies close over.
 
@@ -104,6 +133,9 @@ class ScanBucket:
     # chaos is active)
     single_attempt: bool = False
     any_finite_timeout: bool = True
+    # some call's attempts are leaves (compiler.program.HopLevel): xs
+    # then carries ``att_leaf`` / ``sub_child``
+    any_leaf: bool = False
 
     @property
     def num_hops(self) -> int:
@@ -125,8 +157,8 @@ def build_bucket(
     them while lowering); padding conventions (see module docstring):
     child lanes pad to index 0 / value 0, call lanes pad to slot 0 with
     +inf timeouts and all-False attempt validity, and the attempt table
-    remaps each level's local dummy column (its child count) to the
-    shared bucket dummy column ``B``.
+    (with a leaf call's subtree child) remaps each level's local dummy
+    column (its child count) to the shared bucket dummy column ``B``.
     """
     B, P = plan.bound_hops, plan.bound_steps
     K, A = plan.bound_calls, plan.bound_attempts
@@ -145,6 +177,7 @@ def build_bucket(
         "loff", "choff", "step_mask", "step_base", "cpl", "cstep",
         "crtt", "cnet", "cprob", "centry", "child_seg", "call_seg",
         "call_hop", "call_step", "call_timeout", "att_child", "att_valid",
+        "att_leaf", "sub_child",
     )}
     for li, m in enumerate(lvls):
         size, c, k = int(m["size"]), int(m["C"]), int(m["K"])
@@ -192,8 +225,16 @@ def build_bucket(
         att_v[:a_l, :k_l] = m["att_valid"]
         stack["att_child"].append(att_c)
         stack["att_valid"].append(att_v)
+        stack["att_leaf"].append(padv(m["att_leaf"], K))
+        stack["sub_child"].append(
+            padv(np.where(m["sub_child"] == c, B, m["sub_child"]), K,
+                 value=B).astype(np.int32)
+        )
     if not num_churn:
         del stack["centry"]
+    any_leaf = any(bool(np.any(m["att_leaf"])) for m in lvls)
+    if not any_leaf:
+        del stack["att_leaf"], stack["sub_child"]
     xs = {k: jnp.asarray(np.stack(v)) for k, v in stack.items()}
     return ScanBucket(
         plan=plan,
@@ -208,6 +249,7 @@ def build_bucket(
             bool(np.isfinite(np.asarray(m["call_timeout"])).any())
             for m in lvls
         ),
+        any_leaf=any_leaf,
     )
 
 
@@ -384,6 +426,13 @@ def up_sweep(
                 used_b = jnp.zeros((n, B + 1), bool)
                 att_off = jnp.zeros((n, B + 1))
                 used_a = coin_a
+                # leaf attempts: some attempt answered 200 - the same
+                # ops as the unrolled loop's
+                leaf = x["att_leaf"] if b.any_leaf else None
+                answered = (
+                    None if leaf is None
+                    else jnp.zeros((n, a0.shape[0]), bool)
+                )
                 for a in range(A):
                     idx = x["att_child"][a]
                     valid = x["att_valid"][a]
@@ -393,6 +442,7 @@ def up_sweep(
                         # attempt's failure to the caller (Envoy budget
                         # semantics) — same op as the unrolled gate
                         use = use & retry_gate[:, idx]
+                    tried = use
                     t = rtt_child[idx] + lat_child[:, idx]
                     if tax is not None:
                         t = t + 2.0 * tax[:, None]
@@ -407,6 +457,10 @@ def up_sweep(
                             if failed_a is None
                             else failed_a | err_child[:, idx]
                         )
+                    if leaf is not None:
+                        use, answered = leaf_attempt(
+                            tried, leaf, failed_a, answered
+                        )
                     att_off = att_off.at[:, idx].set(
                         jnp.where(use, dur_call, 0.0)
                     )
@@ -414,12 +468,17 @@ def up_sweep(
                     dur_call = dur_call + jnp.where(use, dur_a, 0.0)
                     if final_transport is not None:
                         final_transport = jnp.where(
-                            use, transport_a, final_transport
+                            tried, transport_a, final_transport
                         )
                     used_a = (
-                        use & failed_a
+                        tried & failed_a
                         if failed_a is not None
                         else jnp.zeros_like(use)
+                    )
+                if leaf is not None:
+                    att_off, used_b, dur_call = subtree_attempt(
+                        answered, x["sub_child"], rtt_child, lat_child,
+                        tax, att_off, used_b, dur_call,
                     )
                 used = used_b[:, :B]
         # -- aggregate calls into (hop, step) slots; padded calls carry

@@ -25,7 +25,9 @@ from benchmark import run  # noqa: E402
 from benchmark.tests.test_checks import *  # noqa: E402,F401,F403
 from benchmark.tests.test_checks_outcomes import *  # noqa: E402,F401,F403
 from benchmark.tests.test_checks_retries import *  # noqa: E402,F401,F403
+from benchmark.tests.test_checks_retries1000 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
+from benchmark.tests.test_contract_multitier1000 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deadline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_host_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_layer_metrics_retries import *  # noqa: E402,F401,F403

@@ -39,6 +39,8 @@ MODEL = {"cpu_time_s": 1 / 13000, "base_latency_s": 250e-6,
          "bytes_per_second": 1.25e9}
 REQUESTS = 4096
 COUNTERS = ("attempt_hops_compiled", "retry_call_sites", "copula_mix_bytes",
+            "attempt_leaf_hops_compiled", "attempt_subtree_hops_compiled",
+            "hop_columns_compiled",
             "retries_fired", "responses_500", "hop_events_executed")
 
 
@@ -92,16 +94,21 @@ def test_vendored_retry_topology_is_the_generators_output(tmp_path):
 
 def test_the_plan_of_the_retry_mesh_is_pinned():
     """What three attempts a call make of 50 services: host work only
-    (the compile and one ``Simulator`` build, under two seconds)."""
+    (the compile and one ``Simulator`` build, under two seconds).  No
+    call has a timeout, so a failed attempt is a leaf and one hop a call
+    site carries the callee's subtree: 1 + 4 x 49 columns (7,456 until
+    PR 43, when every attempt had a subtree of its own)."""
     before = counters_now()
     compiled = compile_graph(ServiceGraph.decode(cell_doc()))
-    assert compiled.num_hops == 7456 and compiled.max_steps == 7
+    assert compiled.num_hops == 197 == 1 + 4 * 49 and compiled.max_steps == 7
     assert [lvl.num_hops for lvl in compiled.levels] == [
-        1, 21, 90, 216, 810, 2673, 1458, 2187]
+        1, 28, 40, 32, 40, 44, 8, 4]
     assert [lvl.num_calls for lvl in compiled.levels] == [
-        7, 30, 72, 270, 891, 486, 729, 0]
+        7, 10, 8, 10, 11, 2, 1, 0]
     assert [lvl.max_attempts for lvl in compiled.levels[:-1]] == [3] * 7
-    assert all(lvl.att_valid.all() for lvl in compiled.levels[:-1])
+    assert all(lvl.att_valid.all() and lvl.att_leaf.all()
+               for lvl in compiled.levels[:-1])
+    assert int(compiled.hop_subtree.sum()) == 49
     # without the policy: one column a service
     bare = compile_graph(ServiceGraph.decode(realistic_topology(
         num_services=50, archetype="multitier", seed=0,
@@ -114,32 +121,46 @@ def test_the_plan_of_the_retry_mesh_is_pinned():
     # a call's three attempts share a step: a sibling group AND a retry
     # group, so both copulas are on (powerlaw100 draws plain uniforms)
     assert sim._copula_active and sim._retry_active
-    assert sim._num_retry_groups == 2485 == sum(
+    assert sim._num_retry_groups == 49 == sum(
         lvl.num_calls for lvl in compiled.levels)
-    assert sim._copula_mix.shape == (2485, 3352)
-    assert int((sim._copula_mix != 0).sum()) == 16322
-    assert sim.default_block_size() == 4500
+    # a retry group is a call's three leaves and its subtree hop
+    assert (sim._retry_group < 49).sum() == 4 * 49
+    assert sim._copula_mix.shape == (49, 108)
+    assert int((sim._copula_mix != 0).sum()) == 214
+    assert sim.default_block_size() == 170327
     plan = [(s.plan.d0, s.plan.d1) if isinstance(s, ScanBucket) else s.d
             for s in sim._segments]
-    assert plan == [0, 1, 2, 3, 4, (5, 6), 7]
+    assert plan == [0, (1, 4), 5, 6, 7]
     bucket = next(s for s in sim._segments if isinstance(s, ScanBucket))
-    assert bucket.plan.bound_hops == 2673
+    assert bucket.plan.bound_hops == 44 and bucket.any_leaf
     assert [lvl.ident_attempts for lvl in sim._levels] == [False] * 7 + [True]
     got = moved(before)
-    assert got["attempt_hops_compiled"] == 2 * 2485
-    assert got["retry_call_sites"] == 2485
-    assert got["copula_mix_bytes"] == 2485 * 3352 * 4
-    assert bare.hop_attempt.max() == 0
+    assert got["attempt_hops_compiled"] == 2 * 49
+    assert got["attempt_leaf_hops_compiled"] == 3 * 49
+    assert got["attempt_subtree_hops_compiled"] == 0
+    assert got["retry_call_sites"] == 49
+    assert got["hop_columns_compiled"] == 197 + 50
+    assert got["copula_mix_bytes"] == 49 * 108 * 4
+    assert bare.hop_attempt.max() == 0 and not bare.hop_subtree.any()
 
 
-def test_the_next_size_of_the_family_is_64708_columns():
-    """``multitier-100`` + ``retries: 2``: what the unroll makes of
-    ``powerlaw100``'s graph, by its compile alone (host work, under a
-    second); its served call does not fit a window (``ROADMAP.md``)."""
-    compiled = compile_graph(ServiceGraph.decode(cell_doc(services=100)))
-    assert compiled.num_hops == 64708 and len(compiled.levels) == 10
+@pytest.mark.parametrize("services, columns, levels", [
+    (100, 397, 10), (10_000, 39_997, 19)])
+def test_the_next_sizes_of_the_family_compile(services, columns, levels):
+    """``multitier-100`` + ``retries: 2`` (``powerlaw100``'s graph:
+    64,708 columns until PR 43) and ``multitier-10000`` + ``retries: 2``
+    (``svc10k``'s: 3^18 columns at its deepest level alone, refused) by
+    their compile alone - host work, no ``Simulator`` build: one column
+    a service and four a call site."""
+    before = counters_now()
+    compiled = compile_graph(ServiceGraph.decode(cell_doc(services)))
+    assert compiled.num_hops == columns == 1 + 4 * (services - 1)
+    assert len(compiled.levels) == levels
     assert int((compiled.hop_attempt == 0).sum()) - 1 == sum(
-        lvl.num_calls for lvl in compiled.levels)
+        lvl.num_calls for lvl in compiled.levels) == services - 1
+    got = moved(before)
+    assert got["attempt_leaf_hops_compiled"] == 3 * (services - 1)
+    assert got["attempt_subtree_hops_compiled"] == 0
 
 
 def simulate(graph, tmp_path, tag, *extra):
@@ -162,10 +183,10 @@ def simulate(graph, tmp_path, tag, *extra):
 #: of the cell's generator, 3-5 levels deep, whose retried calls sit in
 #: an unrolled level (the entrypoint's) and inside a scan bucket
 SMALL = [
-    (8, 0, 2, "20%", [0, (1, 2), 3]),
-    (9, 4, 2, "50%", [0, (1, 2), 3]),
-    (7, 1, 1, "5%", [0, (1, 2), 3]),
-    (10, 4, 2, "10%", [0, (1, 2), 3, 4]),
+    (9, 0, 2, "20%", [0, (1, 2), 3]),
+    (12, 4, 2, "50%", [0, (1, 2), (3, 4), 5]),
+    (7, 1, 1, "5%", [(0, 1), 2, 3]),
+    (11, 4, 2, "10%", [0, (1, 2), 3, 4]),
 ]
 
 
@@ -182,6 +203,7 @@ def test_program_and_walk_agree_through_the_clis_artifacts(
         (s.plan.d0, s.plan.d1) if isinstance(s, ScanBucket) else s.d
         for s in sim._segments]
     assert not sim._levels[0].ident_attempts
+    assert all(s.any_leaf for s in sim._segments if isinstance(s, ScanBucket))
     assert sim._copula_active and sim._retry_active
     ref = walk_retries.walk(graph, MODEL)
     assert set(ref.edge_retries.values()) == {0, retries}
@@ -229,7 +251,7 @@ def test_the_traced_program_names_the_attempt_loop_and_the_copula(tmp_path):
     program, in the unrolled level and inside the bucket's scan body."""
     import importlib.util
 
-    graph = dump(tmp_path / "small.yaml", cell_doc(8, "20%", 2, 0))
+    graph = dump(tmp_path / "small.yaml", cell_doc(9, "20%", 2, 0))
     simulate(graph, tmp_path, "run", "--qps", "400", "--duration", "2s")
     scopes = {scope for ops in telemetry.program_scopes().values()
               for scope in ops.values()}
@@ -279,7 +301,7 @@ def test_the_retry_cell_is_judged_by_its_own_pair_end_to_end(
             "precheck.executions_outside_buckets",
             "window.engine_retraces"} <= set(result["compared"])
     # executed hop-events, read off the artifacts: 50 a request and a
-    # retry now and then, of 7,456 columns computed
+    # retry now and then, of 197 columns computed
     window = by_line["window"]
     per_request = window["hop_events"] / (window["calls"] * 3968)
     assert 49.9 < per_request < 50.1

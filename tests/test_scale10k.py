@@ -46,12 +46,17 @@ def test_star10k_with_timeouts_keeps_sparse_encoding():
     # served run of this graph is tests/test_star10k.py
     # BASELINE configs[3] names retries/timeouts on the 10k graph; this
     # test passes a TIMEOUT alone and builds the Simulator without
-    # running it: `retries: 2` on a 10,000-service multitier mesh cannot
-    # compile (attempts unroll as sibling subtrees, 3^18 columns at the
-    # deepest level).  The pin of a retry plan, and the served run of
-    # one, is tests/test_multitier50_retry2.py (50 services; the cell
-    # `multitier50_retry2_served`); `svc10k` carries configs[3]'s name
-    # without its retries (ROADMAP.md R1).  The
+    # running it: with a timeout, `retries: 2` on a 10,000-service
+    # multitier mesh cannot compile (a timed-out attempt did start the
+    # callee's script, so attempts unroll as sibling subtrees, 3^18
+    # columns at the deepest level); without one a failed attempt is a
+    # leaf and it compiles to 39,997 columns (PR 43).  The pins of a
+    # retry plan, and the served runs, are
+    # tests/test_multitier50_retry2.py and
+    # tests/test_multitier1000_retry2.py (the cells
+    # `multitier50_retry2_served`, `multitier1000_retry2_served`);
+    # `svc10k` carries configs[3]'s name without its retries
+    # (ROADMAP.md R1b).  The
     # star archetype's skewed hub level is exactly where the non-dense
     # step encodings matter (a dense grid block-starves it), and until
     # r5 finite timeouts forced the dense fallback.  Since PR 6 the

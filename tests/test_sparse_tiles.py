@@ -532,8 +532,8 @@ def test_level_encoding_decision_points():
 # the level, widest script, call slots, script widths): the widest
 # level of the 10,000-service multitier mesh is 2.66 x the graph's
 # hops, its level 3 0.54 x, level 2 of the 10,000-service star 17.6 x;
-# level 5 of the 50-service mesh under two retries is a width-1 grid
-# no tile plan shrinks, level 3 of the 100-service mesh a 108-cell one
+# level 5 of the 50-service mesh under two retries was (until PR 43) a
+# width-1 grid no tile plan shrinks, level 3 of the 100-service mesh a 108-cell one
 _MULTITIER_L8 = (1402, 19, 1164,
                  np.asarray([0] * 600 + [1] * 700 + [4] * 80 + [12] * 21
                             + [19]))
@@ -584,8 +584,9 @@ _SMALL_GRAPH_LEVEL_LESS = (204, 5, 50,
     (_STAR_L2, {"num_hops": 22_045, "tiling": False}, "dense"),
     (_STAR_L2, {"num_hops": 40_000, "tiling": False}, "dense"),
     # ... and a level whose tile plan does not halve its grid stays
-    # dense under 8 x hops: the width-1 level of the retried mesh
-    # (2,673 cells, 5.5 x its slots, 7,456 hops) at the default, at a
+    # dense under 8 x hops: the width-1 level the retried mesh had until
+    # PR 43, when every attempt had a subtree of its own (level 5: 2,673
+    # cells, 5.5 x its slots, 7,456 hops), at the default, at a
     # knob that puts the tiled floor at 0.25 x hops (1,864) and the
     # sparse one at 4 x, and sparse only past the sparse floor itself
     (_RETRY2_L5, {"num_hops": 7_456}, "dense"),
@@ -648,11 +649,20 @@ _VENDORED = {
         ("unrolled", 0), ("scan", 1, 5, 21, 6, 21, 1),
         *_unrolled(6, 7, 8, 9))),
     # PR 41: three attempts a call; the bucket's last field is the
-    # attempts it scans.  Its level 5 (2,673 x 1) is 0.36 x its hops:
-    # under the tiled floor, and no tile plan would shrink it
+    # attempts it scans.  Since PR 43 a failed attempt is a leaf: 197
+    # hops for 7,456, levels 1-4 (28 / 40 / 32 / 40 hops, under the
+    # 1,024-cell floor) in one bucket where levels 5-6 (2,673 / 1,458)
+    # were
     "realistic-multitier-50-errors-retries2.yaml": ((), (
-        *_unrolled(0, 1, 2, 3, 4), ("scan", 5, 6, 2673, 1, 729, 3),
-        ("unrolled", 7))),
+        ("unrolled", 0), ("scan", 1, 4, 44, 5, 11, 3),
+        *_unrolled(5, 6, 7))),
+    # PR 43: the same policy at 1,000 services, 3,997 hops: levels 2-8
+    # (204-644 hops x 7-14 steps, 0.55-1.9 x its hops) tile, level 9
+    # (244 x 4 = 0.24 x) stays dense, levels 10-11 share a bucket
+    "realistic-multitier-1000-errors-retries2.yaml": (
+        tuple(range(2, 9)), (
+            *_unrolled(*range(10)), ("scan", 10, 11, 136, 5, 24, 3),
+            ("unrolled", 12))),
     # levels 1 and 2 off the grid since PR 36 / PR 38; level 3 (990
     # cells, 0.1 x its hops) stays
     "star-10000.yaml": ((1, 2), _unrolled(0, 1, 2, 3, 4)),

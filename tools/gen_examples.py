@@ -105,6 +105,21 @@ def main() -> None:
         ),
     )
 
+    # the same policy on the multitier archetype at a thousand
+    # services (BASELINE.json configs[2]'s size): the benchmark's
+    # `multitier1000_retry2` (benchmark/configs/multitier1000_retry2.json),
+    # whose benchmark/topologies/ copy is this file byte for byte
+    dump(
+        "realistic-multitier-1000-errors-retries2.yaml",
+        generators.with_call_policy(
+            generators.realistic_topology(
+                num_services=1000, archetype="multitier", seed=0,
+                callee_error_rate="0.01%",
+            ),
+            retries=2,
+        ),
+    )
+
     # the star archetype at the north star's 10,000 services: the
     # benchmark's `star10k` (benchmark/configs/star10k.json), whose
     # benchmark/topologies/ copy is this file byte for byte
