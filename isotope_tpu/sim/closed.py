@@ -54,7 +54,7 @@ from __future__ import annotations
 from typing import Dict, NamedTuple, Tuple
 
 import numpy as np
-from scipy.special import gammainc
+from scipy.special import gammainc, gammaincc, gammaln
 
 from isotope_tpu import telemetry
 
@@ -392,31 +392,128 @@ def _erlang_mixture_quantiles(
     Erlang exactly; deterministic service (scv ~ 0) collapses the
     conditional wait onto its mean, which is what the DES shows (an
     exponential-stage tail overestimated M/D/k saturated p99 by +38%).
+
+    The root of every grid point is found at once, by a bracketed
+    Newton iteration (tests/test_closed.py keeps the 60 halvings over
+    all stages this replaced, as the reference):
+
+    - **The stages that count.**  The lightest stages are left out
+      while their mass together stays under 2^-53 of the smallest
+      number a comparison is made with: u at the bottom of the grid,
+      exp(-v) at the top.  What is dropped then cannot reach the last
+      bit of either side of a comparison (a census that decays 10 x a
+      stage keeps 20-24 of 62 stages; one whose mass sits high keeps
+      every stage that holds any of it).
+    - **The side that is well conditioned.**  Up to u = 1/2 a point
+      compares the CDF with u; above, the survival function with
+      exp(-v): 1 - 1.1e-7 is one float64 ulp from losing the tail, the
+      tail itself is not.
+    - **The bracket** is the 60-halvings one: mean x 4 (at least one
+      service), doubled while a point's root lies beyond it, and what
+      the doubling walked past becomes the lower end.
+    - **The start** is read off one survival curve over the bracket
+      (``np.interp`` of v against -log S), so no step is spent finding
+      the basin.
+    - **The step** is Newton's on log S + v above the median (a
+      straight line under an exponential tail) and on log F against
+      log t below it (a straight line where F ~ t^shape), with the
+      mixture's density in closed form.  A step that leaves the
+      bracket, is not finite, or is more than half the step before
+      last gives way to the midpoint, so no input does worse than the
+      bisection; a point leaves the iteration when its step falls
+      under 1e-10 of the root (the error after that step is the
+      square of it) or when its bracket is down to round-off, or to
+      2^-60 of the bracket it began with, which is as far as 60
+      halvings went.
     """
     m = np.arange(1, len(weights) + 1, dtype=np.float64)
-    u = -np.expm1(-v_grid)
     scv = min(max(float(scv), 1e-3), 25.0)
-    shape = m / scv
-    rate_g = rate / scv
+    u = -np.expm1(-v_grid)
+    q = np.exp(-v_grid)
+    upper = u > 0.5
+    target = np.where(upper, q, u)
 
-    def cdf(t: np.ndarray) -> np.ndarray:
-        # regularized lower incomplete gamma = Gamma(shape, rate_g) CDF
-        return (
-            weights[None, :] * gammainc(shape[None, :], rate_g * t[:, None])
-        ).sum(axis=1)
+    order = np.argsort(weights)
+    light = np.cumsum(weights[order]) < 2.0**-53 * min(u.min(), q.min())
+    keep = np.sort(order[~light])
+    w = weights[keep]
+    shape = m[keep] / scv
+    rate_g = rate / scv
+    log_norm = np.log(w) + np.log(rate_g) - gammaln(shape)
+
+    def mass(t: np.ndarray, up: np.ndarray) -> np.ndarray:
+        # the mixture's survival function where ``up``, else its CDF
+        # (regularized upper / lower incomplete gamma)
+        telemetry.counter_inc("closed_rate_quantile_cdf_evals")
+        x = rate_g * t[:, None]
+        out = np.empty(len(t))
+        out[~up] = (w * gammainc(shape, x[~up])).sum(axis=1)
+        out[up] = (w * gammaincc(shape, x[up])).sum(axis=1)
+        return out
+
+    def density(t: np.ndarray) -> np.ndarray:
+        x = rate_g * t[:, None]
+        return np.exp(log_norm + (shape - 1.0) * np.log(x) - x).sum(axis=1)
 
     # bracket: mean + generous multiple of the largest-stage scale
     mean = float((weights * m).sum()) / rate
-    hi = np.full(len(v_grid), max(mean * 4.0, 1.0 / rate))
-    while (cdf(hi) < u).any():
-        hi = np.where(cdf(hi) < u, hi * 2.0, hi)
-    lo = np.zeros_like(hi)
-    for _ in range(60):
-        mid = 0.5 * (lo + hi)
-        below = cdf(mid) < u
-        lo = np.where(below, mid, lo)
-        hi = np.where(below, hi, mid)
-    return 0.5 * (lo + hi)
+    edge = max(mean * 4.0, 1.0 / rate)
+    lo = np.zeros(len(v_grid))
+    hi = np.full(len(v_grid), edge)
+    while True:
+        f_edge, s_edge = mass(np.full(2, edge), np.array([False, True]))
+        beyond = (hi == edge) & np.where(upper, s_edge > q, f_edge < u)
+        if not beyond.any():
+            break
+        lo[beyond] = edge
+        edge *= 2.0
+        hi[beyond] = edge
+
+    t_tab = np.linspace(0.0, edge, len(v_grid) + 1)
+    s_tab = mass(t_tab[1:], np.ones(len(v_grid), bool))
+    with np.errstate(divide="ignore"):
+        v_tab = np.maximum.accumulate(np.append(0.0, -np.log(s_tab)))
+    t = np.interp(v_grid, v_tab, t_tab)
+    t = np.where((t > lo) & (t <= hi), t, 0.5 * (lo + hi))
+
+    floor = 2.0**-60 * edge
+    step = hi - lo
+    step_before = step.copy()
+    active = np.arange(len(v_grid))
+    # every two steps at least halve a point's bracket or its step, so
+    # the bound is never met: it keeps a nan in the weights from looping
+    for _ in range(256):
+        if not len(active):
+            break
+        t_a, up_a, goal = t[active], upper[active], target[active]
+        g = mass(t_a, up_a)
+        below = np.where(up_a, g > goal, g < goal)
+        lo_a = lo[active] = np.where(below, t_a, lo[active])
+        hi_a = hi[active] = np.where(below, hi[active], t_a)
+        with np.errstate(all="ignore"):
+            hazard = density(t_a) / g
+            cand = np.where(
+                up_a,
+                t_a + (np.log(g) + v_grid[active]) / hazard,
+                t_a * np.exp(np.log(goal / g) / (t_a * hazard)),
+            )
+            # a root on an end of its bracket: evaluate the end, not
+            # midpoints towards it
+            end = np.clip(cand, lo_a, hi_a)
+            cand = np.where(np.abs(cand - end) <= 1e-10 * t_a, end, cand)
+            newton = cand - t_a
+            landed = np.abs(newton) <= 1e-10 * t_a
+            safe = (
+                (cand >= lo_a) & (cand <= hi_a) & (cand > 0.0)
+                & (2.0 * np.abs(newton) <= np.abs(step_before[active]))
+            )
+        nxt = np.where(landed | safe, cand, 0.5 * (lo_a + hi_a))
+        step_before[active] = step[active]
+        step[active] = nxt - t_a
+        t[active] = nxt
+        tight = hi_a - lo_a <= np.maximum(2.0**-52 * hi_a, floor)
+        active = active[~(landed | tight)]
+    return t
 
 
 def repairman_marginals(

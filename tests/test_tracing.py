@@ -132,9 +132,11 @@ def test_served_call_counters(served):
         ("libyaml" if yaml.__with_libyaml__ else "python")
     assert snap.counters["closed_rate_pilot_runs"] >= 1
     assert snap.counters["artifact_bytes_written"] > 1000
-    # a paced call never reaches sim/closed.py's census
+    # a paced call never reaches sim/closed.py's census, nor its fits
     assert "closed_rate_census_sweeps" not in snap.counters
     assert "closed_rate.census" not in snap.phases
+    assert "closed_rate_quantile_cdf_evals" not in snap.counters
+    assert "closed_rate.tables_from_pi" not in snap.phases
 
 
 def test_fallback_loader_serves_the_same_call(served, tmp_path, monkeypatch):
@@ -196,12 +198,21 @@ def test_saturated_solve_names_its_fits(saturated):
         assert snap.phase_parents[child] == ["closed_rate.mva"], child
         assert snap.phases[child] > 0
     assert snap.phase_parents["closed_rate.mva"] == ["closed_rate.solve"]
+    assert snap.phases["closed_rate.tables_from_pi"] <= \
+        snap.phases["closed_rate.mva"]
     # what the four leave of the phase has a name of its own
     assert snap.phases["closed_rate.mva.self"] == pytest.approx(
         snap.phases["closed_rate.mva"] - sum(
             snap.phases[child] for child, parents
             in snap.phase_parents.items()
             if parents == ["closed_rate.mva"]), abs=1e-4)
+
+
+def test_saturated_fits_are_a_handful_of_cdf_evaluations(saturated):
+    """Six fits (five probe cycles and the solved one) of canonical's
+    two station classes: the 60 halvings over every stage made 61 or
+    more evaluations a fit, 700 and more a call."""
+    assert 0 < saturated.counters["closed_rate_quantile_cdf_evals"] < 100
 
 
 # -- (b') parents, self times and the leaves inside the host phases --------
