@@ -33,19 +33,137 @@ from isotope_tpu.models.svctype import ServiceType
 
 # libyaml scans and parses the text where the installed PyYAML carries
 # it: 4-8 x faster at every size, and the parser is nearly all of a
-# served call's ``graph.decode``.  The constructor and resolver are
-# SafeLoader's under both, so documents and error classes are equal.
+# served call's ``graph.decode``.  The resolver and the scalar
+# constructors are SafeLoader's under both, so documents and error
+# classes are equal.
 _LOADER = getattr(yaml, "CSafeLoader", None) or yaml.SafeLoader
+
+_NO_KEY = object()   # a mapping that waits for its next key
+
+# whether the builder, and not ``yaml.load``, made the document that
+# ``_load`` returned last
+_direct = False
 
 
 def parses_with_libyaml() -> bool:
     return _LOADER is not yaml.SafeLoader
 
 
+def loaded_directly() -> bool:
+    """Whether the last document came straight from the parser's events
+    (``_build_document``) and not from ``yaml.load``, which takes over
+    where a text uses what the builder does not recognise."""
+    return _direct
+
+
+class _Unrecognised(Exception):
+    """The text holds what only ``yaml.load`` handles."""
+
+
+def _build_document(text: str):
+    """The document of ``text`` built from the parser's events as they
+    arrive: no node tree, no generic constructor.
+
+    Block and flow mappings and sequences, quoted and block scalars
+    (``str``) and plain scalars, which PyYAML's own tables resolve and
+    construct: the loader's ``yaml_implicit_resolvers`` as
+    ``Resolver.resolve`` reads them, then its ``yaml_constructors``.
+    Raises on anything else - an anchor, an alias, an explicit tag or
+    directive, ``<<``, ``=``, a key that is not a scalar, no document
+    or a second one, and whatever the scanner, the parser or a scalar's
+    constructor raises - and the caller hands the text to ``yaml.load``.
+    """
+    loader = _LOADER(text)
+    try:
+        return _walk(loader)
+    finally:
+        loader.dispose()
+
+
+def _walk(loader):
+    get_event = loader.get_event
+    constructors = loader.yaml_constructors
+    resolvers = loader.yaml_implicit_resolvers
+    wildcard = resolvers.get(None, [])
+    # Resolver.resolve's candidates for a plain scalar, by first character
+    by_first = {first: found + wildcard
+                for first, found in resolvers.items() if first is not None}
+
+    def construct(tag, value):
+        if tag not in constructors:   # merge, value, yaml
+            raise _Unrecognised(tag)
+        return constructors[tag](loader, yaml.ScalarNode(tag, value))
+
+    if type(get_event()) is not yaml.StreamStartEvent:
+        raise _Unrecognised("stream")
+    event = get_event()
+    if (type(event) is not yaml.DocumentStartEvent
+            or event.version is not None or event.tags is not None):
+        raise _Unrecognised("document")
+    root = container = None   # the innermost open list or dict
+    is_map = False
+    key = _NO_KEY
+    outer = []   # the open containers around it
+    while True:
+        event = get_event()
+        kind = type(event)
+        if kind is yaml.ScalarEvent:
+            opened = None
+            value = event.value
+            if event.implicit[0]:
+                for tag, regexp in by_first.get(value[:1], wildcard):
+                    if regexp.match(value):
+                        value = construct(tag, value)
+                        break
+        elif kind is yaml.MappingStartEvent:
+            opened = value = {}
+        elif kind is yaml.SequenceStartEvent:
+            opened = value = []
+        elif kind is yaml.MappingEndEvent or kind is yaml.SequenceEndEvent:
+            container, is_map = outer.pop()
+            continue
+        elif kind is yaml.DocumentEndEvent:
+            break
+        else:
+            raise _Unrecognised("alias")
+        if event.anchor is not None or event.tag is not None:
+            raise _Unrecognised("anchor or tag")
+        if is_map:
+            if key is _NO_KEY:
+                if opened is not None:
+                    raise _Unrecognised("key")
+                key = value
+            else:
+                container[key] = value
+                key = _NO_KEY
+        elif container is None:
+            root = value
+        else:
+            container.append(value)
+        if opened is not None:
+            outer.append((container, is_map))
+            container = opened
+            is_map = kind is yaml.MappingStartEvent
+    if type(get_event()) is not yaml.StreamEndEvent:
+        raise _Unrecognised("second document")
+    return root
+
+
 @telemetry.phase("graph.decode.yaml")
 def _load(text: str):
     """The document of a topology's text: the scanner and parser
-    (libyaml's where present) and PyYAML's constructor."""
+    (libyaml's where present), then the direct builder, or PyYAML's
+    composer and constructor where the builder raised.  ``yaml.load``
+    is the reference: it returns or raises what it always did."""
+    global _direct
+    try:
+        doc = _build_document(text)
+        _direct = True
+        return doc
+    except Exception:
+        _direct = False
+    # outside the handler: its errors are raised as they always were,
+    # with no builder's error chained behind them
     return yaml.load(text, Loader=_LOADER)
 
 

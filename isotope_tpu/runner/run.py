@@ -42,7 +42,11 @@ from isotope_tpu.metrics.fortio import (
     write_json,
 )
 from isotope_tpu.metrics.prometheus import MetricsCollector
-from isotope_tpu.models.graph import ServiceGraph, parses_with_libyaml
+from isotope_tpu.models.graph import (
+    ServiceGraph,
+    loaded_directly,
+    parses_with_libyaml,
+)
 from isotope_tpu.parallel import (
     MeshSpec,
     ShardedSimulator,
@@ -198,6 +202,11 @@ class _LazyTopology:
             # where the installed PyYAML lacks it (the slow loader)
             libyaml = parses_with_libyaml()
             telemetry.counter_inc("graphs_decoded_libyaml", int(libyaml))
+            # equal to graphs_decoded where the document came straight
+            # from the parser's events, 0 where the text uses what only
+            # yaml.load handles (an anchor, a tag, a merge key)
+            telemetry.counter_inc("graphs_decoded_direct",
+                                  int(loaded_directly()))
             telemetry.set_meta("yaml_parser",
                                "libyaml" if libyaml else "python")
             self._graph = graph

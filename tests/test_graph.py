@@ -3,9 +3,11 @@
 Coverage mirrors the reference's graph/unmarshal_test.go end-to-end fixture
 (defaults inheritance) and validation.go error cases.
 """
+import datetime
 import functools
 import glob
 import importlib.util
+import random
 import sys
 
 import pytest
@@ -256,6 +258,15 @@ def test_topology_decodes_alike_under_both_loaders(loader_mod, path):
         assert got == want  # the dataclasses themselves, not only encode()
 
 
+def assert_same_error(got, want):
+    assert type(got) is type(want)
+    # parser messages differ in wording; the place they point at does not
+    mark, want_mark = (getattr(e, "problem_mark", None) for e in (got, want))
+    assert (mark is None) == (want_mark is None)
+    if mark is not None:
+        assert (mark.line, mark.column) == (want_mark.line, want_mark.column)
+
+
 @pytest.mark.parametrize("case", sorted(MALFORMED))
 def test_malformed_text_raises_alike_under_both_loaders(loader_mod, case):
     text = MALFORMED[case]
@@ -263,16 +274,172 @@ def test_malformed_text_raises_alike_under_both_loaders(loader_mod, case):
         ServiceGraph.decode(yaml.load(text, Loader=yaml.SafeLoader))
     with pytest.raises((yaml.YAMLError, ValueError)) as got:
         loader_mod.ServiceGraph.from_yaml(text)
-    assert type(got.value) is type(want.value)
-    # parser messages differ in wording; the place they point at does not
-    mark, want_mark = (getattr(e.value, "problem_mark", None)
-                       for e in (got, want))
-    assert (mark is None) == (want_mark is None)
-    if mark is not None:
-        assert (mark.line, mark.column) == (want_mark.line, want_mark.column)
+    assert_same_error(got.value, want.value)
 
 
 def test_duplicate_key_resolves_alike_under_both_loaders(loader_mod):
     text = "services:\n- name: a\n  numReplicas: 2\n  numReplicas: 3\n"
     assert loader_mod.ServiceGraph.from_yaml(text).services[0].num_replicas \
         == 3
+
+
+# -- the direct document builder, against yaml.load (ISSUE 45) ------------
+
+def same(a, b):
+    """Equal in value and in type at every leaf: ``1``, ``True``,
+    ``1.0`` and ``"1"`` all differ, a NaN equals a NaN, and a mapping's
+    keys come in the same order."""
+    if type(a) is not type(b):
+        return False
+    if isinstance(a, dict):
+        return len(a) == len(b) and all(
+            same(ka, kb) and same(a[ka], b[kb]) for ka, kb in zip(a, b)
+        )
+    if isinstance(a, list):
+        return len(a) == len(b) and all(map(same, a, b))
+    return repr(a) == repr(b)
+
+
+def outcome(load, text):
+    """What ``load(text)`` returns, or the error it raises."""
+    try:
+        return load(text)
+    except (yaml.YAMLError, ValueError) as e:
+        return e
+
+
+def assert_loads_like_yaml_load(loader_mod, text, direct):
+    """``_load(text)`` returns or raises what ``yaml.load`` does with
+    ``SafeLoader``, and the builder (``direct``) or the reference path
+    produced it."""
+    want = outcome(lambda t: yaml.load(t, Loader=yaml.SafeLoader), text)
+    got = outcome(loader_mod._load, text)
+    assert loader_mod.loaded_directly() == direct
+    if isinstance(want, Exception):
+        assert_same_error(got, want)
+    else:
+        assert same(got, want), (got, want)
+
+
+def test_same_tells_types_apart():
+    assert same({"a": [1, float("nan")]}, {"a": [1, float("nan")]})
+    for a, b in ((1, True), (1, 1.0), (1, "1"), (None, "null"),
+                 ({1: "a"}, {True: "a"}), ({"a": 1, "b": 2}, {"b": 2, "a": 1}),
+                 ([1], [1, 1]), (0.0, -0.0)):
+        assert not same(a, b) and same(a, a) and same(b, b)
+
+
+@pytest.mark.parametrize("path", TOPOLOGIES)
+def test_builder_makes_the_topologys_document(loader_mod, path):
+    with open(path) as f:
+        text = f.read()
+    assert_loads_like_yaml_load(loader_mod, text, direct=True)
+
+
+SCALARS = [
+    "12", "1_000", "0x1F", "0o17", "017", "1:30", "-7", ".5", "1e3",
+    "1.0e+3", ".inf", "-.INF", ".nan", "~", "null", "", "yes", "No", "ON",
+    "off", "true", "2001-12-14", "2001-12-14t21:59:43.10-05:00", "10ms",
+    "0.01%", "128 KB", "mock-1", "gr\u00f6\u00dfe-\u670d\u52a1",
+]
+
+BLOCK = """\
+k: {0}
+  {1}
+seq:
+- {0}
+  {1}
+? {0}
+  {1}
+: v
+"""
+
+#: a scalar as a mapping's value, a sequence's item and a mapping's key
+FORMS = {
+    "plain": "k: {0}\nseq:\n- {0}\n? {0}\n: v\n".format,
+    "single_quoted": "k: '{0}'\nseq:\n- '{0}'\n'{0}': v\n".format,
+    "double_quoted": 'k: "{0}"\nseq:\n- "{0}"\n"{0}": v\n'.format,
+    "literal": functools.partial(BLOCK.format, "|"),
+    "folded": functools.partial(BLOCK.format, ">"),
+    "flow": "{{k: [yes, {0}], seq: {{a: {0}, b: 1}}, ? {0} : v}}\n".format,
+}
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+@pytest.mark.parametrize("scalar", SCALARS, ids=lambda s: s or "empty")
+def test_builder_resolves_scalars_as_yaml_load_does(loader_mod, scalar, form):
+    assert_loads_like_yaml_load(loader_mod, FORMS[form](scalar), direct=True)
+
+
+def random_document(rng, depth=0):
+    """A nested dict / list / scalar, from ``rng`` alone."""
+    def scalar():
+        return rng.choice([
+            rng.randrange(-10**6, 10**6), rng.random() * 10**rng.randrange(9),
+            float("inf"), float("nan"), True, False, None, "", "12", "yes",
+            "~", "a: b", " padded ", "two\nlines\n", "- dash", "#hash",
+            "svc-%d" % rng.randrange(100), "%dms" % rng.randrange(100),
+            "k" * rng.randrange(1, 200), "na\u00efve \u670d\u52a1",
+            datetime.date(2001, 1, 1 + rng.randrange(28)),
+        ])
+    kind = rng.random()
+    if depth < 4 and kind < 0.35:
+        return {scalar(): random_document(rng, depth + 1)
+                for _ in range(rng.randrange(5))}
+    if depth < 4 and kind < 0.7:
+        return [random_document(rng, depth + 1)
+                for _ in range(rng.randrange(5))]
+    return scalar()
+
+
+@pytest.mark.parametrize("flow_style", [False, None, True])
+@pytest.mark.parametrize("seed", range(8))
+def test_builder_loads_what_safe_dump_writes(loader_mod, seed, flow_style):
+    rng = random.Random(seed)
+    doc = [random_document(rng) for _ in range(12)]
+    text = yaml.safe_dump(doc, default_flow_style=flow_style,
+                          allow_unicode=bool(seed % 2))
+    assert_loads_like_yaml_load(loader_mod, text, direct=True)
+
+
+#: what the builder leaves to yaml.load
+REFERENCE_ONLY = {
+    "anchor_and_alias": "a: &x [1, 2]\nb: *x\n",
+    "merge_key": "base: &b {a: 1}\nd:\n  <<: *b\n  c: 3\n",
+    "merge_key_inline": "d:\n  <<: {a: 1}\n  c: 3\n",
+    "explicit_tag": "a: !!str 5\n",
+    "explicit_tag_on_a_mapping": "a: !!map {b: 1}\n",
+    "non_specific_tag": "a: ! 5\n",
+    "sequence_as_key": "? [a, b]\n: 1\n",
+    "mapping_as_key": "? {a: b}\n: 1\n",
+    "value_indicator": "a: =\n",
+    "merge_indicator_as_a_value": "a: <<\n",
+    "two_documents": "a: 1\n---\nb: 2\n",
+    "python_object": "a: !!python/object:os.system {}\n",
+    "yaml_directive": "%YAML 1.1\n---\na: 1\n",
+    "no_such_date": "a: 2001-13-45\n",
+    "empty_text": "",
+    "comment_only": "# nothing here\n",
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFERENCE_ONLY))
+def test_builder_leaves_the_rest_to_yaml_load(loader_mod, case):
+    assert_loads_like_yaml_load(loader_mod, REFERENCE_ONLY[case],
+                                direct=False)
+
+
+@pytest.mark.parametrize("case", sorted(MALFORMED))
+def test_malformed_text_takes_the_reference_path(loader_mod, case):
+    # "not_a_mapping" is a fine document; ServiceGraph.decode refuses it
+    assert_loads_like_yaml_load(loader_mod, MALFORMED[case],
+                                direct=case == "not_a_mapping")
+
+
+def test_load_reports_each_document_apart(loader_mod):
+    loader_mod._load("a: 1\n")
+    assert loader_mod.loaded_directly()
+    loader_mod._load("a: &x 1\n")
+    assert not loader_mod.loaded_directly()
+    loader_mod._load("a: 1\n")
+    assert loader_mod.loaded_directly()

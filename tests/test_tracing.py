@@ -32,14 +32,15 @@ CASE_LEAVES = (
 CALL_LEAVES = CASE_LEAVES + ("artifacts.write", "cli.parse", "cli.config")
 
 
-def serve(tmp_path, tag, seed=7, connections=4, qps="100"):
+def serve(tmp_path, tag, seed=7, connections=4, qps="100",
+          topology=TOPOLOGY):
     """One ``isotope-tpu simulate`` in-process: (rc, stdout, .prom)."""
     prom = tmp_path / f"{tag}.prom"
     out = io.StringIO()
     with contextlib.redirect_stdout(out), \
             contextlib.redirect_stderr(io.StringIO()):
         rc = cli.main([
-            "simulate", TOPOLOGY, "--qps", qps, "-c", str(connections),
+            "simulate", str(topology), "--qps", qps, "-c", str(connections),
             "--duration", "20s", "--load-kind", "closed", "--seed",
             str(seed), "--prometheus", str(prom), "--no-degrade",
             "--compile-cache", "off",
@@ -130,6 +131,8 @@ def test_served_call_counters(served):
         int(yaml.__with_libyaml__)
     assert snap.meta["yaml_parser"] == \
         ("libyaml" if yaml.__with_libyaml__ else "python")
+    # ... into a document built straight from the parser's events
+    assert snap.counters["graphs_decoded_direct"] == 1
     assert snap.counters["closed_rate_pilot_runs"] >= 1
     assert snap.counters["artifact_bytes_written"] > 1000
     # a paced call never reaches sim/closed.py's census, nor its fits
@@ -150,6 +153,24 @@ def test_fallback_loader_serves_the_same_call(served, tmp_path, monkeypatch):
     assert snap.counters["graphs_decoded"] == 1
     assert snap.counters["graphs_decoded_libyaml"] == 0
     assert snap.meta["yaml_parser"] == "python"
+    # the builder reads the Python parser's events as it reads libyaml's
+    assert snap.counters["graphs_decoded_direct"] == 1
+
+
+def test_reference_path_serves_the_same_call(served, tmp_path):
+    """A topology whose text uses what the direct builder does not
+    recognise (here a ``%YAML`` directive) goes through ``yaml.load``:
+    the counter reads 0, the artifacts are the same bytes."""
+    topology = tmp_path / "canonical.yaml"
+    with open(TOPOLOGY) as f:
+        topology.write_text("%YAML 1.1\n---\n" + f.read())
+    telemetry.reset()
+    rc, _, prom = serve(tmp_path, "reference", topology=topology)
+    snap = telemetry.snapshot()
+    assert rc == 0 and prom == served[2]
+    assert snap.counters["graphs_decoded"] == 1
+    assert snap.counters["graphs_decoded_direct"] == 0
+    assert snap.phases["graph.decode.yaml"] > 0
 
 
 def test_sharded_call_accrues_its_phases(served, tmp_path):
