@@ -367,7 +367,7 @@ class ShardedSimulator:
             visits_pc, phase_windows,
             self.sim._observers(shape[0], attr, timeline, tail_cut),
             shards=self.n_shards,
-        )
+        )[:2]
 
     def _body(self, shape: tuple, attr, timeline, *args):
         """The shard_map body: the local scan, then the summary's

@@ -3826,20 +3826,13 @@ class Simulator:
                        roll: bool = False):
         """Jitted scan-over-blocks program co-simulating the in-graph
         control planes — the PR 9 policy loops, the rollout controller,
-        or BOTH in the same carry: carry = (clocks, timeline
-        accumulator[, (S, 2, W, 4) per-version observation accumulator,
-        rollout state, rollout series][, policy obs/state/series][,
-        exemplar state]).  An absent layer rides as ``None`` (an empty
-        pytree — the traced program never mentions it), so ONE body
-        serves both protected runners and a fix applied to the policy
-        wiring cannot diverge the composed path (the same rationale as
-        parallel/sharded.py's ``_prot_body``).
+        or BOTH: :func:`~isotope_tpu.sim.blockscan.block_scan` with
+        the run's :func:`~isotope_tpu.sim.blockscan.control_plane`.
 
         Return ordering (the runner unpacks by construction):
         ``roll`` -> (summary, tl, roll[, pol][, attr]); policies-only
         -> (summary, tl, pol[, attr])."""
-        from isotope_tpu.metrics import timeline as timeline_mod
-        from isotope_tpu.sim import summary as summary_mod
+        from isotope_tpu.sim import blockscan
 
         with_pol = self._policies is not None
         tag = "rollouts" if roll else "policies"
@@ -3847,35 +3840,12 @@ class Simulator:
                      collector is not None, trim, tl_plan, attr,
                      with_pol, tag)
         if cache_key not in self._summary_fns:
-            c = max(connections, 1)
-            per = block // c
-            tspec = timeline_mod.build_spec(
-                self.compiled, tl_plan[0], tl_plan[1]
-            )
-            S = self.compiled.num_services
-            W = tspec.num_windows
-            packed = self.params.packed_carries
-            if roll:
-                from isotope_tpu.sim import rollout as rollout_mod
-
-                rdtab = rollout_mod.device_tables(self._rollouts)
-            if with_pol:
-                from isotope_tpu.sim import policies as policies_mod
-
-                pdtab = policies_mod.device_tables(self._policies)
-                # rollout runs split the canary-first kill delta off
-                # the baseline arm the autoscaler manages
-                downed_w = self._policy_downed_windows(
-                    tspec, base_split=roll
-                )
-                stuck = faults.stuck_breaker()
-                lag = faults.autoscaler_lag()
-                retry_mask = jnp.asarray(self.compiled.hop_attempt > 0)
+            control = blockscan.control_plane(self, tl_plan, roll)
             if attr is not None:
-                from isotope_tpu.metrics import attribution
-
-                atables = self._attribution_tables()
-                top_k = self.params.attribution_top_k
+                # eager: built inside the trace, the cached tables
+                # would hold tracers
+                self._attribution_tables()
+            shape = (block, num_blocks, kind, connections, trim, 0)
 
             def scanfn(key, offered_qps, pace_gap, arrival_qps,
                        nominal_gap, win_lo, win_hi, tail_cut,
@@ -3885,146 +3855,14 @@ class Simulator:
                     tracing=isinstance(key, jax.core.Tracer),
                     requests=block, hops=self.compiled.num_hops,
                 )
-
-                def body(carry, b):
-                    ((t0, conn_t0, req_off), tl_acc, robs_acc,
-                     rstate, roll_acc, pobs_acc, pstate, pol_acc,
-                     ex) = carry
-                    rfx = rollout_mod.effects(rstate) if roll else None
-                    pfx = (
-                        policies_mod.effects(pstate)
-                        if with_pol else None
-                    )
-                    kb = jax.random.fold_in(key, 1_000_000 + b)
-                    res, t_end, conn_end = self._simulate_core(
-                        block, kind, connections, kb, offered_qps,
-                        pace_gap, arrival_qps, nominal_gap, t0,
-                        conn_t0, req_off,
-                        visits_pc=visits_pc,
-                        phase_windows=phase_windows,
-                        policy_fx=pfx,
-                        rollout_fx=rfx,
-                    )
-                    s = summary_mod.summarize(
-                        res, collector,
-                        window=(win_lo, win_hi) if trim else None,
-                    )
-                    tl_acc = timeline_mod.accumulate(
-                        tl_acc,
-                        timeline_mod.timeline_block(
-                            res, tspec, packed=packed
-                        ),
-                    )
-                    # closed loop: a window is final only once the
-                    # SLOWEST connection passed it — later blocks on
-                    # faster connections still write into windows
-                    # before conn_end.max()
-                    t_done = (
-                        jnp.min(conn_end)
-                        if kind == CLOSED_LOOP
-                        else t_end
-                    )
-                    if roll:
-                        robs_acc = (
-                            robs_acc
-                            + rollout_mod.observe_block(res, tspec)
-                        )
-                        rstate, rdelta = rollout_mod.advance(
-                            rstate, rdtab, robs_acc, t_done, tspec
-                        )
-                        roll_acc = rollout_mod.accumulate_summary(
-                            roll_acc, rdelta
-                        )
-                    if with_pol:
-                        pobs_acc = (
-                            pobs_acc
-                            + policies_mod.observe_block(
-                                res, tspec, retry_mask
-                            )
-                        )
-                        pstate, pdelta = policies_mod.advance(
-                            pstate, pdtab, tl_acc, pobs_acc, t_done,
-                            tspec, stuck_breaker=stuck,
-                            downed_w=downed_w,
-                        )
-                        pol_acc = policies_mod.accumulate_summary(
-                            pol_acc, pdelta
-                        )
-                    ys = s
-                    if attr is not None:
-                        a, ex = attribution.attribute_block(
-                            res, atables,
-                            tail_cut=(
-                                tail_cut if attr == "tail" else None
-                            ),
-                            top_k=top_k, ex_state=ex,
-                            packed=packed,
-                        )
-                        ys = (s, a)
-                    return (
-                        (t_end, conn_end, req_off + per),
-                        tl_acc, robs_acc, rstate, roll_acc,
-                        pobs_acc, pstate, pol_acc, ex,
-                    ), ys
-
-                ex0 = None
-                if attr is not None:
-                    k0 = min(top_k, block) if top_k > 0 else 0
-                    H = self.compiled.num_hops
-                    ex0 = (
-                        attribution.empty_exemplars(k0, H)
-                        if k0 > 0
-                        else None
-                    )
-                carry0 = (
-                    (
-                        jnp.float32(0.0),
-                        jnp.zeros((c,), jnp.float32),
-                        jnp.float32(0.0),
-                    ),
-                    timeline_mod.zeros_summary(tspec, packed=packed),
-                    jnp.zeros((S, 2, W, 4)) if roll else None,
-                    rollout_mod.init_state(rdtab) if roll else None,
-                    (
-                        rollout_mod.zeros_summary(tspec, S)
-                        if roll else None
-                    ),
-                    jnp.zeros((S, W)) if with_pol else None,
-                    (
-                        policies_mod.init_state(pdtab, lag_periods=lag)
-                        if with_pol else None
-                    ),
-                    (
-                        policies_mod.zeros_summary(tspec, S)
-                        if with_pol else None
-                    ),
-                    ex0,
+                summary, observed, (_, ctl) = blockscan.block_scan(
+                    self, collector, shape, key, offered_qps, pace_gap,
+                    arrival_qps, nominal_gap, win_lo, win_hi,
+                    visits_pc, phase_windows,
+                    self._observers(block, attr, None, tail_cut),
+                    control=control,
                 )
-                (
-                    (_, tl_final, robs_final, _, roll_final, _, _,
-                     pol_final, ex_final),
-                    ys,
-                ) = jax.lax.scan(body, carry0, jnp.arange(num_blocks))
-                if roll:
-                    roll_final = rollout_mod.attach_observations(
-                        roll_final, robs_final
-                    )
-                if attr is not None:
-                    parts, aparts = ys
-                    summary = summary_mod.reduce_stacked(parts)
-                    a_out = attribution.reduce_stacked(
-                        aparts, ex_final
-                    )
-                else:
-                    summary = summary_mod.reduce_stacked(ys)
-                out = (summary, tl_final)
-                if roll:
-                    out = out + (roll_final,)
-                if with_pol:
-                    out = out + (pol_final,)
-                if attr is not None:
-                    out = out + (a_out,)
-                return out
+                return (summary, *control.finish(ctl), *observed)
 
             self._summary_fns[cache_key] = executable_cache.get_or_jit(
                 (tag, self.signature) + cache_key,
@@ -4593,7 +4431,7 @@ class Simulator:
                     tracing=isinstance(key, jax.core.Tracer),
                     requests=block, hops=self.compiled.num_hops,
                 )
-                summary, observed = blockscan.block_scan(
+                summary, observed, _ = blockscan.block_scan(
                     self, collector, shape, key, offered_qps, pace_gap,
                     arrival_qps, nominal_gap, win_lo, win_hi,
                     visits_pc, phase_windows,
