@@ -2464,47 +2464,39 @@ class Simulator:
                    attr: Optional[str] = None,
                    tl_plan: Optional[Tuple[int, float]] = None,
                    prot: Optional[str] = None):
-        """The ONE universal member block-scan program every fleet
-        maps — plain, observed, protected, and search-bracket members
-        are all flag combinations of the same body, with every layer
-        an OPTIONAL leaf of one scan carry: absent layers ride as
-        ``None`` and vanish from the jaxpr.
+        """The member program every fleet maps: one stream through
+        :func:`~isotope_tpu.sim.blockscan.block_scan`, whose flags
+        choose the observers, the control planes and the core keywords
+        — plain, observed, protected and search-bracket members are
+        all calls of the one loop.
 
-        Body-identical to the plain ``_get_summary`` scan (same
-        fold_in layout, same summarize/reduce), so a seeds-only member
-        reproduces its solo ``run_summary`` twin bit-for-bit; the
-        jitter scales thread into ``_simulate_core`` only when
-        ``jittered`` (the seeds-only fleet trace stays the solo trace,
-        just batched).
+        A seeds-only member therefore reproduces its solo twin
+        (``run_summary`` / ``run_attributed`` / ``run_timeline`` /
+        ``run_policies`` / ``run_rollouts``) bit-for-bit; the jitter
+        scales reach ``_simulate_core`` only when ``jittered`` (the
+        seeds-only fleet trace stays the solo trace, just batched).
 
         ``carry_io`` is the search-bracket contract (sim/search.py):
         the member takes extra traced arguments after the ten standard
-        ones — a block offset ``b0`` plus the flattened scan-carry
-        leaves (plain members: ``(t0, conn_t0, req_off)``; protected
-        members: every leaf of :meth:`_protected_carry0`) — and
-        returns ``(out, carry_out)``.  The per-block RNG folds
-        ``1_000_000 + b0 + b`` so a member resumed at ``b0`` draws the
-        EXACT streams the unbroken run drew for those blocks; with
-        ``b0 == 0`` and zero carries the program is value-identical to
-        the plain member (pinned by tests/test_search.py).
+        ones — a block offset ``b0`` plus the flattened leaves of the
+        loop's resumable carry (plain members: the clocks ``(t0,
+        conn_t0, req_off)``; protected members: ``(clocks,
+        control_plane(...).init())``, what
+        :meth:`zero_protected_carry` stacks) — and returns ``(out,
+        carry_out)``.  A member resumed at ``b0`` draws the EXACT
+        streams the unbroken run drew for those blocks; with ``b0 ==
+        0`` and zero carries the program is value-identical to the
+        plain member (pinned by tests/test_search.py).
 
-        ``attr`` / ``tl_plan`` arm the fleet observability pass: the
-        member reduces an ``AttributionSummary`` (blame exemplar state
-        in the scan carry, per-block blame vectors/hists in the
-        stacked ys) and/or a ``TimelineSummary`` (carry-resident, the
-        PR 7 recorder body), returning ``(summary[, tl][, attr])``.
-        With ``attr`` the member takes ONE extra traced argument
-        before the chaos rows: its ``tail_cut`` (``+inf`` = mean
-        attribution).  Member k's blame/windows are bit-identical to
-        its solo ``run_attributed`` / ``run_timeline`` twin.
+        ``attr`` / ``tl_plan`` arm the fleet observability pass
+        (:meth:`_observers`): the member returns ``(summary[, tl][,
+        attr])``.  With ``attr`` the member takes ONE extra traced
+        argument before the chaos rows: its ``tail_cut`` (``+inf`` =
+        mean attribution).
 
-        ``prot`` arms the protected layers: ``"policies"`` /
-        ``"rollouts"`` thread the control state (breakers / budgets /
-        HPA, rollout controller) through the carry exactly like the
-        solo ``_get_protected`` body, returning
-        ``(summary, tl[, roll][, pol][, attr])`` — a seeds-only
-        member reproduces its solo ``run_policies`` / ``run_rollouts``
-        twin bit-for-bit.
+        ``prot`` (``"policies"`` / ``"rollouts"``) arms the control
+        planes over ``tl_plan``'s windows, returning ``(summary, tl[,
+        roll][, pol][, attr])``.
 
         ``member_chaos`` appends the member's stacked chaos rows — the
         composition's ``chaos_fx_layout`` fields (eff replicas, outage
@@ -2513,9 +2505,8 @@ class Simulator:
         ungraceful-kill reset rows, saturated finite-population
         tables), plus, under policies, the recorder-window down table
         the autoscaler's alive-capacity denominator reads — as
-        trailing traced arguments.  With everything off this member
-        program is the historical one, untouched."""
-        from isotope_tpu.sim import summary as summary_mod
+        trailing traced arguments."""
+        from isotope_tpu.sim import blockscan
 
         protected = prot is not None
         roll = prot == "rollouts"
@@ -2539,37 +2530,10 @@ class Simulator:
                 "attribution/timeline reductions (screen first, then "
                 "explain the winner with an observed fleet)"
             )
-        c = max(connections, 1)
-        per = block // c
-        observed = attr is not None or tl_plan is not None
-        packed = self.params.packed_carries
         if attr is not None:
-            from isotope_tpu.metrics import attribution
-
-            # trace constants (tables/top_k) build OUTSIDE the member
-            # body — inside they would be cached as tracers and leak
-            atables = self._attribution_tables()
-            top_k = self.params.attribution_top_k
-        if tl_plan is not None:
-            from isotope_tpu.metrics import timeline as timeline_mod
-
-            tspec = timeline_mod.build_spec(
-                self.compiled, tl_plan[0], tl_plan[1]
-            )
-        if roll:
-            from isotope_tpu.sim import rollout as rollout_mod
-
-            rdtab = rollout_mod.device_tables(self._rollouts)
-        if with_pol:
-            from isotope_tpu.sim import policies as policies_mod
-
-            pdtab = policies_mod.device_tables(self._policies)
-            downed_w_const = self._policy_downed_windows(
-                tspec, base_split=roll
-            )
-            stuck = faults.stuck_breaker()
-            lag = faults.autoscaler_lag()
-            retry_mask = jnp.asarray(self.compiled.hop_attempt > 0)
+            # eager: built inside the member trace, the cached tables
+            # would hold tracers
+            self._attribution_tables()
         if member_chaos:
             from isotope_tpu.compiler.compile import chaos_fx_layout
 
@@ -2581,11 +2545,8 @@ class Simulator:
             ("rollouts-fleet" if roll else "policies-fleet")
             if protected else "ensemble"
         )
-
-        def zero_carry(ex0=None):
-            return self._protected_carry0(
-                connections, tl_plan, roll=roll, with_pol=with_pol
-            )[:-1] + (ex0,)
+        shape = (block, num_blocks, kind, connections, trim,
+                 connections if sat else 0)
 
         def member_scan(key, offered_qps, pace_gap, nominal_gap,
                         win_lo, win_hi, visits_pc, phase_windows,
@@ -2612,230 +2573,55 @@ class Simulator:
                     requests=block * num_blocks,
                     hops=self.compiled.num_hops,
                 )
-            b0 = 0
-            tail_cut = None
-            chaos_rows = ()
+            b0, carry_leaves, tail_cut, chaos_rows = 0, None, None, ()
             if carry_io:
-                b0 = rest[0]
-                carry_leaves = rest[1:]
+                b0, carry_leaves = rest[0], rest[1:]
             else:
-                pos = 0
                 if attr is not None:
-                    tail_cut = rest[0]
-                    pos = 1
-                chaos_rows = rest[pos:pos + n_rows]
+                    tail_cut, rest = rest[0], rest[1:]
+                chaos_rows = rest[:n_rows]
+            core_kw = {}
+            if jittered:
+                core_kw.update(cpu_scale=cpu_scale, err_scale=err_scale)
+            downed_w = None
             if member_chaos:
-                cfx = self._member_chaos_fx(
+                core_kw["chaos_fx"] = self._member_chaos_fx(
                     chaos_rows[:len(layout)], layout
                 )
-                downed_w = (
-                    chaos_rows[len(layout)] if with_pol else None
-                )
-            else:
-                cfx = None
-                downed_w = downed_w_const if with_pol else None
-
-            def body(carry, b):
-                ((t0, conn_t0, req_off), tl_acc, robs_acc,
-                 rstate, roll_acc, pobs_acc, pstate, pol_acc,
-                 ex) = carry
-                rfx = rollout_mod.effects(rstate) if roll else None
-                pfx = (
-                    policies_mod.effects(pstate)
-                    if with_pol else None
-                )
-                kb = jax.random.fold_in(key, 1_000_000 + b0 + b)
-                res, t_end, conn_end = self._simulate_core(
-                    block, kind, connections, kb, offered_qps,
-                    pace_gap, offered_qps, nominal_gap, t0,
-                    conn_t0, req_off,
-                    sat_conns=connections if sat else 0,
-                    visits_pc=visits_pc,
-                    phase_windows=phase_windows,
-                    policy_fx=pfx,
-                    rollout_fx=rfx,
-                    cpu_scale=cpu_scale if jittered else None,
-                    err_scale=err_scale if jittered else None,
-                    chaos_fx=cfx,
-                )
-                s = summary_mod.summarize(
-                    res, None,
-                    window=(win_lo, win_hi) if trim else None,
-                )
-                if tl_plan is not None:
-                    tl_acc = timeline_mod.accumulate(
-                        tl_acc,
-                        timeline_mod.timeline_block(
-                            res, tspec, packed=packed
-                        ),
-                    )
-                if protected:
-                    t_done = (
-                        jnp.min(conn_end)
-                        if kind == CLOSED_LOOP
-                        else t_end
-                    )
-                if roll:
-                    robs_acc = (
-                        robs_acc
-                        + rollout_mod.observe_block(res, tspec)
-                    )
-                    rstate, rdelta = rollout_mod.advance(
-                        rstate, rdtab, robs_acc, t_done, tspec
-                    )
-                    roll_acc = rollout_mod.accumulate_summary(
-                        roll_acc, rdelta
-                    )
                 if with_pol:
-                    pobs_acc = (
-                        pobs_acc
-                        + policies_mod.observe_block(
-                            res, tspec, retry_mask
-                        )
-                    )
-                    pstate, pdelta = policies_mod.advance(
-                        pstate, pdtab, tl_acc, pobs_acc, t_done,
-                        tspec, stuck_breaker=stuck,
-                        downed_w=downed_w,
-                    )
-                    pol_acc = policies_mod.accumulate_summary(
-                        pol_acc, pdelta
-                    )
-                ys = s
-                if attr is not None:
-                    a, ex = attribution.attribute_block(
-                        res, atables,
-                        tail_cut=(
-                            tail_cut if attr == "tail" else None
-                        ),
-                        top_k=top_k, ex_state=ex,
-                        packed=packed,
-                    )
-                    ys = (s, a)
-                return (
-                    (t_end, conn_end, req_off + per),
-                    tl_acc, robs_acc, rstate, roll_acc,
-                    pobs_acc, pstate, pol_acc, ex,
-                ), ys
-
-            if carry_io:
-                if protected:
-                    carry0 = jax.tree.unflatten(
-                        jax.tree.structure(zero_carry()),
-                        carry_leaves,
-                    )
-                else:
-                    t0_in, conn_t0_in, req_off_in = carry_leaves
-                    carry0 = (
-                        (
-                            jnp.asarray(t0_in, jnp.float32),
-                            jnp.asarray(conn_t0_in, jnp.float32),
-                            jnp.asarray(req_off_in, jnp.float32),
-                        ),
-                    ) + zero_carry()[1:]
-            else:
-                ex0 = None
-                if attr is not None:
-                    k0 = min(top_k, block) if top_k > 0 else 0
-                    ex0 = (
-                        attribution.empty_exemplars(
-                            k0, self.compiled.num_hops
-                        )
-                        if k0 > 0
-                        else None
-                    )
-                carry0 = zero_carry(ex0)
-            carry_out, ys = jax.lax.scan(
-                body, carry0, jnp.arange(num_blocks)
+                    downed_w = chaos_rows[len(layout)]
+            control = (
+                blockscan.control_plane(self, tl_plan, roll, downed_w)
+                if protected else None
             )
-            (_, tl_final, robs_final, _, roll_final, _, _,
-             pol_final, ex_final) = carry_out
-            if roll:
-                roll_final = rollout_mod.attach_observations(
-                    roll_final, robs_final
-                )
-            if attr is not None:
-                parts, aparts = ys
-                summary = summary_mod.reduce_stacked(parts)
-                a_out = attribution.reduce_stacked(aparts, ex_final)
-            else:
-                summary = summary_mod.reduce_stacked(ys)
-            if protected:
-                out = (summary, tl_final)
-                if roll:
-                    out = out + (roll_final,)
-                if with_pol:
-                    out = out + (pol_final,)
-                if attr is not None:
-                    out = out + (a_out,)
-                if carry_io:
-                    return out, carry_out
-                return out
-            if observed:
-                out = (summary,)
-                if tl_plan is not None:
-                    out = out + (tl_final,)
-                if attr is not None:
-                    out = out + (a_out,)
-                return out
+            carry0 = None
             if carry_io:
-                return summary, carry_out[0]
-            return summary
+                zero = (
+                    blockscan.zero_clocks(connections),
+                    control.init() if protected else None,
+                )
+                carry0 = jax.tree.unflatten(
+                    jax.tree.structure(zero), carry_leaves
+                )
+            summary, observed, carry = blockscan.block_scan(
+                self, None, shape, key, offered_qps, pace_gap,
+                offered_qps, nominal_gap, win_lo, win_hi, visits_pc,
+                phase_windows,
+                self._observers(
+                    block, attr, None if protected else tl_plan,
+                    tail_cut,
+                ),
+                control=control, core_kw=core_kw, b0=b0, carry0=carry0,
+            )
+            if protected:
+                out = (summary, *control.finish(carry[1]), *observed)
+                return (out, carry) if carry_io else out
+            if carry_io:
+                return summary, carry[0]
+            # (summary[, timeline][, attr]): attribution LAST
+            return (summary, *observed[::-1]) if observed else summary
 
         return member_scan
-
-    def _protected_carry0(self, connections: int,
-                          tl_plan: Optional[Tuple[int, float]],
-                          roll: bool = False,
-                          with_pol: Optional[bool] = None):
-        """The solo zero scan carry of the universal member body —
-        every layer an optional pytree leaf: ``((t0, conn_t0,
-        req_off), timeline, rollout obs/state/summary, policy
-        obs/state/summary, exemplars)``, with ``None`` for the layers
-        the composition leaves off.  The carry-I/O fleet contract
-        flattens exactly these leaves (:meth:`zero_protected_carry`
-        stacks them per member)."""
-        if with_pol is None:
-            with_pol = self._policies is not None
-        c = max(connections, 1)
-        tl0 = None
-        if tl_plan is not None:
-            from isotope_tpu.metrics import timeline as timeline_mod
-
-            tspec = timeline_mod.build_spec(
-                self.compiled, tl_plan[0], tl_plan[1]
-            )
-            S = self.compiled.num_services
-            W = tspec.num_windows
-            tl0 = timeline_mod.zeros_summary(
-                tspec, packed=self.params.packed_carries
-            )
-        robs0 = rstate0 = racc0 = None
-        if roll:
-            from isotope_tpu.sim import rollout as rollout_mod
-
-            rdtab = rollout_mod.device_tables(self._rollouts)
-            robs0 = jnp.zeros((S, 2, W, 4))
-            rstate0 = rollout_mod.init_state(rdtab)
-            racc0 = rollout_mod.zeros_summary(tspec, S)
-        pobs0 = pstate0 = pacc0 = None
-        if with_pol:
-            from isotope_tpu.sim import policies as policies_mod
-
-            pdtab = policies_mod.device_tables(self._policies)
-            pobs0 = jnp.zeros((S, W))
-            pstate0 = policies_mod.init_state(
-                pdtab, lag_periods=faults.autoscaler_lag()
-            )
-            pacc0 = policies_mod.zeros_summary(tspec, S)
-        return (
-            (
-                jnp.float32(0.0),
-                jnp.zeros((c,), jnp.float32),
-                jnp.float32(0.0),
-            ),
-            tl0, robs0, rstate0, racc0, pobs0, pstate0, pacc0, None,
-        )
 
     @staticmethod
     def _member_chaos_fx(chaos_rows, layout):
@@ -3464,14 +3250,19 @@ class Simulator:
                              roll: bool = False):
         """The fresh-start member-stacked PROTECTED scan carry — the
         carry-I/O contract of :meth:`run_policies_ensemble` /
-        :meth:`run_rollouts_ensemble`: every leaf of the universal
-        member carry (:meth:`_protected_carry0` — clocks, timeline
-        accumulator, rollout obs/state/summary, policy
-        obs/state/summary) broadcast along a leading member axis.
-        A protected search bracket resuming from exactly these zeros
-        at ``block_offset=0`` is bit-identical to the unbroken
-        protected fleet."""
-        carry = self._protected_carry0(connections, tl_plan, roll=roll)
+        :meth:`run_rollouts_ensemble`: the block loop's resumable
+        carry (``blockscan.zero_clocks`` and the run's
+        ``blockscan.control_plane(...).init()``, the structure
+        :meth:`_member_fn` unflattens its leaves into) broadcast along
+        a leading member axis.  A protected search bracket resuming
+        from exactly these zeros at ``block_offset=0`` is bit-identical
+        to the unbroken protected fleet."""
+        from isotope_tpu.sim import blockscan
+
+        carry = (
+            blockscan.zero_clocks(connections),
+            blockscan.control_plane(self, tl_plan, roll).init(),
+        )
         return jax.tree.map(
             lambda x: jnp.broadcast_to(
                 jnp.asarray(x)[None],
