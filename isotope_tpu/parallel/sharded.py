@@ -1692,44 +1692,6 @@ class ShardedSimulator:
             merged.append(attr_mod.merge_host(list(rest.pop(0))))
         return tuple(merged)
 
-    def _prot_block_ctx(self, tl_plan: Tuple[int, float], roll: bool):
-        """Static protected-scan context shared by the shard_map body
-        and the emulated twin (identical traced control program)."""
-        from isotope_tpu.metrics import timeline as timeline_mod
-
-        spec = timeline_mod.build_spec(
-            self.compiled, tl_plan[0], tl_plan[1]
-        )
-        ctx = dict(
-            spec=spec,
-            packed=self.sim.params.packed_carries,
-            tl_mod=timeline_mod,
-            with_pol=self.sim._policies is not None,
-            pol_mod=None,
-            roll_mod=None,
-        )
-        if ctx["with_pol"]:
-            from isotope_tpu.sim import policies as policies_mod
-
-            ctx.update(
-                pol_mod=policies_mod,
-                dtab=policies_mod.device_tables(self.sim._policies),
-                downed_w=self.sim._policy_downed_windows(
-                    spec, base_split=roll
-                ),
-                stuck=faults.stuck_breaker(),
-                lag=faults.autoscaler_lag(),
-                retry_mask=jnp.asarray(self.compiled.hop_attempt > 0),
-            )
-        if roll:
-            from isotope_tpu.sim import rollout as rollout_mod
-
-            ctx.update(
-                roll_mod=rollout_mod,
-                rdtab=rollout_mod.device_tables(self.sim._rollouts),
-            )
-        return ctx
-
     def _prot_body(
         self,
         block: int,
@@ -1750,163 +1712,49 @@ class ShardedSimulator:
         visits_pc: jax.Array,
         phase_windows: jax.Array,
     ):
-        ctx = self._prot_block_ctx(tl_plan, roll)
-        spec, tl_mod = ctx["spec"], ctx["tl_mod"]
-        pol_mod, roll_mod = ctx["pol_mod"], ctx["roll_mod"]
-        with_pol = ctx["with_pol"]
+        """The protected shard_map body: this shard's block scan with
+        the control planes, whose (replicated) state advances on
+        GLOBAL window signals — each block's observation channels psum
+        across the mesh, and a window is final once EVERY shard's
+        slowest clock passed it.  The emulated twin replays these
+        collectives in shard order."""
+        from isotope_tpu.metrics import timeline as timeline_mod
+
         both = tuple(self.mesh.axis_names)
         shard = jnp.int32(0)
         for a in self.mesh.axis_names:
             shard = shard * self.mesh.shape[a] + jax.lax.axis_index(a)
-        local_key = jax.random.fold_in(key, 500_000 + shard)
-        c = max(conns_local, 1)
-        per = block // c
-        S = self.compiled.num_services
-        W = spec.num_windows
-        if attr is not None:
-            from isotope_tpu.metrics import attribution
 
-            atables = self.sim._attribution_tables()
-            top_k = self.sim.params.attribution_top_k
-
-        def block_body(carry, b):
-            ((t0, conn_t0, req_off), tl_acc, pobs_acc, pstate,
-             pol_acc, robs_acc, rstate, roll_acc, ex) = carry
-            pfx = pol_mod.effects(pstate) if with_pol else None
-            rfx = roll_mod.effects(rstate) if roll else None
-            kb = jax.random.fold_in(local_key, 1_000_000 + b)
-            res, t_end, conn_end = self.sim._simulate_core(
-                block, kind, conns_local, kb, offered_qps, pace_gap,
-                offered_qps / self.n_shards, nominal_gap, t0, conn_t0,
-                req_off,
-                visits_pc=visits_pc,
-                phase_windows=phase_windows,
-                policy_fx=pfx,
-                rollout_fx=rfx,
-            )
-            s = summarize(
-                res, self.collector,
-                window=(win_lo, win_hi) if trim else None,
-            )
-            # the control loops consume GLOBAL window signals: each
-            # block's recorder contribution (and the policy/rollout
-            # observation channels) psums across the mesh before the
-            # (replicated) state advances — the collectives the
-            # emulated twin replays in shard order
-            tl_blk = tl_mod.timeline_block(res, spec,
-                                           packed=ctx["packed"])
-            tl_blk = jax.tree.map(
+        def combine(obs, t_local):
+            # an absent channel is None: no leaf, no collective
+            summed = jax.tree.map(
                 lambda x: jax.lax.psum(x, both),
-                tl_blk._replace(window_s=jnp.float32(0.0)),
-            )._replace(window_s=jnp.float32(spec.window_s))
-            tl_acc = tl_mod.accumulate(tl_acc, tl_blk)
-            if with_pol:
-                pobs_acc = pobs_acc + jax.lax.psum(
-                    pol_mod.observe_block(res, spec,
-                                          ctx["retry_mask"]),
-                    both,
-                )
-            if roll:
-                robs_acc = robs_acc + jax.lax.psum(
-                    roll_mod.observe_block(res, spec), both
-                )
-            # a window is final once EVERY shard's SLOWEST clock
-            # passed it (closed loop: the slowest connection, not
-            # conn_end.max() — faster connections' later blocks still
-            # write into earlier windows)
-            t_local = (
-                jnp.min(conn_end)
-                if kind != OPEN_LOOP
-                else t_end
+                obs._replace(timeline=None),
             )
-            t_done = jax.lax.pmin(t_local, both)
-            if roll:
-                rstate, rdelta = roll_mod.advance(
-                    rstate, ctx["rdtab"], robs_acc, t_done, spec
-                )
-                roll_acc = roll_mod.accumulate_summary(
-                    roll_acc, rdelta
-                )
-            if with_pol:
-                pstate, delta = pol_mod.advance(
-                    pstate, ctx["dtab"], tl_acc, pobs_acc, t_done,
-                    spec, stuck_breaker=ctx["stuck"],
-                    downed_w=ctx["downed_w"],
-                )
-                pol_acc = pol_mod.accumulate_summary(pol_acc, delta)
-            ys = s
-            if attr is not None:
-                a_blk, ex = attribution.attribute_block(
-                    res, atables,
-                    tail_cut=tail_cut if attr == "tail" else None,
-                    top_k=top_k, ex_state=ex,
-                    packed=ctx["packed"],
-                )
-                ys = (s, a_blk)
-            return (
-                (t_end, conn_end, req_off + per),
-                tl_acc, pobs_acc, pstate, pol_acc,
-                robs_acc, rstate, roll_acc, ex,
-            ), ys
+            return summed._replace(
+                timeline=timeline_mod.merge_collective(
+                    obs.timeline, both
+                ),
+            ), jax.lax.pmin(t_local, both)
 
-        ex0 = None
-        if attr is not None:
-            k0 = (
-                min(top_k, block) if top_k > 0 else 0
-            )
-            H = self.compiled.num_hops
-            ex0 = (
-                attribution.empty_exemplars(k0, H)
-                if k0 > 0
-                else None
-            )
-        carry0 = (
-            (
-                jnp.float32(0.0),
-                jnp.zeros((c,), jnp.float32),
-                jnp.float32(0.0),
-            ),
-            tl_mod.zeros_summary(spec, packed=ctx["packed"]),
-            jnp.zeros((S, W)) if with_pol else None,
-            (
-                pol_mod.init_state(ctx["dtab"],
-                                   lag_periods=ctx["lag"])
-                if with_pol else None
-            ),
-            pol_mod.zeros_summary(spec, S) if with_pol else None,
-            jnp.zeros((S, 2, W, 4)) if roll else None,
-            roll_mod.init_state(ctx["rdtab"]) if roll else None,
-            roll_mod.zeros_summary(spec, S) if roll else None,
-            ex0,
+        control = blockscan.control_plane(self.sim, tl_plan, roll)
+        observers = self.sim._observers(block, attr, None, tail_cut)
+        local, observed, (_, ctl) = blockscan.block_scan(
+            self.sim, self.collector,
+            (block, num_blocks, kind, conns_local, trim, 0),
+            jax.random.fold_in(key, 500_000 + shard), offered_qps,
+            pace_gap, offered_qps, nominal_gap, win_lo, win_hi,
+            visits_pc, phase_windows, observers,
+            shards=self.n_shards, control=control, combine=combine,
         )
-        (
-            (_, tl_final, _, _, pol_final, robs_final, _, roll_final,
-             ex_final),
-            ys,
-        ) = jax.lax.scan(block_body, carry0, jnp.arange(num_blocks))
-        if attr is not None:
-            parts, aparts = ys
-        else:
-            parts = ys
-        merged_summary = self._merge_summary_collective(
-            reduce_stacked(parts), both
+        # the control planes' outputs are already global (per-block
+        # psums) and replicated; blame merges like run_attributed
+        return (
+            self._merge_summary_collective(local, both),
+            *control.finish(ctl),
+            *(o.merge_collective(x, both)
+              for o, x in zip(observers, observed)),
         )
-        # tl/pol/roll finals are already global (per-block psums) and
-        # replicated across shards
-        out = (merged_summary, tl_final)
-        if roll:
-            out = out + (
-                roll_mod.attach_observations(roll_final, robs_final),
-            )
-        if with_pol:
-            out = out + (pol_final,)
-        if attr is not None:
-            # blame accumulators merge exactly like run_attributed
-            merged_attr = attribution.merge_collective(
-                attribution.reduce_stacked(aparts, ex_final), both
-            )
-            out = out + (merged_attr,)
-        return out
 
     def _local_prot_scan_all(
         self,
@@ -1932,24 +1780,17 @@ class ShardedSimulator:
         whose block body sweeps every shard (unrolled, shard order)
         and replays the per-block psums as sequential sums in the
         device merge's association order (ICI shards within each
-        slice first, slice partials last).  Per-shard blame stacks
-        (``attr``) come back un-merged; the caller host-merges them."""
-        ctx = self._prot_block_ctx(tl_plan, roll)
-        spec, tl_mod = ctx["spec"], ctx["tl_mod"]
-        pol_mod, roll_mod = ctx["pol_mod"], ctx["roll_mod"]
-        with_pol = ctx["with_pol"]
+        slice first, slice partials last).  R streams inside one body
+        is not ``block_scan``'s loop, so this keeps its own; the
+        control planes are the same ``control_plane`` object.  Per-shard
+        blame stacks (``attr``) come back un-merged; the caller
+        host-merges them."""
         R = self.n_shards
-        c = max(conns_local, 1)
-        per = block // c
-        S = self.compiled.num_services
-        W = spec.num_windows
-        n_slices = dict(self.mesh.shape).get(SLICE_AXIS, 1)
-        per_slice = R // max(n_slices, 1)
-        if attr is not None:
-            from isotope_tpu.metrics import attribution
-
-            atables = self.sim._attribution_tables()
-            top_k = self.sim.params.attribution_top_k
+        per = block // max(conns_local, 1)
+        n_slices = max(dict(self.mesh.shape).get(SLICE_AXIS, 1), 1)
+        per_slice = R // n_slices
+        control = blockscan.control_plane(self.sim, tl_plan, roll)
+        observers = self.sim._observers(block, attr, None, tail_cut)
 
         def _hier_sum(vals):
             def _seq(vs):
@@ -1960,22 +1801,14 @@ class ShardedSimulator:
 
             return _seq([
                 _seq(vals[i * per_slice:(i + 1) * per_slice])
-                for i in range(max(n_slices, 1))
+                for i in range(n_slices)
             ])
 
         def block_body(carry, b):
-            (t0s, conn_t0s, req_offs), tl_acc, pobs_acc, pstate, \
-                pol_acc, robs_acc, rstate, roll_acc, exs = carry
-            pfx = pol_mod.effects(pstate) if with_pol else None
-            rfx = roll_mod.effects(rstate) if roll else None
-            sums = []
-            ablks = []
-            exs_out = []
-            tl_parts = []
-            pobs_parts = []
-            robs_parts = []
-            t_ends = []
-            conn_ends = []
+            (t0s, conn_t0s, req_offs), ctl, obs = carry
+            fx = control.effects(ctl)
+            sums, seen, t_locals, t_ends, conn_ends = [], [], [], [], []
+            stepped = []
             for s_i in range(R):
                 kb = jax.random.fold_in(
                     jax.random.fold_in(key, 500_000 + s_i),
@@ -1987,137 +1820,67 @@ class ShardedSimulator:
                     t0s[s_i], conn_t0s[s_i], req_offs[s_i],
                     visits_pc=visits_pc,
                     phase_windows=phase_windows,
-                    policy_fx=pfx,
-                    rollout_fx=rfx,
+                    **fx,
                 )
                 sums.append(summarize(
                     res, self.collector,
                     window=(win_lo, win_hi) if trim else None,
                 ))
-                tl_parts.append(
-                    tl_mod.timeline_block(res, spec,
-                                          packed=ctx["packed"])
-                )
-                if with_pol:
-                    pobs_parts.append(
-                        pol_mod.observe_block(res, spec,
-                                              ctx["retry_mask"])
-                    )
-                if roll:
-                    robs_parts.append(
-                        roll_mod.observe_block(res, spec)
-                    )
-                if attr is not None:
-                    a_blk, ex_i = attribution.attribute_block(
-                        res, atables,
-                        tail_cut=(
-                            tail_cut if attr == "tail" else None
-                        ),
-                        top_k=top_k, ex_state=exs[s_i],
-                        packed=ctx["packed"],
-                    )
-                    ablks.append(a_blk)
-                    exs_out.append(ex_i)
+                seen.append(control.observe(res))
+                stepped.append([
+                    o.step(res, oc) for o, oc in zip(observers, obs[s_i])
+                ])
                 t_ends.append(t_end)
                 conn_ends.append(conn_end)
-            tl_blk = _hier_sum([
-                p._replace(window_s=jnp.float32(0.0))
-                for p in tl_parts
-            ])._replace(window_s=jnp.float32(spec.window_s))
-            tl_acc = tl_mod.accumulate(tl_acc, tl_blk)
-            if with_pol:
-                pobs_acc = pobs_acc + _hier_sum(pobs_parts)
-            if roll:
-                robs_acc = robs_acc + _hier_sum(robs_parts)
-            locals_ = [
-                jnp.min(ce) if kind != OPEN_LOOP else te
-                for te, ce in zip(t_ends, conn_ends)
-            ]
-            t_done = locals_[0]
-            for t in locals_[1:]:
+                t_locals.append(
+                    jnp.min(conn_end) if kind != OPEN_LOOP else t_end
+                )
+            window_s = seen[0].timeline.window_s
+            total = _hier_sum([
+                o._replace(timeline=o.timeline._replace(
+                    window_s=jnp.float32(0.0)
+                )) for o in seen
+            ])
+            total = total._replace(
+                timeline=total.timeline._replace(window_s=window_s)
+            )
+            t_done = t_locals[0]
+            for t in t_locals[1:]:
                 t_done = jnp.minimum(t_done, t)
-            if roll:
-                rstate, rdelta = roll_mod.advance(
-                    rstate, ctx["rdtab"], robs_acc, t_done, spec
-                )
-                roll_acc = roll_mod.accumulate_summary(
-                    roll_acc, rdelta
-                )
-            if with_pol:
-                pstate, delta = pol_mod.advance(
-                    pstate, ctx["dtab"], tl_acc, pobs_acc, t_done,
-                    spec, stuck_breaker=ctx["stuck"],
-                    downed_w=ctx["downed_w"],
-                )
-                pol_acc = pol_mod.accumulate_summary(pol_acc, delta)
-            carry_out = (
+            return (
                 (
                     jnp.stack(t_ends),
                     jnp.stack(conn_ends),
                     req_offs + per,
                 ),
-                tl_acc, pobs_acc, pstate, pol_acc,
-                robs_acc, rstate, roll_acc,
-                tuple(exs_out) if attr is not None else None,
+                control.advance(ctl, total, t_done),
+                tuple(tuple(oc for oc, _ in st) for st in stepped),
+            ), (
+                tuple(sums),
+                tuple(tuple(ys for _, ys in st) for st in stepped),
             )
-            ys = tuple(sums)
-            if attr is not None:
-                ys = (ys, tuple(ablks))
-            return carry_out, ys
 
-        carry0 = (
-            (
-                jnp.zeros((R,), jnp.float32),
-                jnp.zeros((R, c), jnp.float32),
-                jnp.zeros((R,), jnp.float32),
-            ),
-            tl_mod.zeros_summary(spec, packed=ctx["packed"]),
-            jnp.zeros((S, W)) if with_pol else None,
-            (
-                pol_mod.init_state(ctx["dtab"],
-                                   lag_periods=ctx["lag"])
-                if with_pol else None
-            ),
-            pol_mod.zeros_summary(spec, S) if with_pol else None,
-            jnp.zeros((S, 2, W, 4)) if roll else None,
-            roll_mod.init_state(ctx["rdtab"]) if roll else None,
-            roll_mod.zeros_summary(spec, S) if roll else None,
-            None,
+        clocks0 = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (R,) + x.shape),
+            blockscan.zero_clocks(conns_local),
         )
-        if attr is not None:
-            k0 = min(top_k, block) if top_k > 0 else 0
-            H = self.compiled.num_hops
-            ex0 = (
-                attribution.empty_exemplars(k0, H)
-                if k0 > 0
-                else None
-            )
-            carry0 = carry0[:-1] + (tuple(ex0 for _ in range(R)),)
-        (
-            (_, tl_final, _, _, pol_final, robs_final, _, roll_final,
-             exs_final),
-            ys,
-        ) = jax.lax.scan(block_body, carry0, jnp.arange(num_blocks))
-        if attr is not None:
-            parts, aparts = ys
-        else:
-            parts = ys
-        out = (
+        (_, ctl, finals), (parts, ys) = jax.lax.scan(
+            block_body,
+            (
+                clocks0, control.init(),
+                tuple(tuple(o.init() for o in observers)
+                      for _ in range(R)),
+            ),
+            jnp.arange(num_blocks),
+        )
+        return (
             tuple(reduce_stacked(p) for p in parts),
-            tl_final,
+            *control.finish(ctl),
+            *(
+                tuple(o.reduce(y[i], f[i]) for y, f in zip(ys, finals))
+                for i, o in enumerate(observers)
+            ),
         )
-        if roll:
-            out = out + (
-                roll_mod.attach_observations(roll_final, robs_final),
-            )
-        if with_pol:
-            out = out + (pol_final,)
-        if attr is not None:
-            out = out + (tuple(
-                attribution.reduce_stacked(ap, ex)
-                for ap, ex in zip(aparts, exs_final)
-            ),)
-        return out
 
     def _prot_cache_key(self, plan: RunPlan, tl_plan, attr,
                         roll: bool):
