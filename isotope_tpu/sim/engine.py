@@ -2808,14 +2808,11 @@ class Simulator:
                     f"{n_mem} members"
                 )
             keys_arr = jnp.stack(member_keys)
-        if load.kind == OPEN_LOOP:
-            conns = 0
-            block = max(1, min(block_size, num_requests))
-        else:
-            conns = load.connections
-            per = max(1, min(block_size, num_requests) // conns)
-            block = per * conns
-        num_blocks = max(1, -(-num_requests // block))
+        from isotope_tpu.sim import blockscan
+
+        conns, block, num_blocks = blockscan.block_shape(
+            load, num_requests, block_size
+        )
         if trim:
             from isotope_tpu.metrics.fortio import trim_window_bounds
 
@@ -3462,53 +3459,40 @@ class Simulator:
             tail_cut = self.estimate_tail_cut(
                 load, num_requests, key, block_size=block_size
             )
-        if load.kind == OPEN_LOOP:
-            offered = float(load.qps)
-            pace = 0.0
-            nominal = 0.0
-            conns = 0
-            block = max(1, min(block_size, num_requests))
-        else:
-            conns = load.connections
-            offered = self.solve_closed_rate(load, num_requests, key,
-                                             fixed_point_iters)
-            pace = conns / load.qps if load.qps is not None else 0.0
-            nominal = conns / offered
-            per = max(1, min(block_size, num_requests) // conns)
-            block = per * conns
-        num_blocks = max(1, -(-num_requests // block))
-        if trim:
-            from isotope_tpu.metrics.fortio import trim_window_bounds
+        from isotope_tpu.sim import blockscan
 
-            window = trim_window_bounds(num_blocks * block, offered)
-        else:
-            window = (0.0, np.inf)
+        plan = blockscan.plan_run(
+            self, load, num_requests, key, block_size=block_size,
+            trim=trim, fixed_point_iters=fixed_point_iters,
+        )
         tl_plan = self.plan_timeline_windows(
-            num_blocks * block, offered, window_s
+            plan.num_blocks * plan.block, plan.offered, window_s
         )
         fn = self._get_protected(
-            block, num_blocks, load.kind, conns, collector, trim,
-            tl_plan,
+            plan.block, plan.num_blocks, plan.kind, plan.conns_local,
+            collector, trim, tl_plan,
             attr=("tail" if tail else "mean") if attribution else None,
             roll=roll,
         )
         faults.check("engine.run")
         self._check_lb_load(load)
-        telemetry.gauge_set("engine_block_requests", block)
-        telemetry.gauge_set("engine_num_blocks", num_blocks)
+        telemetry.gauge_set("engine_block_requests", plan.block)
+        telemetry.gauge_set("engine_num_blocks", plan.num_blocks)
         telemetry.counter_inc("rollout_runs" if roll else "policy_runs")
         with self._detail_ctx():
             return fn(
-                key, jnp.float32(offered), jnp.float32(pace),
-                jnp.float32(offered), jnp.float32(nominal),
-                jnp.float32(window[0]), jnp.float32(window[1]),
+                key, jnp.float32(plan.offered), jnp.float32(plan.gap),
+                jnp.float32(plan.offered),
+                jnp.float32(plan.nominal_gap),
+                jnp.float32(plan.window[0]),
+                jnp.float32(plan.window[1]),
                 jnp.float32(
                     tail_cut
                     if (attribution and tail_cut is not None)
                     else np.inf
                 ),
-                self._vis_arg(offered),
-                self._windows_arg(offered, False),
+                self._vis_arg(plan.offered),
+                self._windows_arg(plan.offered, False),
             )
 
     def _policy_downed_windows(self, spec, base_split: bool = False):
