@@ -1,7 +1,8 @@
 """The one request-block loop (sim/blockscan.py): its plain program is
 the plain scan and nothing else, its observers plug in without a
-second simulation, and its planner is the run shape every caller
-computed."""
+second simulation, its planner is the run shape every caller
+computed, and with the control planes it is the protected scan as the
+engine used to write it out."""
 import re
 
 import jax
@@ -10,7 +11,11 @@ import numpy as np
 import pytest
 
 from isotope_tpu import telemetry
-from isotope_tpu.compiler import compile_graph
+from isotope_tpu.compiler import (
+    compile_graph,
+    compile_policies,
+    compile_rollouts,
+)
 from isotope_tpu.metrics.fortio import trim_window_bounds
 from isotope_tpu.metrics.prometheus import MetricsCollector
 from isotope_tpu.models.graph import ServiceGraph
@@ -207,3 +212,243 @@ def test_sharded_plain_program_has_no_observer_scope(compiled):
     assert any(s.startswith("engine/") for s in names)
     assert not [s for s in names
                 if s.startswith(("attribution/", "timeline/"))]
+
+
+# -- (5) the control planes ride the same loop --------------------------
+
+CONTROL = {
+    "policies": """
+policies:
+  z:
+    breaker: {max_pending: 1}
+    retry_budget: {budget_percent: 20%, min_retries_concurrent: 1}
+  x:
+    autoscaler: {min_replicas: 1, max_replicas: 4,
+                 target_utilization: 50%, sync_period: 1s,
+                 stabilization_window: 1s}
+""",
+    "rollouts": """
+rollouts:
+  y:
+    steps: [20%, 100%]
+    bake: 1s
+    gates: {min_samples: 5}
+    canary: {error_rate: 30%}
+""",
+}
+WINDOW_S = 0.05
+
+
+def _reference_protected_scan(sim, block, num_blocks, kind, conns,
+                              tl_plan, roll):
+    """The protected summary program, written out as
+    ``Simulator._get_protected`` held it before the loop took the
+    control planes: effects, fold, core, summarize, record, observe and
+    advance each layer, carry everything, reduce."""
+    from isotope_tpu.metrics import timeline as timeline_mod
+    from isotope_tpu.resilience import faults
+    from isotope_tpu.sim import policies as policies_mod
+    from isotope_tpu.sim import rollout as rollout_mod
+
+    with_pol = sim._policies is not None
+    c = max(conns, 1)
+    per = block // c
+    tspec = timeline_mod.build_spec(sim.compiled, *tl_plan)
+    S, W = sim.compiled.num_services, tspec.num_windows
+    packed = sim.params.packed_carries
+    if roll:
+        rdtab = rollout_mod.device_tables(sim._rollouts)
+    if with_pol:
+        pdtab = policies_mod.device_tables(sim._policies)
+        downed_w = sim._policy_downed_windows(tspec, base_split=roll)
+        retry_mask = jnp.asarray(sim.compiled.hop_attempt > 0)
+
+    def scanfn(key, offered_qps, pace_gap, arrival_qps, nominal_gap,
+               visits_pc, phase_windows):
+        def body(carry, b):
+            ((t0, conn_t0, req_off), tl_acc, robs_acc, rstate,
+             roll_acc, pobs_acc, pstate, pol_acc) = carry
+            rfx = rollout_mod.effects(rstate) if roll else None
+            pfx = policies_mod.effects(pstate) if with_pol else None
+            kb = jax.random.fold_in(key, 1_000_000 + b)
+            res, t_end, conn_end = sim._simulate_core(
+                block, kind, conns, kb, offered_qps, pace_gap,
+                arrival_qps, nominal_gap, t0, conn_t0, req_off,
+                visits_pc=visits_pc, phase_windows=phase_windows,
+                policy_fx=pfx, rollout_fx=rfx,
+            )
+            s = summarize(res, None, window=None)
+            tl_acc = timeline_mod.accumulate(
+                tl_acc,
+                timeline_mod.timeline_block(res, tspec, packed=packed),
+            )
+            t_done = jnp.min(conn_end) if kind == "closed" else t_end
+            if roll:
+                robs_acc = robs_acc + rollout_mod.observe_block(
+                    res, tspec
+                )
+                rstate, rdelta = rollout_mod.advance(
+                    rstate, rdtab, robs_acc, t_done, tspec
+                )
+                roll_acc = rollout_mod.accumulate_summary(
+                    roll_acc, rdelta
+                )
+            if with_pol:
+                pobs_acc = pobs_acc + policies_mod.observe_block(
+                    res, tspec, retry_mask
+                )
+                pstate, pdelta = policies_mod.advance(
+                    pstate, pdtab, tl_acc, pobs_acc, t_done, tspec,
+                    stuck_breaker=faults.stuck_breaker(),
+                    downed_w=downed_w,
+                )
+                pol_acc = policies_mod.accumulate_summary(
+                    pol_acc, pdelta
+                )
+            return (
+                (t_end, conn_end, req_off + per),
+                tl_acc, robs_acc, rstate, roll_acc,
+                pobs_acc, pstate, pol_acc,
+            ), s
+
+        carry0 = (
+            (jnp.float32(0.0), jnp.zeros((c,), jnp.float32),
+             jnp.float32(0.0)),
+            timeline_mod.zeros_summary(tspec, packed=packed),
+            jnp.zeros((S, 2, W, 4)) if roll else None,
+            rollout_mod.init_state(rdtab) if roll else None,
+            rollout_mod.zeros_summary(tspec, S) if roll else None,
+            jnp.zeros((S, W)) if with_pol else None,
+            (
+                policies_mod.init_state(
+                    pdtab, lag_periods=faults.autoscaler_lag()
+                )
+                if with_pol else None
+            ),
+            policies_mod.zeros_summary(tspec, S) if with_pol else None,
+        )
+        (
+            (_, tl_final, robs_final, _, roll_final, _, _, pol_final),
+            parts,
+        ) = jax.lax.scan(body, carry0, jnp.arange(num_blocks))
+        out = (reduce_stacked(parts), tl_final)
+        if roll:
+            out += (rollout_mod.attach_observations(
+                roll_final, robs_final
+            ),)
+        if with_pol:
+            out += (pol_final,)
+        return out
+
+    return scanfn
+
+
+def _protected_case(layers, load):
+    """``(sim, roll, plan, tl_plan, traced args)`` of a protected run
+    of ``layers`` under ``load``."""
+    graph = ServiceGraph.from_yaml(
+        YAML + "".join(CONTROL[k] for k in layers)
+    )
+    compiled = compile_graph(graph)
+    roll = "rollouts" in layers
+    sim = Simulator(
+        compiled, SimParams(timeline=True),
+        policies=(compile_policies(graph, compiled)
+                  if "policies" in layers else None),
+        rollouts=compile_rollouts(graph, compiled) if roll else None,
+    )
+    plan = blockscan.plan_run(sim, load, N, KEY, block_size=BLOCK)
+    tl_plan = sim.plan_timeline_windows(
+        plan.num_blocks * plan.block, plan.offered, WINDOW_S
+    )
+    args = (
+        KEY, jnp.float32(plan.offered), jnp.float32(plan.gap),
+        jnp.float32(plan.offered), jnp.float32(plan.nominal_gap),
+        sim._vis_arg(plan.offered),
+        sim._windows_arg(plan.offered, False),
+    )
+    return sim, roll, plan, tl_plan, args
+
+
+def _control_scan(sim, roll, plan, tl_plan, num_blocks, b0=0,
+                  carry0=None):
+    """``block_scan`` with the run's control planes: ``(outputs in the
+    runners' order, final carry)``."""
+    shape = (plan.block, num_blocks, plan.kind, plan.conns_local,
+             False, 0)
+
+    def scanfn(key, offered_qps, pace_gap, arrival_qps, nominal_gap,
+               visits_pc, phase_windows):
+        control = blockscan.control_plane(sim, tl_plan, roll)
+        summary, observed, carry = blockscan.block_scan(
+            sim, None, shape, key, offered_qps, pace_gap, arrival_qps,
+            nominal_gap, 0.0, np.inf, visits_pc, phase_windows,
+            control=control, b0=b0, carry0=carry0,
+        )
+        assert observed == ()
+        return (summary, *control.finish(carry[1])), carry
+
+    return scanfn
+
+
+def _assert_bit_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("load", [OPEN, PACED], ids=["open", "paced"])
+@pytest.mark.parametrize(
+    "layers", [("policies",), ("rollouts",), ("policies", "rollouts")],
+    ids=["policies", "rollouts", "both"],
+)
+def test_control_scan_is_the_reference_protected_scan(layers, load):
+    sim, roll, plan, tl_plan, args = _protected_case(layers, load)
+    assert plan.num_blocks == 2
+    ref = _reference_protected_scan(
+        sim, plan.block, plan.num_blocks, plan.kind, plan.conns_local,
+        tl_plan, roll,
+    )
+    got = _control_scan(sim, roll, plan, tl_plan, plan.num_blocks)
+    want = ref(*args)
+    assert len(want) == 2 + len(layers)
+    # the loops ran: every recorder window the run covered was seen
+    assert float(np.asarray(want[-1].windows_done).sum()) > 0
+    _assert_bit_equal(got(*args)[0], want)
+    assert_ulp_equal(jax.jit(got)(*args)[0], jax.jit(ref)(*args))
+    # and it is the program the public runner serves
+    run = (sim.run_rollouts if roll else sim.run_policies)(
+        load, N, KEY, block_size=BLOCK, window_s=WINDOW_S
+    )
+    assert_ulp_equal(run, want)
+
+
+@pytest.mark.parametrize("load", [OPEN, PACED], ids=["open", "paced"])
+def test_control_scan_resumes_where_a_segment_stopped(load):
+    sim, roll, plan, tl_plan, args = _protected_case(
+        ("policies", "rollouts"), load
+    )
+    whole, carry_whole = _control_scan(
+        sim, roll, plan, tl_plan, 2
+    )(*args)
+    first, carry1 = _control_scan(sim, roll, plan, tl_plan, 1)(*args)
+    second, carry2 = _control_scan(
+        sim, roll, plan, tl_plan, 1, b0=1, carry0=carry1
+    )(*args)
+    # the clocks and the control state land where the unbroken run's
+    # did, and the control planes' series are the unbroken run's
+    _assert_bit_equal(carry2, carry_whole)
+    _assert_bit_equal(second[1:], whole[1:])
+    # each segment's RunSummary is its own blocks'
+    assert float(first[0].count) + float(second[0].count) == float(
+        whole[0].count
+    )
+    # a traced offset is the same program (the search brackets' b0)
+    traced, carry_t = jax.jit(
+        lambda b0, c0, *a: _control_scan(
+            sim, roll, plan, tl_plan, 1, b0=b0, carry0=c0
+        )(*a)
+    )(jnp.int32(1), carry1, *args)
+    assert_ulp_equal(carry_t, carry_whole)
+    assert_ulp_equal(traced[1:], whole[1:])
