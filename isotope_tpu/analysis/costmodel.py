@@ -546,10 +546,12 @@ def timeline_findings(estimate: CostEstimate) -> List[Finding]:
 def protected_carry_bytes(sim, num_windows: int,
                           roll: bool = False) -> float:
     """Per-member bytes of a PROTECTED fleet's stacked scan carry
-    (engine ``_member_fn`` with ``prot`` armed): the flight-recorder windowed
-    accumulator plus the policy / rollout control state, observation
-    channels, and actuation series — the terms a plain fleet does not
-    carry and VET-T025 accounts for.  All f32."""
+    (``sim/blockscan.py`` ``control_plane(...).init()``, which engine
+    ``_member_fn`` hands the block loop when ``prot`` is armed): the
+    flight-recorder windowed accumulator plus the policy / rollout
+    control state, observation channels, and actuation series — the
+    terms a plain fleet does not carry and VET-T025 accounts for.
+    All f32."""
     s = max(sim.compiled.num_services, 1)
     w = max(int(num_windows), 1)
     total = timeline_bytes(sim, num_windows=w)
@@ -570,8 +572,9 @@ def observability_carry_bytes(sim, attr: bool = False,
                               timeline_windows: Optional[int] = None
                               ) -> float:
     """Per-member bytes of an OBSERVED fleet's stacked observability
-    carry (engine ``_member_fn`` with attribution / timeline armed,
-    protected or not): the
+    carry (the block loop's observers, ``Simulator._observers``, which
+    engine ``_member_fn`` arms with attribution / timeline, protected
+    or not): the
     blame reduction's exemplar state plus its reduced
     ``AttributionSummary`` leaves (5 scalars, 11 per-hop vectors, two
     ``(S, 64)`` blame histograms), and the flight recorder's windowed

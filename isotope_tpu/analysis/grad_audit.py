@@ -6,10 +6,10 @@ which of the engine's hard joins actually sit on the gradient path
 from each design parameter to the SLO objective, and which knobs no
 relaxation can rescue because they never enter the jaxpr at all.
 
-This pass answers that statically.  It traces the engine's universal
-member body (``Simulator._member_fn`` with the jitter scales armed, so
-``cpu_scale`` / ``err_scale`` are *traced invars* rather than baked
-constants) via ``jax.make_jaxpr`` — same trace-only discipline as
+This pass answers that statically.  It traces the fleet member program
+(``Simulator._member_fn`` with the jitter scales armed, so ``cpu_scale``
+/ ``err_scale`` are *traced invars* rather than baked constants; its
+body is ``sim/blockscan.py`` ``block_scan``) via ``jax.make_jaxpr`` — same trace-only discipline as
 :mod:`~isotope_tpu.analysis.jaxpr_audit`, no device execution, pinned
 by test — then runs a forward dataflow over the ClosedJaxpr:
 
@@ -56,8 +56,9 @@ CLASS_DIFFERENTIABLE = "differentiable"
 CLASS_DEAD = "gradient-dead"
 CLASS_CONSTANT = "trace-constant"
 
-#: the ten traced invars of the engine's universal member body
-#: (engine.Simulator._member_fn -> member_scan), in position order;
+#: the ten traced invars of the fleet member program
+#: (engine.Simulator._member_fn -> member_scan, a caller of
+#: sim/blockscan.py block_scan), in position order;
 #: DESIGN_PARAMS entries name these to say where their taint seeds
 GRAD_INVARS = (
     "key",

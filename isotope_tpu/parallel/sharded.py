@@ -1835,15 +1835,9 @@ class ShardedSimulator:
                 t_locals.append(
                     jnp.min(conn_end) if kind != OPEN_LOOP else t_end
                 )
-            window_s = seen[0].timeline.window_s
-            total = _hier_sum([
-                o._replace(timeline=o.timeline._replace(
-                    window_s=jnp.float32(0.0)
-                )) for o in seen
-            ])
-            total = total._replace(
-                timeline=total.timeline._replace(window_s=window_s)
-            )
+            # the recorder block's window_s sums too; accumulate keeps
+            # its accumulator's and never reads the block's
+            total = _hier_sum(seen)
             t_done = t_locals[0]
             for t in t_locals[1:]:
                 t_done = jnp.minimum(t_done, t)

@@ -150,9 +150,9 @@ class Control(NamedTuple):
 
 
 class ControlObs(NamedTuple):
-    """One block's observation channels; all sum across streams (the
-    recorder's ``window_s`` is a constant held out of the sum:
-    ``timeline.merge_collective``).  An absent layer is ``None``."""
+    """One block's observation channels; all sum across streams (but
+    for the recorder's ``window_s``, a constant ``advance`` does not
+    read).  An absent layer is ``None``."""
 
     timeline: object
     rollout: object

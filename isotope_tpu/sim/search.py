@@ -35,7 +35,8 @@ bracket memory O(survivors).
 Determinism contract: candidate k's rung-0 rows are bit-identical to
 its ``run_ensemble`` member (same fold_in layout), a survivor's
 continued trajectory replays the unbroken solo run's RNG streams and
-carries exactly (``fold_in(key, 1_000_000 + b0 + b)``), and ties rank
+carries exactly (``sim/blockscan.py`` ``block_scan`` resumed at block
+``b0`` from the carry the previous rung returned), and ties rank
 through a fold_in-derived per-candidate uniform — so the full survivor
 lineage is a pure function of (spec, key, horizon) on every path
 (solo / sharded / emulated; pinned by tests/test_search.py).
@@ -829,8 +830,8 @@ def run_search_protected(sim, load, num_requests: int, key,
     the full horizon (the carry's windowed accumulator must keep one
     static shape across rungs), so a 1-rung bracket is bit-identical
     to the protected fleet at the same horizon, and rung 0's member
-    rows replay the protected fleet's exact streams (fold
-    ``1_000_000 + b``, zero carries)."""
+    rows replay the protected fleet's exact streams (``block_scan``
+    at ``b0 = 0`` from ``Simulator.zero_protected_carry``)."""
     import jax
     import jax.numpy as jnp
 

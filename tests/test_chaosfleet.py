@@ -222,7 +222,6 @@ def test_chaos_jitter_parse():
         faults.parse_chaos_jitter("tim=0.2")
 
 
-@pytest.mark.slow
 def test_member_chaos_identity_matches_plain_fleet(psim):
     """Per-member chaos OFF (and the identity jitter) = the PR 12
     fleet bit-for-bit: the traced chaos rows carry the same values the
@@ -555,7 +554,6 @@ def test_protected_fleet_severity_and_doc(pfleet):
     assert doc_member_quantiles(doc).shape == (3, 3)
 
 
-@pytest.mark.slow
 def test_protected_fleet_vmap_matches_map(psim, pfleet):
     v = psim.run_policies_ensemble(
         OPEN, N, KEY, EnsembleSpec.of(3, mode="vmap"),
@@ -571,8 +569,6 @@ def test_protected_fleet_vmap_matches_map(psim, pfleet):
     )
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_sharded_protected_fleet_bit_equal_twin(storm):
     from isotope_tpu.parallel import (
         MeshSpec,

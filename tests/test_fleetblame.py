@@ -140,8 +140,6 @@ def test_member_k_blame_bit_equals_solo_open(asim, obs4):
     assert_ulp_equal(solo_tl, obs4.member_timeline(k))
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_member_k_blame_bit_equals_solo_closed(asim):
     fleet = asim.run_ensemble(
         CLOSED, N, KEY, EnsembleSpec.of(3), block_size=BLOCK,
@@ -163,8 +161,6 @@ def test_chunked_observed_equals_unchunked(asim, obs4):
     assert _leaves_equal(obs4.timelines, chunked.timelines)
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_tail_mode_fleet_equals_solo(asim):
     cut = 0.012
     fleet = asim.run_ensemble(
@@ -182,7 +178,6 @@ def test_tail_mode_fleet_equals_solo(asim):
 # -- sharded == emulated twin == engine --------------------------------
 
 
-@pytest.mark.slow
 def test_sharded_observed_fleet_bit_equal(compiled, asim, obs4):
     from isotope_tpu.parallel import (
         MeshSpec,
@@ -233,8 +228,6 @@ policies:
 """
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_protected_fleet_blame_bit_equals_solo():
     g = ServiceGraph.from_yaml(STORM)
     compiled = compile_graph(g)

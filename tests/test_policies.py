@@ -166,7 +166,6 @@ def test_policies_default_keeps_bucketed_plan():
     assert any(isinstance(p, ScanBucketPlan) for p in sim._plan)
 
 
-@pytest.mark.slow
 def test_neutral_policies_match_unpoliced_run():
     """A policy set that never actuates (huge caps, budget slack, HPA
     pinned at the static count) must leave the protected run's summary
@@ -708,7 +707,6 @@ policies:
     return g, compiled, tables_for(g)
 
 
-@pytest.mark.slow
 def test_protected_run_beats_unprotected(storm_case):
     g, compiled, tables = storm_case
     params = SimParams(timeline=True, timeline_window_s=1.0)
@@ -736,7 +734,6 @@ def test_protected_run_beats_unprotected(storm_case):
     assert "replicas" in pol_mod.format_table(doc)
 
 
-@pytest.mark.slow
 def test_closed_loop_policy_run(storm_case):
     """Paced closed-loop policy runs work; window completion is gated
     by the SLOWEST connection's clock (review regression: conn_end
@@ -756,8 +753,6 @@ def test_closed_loop_policy_run(storm_case):
     assert (done[:k] == 1).all() and (done[k:] == 0).all()
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_attributed_policy_run(storm_case):
     """run_policies(attribution=True) reduces blame over the SAME
     protected blocks: counts reconcile, and the protected worker's
@@ -869,8 +864,6 @@ def test_sharded_policies_reject_svc_mesh(storm_case):
         )
 
 
-@pytest.mark.slow
-@pytest.mark.slow
 def test_emulated_mesh_policy_twin_runs(storm_case):
     """An EmulatedMesh (no devices) replays the policy program for any
     host count on one device."""

@@ -543,9 +543,6 @@ def test_sharded_rollouts_reject_svc_mesh(canary_case):
         )
 
 
-@pytest.mark.slow
-@pytest.mark.slow
-@pytest.mark.slow
 def test_emulated_mesh_rollout_twin_runs(canary_case):
     from isotope_tpu.parallel import MeshSpec, ShardedSimulator
     from isotope_tpu.parallel.mesh import EmulatedMesh
