@@ -269,6 +269,15 @@ def zero_clocks(connections: int):
     )
 
 
+def zero_carry(connections: int, control: Optional[Control] = None):
+    """The loop's resumable carry at t = 0: ``(clocks, control
+    carry)``, the second ``None`` without control planes."""
+    return (
+        zero_clocks(connections),
+        control.init() if control is not None else None,
+    )
+
+
 def block_scan(sim, collector, plan_shape, key, offered_qps, pace_gap,
                arrival_qps, nominal_gap, win_lo, win_hi, visits_pc,
                phase_windows, observers: Sequence[Observer] = (),
@@ -302,7 +311,7 @@ def block_scan(sim, collector, plan_shape, key, offered_qps, pace_gap,
 
     A search bracket resumes a run: ``carry0`` is the ``(clocks,
     control carry)`` a previous segment returned and ``b0`` the blocks
-    it scanned (default: :func:`zero_clocks` and ``control.init()``).
+    it scanned (default: :func:`zero_carry`).
 
     Returns ``(RunSummary, observed, carry)``: ``observed`` one
     summary per observer, ``carry`` the final ``(clocks, control
@@ -350,10 +359,7 @@ def block_scan(sim, collector, plan_shape, key, offered_qps, pace_gap,
         ), (s, tuple(ys for _, ys in stepped))
 
     if carry0 is None:
-        carry0 = (
-            zero_clocks(connections),
-            control.init() if control is not None else None,
-        )
+        carry0 = zero_carry(connections, control)
     (clocks, ctl, finals), (parts, ys) = jax.lax.scan(
         body, (*carry0, tuple(o.init() for o in observers)),
         jnp.arange(num_blocks),

@@ -2479,9 +2479,9 @@ class Simulator:
         ``carry_io`` is the search-bracket contract (sim/search.py):
         the member takes extra traced arguments after the ten standard
         ones — a block offset ``b0`` plus the flattened leaves of the
-        loop's resumable carry (plain members: the clocks ``(t0,
-        conn_t0, req_off)``; protected members: ``(clocks,
-        control_plane(...).init())``, what
+        loop's resumable carry (``blockscan.zero_carry``; plain
+        members: the clocks ``(t0, conn_t0, req_off)``; protected
+        members: the clocks and the control planes' carry, what
         :meth:`zero_protected_carry` stacks) — and returns ``(out,
         carry_out)``.  A member resumed at ``b0`` draws the EXACT
         streams the unbroken run drew for those blocks; with ``b0 ==
@@ -2596,12 +2596,11 @@ class Simulator:
             )
             carry0 = None
             if carry_io:
-                zero = (
-                    blockscan.zero_clocks(connections),
-                    control.init() if protected else None,
-                )
                 carry0 = jax.tree.unflatten(
-                    jax.tree.structure(zero), carry_leaves
+                    jax.tree.structure(
+                        blockscan.zero_carry(connections, control)
+                    ),
+                    carry_leaves,
                 )
             summary, observed, carry = blockscan.block_scan(
                 self, None, shape, key, offered_qps, pace_gap,
@@ -3248,17 +3247,16 @@ class Simulator:
         """The fresh-start member-stacked PROTECTED scan carry — the
         carry-I/O contract of :meth:`run_policies_ensemble` /
         :meth:`run_rollouts_ensemble`: the block loop's resumable
-        carry (``blockscan.zero_clocks`` and the run's
-        ``blockscan.control_plane(...).init()``, the structure
+        carry (``blockscan.zero_carry`` of the run's
+        ``blockscan.control_plane``, the structure
         :meth:`_member_fn` unflattens its leaves into) broadcast along
         a leading member axis.  A protected search bracket resuming
         from exactly these zeros at ``block_offset=0`` is bit-identical
         to the unbroken protected fleet."""
         from isotope_tpu.sim import blockscan
 
-        carry = (
-            blockscan.zero_clocks(connections),
-            blockscan.control_plane(self, tl_plan, roll).init(),
+        carry = blockscan.zero_carry(
+            connections, blockscan.control_plane(self, tl_plan, roll)
         )
         return jax.tree.map(
             lambda x: jnp.broadcast_to(

@@ -391,13 +391,6 @@ def _control_scan(sim, roll, plan, tl_plan, num_blocks, b0=0,
     return scanfn
 
 
-def _assert_bit_equal(a, b):
-    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
-    assert len(la) == len(lb)
-    for x, y in zip(la, lb):
-        assert np.array_equal(np.asarray(x), np.asarray(y))
-
-
 @pytest.mark.parametrize("load", [OPEN, PACED], ids=["open", "paced"])
 @pytest.mark.parametrize(
     "layers", [("policies",), ("rollouts",), ("policies", "rollouts")],
@@ -415,7 +408,7 @@ def test_control_scan_is_the_reference_protected_scan(layers, load):
     assert len(want) == 2 + len(layers)
     # the loops ran: every recorder window the run covered was seen
     assert float(np.asarray(want[-1].windows_done).sum()) > 0
-    _assert_bit_equal(got(*args)[0], want)
+    assert_ulp_equal(got(*args)[0], want, maxulp=0)
     assert_ulp_equal(jax.jit(got)(*args)[0], jax.jit(ref)(*args))
     # and it is the program the public runner serves
     run = (sim.run_rollouts if roll else sim.run_policies)(
@@ -438,8 +431,8 @@ def test_control_scan_resumes_where_a_segment_stopped(load):
     )(*args)
     # the clocks and the control state land where the unbroken run's
     # did, and the control planes' series are the unbroken run's
-    _assert_bit_equal(carry2, carry_whole)
-    _assert_bit_equal(second[1:], whole[1:])
+    assert_ulp_equal(carry2, carry_whole, maxulp=0)
+    assert_ulp_equal(second[1:], whole[1:], maxulp=0)
     # each segment's RunSummary is its own blocks'
     assert float(first[0].count) + float(second[0].count) == float(
         whole[0].count
