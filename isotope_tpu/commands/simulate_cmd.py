@@ -474,14 +474,19 @@ def run_simulate(args) -> int:
     if result.failed:
         print(f"error: run failed: {result.error}", file=sys.stderr)
         return 1
+    # an output asked for BY NAME and not written fails the call, after
+    # the artifacts it has: the table on stderr alone stays best-effort
+    unwritten = []
     if args.attribution and result.blame is not None:
         from isotope_tpu.metrics import attribution as attr_mod
 
-        print(attr_mod.format_table(result.blame), file=sys.stderr)
-        if args.blame_out:
-            with open(args.blame_out, "w") as f:
-                json.dump(result.blame, f, indent=2)
-            print(f"blame tables -> {args.blame_out}", file=sys.stderr)
+        with telemetry.phase("artifacts.blame"):
+            print(attr_mod.format_table(result.blame), file=sys.stderr)
+            if args.blame_out:
+                with open(args.blame_out, "w") as f:
+                    json.dump(result.blame, f, indent=2)
+                print(f"blame tables -> {args.blame_out}",
+                      file=sys.stderr)
         if result.attribution is not None:
             _write_attribution_artifacts(args, result)
     elif args.attribution:
@@ -489,6 +494,8 @@ def run_simulate(args) -> int:
             "warning: attribution pass produced no blame document",
             file=sys.stderr,
         )
+        if args.blame_out:
+            unwritten.append(("--blame-out", args.blame_out))
     if args.policies and result.policies is not None:
         from isotope_tpu.sim import policies as policies_mod
 
@@ -569,6 +576,8 @@ def run_simulate(args) -> int:
             "warning: timeline pass produced no windowed series",
             file=sys.stderr,
         )
+        if args.timeline_out:
+            unwritten.append(("--timeline-out", args.timeline_out))
     with telemetry.phase("artifacts.write"):
         doc = result.flat if args.flat else result.fortio_json
         text = json.dumps(doc, indent=None if args.flat else 2) + "\n"
@@ -618,7 +627,10 @@ def run_simulate(args) -> int:
             f"{result.window.discard_reason}",
             file=sys.stderr,
         )
-    return 0
+    for flag, path in unwritten:
+        print(f"error: {flag} {path} was not written: its pass failed "
+              "(see the warning above)", file=sys.stderr)
+    return 1 if unwritten else 0
 
 
 def _write_attribution_artifacts(args, result) -> None:
@@ -670,13 +682,15 @@ def _write_timeline_artifacts(args, result) -> None:
     """The flight recorder's artifacts (simulate-only flags): the
     per-window table on stderr, plus the JSON / Perfetto / timestamped
     Prometheus files when requested."""
+    from isotope_tpu import telemetry
     from isotope_tpu.metrics import timeline as timeline_mod
 
-    print(timeline_mod.format_table(result.timeline), file=sys.stderr)
-    if args.timeline_out:
-        with open(args.timeline_out, "w") as f:
-            json.dump(result.timeline, f, indent=2)
-        print(f"timeline -> {args.timeline_out}", file=sys.stderr)
+    with telemetry.phase("artifacts.timeline"):
+        print(timeline_mod.format_table(result.timeline), file=sys.stderr)
+        if args.timeline_out:
+            with open(args.timeline_out, "w") as f:
+                json.dump(result.timeline, f, indent=2)
+            print(f"timeline -> {args.timeline_out}", file=sys.stderr)
     needs_summary = args.timeline_perfetto or args.timeline_prometheus
     if not needs_summary:
         return

@@ -24,16 +24,20 @@ shards merge with ``psum`` bit-equal to the emulated host merge, and
 like attribution).
 
 The occupancy integral: for events ``[s_i, e_i)`` truncated to the
-horizon ``T = W * dt``, the cumulative busy-seconds before time ``t``
-is ``F(t) = sum_i min(t, e_i) - min(t, s_i)``.  With per-window scatter
-sums of start/end counts and clamped start/end times, ``F`` at every
-window boundary is a cumulative sum —
+horizon ``T = W * dt``, the busy-seconds before time ``t`` are
 
-    F(t) = Esum(<t) - Ssum(<t) + t * (A(<t) - B(<t))
+    F(t) = sum_i clip(t - s_i, 0, e_i - s_i)
 
-(``A``/``B`` = starts/ends before ``t``) — and the per-window busy
-seconds are first differences of ``F``.  Exact, linear in events, and
-additive across blocks and shards.
+and the per-window busy seconds are first differences of ``F`` at the
+window boundaries.  Each term is formed from the event's own DURATION
+(never as a difference of two absolute clocks) and only then summed, so
+the result keeps float32's relative precision however late in the run
+the events lie: the sum over the windows of a service is the sum of its
+executions' latencies, as the collector's ``duration_sum`` is.  (The
+same ``F`` assembled from per-window sums of absolute start and end
+times, ``Esum - Ssum + t * (A - B)``, cancels catastrophically: 240 s
+into a run a leaf service's 77 us executions read 13-18 % long.)
+Linear in events, and additive across blocks and shards.
 """
 from __future__ import annotations
 
@@ -221,6 +225,105 @@ def _service_boundary_prefixes(
     return jnp.pad(jnp.cumsum(bins, axis=1), ((0, 0), (1, 0), (0, 0)))
 
 
+def _service_series(
+    spec: TimelineSpec,
+    sent: jax.Array,       # (N, H) f32 0/1 — executed hops
+    err: jax.Array,        # (N, H) f32 0/1 — executed hops that 500ed
+    s_c: jax.Array,        # (N, H) f32 — hop starts, clamped to [0, T]
+    e_c: jax.Array,        # (N, H) f32 — hop ends, clamped to [s_c, T]
+    lat: jax.Array,        # (N, H) f32 — hop latencies, >= 0
+    wait: jax.Array,       # (N, H) f32 — queueing waits, in [0, lat]
+) -> jax.Array:
+    """(S, W, 5) per-service x per-window series of one block:
+    arrivals (by hop start), errors (by hop start), completions (by hop
+    end), in-flight seconds and busy seconds.
+
+    The occupancy seconds are sums of per-event overlaps, each formed
+    from the event's own duration: ``clip(t - s, 0, lat)`` is what an
+    event has spent in flight before ``t``, exact to float32 relative
+    to ``lat`` wherever on the run's clock the event lies (module
+    docstring).  Both lowering regimes compute the same series —
+    selection is static in W, as in :func:`_service_boundary_prefixes`.
+    """
+    W = spec.num_windows
+    S = spec.num_services
+    dt = spec.window_s
+    T = W * dt
+    busy_lat = lat - wait
+    if W <= DENSE_WINDOWS_MAX:
+        # per-hop prefixes at each boundary: one masked column sum per
+        # series (no O(N x H) scatter), then one H-row scatter folds
+        # hops into services; the last column is the horizon's, which
+        # holds every event (the clamped final window)
+        cols = [jnp.zeros((s_c.shape[1], 5))]
+        for j in range(1, W + 1):
+            t = j * dt
+            started = (s_c < t).astype(jnp.float32) if j < W else 1.0
+            ended = (e_c < t).astype(jnp.float32) if j < W else 1.0
+            rel = t - s_c
+            cols.append(jnp.stack([
+                (sent * started).sum(0),
+                (err * started).sum(0),
+                (sent * ended).sum(0),
+                (sent * jnp.clip(rel, 0.0, lat)).sum(0),
+                (sent * jnp.clip(rel - wait, 0.0, busy_lat)).sum(0),
+            ], axis=-1))
+        per_hop = jnp.stack(cols, axis=1)  # (H, W+1, 5)
+        pref = (
+            jnp.zeros((S, W + 1, 5)).at[spec.hop_service].add(per_hop)
+        )
+        return pref[:, 1:, :] - pref[:, :-1, :]
+    # wide grids: per-channel scatters, O(N x H) whatever W.  An event
+    # gives its first window the part up to that window's end, its last
+    # window the part from that window's start, and every window
+    # strictly between a whole ``dt`` (a difference array of counts,
+    # summed along the window axis).
+    base = jnp.broadcast_to(spec.hop_service[None, :], s_c.shape) * W
+
+    def bins(window, values):
+        return (
+            jnp.zeros(S * W)
+            .at[(base + window).reshape(-1)]
+            .add(values.reshape(-1))
+            .reshape(S, W)
+        )
+
+    w_s = _window_index(spec, s_c)
+    w_e = _window_index(spec, e_c)
+
+    def occupancy(w_0, start_off, dur):
+        # the interval [s_c + start_off, + dur), truncated to T; w_0
+        # its first window, w_e its last
+        dur = jnp.clip(T - s_c - start_off, 0.0, dur)
+        spans = (w_e > w_0).astype(jnp.float32) * sent
+        head = jnp.where(
+            w_e > w_0,
+            jnp.clip((w_0 + 1).astype(jnp.float32) * dt - s_c - start_off,
+                     0.0, dur),
+            dur,
+        )
+        tail = dur - jnp.clip(
+            w_e.astype(jnp.float32) * dt - s_c - start_off, 0.0, dur
+        )
+        between = jnp.cumsum(
+            bins(jnp.minimum(w_0 + 1, W - 1), spans) - bins(w_e, spans),
+            axis=1,
+        )
+        return (
+            bins(w_0, sent * head) + bins(w_e, spans * tail)
+            + dt * between
+        )
+
+    return jnp.stack([
+        bins(w_s, sent),
+        bins(w_s, err),
+        bins(w_e, sent),
+        occupancy(w_s, 0.0, lat),
+        occupancy(jnp.minimum(_window_index(spec, s_c + wait), w_e),
+                  wait, busy_lat),
+    ], axis=-1)
+
+
 def versioned_service_windows(
     spec: TimelineSpec,
     t: jax.Array,            # (N, H) f32 — clamped event times, [0, T]
@@ -280,7 +383,16 @@ def timeline_block(
         .at[start_w]
         .add(res.client_error.astype(count_dtype))
     )
-    latency_sum = jnp.zeros(W).at[start_w].add(res.client_latency)
+    if W <= DENSE_WINDOWS_MAX:
+        # a masked column sum, not a scatter-add: one cell of a scatter
+        # takes its ~N / W addends one after the other, and a float32
+        # accumulator adding equal terms drifts by its count x 2^-24
+        latency_sum = jnp.where(
+            start_w[:, None] == jnp.arange(W)[None, :],
+            res.client_latency[:, None], 0.0,
+        ).sum(0)
+    else:
+        latency_sum = jnp.zeros(W).at[start_w].add(res.client_latency)
     hist = (
         jnp.zeros(W * NUM_BLAME_BUCKETS, count_dtype)
         .at[
@@ -291,41 +403,20 @@ def timeline_block(
     ).reshape(W, NUM_BLAME_BUCKETS)
 
     # -- per-service series ---------------------------------------------
-    # Three time families (hop start, hop end, busy start = start +
-    # queueing wait), each reduced to per-service boundary prefixes;
-    # every reported series is a first difference of those.  The
-    # occupancy identity (module docstring):
-    #   F(t) = Esum(<t) - Ssum(<t) + t * (A(<t) - B(<t))
-    # gives exact per-window busy-seconds of the event intervals
-    # truncated to the horizon.
-    dt = spec.window_s
-    T = W * dt
+    # Hop starts and ends are binned by window; the two occupancy
+    # integrals (in flight: [start, end); busy: [start + wait, end))
+    # are per-event overlaps with each window, truncated to the
+    # horizon (module docstring).
+    T = W * spec.window_s
     sent_f = res.hop_sent.astype(jnp.float32)
     err_f = (res.hop_sent & res.hop_error).astype(jnp.float32)
     s_c = jnp.clip(res.hop_start, 0.0, T)
     e_c = jnp.clip(res.hop_start + res.hop_latency, s_c, T)
-    b_c = jnp.clip(res.hop_start + res.hop_wait, s_c, e_c)
-
-    p_start = _service_boundary_prefixes(
-        spec, s_c, (sent_f, sent_f * s_c, err_f)
+    lat = jnp.maximum(res.hop_latency, 0.0)
+    series = _service_series(
+        spec, sent_f, err_f, s_c, e_c, lat,
+        jnp.clip(res.hop_wait, 0.0, lat),
     )
-    p_end = _service_boundary_prefixes(
-        spec, e_c, (sent_f, sent_f * e_c)
-    )
-    p_busy = _service_boundary_prefixes(
-        spec, b_c, (sent_f, sent_f * b_c)
-    )
-    a_pref, ssum = p_start[..., 0], p_start[..., 1]
-    err_pref = p_start[..., 2]
-    b_pref, esum = p_end[..., 0], p_end[..., 1]
-    ab_pref, bsum = p_busy[..., 0], p_busy[..., 1]
-
-    def diff(x):
-        return x[:, 1:] - x[:, :-1]
-
-    bounds = jnp.arange(W + 1, dtype=jnp.float32) * dt
-    inflight = diff(esum - ssum + bounds[None, :] * (a_pref - b_pref))
-    busy = diff(esum - bsum + bounds[None, :] * (ab_pref - b_pref))
 
     return TimelineSummary(
         window_s=jnp.float32(spec.window_s),
@@ -335,11 +426,11 @@ def timeline_block(
         errors=errors,
         latency_sum=latency_sum,
         latency_hist=hist,
-        svc_arrivals=diff(a_pref).astype(count_dtype),
-        svc_completions=diff(b_pref).astype(count_dtype),
-        svc_errors=diff(err_pref).astype(count_dtype),
-        svc_inflight_s=inflight,
-        svc_busy_s=busy,
+        svc_arrivals=series[..., 0].astype(count_dtype),
+        svc_completions=series[..., 2].astype(count_dtype),
+        svc_errors=series[..., 1].astype(count_dtype),
+        svc_inflight_s=series[..., 3],
+        svc_busy_s=series[..., 4],
     )
 
 
@@ -534,7 +625,14 @@ def to_doc(
 ) -> dict:
     """The ``timeline.json`` artifact (``isotope-timeline/v1``):
     per-window client rows, the most-active services' series, and the
-    convoy verdict."""
+    convoy verdict.
+
+    ``utilization`` is the BUSY OCCUPANCY (in-flight seconds less the
+    queueing wait: CPU, sleeps and time blocked on callees) over
+    window x replicas, not a CPU share: a service that waits on its
+    callees reads above 1 (see :func:`window_stores`).  The document
+    holds the ``top_services`` busiest services (0: all) and says in
+    ``services_truncated`` how many it leaves out."""
     W = tl.num_windows
     dt = float(tl.window_s)
     arr = _np(tl.arrivals)
@@ -585,6 +683,11 @@ def to_doc(
             "in_flight": [
                 round(float(v) / dt, 6) for v in inflight[s]
             ],
+            # run totals in seconds, unrounded: the per-window series
+            # above are levels rounded to 1e-6, which a quiet run's
+            # very wide windows flatten to 0
+            "in_flight_s": float(inflight[s].sum()),
+            "busy_s": float(busy[s].sum()),
             "peak_utilization": round(float(util[peak_w]), 6),
             "peak_window": peak_w,
         }

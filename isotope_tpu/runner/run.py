@@ -794,7 +794,10 @@ def _attribution_pass(sim, sharded, use_sharded, topo, load, n, key,
     trim window applies to the reported percentiles only (``trim`` is
     passed for stream parity, it does not restrict the blame
     accumulators).  Best-effort — a blame failure must never fail a
-    case whose metrics already landed."""
+    case whose metrics already landed: it is counted
+    (``attribution_pass_failures``) and warned of, and the case goes on
+    without a blame document.  A caller that OWES the document to a
+    file (``simulate --blame-out``) fails its call on the absence."""
     from isotope_tpu.metrics import attribution as attr_mod
 
     runner = sharded if (use_sharded and sharded is not None) else sim
@@ -804,7 +807,8 @@ def _attribution_pass(sim, sharded, use_sharded, topo, load, n, key,
                 load, n, key, block_size=block, tail=tail, trim=True,
             )
             jax.block_until_ready(attr.count)
-        doc = attr_mod.to_doc(topo.compiled, attr)
+        with telemetry.phase("artifacts.blame"):
+            doc = attr_mod.to_doc(topo.compiled, attr)
         telemetry.counter_inc("attribution_passes")
         return doc, attr
     except Exception as e:  # pragma: no cover - best-effort surface
@@ -820,7 +824,9 @@ def _timeline_pass(sim, sharded, use_sharded, topo, load, n, key,
     streams to the main scan run (same executor, key, and blocking —
     the sharded twin when the mesh served the case), reduced to the
     windowed series on device.  Best-effort — a recorder failure must
-    never fail a case whose metrics already landed."""
+    never fail a case whose metrics already landed
+    (``timeline_pass_failures`` counts it); ``simulate --timeline-out``
+    fails its call on the absent document, as ``--blame-out`` does."""
     from isotope_tpu.metrics import timeline as timeline_mod
 
     runner = sharded if (use_sharded and sharded is not None) else sim
@@ -831,7 +837,8 @@ def _timeline_pass(sim, sharded, use_sharded, topo, load, n, key,
                 window_s=window_s,
             )
             jax.block_until_ready(tl.count)
-        doc = timeline_mod.to_doc(topo.compiled, tl)
+        with telemetry.phase("artifacts.timeline"):
+            doc = timeline_mod.to_doc(topo.compiled, tl)
         telemetry.counter_inc("timeline_passes")
         return doc, tl
     except Exception as e:  # pragma: no cover - best-effort surface

@@ -62,16 +62,19 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _lower_served(sim, compiled, requests, one_chip):
+def _lower_served(sim, compiled, requests, one_chip, collect=True,
+                  **observers):
     """``run_summary``'s program as the CLI's closed loop builds it -
     the block scan with the collector and the trim window - lowered
     for the described chip; its argument list is the single-block
     entry's, plus the two trim-window bounds before the visit / phase
-    tables."""
+    tables.  ``observers``: ``_get_summary``'s ``attr`` / ``timeline``,
+    the program of an observer pass (which carries no collector)."""
     blk = sim.default_block_size() // CONNECTIONS * CONNECTIONS
     fn = sim._get_summary(
         blk, -(-requests // blk), "closed", CONNECTIONS,
-        MetricsCollector(compiled), True, sat=False,
+        MetricsCollector(compiled) if collect else None, True, sat=False,
+        **observers,
     )
     _, a = sim.trace_entry_args(blk, "closed", CONNECTIONS)
     scalar = jax.ShapeDtypeStruct((), jnp.float32)
@@ -102,6 +105,25 @@ def test_cli_summary_program_compiles_for_v5e(one_chip, name, hops, block):
     assert 0 < mem.temp_size_in_bytes < HBM_BYTES
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < HBM_BYTES
+
+
+def test_recorder_pass_of_svc1000_observed_compiles_for_v5e(one_chip):
+    """``svc1000_observed``'s flight-recorder pass (``latency240`` +
+    ``--timeline``: 8 blocks of 33,536 x 1,000 hops, 27 windows of 10 s
+    for 1,000 services).  Its per-window series are masked column sums
+    over the block (PR 47): nothing of (requests x hops x channels) is
+    stacked, where the einsums they replaced held three 0.4 GB copies a
+    block and the cell 4.79 GB on the chip."""
+    compiled = compile_graph(ServiceGraph.from_yaml_file(
+        os.path.join(TOPOLOGIES, "1000-svc_2000-end.yaml")))
+    sim = Simulator(compiled, SimParams(timeline=True))
+    blk = sim.default_block_size() // CONNECTIONS * CONNECTIONS
+    assert blk == 33_536
+    windows = sim.plan_timeline_windows(8 * blk, 1000.0, 10.0)
+    assert windows == (27, 10.0)
+    mem = _lower_served(sim, compiled, 240_000, one_chip, collect=False,
+                        timeline=windows).compile().memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < 1.5e9
 
 
 @pytest.mark.slow

@@ -174,3 +174,86 @@ def test_sweep_profile_captures_traces(tmp_path):
     for r in runs:
         assert glob.glob(str(prof / r / "**" / "*.xplane.pb"),
                          recursive=True)
+
+
+# -- an output asked for by name is written, or the call fails --------------
+
+
+def _observed_argv(tmp_path, *flags):
+    return [
+        "simulate", str(TOPO), "--qps", "200", "--duration", "2s",
+        "--load-kind", "open", "--seed", "3", "--max-requests", "400",
+        "--prometheus", str(tmp_path / "run.prom"), *flags,
+    ]
+
+
+def _break(monkeypatch, method):
+    from isotope_tpu.sim.engine import Simulator
+
+    def boom(self, *args, **kwargs):
+        raise RuntimeError(f"planted: {method} cannot run")
+
+    monkeypatch.setattr(Simulator, method, boom)
+    monkeypatch.setenv("ISOTOPE_MESH", "1x1")
+
+
+@pytest.mark.parametrize("method, flags, out, counter", [
+    ("run_attributed", ("--attribution",), "--blame-out",
+     "attribution_pass_failures"),
+    ("run_timeline", ("--timeline", "1s"), "--timeline-out",
+     "timeline_pass_failures"),
+])
+def test_a_named_output_its_pass_cannot_write_fails_the_call(
+        tmp_path, capsys, monkeypatch, method, flags, out, counter):
+    from isotope_tpu import telemetry
+
+    _break(monkeypatch, method)
+    before = telemetry.snapshot().counters.get(counter, 0)
+    named = tmp_path / "asked-for.json"
+    rc = cli.main(_observed_argv(tmp_path, *flags, out, str(named)))
+    cap = capsys.readouterr()
+    assert rc == 1
+    assert not named.exists()
+    assert f"error: {out} {named} was not written" in cap.err
+    assert "planted" in cap.err            # the pass's own warning
+    # after the artifacts it has: the Fortio document and the exposition
+    assert json.loads(cap.out)["DurationHistogram"]["Count"] >= 400
+    assert (tmp_path / "run.prom").stat().st_size > 0
+    assert telemetry.snapshot().counters[counter] == before + 1
+
+
+@pytest.mark.parametrize("method, flags, counter", [
+    ("run_attributed", ("--attribution",), "attribution_pass_failures"),
+    ("run_timeline", ("--timeline", "1s"), "timeline_pass_failures"),
+])
+def test_a_table_on_stderr_alone_stays_best_effort(
+        tmp_path, capsys, monkeypatch, method, flags, counter):
+    from isotope_tpu import telemetry
+
+    _break(monkeypatch, method)
+    before = telemetry.snapshot().counters.get(counter, 0)
+    rc = cli.main(_observed_argv(tmp_path, *flags))
+    cap = capsys.readouterr()
+    assert rc == 0
+    assert "warning" in cap.err and "error:" not in cap.err
+    assert json.loads(cap.out)["DurationHistogram"]["Count"] >= 400
+    assert telemetry.snapshot().counters[counter] == before + 1
+
+
+def test_named_outputs_are_written_where_the_passes_run(tmp_path, capsys,
+                                                        monkeypatch):
+    monkeypatch.setenv("ISOTOPE_MESH", "1x1")
+    rc = cli.main(_observed_argv(
+        tmp_path, "--attribution", "--blame-out", str(tmp_path / "b.json"),
+        "--timeline", "1s", "--timeline-out", str(tmp_path / "t.json")))
+    capsys.readouterr()
+    assert rc == 0
+    assert json.loads((tmp_path / "b.json").read_text())[
+        "schema"] == "isotope-blame/v1"
+    timeline = json.loads((tmp_path / "t.json").read_text())
+    assert timeline["schema"] == "isotope-timeline/v1"
+    # the run totals in seconds stand beside the rounded levels
+    for row in timeline["services"].values():
+        assert row["in_flight_s"] == pytest.approx(
+            sum(row["in_flight"]) * timeline["window_s"], abs=1e-5)
+        assert 0.0 < row["busy_s"] <= row["in_flight_s"] * (1 + 1e-5)

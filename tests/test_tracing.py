@@ -295,6 +295,39 @@ def test_self_times_of_a_call_sum_to_its_root_span(served):
     assert snap.phases["cli.parse.self"] < 0.1 * snap.phases["cli.parse"]
 
 
+def test_observed_call_names_its_passes_and_its_documents(tmp_path):
+    """``--attribution --blame-out`` and ``--timeline --timeline-out``:
+    each pass is a phase under ``run.case``, and each document's
+    ``to_doc`` (in the runner, under ``run.case``) and table + JSON
+    write (in the command, under ``cli.main``) one ``artifacts.*`` leaf:
+    none of the new work falls to a container's self time."""
+    telemetry.reset()
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out), \
+            contextlib.redirect_stderr(io.StringIO()):
+        rc = cli.main([
+            "simulate", TOPOLOGY, "--qps", "100", "-c", "4", "--duration",
+            "20s", "--load-kind", "closed", "--seed", "7", "--no-degrade",
+            "--compile-cache", "off", "--prometheus",
+            str(tmp_path / "run.prom"), "--attribution", "--blame-out",
+            str(tmp_path / "blame.json"), "--timeline", "--timeline-out",
+            str(tmp_path / "timeline.json")])
+    assert rc == 0
+    snap = telemetry.snapshot()
+    for name in ("attribution.pass", "timeline.pass"):
+        assert snap.phase_parents[name] == ["run.case"]
+        assert snap.phases[name] > 0
+    for name in ("artifacts.blame", "artifacts.timeline"):
+        assert sorted(snap.phase_parents[name]) == ["cli.main", "run.case"]
+        assert snap.phase_self[name] == snap.phases[name] > 0
+    assert snap.counters["attribution_passes"] == 1
+    assert snap.counters["timeline_passes"] == 1
+    assert snap.counters.get("attribution_pass_failures", 0) == 0
+    assert snap.counters.get("timeline_pass_failures", 0) == 0
+    own = sum(snap.phase_self[name] for name in snap.phase_parents)
+    assert own == pytest.approx(snap.phases["cli.main"], abs=1e-4)
+
+
 def test_served_call_counts_at_the_new_boundaries(served):
     snap = served[0]
     assert snap.counters["signature_bytes_hashed"] > 0
