@@ -8,7 +8,14 @@ critical path of its unrolled call tree *inside* the existing
 ``lax.scan`` block reduction (and the sharded ``psum`` merge), so the
 per-request tensors are reduced to O(H) blame vectors + O(S * buckets)
 blame histograms before they ever leave the device.  Nothing O(N * H)
-reaches the host.
+reaches the host.  Every accumulator is reduced over requests, then
+scattered over a static axis: the histogram is a census per (hop,
+bucket) whose H rows land on services, and a level's winner search is
+a max over the width axis of a padded (slot x width) layout of its
+calls wherever the level's static structure allows one - no scatter
+carries the request axis (a level outside the dense form keeps the
+scatter-max / scatter-min search; ``attribution_calls_dense`` /
+``attribution_calls_scatter`` count the calls on each path).
 
 Decomposition (exact, telescoping):
 
@@ -47,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from isotope_tpu import telemetry
 from isotope_tpu.compiler.program import CompiledGraph, hop_wire_times
 
 # Coarse log-spaced blame buckets: per-service blame histograms are
@@ -163,6 +171,94 @@ class AttributionSummary(NamedTuple):
 # -- static tables ----------------------------------------------------------
 
 
+# A level's calls take the padded (slot x width) layout while the padding
+# stays under this share of the calls (svc1000's ragged level: 744 cells
+# for 741 calls); past it (one hub slot among slots of 1) the level keeps
+# the scatter search.
+MAX_PAD_SHARE = 0.25
+# A static column gather whose index is this few contiguous runs is
+# copied as slices; one run over everything is the array itself.
+_MAX_COPY_RUNS = 8
+
+
+def padded_slots(seg: np.ndarray, n: int) -> Optional[np.ndarray]:
+    """The members ``0..n-1`` of sorted segments as a padded table.
+
+    ``seg[i]`` is member i's segment key.  Where the keys never fall -
+    a segment's members are then one contiguous run, which is how
+    ``compile_graph`` emits a level's calls - and padding every segment
+    to the widest costs at most ``MAX_PAD_SHARE`` of ``n``, returns the
+    ``(segments, width)`` int32 table of member ids, row by row in key
+    order, ``n`` in the padding cells.  ``None`` otherwise.
+    """
+    seg = np.asarray(seg)
+    if n == 0 or np.any(np.diff(seg) < 0):
+        return None
+    starts = np.flatnonzero(np.r_[True, np.diff(seg) != 0])
+    widths = np.diff(np.r_[starts, n])
+    width = int(widths.max())
+    if len(starts) * width > (1.0 + MAX_PAD_SHARE) * n:
+        return None
+    rank = np.arange(width)
+    return np.where(
+        rank < widths[:, None], starts[:, None] + rank, n
+    ).astype(np.int32)
+
+
+@dataclasses.dataclass(frozen=True)
+class _DenseLevel:
+    """Host index tables of one level's scatter-free winner search:
+    its calls as a padded (slot x width) layout, its slots as a padded
+    (parent x steps) one.  A table's sentinel is one past its source's
+    last column."""
+
+    att_cols: Tuple[np.ndarray, ...]  # per attempt (K,) child of a call
+    slots: np.ndarray          # (n_slots, W) call per cell
+    call_cell: np.ndarray      # (K,) flat cell of each call
+    call_of_child: np.ndarray  # (C,) owning call of each child
+    slot_parent: np.ndarray    # (n_slots,) level-local parent hop
+    steps: np.ndarray          # (P, Q) slot per cell, P calling parents
+    parent_row: np.ndarray     # (L,) row of ``steps`` per hop
+
+
+def _dense_level(
+    call_seg: np.ndarray,
+    slot_parent: np.ndarray,
+    call_of_child: np.ndarray,
+    num_hops: int,
+) -> Optional[_DenseLevel]:
+    """The dense tables of a level, or ``None`` where its structure
+    does not allow them (``padded_slots`` decides, twice)."""
+    K, C = len(call_seg), len(call_of_child)
+    slots = padded_slots(call_seg, K)
+    steps = padded_slots(slot_parent, len(slot_parent))
+    if slots is None or steps is None:
+        return None
+    # a call's children (its attempts; a leaf call's subtree child),
+    # in child order
+    order = np.argsort(call_of_child, kind="stable")
+    counts = np.bincount(call_of_child, minlength=K)
+    first = np.cumsum(counts) - counts
+    att_cols = tuple(
+        np.where(
+            a < counts, order[np.minimum(first + a, C - 1)], C
+        ).astype(np.int32)
+        for a in range(int(counts.max()))
+    )
+    parents = np.unique(slot_parent)
+    parent_row = np.full(num_hops, len(parents), np.int32)
+    parent_row[parents] = np.arange(len(parents), dtype=np.int32)
+    return _DenseLevel(
+        att_cols=att_cols,
+        slots=slots,
+        call_cell=np.flatnonzero(slots.ravel() < K).astype(np.int32),
+        call_of_child=np.asarray(call_of_child, np.int32),
+        slot_parent=np.asarray(slot_parent, np.int32),
+        steps=steps,
+        parent_row=parent_row,
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class _LevelTables:
     """Static index tables for one depth level's blame sweep."""
@@ -181,6 +277,7 @@ class _LevelTables:
     child_timeout: Optional[jax.Array]  # (C,) +inf when none
     has_timeout: bool
     svc: np.ndarray             # (L,) static service id per hop
+    dense: Optional[_DenseLevel] = None  # None: the scatter search
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,6 +343,10 @@ def build_tables(compiled: CompiledGraph, net) -> AttrTables:
             slot_segs // compiled.max_steps, slot_segs % compiled.max_steps
         )
         timeout = lvl.call_timeout[call_of_child].astype(np.float32)
+        dense = _dense_level(
+            lvl.call_seg, slot_segs // compiled.max_steps,
+            call_of_child, lvl.num_hops,
+        )
         nxt = compiled.levels[d + 1]
         levels.append(
             _LevelTables(
@@ -260,7 +361,15 @@ def build_tables(compiled: CompiledGraph, net) -> AttrTables:
                 child_timeout=jnp.asarray(timeout),
                 has_timeout=bool(np.isfinite(timeout).any()),
                 svc=svc,
+                dense=dense,
             )
+        )
+    for name, on_path in (
+        ("attribution_calls_dense", lambda t: t.dense is not None),
+        ("attribution_calls_scatter", lambda t: t.dense is None),
+    ):
+        telemetry.counter_inc(
+            name, sum(t.num_calls for t in levels if on_path(t))
         )
     return AttrTables(
         levels=tuple(levels),
@@ -275,21 +384,104 @@ def build_tables(compiled: CompiledGraph, net) -> AttrTables:
 # -- the on-device blame sweep ----------------------------------------------
 
 
+def _take_cols(x: jax.Array, idx: np.ndarray, fill=0.0) -> jax.Array:
+    """``x[:, idx]`` for a STATIC index; ``idx == x.shape[1]`` reads
+    ``fill``.  An index of a few contiguous runs is copied as slices
+    (the whole range: ``x`` itself), anything else is one gather."""
+    n, k = x.shape
+    idx = np.asarray(idx)
+    pad = idx == k
+    joined = np.where(
+        pad[1:] | pad[:-1], pad[1:] & pad[:-1], np.diff(idx) == 1
+    )
+    bounds = np.r_[0, np.flatnonzero(~joined) + 1, len(idx)]
+    if len(bounds) - 1 <= _MAX_COPY_RUNS:
+        pieces = [
+            jnp.full((n, b - a), fill, x.dtype) if pad[a]
+            else x[:, idx[a]:idx[a] + b - a]
+            for a, b in zip(bounds[:-1], bounds[1:])
+        ]
+        return pieces[0] if len(pieces) == 1 else jnp.concatenate(
+            pieces, 1
+        )
+    if pad.any():
+        x = jnp.concatenate([x, jnp.full((n, 1), fill, x.dtype)], 1)
+    return x[:, idx]
+
+
+def _sum_cols(x: jax.Array, cols: Sequence[np.ndarray]) -> jax.Array:
+    """Sum of ``x``'s static column sets, first to last."""
+    total = _take_cols(x, cols[0])
+    for c in cols[1:]:
+        total = total + _take_cols(x, c)
+    return total
+
+
 def _winner_charges(lvl: _LevelTables, w, sent_c, lat_c):
     """Per-child critical-path charges at one level.
 
     ``w`` is the level's (N, L) crit weights; returns
     ``(D, on_crit, att_dur, capped)``: the per-parent charged duration
     and the per-child path weights/durations.
+
+    One algorithm - a segmented max over static segments, first index
+    among equals - in the layout the level's widths allow: reductions
+    over the width axis of ``lvl.dense``'s padded tables, or, for a
+    level they do not take, column scatters with the request axis as
+    the window.
     """
-    n = sent_c.shape[0]
-    K, S = lvl.num_calls, lvl.n_slots
     # attempt duration exactly as the engine's call outcome: capped by
     # the call's timeout; an unsent / refused attempt costs 0
     raw = lvl.child_rtt + lat_c
     att_dur = sent_c * (
         jnp.minimum(raw, lvl.child_timeout) if lvl.has_timeout else raw
     )
+    capped = (raw > lvl.child_timeout) if lvl.has_timeout else None
+    search = _search_scatter if lvl.dense is None else _search_dense
+    D, on_crit = search(lvl, w, sent_c, att_dur)
+    return D, on_crit, att_dur, capped
+
+
+def _search_dense(lvl: _LevelTables, w, sent_c, att_dur):
+    """``(D, on_crit)`` with no scatter: serial attempts of one call
+    sum over its static attempt columns; concurrent calls at one step
+    join via a max over the slot's width, and the winner is the FIRST
+    cell that holds the max - the engine's WaitGroup argmax, the lowest
+    call id among equals."""
+    dn = lvl.dense
+    n = sent_c.shape[0]
+    S, W = dn.slots.shape
+    P, Q = dn.steps.shape
+    dur_call = _sum_cols(att_dur, dn.att_cols)                 # (N, K)
+    dur = _take_cols(dur_call, dn.slots.ravel(), -jnp.inf).reshape(
+        n, S, W
+    )
+    slot_max = jnp.maximum(dur.max(-1), 0.0)                   # (N, S)
+    beats_sleep = slot_max >= lvl.slot_base
+    rank = jnp.arange(W, dtype=jnp.int32)
+    first = jnp.where(dur == slot_max[..., None], rank, W).min(-1)
+    on_slot = _take_cols(w, dn.slot_parent) * beats_sleep      # (N, S)
+    on_call = _take_cols(
+        (on_slot[..., None] * (rank == first[..., None])).reshape(
+            n, S * W
+        ),
+        dn.call_cell,
+    )                                                          # (N, K)
+    on_crit = _take_cols(on_call, dn.call_of_child) * sent_c   # (N, C)
+    d_slot = _take_cols(
+        _sum_cols(on_crit * att_dur, dn.att_cols), dn.slots.ravel()
+    ).reshape(n, S, W).sum(-1)
+    d_parent = _take_cols(d_slot, dn.steps.ravel()).reshape(
+        n, P, Q
+    ).sum(-1)
+    return _take_cols(d_parent, dn.parent_row), on_crit
+
+
+def _search_scatter(lvl: _LevelTables, w, sent_c, att_dur):
+    """``(D, on_crit)`` for a level outside the dense form (a hub slot
+    thousands wide among slots of 1)."""
+    n = sent_c.shape[0]
+    K, S = lvl.num_calls, lvl.n_slots
     # serial attempts of one call sum; concurrent calls at one step join
     # via max — the winner is the engine's WaitGroup argmax (first max)
     dur_call = (
@@ -318,13 +510,38 @@ def _winner_charges(lvl: _LevelTables, w, sent_c, lat_c):
         * is_win[:, lvl.call_of_child]
         * sent_c
     )                                                # (N, C) f32
-    capped = (raw > lvl.child_timeout) if lvl.has_timeout else None
     D = (
         jnp.zeros((n, lvl.size))
         .at[:, lvl.parent_local]
         .add(on_crit * att_dur)
     )
-    return D, on_crit, att_dur, capped
+    return D, on_crit
+
+
+def _bucket_census(idx, weights, dtype) -> List[jax.Array]:
+    """Per-hop blame-bucket censuses ``sum_n w[n, h] * [idx[n, h] == k]``
+    as (L, NUM_BLAME_BUCKETS) arrays, one per (N, L) weight in
+    ``weights``: reduced over requests as exceedances ``idx >= k`` and
+    differenced (the collector's form, metrics/prometheus.py).  The
+    buckets LEAD, so the compare fuses into the reduction and no
+    (N, L, buckets) tensor is ever stored; ``dtype`` sums are exact
+    (the weights are 0/1, a block holds under 2**24 requests)."""
+    ks = jnp.arange(1, NUM_BLAME_BUCKETS, dtype=jnp.int32)
+    at_least = idx[None] >= ks[:, None, None]
+    out = []
+    for wt in weights:
+        wt = wt.astype(dtype)
+        above = jnp.where(at_least, wt[None], 0).sum(1, dtype=dtype)
+        out.append(
+            jnp.concatenate(
+                [
+                    wt.sum(0, dtype=dtype)[None] - above[:1],
+                    above[:-1] - above[1:],
+                    above[-1:],
+                ]
+            ).T
+        )
+    return out
 
 
 @jax.named_scope("attribution/block")
@@ -394,82 +611,85 @@ def attribute_block(
     ]
     t_tmo_l: List[jax.Array] = [jnp.zeros(1)]
     hist = jnp.zeros(
-        tables.num_services * NUM_BLAME_BUCKETS, count_dtype
+        (tables.num_services, NUM_BLAME_BUCKETS), count_dtype
     )
-    t_hist = jnp.zeros(
-        tables.num_services * NUM_BLAME_BUCKETS, count_dtype
-    )
+    t_hist = jnp.zeros_like(hist)
 
     for li, lvl in enumerate(tables.levels):
         sl = slice(lvl.offset, lvl.offset + lvl.size)
         lat = lat_all[:, sl]
         wait = wait_all[:, sl]
+        D, w_next = 0.0, None
         if lvl.child_size:
             csl = slice(
                 lvl.child_offset, lvl.child_offset + lvl.child_size
             )
-            D, on_crit, att_dur, capped = _winner_charges(
-                lvl, w, sent_f[:, csl], lat_all[:, csl]
-            )
-            if capped is not None:
-                w_next = on_crit * ~capped
-                net_c = w_next * lvl.child_rtt
-                tmo_c = on_crit * capped * att_dur
-            else:
-                w_next = on_crit
-                net_c = on_crit * lvl.child_rtt
-                tmo_c = None
-            net_l.append(net_c.sum(0))
-            tmo_l.append(
-                tmo_c.sum(0) if tmo_c is not None
-                else jnp.zeros(lvl.child_size)
-            )
-            per_req = per_req + net_c.sum(1)
-            if tmo_c is not None:
-                per_req = per_req + tmo_c.sum(1)
-            if tail_w is not None:
-                t_net_l.append((net_c * tail_w[:, None]).sum(0))
-                t_tmo_l.append(
-                    (tmo_c * tail_w[:, None]).sum(0)
-                    if tmo_c is not None
+            with jax.named_scope("winner"):
+                D, on_crit, att_dur, capped = _winner_charges(
+                    lvl, w, sent_f[:, csl], lat_all[:, csl]
+                )
+        with jax.named_scope("sums"):
+            if lvl.child_size:
+                if capped is not None:
+                    w_next = on_crit * ~capped
+                    net_c = w_next * lvl.child_rtt
+                    tmo_c = on_crit * capped * att_dur
+                else:
+                    w_next = on_crit
+                    net_c = on_crit * lvl.child_rtt
+                    tmo_c = None
+                net_l.append(net_c.sum(0))
+                tmo_l.append(
+                    tmo_c.sum(0) if tmo_c is not None
                     else jnp.zeros(lvl.child_size)
                 )
-            else:
-                t_net_l.append(jnp.zeros(lvl.child_size))
-                t_tmo_l.append(jnp.zeros(lvl.child_size))
-        else:
-            D = 0.0
-            w_next = None
+                per_req = per_req + net_c.sum(1)
+                if tmo_c is not None:
+                    per_req = per_req + tmo_c.sum(1)
+                if tail_w is not None:
+                    t_net_l.append((net_c * tail_w[:, None]).sum(0))
+                    t_tmo_l.append(
+                        (tmo_c * tail_w[:, None]).sum(0)
+                        if tmo_c is not None
+                        else jnp.zeros(lvl.child_size)
+                    )
+                else:
+                    t_net_l.append(jnp.zeros(lvl.child_size))
+                    t_tmo_l.append(jnp.zeros(lvl.child_size))
 
-        hop_wait = w * wait
-        hop_self = w * (lat - wait) - D
-        contrib = hop_wait + hop_self  # == w * lat - D
-        per_req = per_req + contrib.sum(1)
-        crit_l.append(
-            w.astype(count_dtype).sum(0) if packed else w.sum(0)
-        )
-        wait_l.append(hop_wait.sum(0))
-        self_l.append(hop_self.sum(0))
-        # clamp before bucketing: f32 accumulation can leave an
-        # off-path hop's contribution a hair below zero, and log(<0)
-        # would scatter its weight into the overflow bucket
-        flat_idx = (
-            jnp.asarray(lvl.svc)[None, :] * NUM_BLAME_BUCKETS
-            + blame_bucket_index(jnp.maximum(contrib, 0.0))
-        )
-        hist = hist.at[flat_idx].add(w.astype(count_dtype))
-        if tail_w is not None:
-            wt = w * tail_w[:, None]
-            t_crit_l.append(
-                wt.astype(count_dtype).sum(0) if packed else wt.sum(0)
+            hop_wait = w * wait
+            hop_self = w * (lat - wait) - D
+            contrib = hop_wait + hop_self  # == w * lat - D
+            per_req = per_req + contrib.sum(1)
+            crit_l.append(
+                w.astype(count_dtype).sum(0) if packed else w.sum(0)
             )
-            t_wait_l.append((hop_wait * tail_w[:, None]).sum(0))
-            t_self_l.append((hop_self * tail_w[:, None]).sum(0))
-            t_hist = t_hist.at[flat_idx].add(wt.astype(count_dtype))
-        else:
-            t_crit_l.append(jnp.zeros(lvl.size, count_dtype))
-            t_wait_l.append(jnp.zeros(lvl.size))
-            t_self_l.append(jnp.zeros(lvl.size))
+            wait_l.append(hop_wait.sum(0))
+            self_l.append(hop_self.sum(0))
+            if tail_w is not None:
+                wt = w * tail_w[:, None]
+                t_crit_l.append(
+                    wt.astype(count_dtype).sum(0) if packed
+                    else wt.sum(0)
+                )
+                t_wait_l.append((hop_wait * tail_w[:, None]).sum(0))
+                t_self_l.append((hop_self * tail_w[:, None]).sum(0))
+            else:
+                t_crit_l.append(jnp.zeros(lvl.size, count_dtype))
+                t_wait_l.append(jnp.zeros(lvl.size))
+                t_self_l.append(jnp.zeros(lvl.size))
+        with jax.named_scope("hist"):
+            # clamp before bucketing: f32 accumulation can leave an
+            # off-path hop's contribution a hair below zero, and
+            # log(<0) would put its weight into the overflow bucket
+            idx = blame_bucket_index(jnp.maximum(contrib, 0.0))
+            census = _bucket_census(
+                idx, [w] if tail_w is None else [w, wt], count_dtype
+            )
+            # L rows of buckets onto services: no request axis
+            hist = hist.at[lvl.svc].add(census[0])
+            if tail_w is not None:
+                t_hist = t_hist.at[lvl.svc].add(census[1])
         w = w_next
 
     resid = res.client_latency - per_req
@@ -507,10 +727,8 @@ def attribute_block(
         tail_self_blame=jnp.concatenate(t_self_l),
         tail_net_blame=jnp.concatenate(t_net_l),
         tail_timeout_blame=jnp.concatenate(t_tmo_l),
-        hist=hist.reshape(tables.num_services, NUM_BLAME_BUCKETS),
-        tail_hist=t_hist.reshape(
-            tables.num_services, NUM_BLAME_BUCKETS
-        ),
+        hist=hist,
+        tail_hist=t_hist,
         exemplars=None,
     )
     return summary, ex_state

@@ -19,7 +19,16 @@ appended after them (PR 47's first).  Its tests stay collected: they
 read the file cut off after PR 43's entries (``entries_as_of_pr43``),
 which still holds that those entries stand as PR 43 wrote them; run
 from ``benchmark/tests`` they are red, and the repair is the same
-``benchmark`` PR's."""
+``benchmark`` PR's.
+
+``test_contract_observed.py`` holds the metrics that LIST
+``svc1000_observed`` to PR 47's three; PR 48 appended a fourth
+(``attribution_dense_calls_per_call``, a data file).  The test of that
+name below is PR 47's with the list pinned BY NAME - its three still
+stand, and every other listed metric is named here - and shadows the
+imported one; from ``benchmark/tests`` the original is red, the same
+``benchmark`` PR's repair."""
+import json
 import os
 import sys
 
@@ -75,3 +84,58 @@ def entries_as_of_pr43(monkeypatch):
         return b
 
     monkeypatch.setattr(pr43, "bench", cut)
+
+
+# PR 48's per-layer metric, a data file read by the harness's
+# ``telemetry_counter`` kind
+DENSE_CALLS = "attribution_dense_calls_per_call"
+
+
+def test_the_observed_cell_resolves_and_reports_what_a_cell_must():  # noqa: F811
+    from benchmark.harness.cells import BENCH_DIR, load_cell
+    from benchmark.tests import test_contract_observed as pr47
+
+    entry = next(w for w in pr47.bench()["workloads"]
+                 if w["name"] == pr47.CELL)
+    assert entry == dict(entry, config=pr47.CONFIG, traffic=pr47.TRAFFIC,
+                         chips=1)
+    cell = load_cell(pr47.CELL)
+    assert cell.graph == load_cell("svc1000_served").graph
+    assert {m["name"] for m in cell.end_to_end} == {
+        "hop_events_per_s", "call_p50_s", "setup_s"}
+    listed = {m["name"] for m in cell.per_layer if "workloads" in m}
+    assert listed == set(pr47.METRICS) | {DENSE_CALLS}
+    for m in cell.per_layer:
+        base = os.path.join(BENCH_DIR, "layer_metrics", m["name"])
+        assert os.path.exists(base + ".json") or os.path.exists(base + ".py")
+    # every metric that lists no cells is this cell's to report too
+    assert {m["name"] for m in cell.per_layer} - listed == {
+        m["name"] for m in load_cell("svc1000_served").per_layer
+        if "workloads" not in m}
+
+
+def test_the_dense_calls_metric_reads_the_counter_build_tables_moves():
+    from benchmark.harness import readers
+    from benchmark.harness.cells import BENCH_DIR
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == DENSE_CALLS)
+    assert entry == {
+        "name": DENSE_CALLS, "unit": "count", "better": "higher",
+        "source": "program_counter",
+        "layer": "block scan + summary/collector",
+        "moves": "hop_events_per_s", "workloads": ["svc1000_observed"]}
+    with open(os.path.join(
+            BENCH_DIR, "layer_metrics", DENSE_CALLS + ".json")) as f:
+        spec = json.load(f)
+    assert {k: spec[k] for k in ("kind", "counter", "scope", "per")} == {
+        "kind": "telemetry_counter", "counter": "attribution_calls_dense",
+        "scope": "window", "per": "call"}
+    ctx = {"calls": 3, "telemetry": {"window": {"phases": {}, "counters": {
+        "attribution_calls_dense": 2997.0,
+        "attribution_calls_scatter": 0.0}}}}
+    assert readers.read_metric(DENSE_CALLS, ctx) == 999.0
+    # the parent keeps no such counter: 0, and nothing raises
+    ctx["telemetry"]["window"]["counters"] = {}
+    assert readers.read_metric(DENSE_CALLS, ctx) == 0.0
