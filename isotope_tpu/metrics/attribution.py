@@ -56,6 +56,7 @@ import numpy as np
 
 from isotope_tpu import telemetry
 from isotope_tpu.compiler.program import CompiledGraph, hop_wire_times
+from isotope_tpu.compiler.slots import padded_slots, take_cols
 
 # Coarse log-spaced blame buckets: per-service blame histograms are
 # (S, NUM_BLAME_BUCKETS), so svc100k stays ~25 MB where the fine
@@ -169,40 +170,6 @@ class AttributionSummary(NamedTuple):
 
 
 # -- static tables ----------------------------------------------------------
-
-
-# A level's calls take the padded (slot x width) layout while the padding
-# stays under this share of the calls (svc1000's ragged level: 744 cells
-# for 741 calls); past it (one hub slot among slots of 1) the level keeps
-# the scatter search.
-MAX_PAD_SHARE = 0.25
-# A static column gather whose index is this few contiguous runs is
-# copied as slices; one run over everything is the array itself.
-_MAX_COPY_RUNS = 8
-
-
-def padded_slots(seg: np.ndarray, n: int) -> Optional[np.ndarray]:
-    """The members ``0..n-1`` of sorted segments as a padded table.
-
-    ``seg[i]`` is member i's segment key.  Where the keys never fall -
-    a segment's members are then one contiguous run, which is how
-    ``compile_graph`` emits a level's calls - and padding every segment
-    to the widest costs at most ``MAX_PAD_SHARE`` of ``n``, returns the
-    ``(segments, width)`` int32 table of member ids, row by row in key
-    order, ``n`` in the padding cells.  ``None`` otherwise.
-    """
-    seg = np.asarray(seg)
-    if n == 0 or np.any(np.diff(seg) < 0):
-        return None
-    starts = np.flatnonzero(np.r_[True, np.diff(seg) != 0])
-    widths = np.diff(np.r_[starts, n])
-    width = int(widths.max())
-    if len(starts) * width > (1.0 + MAX_PAD_SHARE) * n:
-        return None
-    rank = np.arange(width)
-    return np.where(
-        rank < widths[:, None], starts[:, None] + rank, n
-    ).astype(np.int32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -384,36 +351,11 @@ def build_tables(compiled: CompiledGraph, net) -> AttrTables:
 # -- the on-device blame sweep ----------------------------------------------
 
 
-def _take_cols(x: jax.Array, idx: np.ndarray, fill=0.0) -> jax.Array:
-    """``x[:, idx]`` for a STATIC index; ``idx == x.shape[1]`` reads
-    ``fill``.  An index of a few contiguous runs is copied as slices
-    (the whole range: ``x`` itself), anything else is one gather."""
-    n, k = x.shape
-    idx = np.asarray(idx)
-    pad = idx == k
-    joined = np.where(
-        pad[1:] | pad[:-1], pad[1:] & pad[:-1], np.diff(idx) == 1
-    )
-    bounds = np.r_[0, np.flatnonzero(~joined) + 1, len(idx)]
-    if len(bounds) - 1 <= _MAX_COPY_RUNS:
-        pieces = [
-            jnp.full((n, b - a), fill, x.dtype) if pad[a]
-            else x[:, idx[a]:idx[a] + b - a]
-            for a, b in zip(bounds[:-1], bounds[1:])
-        ]
-        return pieces[0] if len(pieces) == 1 else jnp.concatenate(
-            pieces, 1
-        )
-    if pad.any():
-        x = jnp.concatenate([x, jnp.full((n, 1), fill, x.dtype)], 1)
-    return x[:, idx]
-
-
 def _sum_cols(x: jax.Array, cols: Sequence[np.ndarray]) -> jax.Array:
     """Sum of ``x``'s static column sets, first to last."""
-    total = _take_cols(x, cols[0])
+    total = take_cols(x, cols[0])
     for c in cols[1:]:
-        total = total + _take_cols(x, c)
+        total = total + take_cols(x, c)
     return total
 
 
@@ -453,28 +395,28 @@ def _search_dense(lvl: _LevelTables, w, sent_c, att_dur):
     S, W = dn.slots.shape
     P, Q = dn.steps.shape
     dur_call = _sum_cols(att_dur, dn.att_cols)                 # (N, K)
-    dur = _take_cols(dur_call, dn.slots.ravel(), -jnp.inf).reshape(
+    dur = take_cols(dur_call, dn.slots.ravel(), -jnp.inf).reshape(
         n, S, W
     )
     slot_max = jnp.maximum(dur.max(-1), 0.0)                   # (N, S)
     beats_sleep = slot_max >= lvl.slot_base
     rank = jnp.arange(W, dtype=jnp.int32)
     first = jnp.where(dur == slot_max[..., None], rank, W).min(-1)
-    on_slot = _take_cols(w, dn.slot_parent) * beats_sleep      # (N, S)
-    on_call = _take_cols(
+    on_slot = take_cols(w, dn.slot_parent) * beats_sleep       # (N, S)
+    on_call = take_cols(
         (on_slot[..., None] * (rank == first[..., None])).reshape(
             n, S * W
         ),
         dn.call_cell,
     )                                                          # (N, K)
-    on_crit = _take_cols(on_call, dn.call_of_child) * sent_c   # (N, C)
-    d_slot = _take_cols(
+    on_crit = take_cols(on_call, dn.call_of_child) * sent_c    # (N, C)
+    d_slot = take_cols(
         _sum_cols(on_crit * att_dur, dn.att_cols), dn.slots.ravel()
     ).reshape(n, S, W).sum(-1)
-    d_parent = _take_cols(d_slot, dn.steps.ravel()).reshape(
+    d_parent = take_cols(d_slot, dn.steps.ravel()).reshape(
         n, P, Q
     ).sum(-1)
-    return _take_cols(d_parent, dn.parent_row), on_crit
+    return take_cols(d_parent, dn.parent_row), on_crit
 
 
 def _search_scatter(lvl: _LevelTables, w, sent_c, att_dur):

@@ -5,7 +5,9 @@ One jit-compiled tensor program replaces the reference's entire data plane:
 - the per-request script interpreter (isotope/service/pkg/srv/handler.go:
   66-76 + executable.go:43-179) becomes two static sweeps over the depth
   levels of the unrolled call tree — an upward pass computing each hop's
-  server-side duration (concurrent fan-out joins via scatter-max, the
+  server-side duration (concurrent fan-out joins via a max over the
+  width axis of the step slots' padded layout - compiler/slots.py; a
+  scatter-max only where a level's slot widths refuse the layout - the
   vectorized WaitGroup of executable.go:171-175; sequential steps sum,
   handler.go:66) and a downward pass assigning absolute start times;
 - Fortio's load loop (perf/benchmark/runner/runner.py:255-268) becomes an
@@ -51,6 +53,7 @@ from isotope_tpu.compiler import buckets
 from isotope_tpu.compiler.cache import array_digest, executable_cache
 from isotope_tpu.resilience import faults
 from isotope_tpu.compiler.program import CompiledGraph, hop_wire_times
+from isotope_tpu.compiler.slots import SlotJoin, join_slots, slot_join
 from isotope_tpu.sim import levelscan, queueing
 from isotope_tpu.sim.config import (
     CLOSED_LOOP,
@@ -152,6 +155,11 @@ class _Level:
     # c when call_seg == repeat(arange(size*pmax), c): the per-step
     # aggregation is a reshape-reduce instead of a scatter
     uniform_calls: Optional[int] = None
+    # the calls' padded (slot x width) layout and its place in the
+    # (size x pmax) grid, where a dense level's calls are not uniform
+    # and ``slot_join`` takes them: the aggregation is a reduction over
+    # the width axis.  None with ``uniform_calls`` None: the scatter
+    join: Optional[SlotJoin] = None
     # sparse call-slot step encoding (skewed wide levels); None = dense
     sparse: Optional["_SparseSteps"] = None
     # dense-blocked tiling of a skewed wide level (the default sparse
@@ -488,18 +496,19 @@ def _sparse_level_sweep(
         slot_agg = dur_call
         slot_fail = final_transport
     else:
-        slot_agg = (
-            jnp.zeros((n, S))
-            .at[:, sp.call_slot]
-            .max(dur_call)
-        )
-        slot_fail = (
-            jnp.zeros((n, S), bool)
-            .at[:, sp.call_slot]
-            .max(final_transport)
-            if final_transport is not None
-            else None
-        )
+        with jax.named_scope("join"):
+            slot_agg = (
+                jnp.zeros((n, S))
+                .at[:, sp.call_slot]
+                .max(dur_call)
+            )
+            slot_fail = (
+                jnp.zeros((n, S), bool)
+                .at[:, sp.call_slot]
+                .max(final_transport)
+                if final_transport is not None
+                else None
+            )
     dyn = jnp.maximum(sp.slot_base, slot_agg)
     if slot_fail is not None:
         fail_slot = (
@@ -575,16 +584,17 @@ def _tile_sweep(
     transportable = final_transport is not None
     need_off = tile.child_sel.size > 0
     if tile.call_sel.size:
-        dc = dur_call[:, tile.call_sel]
-        if tile.uniform_calls is not None:
-            agg = dc.reshape(n, T, W, tile.uniform_calls).max(-1)
-        else:
-            agg = (
-                jnp.zeros((n, T * W))
-                .at[:, tile.call_seg]
-                .max(dc)
-                .reshape(n, T, W)
-            )
+        with jax.named_scope("join"):
+            dc = dur_call[:, tile.call_sel]
+            if tile.uniform_calls is not None:
+                agg = dc.reshape(n, T, W, tile.uniform_calls).max(-1)
+            else:
+                agg = (
+                    jnp.zeros((n, T * W))
+                    .at[:, tile.call_seg]
+                    .max(dc)
+                    .reshape(n, T, W)
+                )
     else:
         agg = None
     fail_t = None
@@ -656,6 +666,77 @@ def _table_bytes(tables) -> int:
             getattr(tables, f.name) for f in dataclasses.fields(tables)
         ))
     return 0
+
+
+def _join_level(
+    lvl: _Level,
+    n: int,
+    dur_call: jax.Array,                   # (n, K)
+    final_transport: Optional[jax.Array],  # (n, K) or None
+):
+    """The join of a dense level's concurrent calls: ``(agg,
+    fail_step)``, the (n, size, pmax) max of ``dur_call`` over the calls
+    of each step slot (0.0 where a slot holds none) and the (n, size)
+    first transport-failed step of each hop (``pmax``: none; ``None``
+    when no transport failure can occur).
+
+    One reduction in the layout the level's ``call_seg`` allows: over
+    the last axis of the identity reshape (``uniform_calls``), over the
+    width axis of the padded slots (``join``), or, where the slot widths
+    refuse the padding, as a column scatter over requests.  Durations
+    are >= 0 and steps < pmax, so each fill is its reduction's identity
+    and the three give the same bits."""
+    P = lvl.pmax
+    grid = (n, lvl.size, P)
+    fail_contrib = None
+    if final_transport is not None:
+        fail_contrib = jnp.where(
+            final_transport, lvl.call_step, P
+        ).astype(jnp.int32)
+    fail_step = None
+    if lvl.uniform_calls is not None:
+        # call_seg == repeat(arange(size*P), c): reshape-reduce
+        agg = dur_call.reshape(grid + (lvl.uniform_calls,)).max(-1)
+        if fail_contrib is not None:
+            fail_step = fail_contrib.reshape(
+                n, lvl.size, P * lvl.uniform_calls
+            ).min(-1)
+    elif lvl.join is not None:
+        agg = join_slots(dur_call, lvl.join, 0.0, jnp.max).reshape(grid)
+        if fail_contrib is not None:
+            fail_step = join_slots(
+                fail_contrib, lvl.join, P, jnp.min
+            ).reshape(grid).min(-1)
+    else:
+        agg = (
+            jnp.zeros((n, lvl.size * P))
+            .at[:, lvl.call_seg]
+            .max(dur_call)
+            .reshape(grid)
+        )
+        if fail_contrib is not None:
+            fail_step = (
+                jnp.full((n, lvl.size), P, jnp.int32)
+                .at[:, lvl.call_seg // P]
+                .min(fail_contrib)
+            )
+    return agg, fail_step
+
+
+def _level_joins(lvl: _Level):
+    """``(calls, on_scatter)`` of each join an unrolled level traces:
+    the level's own, or one a tile and one for the sparse slots."""
+    if lvl.tiled is not None:
+        for tile in lvl.tiled.tiles:
+            yield len(tile.call_sel), tile.uniform_calls is None
+        if lvl.tiled.residual is not None:
+            yield (len(lvl.tiled.res_call_sel),
+                   lvl.tiled.residual.call_slot is not None)
+    elif lvl.sparse is not None:
+        yield lvl.num_calls, lvl.sparse.call_slot is not None
+    else:
+        yield (lvl.num_calls,
+               lvl.uniform_calls is None and lvl.join is None)
 
 
 def _seg_label(seg) -> str:
@@ -735,6 +816,7 @@ class Simulator:
             offset += lvl.num_hops
         self._levels: Tuple[_Level, ...] = tuple(levels)
         self._build_plan(np_meta, chaos, policies, rollouts, lb)
+        self._count_joins()
         self._build_signature(chaos, mtls, policies, rollouts, lb)
         self._build_copula()
         # (the finite-population law handles chaos/churn phases with
@@ -1390,6 +1472,7 @@ class Simulator:
         sparse: Optional[_SparseSteps] = None
         tiled: Optional[_TiledSteps] = None
         leaf_busy: Optional[jax.Array] = None
+        join: Optional[SlotJoin] = None
         step_mask = step_base = None  # host (L, pmax) f32: dense only
         if n_calls == 0:
             is_real, base = lvl.dense_steps(None, pmax)
@@ -1421,6 +1504,8 @@ class Simulator:
                 # scan bucket): its (L x pmax) pair, made once
                 is_real, step_base = lvl.dense_steps(None, pmax)
                 step_mask = is_real.astype(np.float32)
+                if uniform is None:
+                    join = slot_join(call_seg_p, slots)
         meta = dict(
             size=lvl.num_hops, pmax=pmax, C=len(cids), K=n_calls,
             A=lvl.att_child.shape[0], offset=offset,
@@ -1496,6 +1581,7 @@ class Simulator:
                     np.isfinite(lvl.call_timeout).any()
                 ),
                 uniform_calls=uniform,
+                join=join,
                 sparse=sparse,
                 tiled=tiled,
                 leaf_busy=leaf_busy,
@@ -1561,6 +1647,27 @@ class Simulator:
         self._plan_shapes = tuple(shapes)
         self._plan = tuple(plan)
         self._plan_sig = buckets.plan_signature(plan)
+
+    def _count_joins(self) -> None:
+        """``engine_calls_padded`` / ``engine_calls_scatter``: the calls
+        of the levels the plan runs unrolled (dense, tiled or sparse; a
+        scan bucket's join is ``sim/levelscan.py``'s) by the form their
+        join takes - a reduction over a width axis (the identity reshape
+        and a placement with nothing to join among them), or a column
+        scatter over requests."""
+        joins = [
+            join
+            for seg in self._segments
+            if not isinstance(seg, levelscan.ScanBucket)
+            for join in _level_joins(self._levels[seg.d])
+        ]
+        for name, on_path in (
+            ("engine_calls_padded", lambda on_scatter: not on_scatter),
+            ("engine_calls_scatter", lambda on_scatter: on_scatter),
+        ):
+            telemetry.counter_inc(
+                name, sum(calls for calls, sc in joins if on_path(sc))
+            )
 
     @telemetry.phase("engine.build.signature")
     def _build_signature(self, chaos, mtls, policies, rollouts, lb) -> None:
@@ -5369,33 +5476,10 @@ class Simulator:
                         off_lvls[d] = off
                         step_dur = None
                     else:
-                        if lvl.uniform_calls is not None:
-                            # call_seg == repeat(arange(size*P), c):
-                            # reshape-reduce
-                            agg = dur_call.reshape(
-                                n, lvl.size, P, lvl.uniform_calls
-                            ).max(-1)
-                        else:
-                            agg = (
-                                jnp.zeros((n, lvl.size * P))
-                                .at[:, lvl.call_seg]
-                                .max(dur_call)
-                                .reshape(n, lvl.size, P)
+                        with jax.named_scope("join"):
+                            agg, fail_step = _join_level(
+                                lvl, n, dur_call, final_transport
                             )
-                        if final_transport is not None:
-                            fail_contrib = jnp.where(
-                                final_transport, lvl.call_step, P
-                            ).astype(jnp.int32)
-                            if lvl.uniform_calls is not None:
-                                fail_step = fail_contrib.reshape(
-                                    n, lvl.size, P * lvl.uniform_calls
-                                ).min(-1)
-                            else:
-                                fail_step = (
-                                    jnp.full((n, lvl.size), P, jnp.int32)
-                                    .at[:, lvl.call_seg // P]
-                                    .min(fail_contrib)
-                                )
                         step_dur = (
                             jnp.maximum(lvl.step_base, agg)
                             * lvl.step_mask

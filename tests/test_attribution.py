@@ -479,7 +479,7 @@ def _sleep_floor():
 def _interleaved():
     """Twenty mids, every other one a leaf: the level's slot -> parent
     and parent -> row indices are 10 and 20 runs, past what
-    ``_take_cols`` copies as slices (a gather, with its sentinel)."""
+    ``take_cols`` copies as slices (a gather, with its sentinel)."""
     mids = [f"m{i}" for i in range(20)]
     kids = {m: [f"{m}a", f"{m}b"] for m in mids[::2]}
     return {"services": (
@@ -795,21 +795,3 @@ def test_no_scatter_carries_the_request_axis():
     # scatter-min and the scatter-add of D, all over (requests x calls)
     assert sorted(_scatters_with_axis(_block_jaxpr(skewed, n), n)) == [
         "scatter-add", "scatter-add", "scatter-max", "scatter-min"]
-
-
-@pytest.mark.parametrize("seg, n, want", [
-    ([0, 0, 0, 4, 4, 4], 6, [[0, 1, 2], [3, 4, 5]]),
-    ([2, 2, 2, 2, 7, 7, 7, 7, 9, 9, 9], 11,
-     [[0, 1, 2, 3], [4, 5, 6, 7], [8, 9, 10, 11]]),
-    ([5], 1, [[0]]),
-    ([0, 1, 0], 3, None),                     # a slot's calls apart
-    ([0] * 40 + [1, 2, 3, 4], 44, None),      # 200 cells for 44 calls
-    ([], 0, None),
-])
-def test_padded_slots(seg, n, want):
-    got = attribution.padded_slots(np.asarray(seg, np.int32), n)
-    if want is None:
-        assert got is None
-    else:
-        np.testing.assert_array_equal(got, np.asarray(want, np.int32))
-        assert got.dtype == np.int32

@@ -27,7 +27,9 @@ from ``benchmark/tests`` they are red, and the repair is the same
 name below is PR 47's with the list pinned BY NAME - its three still
 stand, and every other listed metric is named here - and shadows the
 imported one; from ``benchmark/tests`` the original is red, the same
-``benchmark`` PR's repair."""
+``benchmark`` PR's repair.  PR 49 appended
+``engine_scatter_calls_per_call`` (a data file, no ``workloads`` list:
+every cell builds an engine), held by the last test below."""
 import json
 import os
 import sys
@@ -139,3 +141,39 @@ def test_the_dense_calls_metric_reads_the_counter_build_tables_moves():
     # the parent keeps no such counter: 0, and nothing raises
     ctx["telemetry"]["window"]["counters"] = {}
     assert readers.read_metric(DENSE_CALLS, ctx) == 0.0
+
+
+# PR 49's per-layer metric, a data file read by the harness's
+# ``telemetry_counter`` kind; it lists no cells, so every cell reports it
+SCATTER_CALLS = "engine_scatter_calls_per_call"
+
+
+def test_the_scatter_calls_metric_reads_the_counter_the_engine_build_moves():
+    from benchmark.harness import readers
+    from benchmark.harness.cells import BENCH_DIR, load_cell
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == SCATTER_CALLS)
+    assert entry == {
+        "name": SCATTER_CALLS, "unit": "count", "better": "lower",
+        "source": "program_counter",
+        "layer": "block scan + summary/collector",
+        "moves": "hop_events_per_s"}
+    with open(os.path.join(
+            BENCH_DIR, "layer_metrics", SCATTER_CALLS + ".json")) as f:
+        spec = json.load(f)
+    assert {k: spec[k] for k in ("kind", "counter", "scope", "per")} == {
+        "kind": "telemetry_counter", "counter": "engine_calls_scatter",
+        "scope": "window", "per": "call"}
+    for cell in ("svc1000_served", "svc1000_mesh4", "canonical_sweep",
+                 "svc1000_observed"):
+        assert SCATTER_CALLS in {
+            m["name"] for m in load_cell(cell).per_layer}
+    # svc1000_mesh4 at the parent's tables: two builds a call
+    ctx = {"calls": 3, "telemetry": {"window": {"phases": {}, "counters": {
+        "engine_calls_padded": 1548.0, "engine_calls_scatter": 4446.0}}}}
+    assert readers.read_metric(SCATTER_CALLS, ctx) == 1482.0
+    # the parent keeps no such counter: 0, and nothing raises
+    ctx["telemetry"]["window"]["counters"] = {}
+    assert readers.read_metric(SCATTER_CALLS, ctx) == 0.0
