@@ -791,7 +791,10 @@ def run_sweep(args) -> int:
     import dataclasses
 
     from isotope_tpu.commands.common import arm_telemetry
-    from isotope_tpu.compiler.cache import enable_persistent_cache
+    from isotope_tpu.compiler.cache import (
+        enable_persistent_cache,
+        executable_cache,
+    )
 
     from isotope_tpu import telemetry
 
@@ -822,26 +825,41 @@ def run_sweep(args) -> int:
             config = dataclasses.replace(
                 config, timeline=True, timeline_window_s=tl_window
             )
-    results = run_experiment(
-        config,
-        out_dir=args.out,
-        progress=lambda label: print(f"running {label}", file=sys.stderr),
-        resume=not args.fresh,
-        profile_dir=args.profile,
-        export=args.export,
-        policy=_policy(args),
-        vet=args.vet,
-        attribution=args.attribution,
-        timeline=tl_window,
-    )
+    evicted0 = executable_cache.evictions
+    with executable_cache.watch() as programs:
+        results = run_experiment(
+            config,
+            out_dir=args.out,
+            progress=lambda label: print(f"running {label}",
+                                         file=sys.stderr),
+            resume=not args.fresh,
+            profile_dir=args.profile,
+            export=args.export,
+            policy=_policy(args),
+            vet=args.vet,
+            attribution=args.attribution,
+            timeline=tl_window,
+        )
+    evicted = executable_cache.evictions - evicted0
+    telemetry.counter_inc("sweep_runs", len(results))
+    telemetry.counter_inc("sweep_programs", len(programs))
     discarded = [r.label for r in results if r.window.discarded]
     failed = [r.label for r in results if r.failed]
     degraded = [r.label for r in results if r.degraded_to is not None]
     print(
         f"{len(results)} runs -> {args.out}/ "
-        f"({len(discarded)} would be discarded by the collector)",
+        f"({len(discarded)} would be discarded by the collector; "
+        f"{len(programs)} programs, {evicted} evicted)",
         file=sys.stderr,
     )
+    if evicted:
+        print(
+            f"warning: this sweep resolved {len(programs)} programs and "
+            f"the executable cache holds {executable_cache.max_entries}: "
+            f"{evicted} were evicted, so the next pass of this grid in "
+            "the same process compiles them again",
+            file=sys.stderr,
+        )
     if degraded:
         print(
             f"{len(degraded)} run(s) completed DEGRADED: "

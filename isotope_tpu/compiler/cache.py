@@ -22,6 +22,7 @@ XLA again:
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import logging
 import os
@@ -262,6 +263,16 @@ class ExecutableCache:
     sized for a sweep's load-shape grid over a few topologies, not a
     museum of every graph ever built.  Call :meth:`clear` to release
     everything (e.g. between unrelated experiments in one process).
+
+    Thrash caveat: a sweep walks its keys in a fixed order, and a
+    cyclic walk over MORE keys than an LRU holds misses every time: a
+    sweep whose grid needs more than ``max_entries`` programs re-traces
+    and re-lowers all of them on every pass of the same process (a
+    suite, a regression loop, back-to-back calls).  Until PR 50 the
+    latency envelope (5 environments x 6 connection counts) was such a
+    sweep: 48 keys, 16 evictions a pass.  :meth:`cache_stats` says so
+    (``thrashing``), :meth:`watch` counts the keys one sweep resolved,
+    and ``sweep`` warns on stderr when a single sweep evicts.
     """
 
     def __init__(self, max_entries: int = 32):
@@ -270,6 +281,20 @@ class ExecutableCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self._watched: Optional[set] = None
+
+    @contextlib.contextmanager
+    def watch(self):
+        """The distinct keys resolved (hit or built) while the block
+        runs, as a set filled as they come: what ``sweep`` reports as
+        its programs."""
+        outer, self._watched = self._watched, set()
+        try:
+            yield self._watched
+        finally:
+            if outer is not None:
+                outer |= self._watched
+            self._watched = outer
 
     @staticmethod
     def key_digest(key: tuple) -> str:
@@ -277,6 +302,8 @@ class ExecutableCache:
         return hashlib.sha256(repr(key).encode()).hexdigest()[:12]
 
     def get_or_build(self, key: tuple, build: Callable[[], object]):
+        if self._watched is not None:
+            self._watched.add(key)
         if key in self._fns:
             self.hits += 1
             telemetry.counter_inc("executable_cache_hits")
@@ -356,6 +383,11 @@ class ExecutableCache:
             "evictions": self.evictions,
             "entries": len(self._fns),
             "max_entries": self.max_entries,
+            # a full cache that has evicted: a grid walked again in
+            # this process re-compiles what it dropped (the docstring)
+            "thrashing": bool(
+                self.evictions and len(self._fns) >= self.max_entries
+            ),
             "keys": keys,
         }
 

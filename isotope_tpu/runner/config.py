@@ -70,10 +70,21 @@ class EnvironmentModel:
     # extra one-way per-edge latency on top of the proxy passes
     extra_hop_latency_s: float = 0.0
 
-    def apply(self, params: SimParams) -> SimParams:
+    def latencies(self) -> Tuple[float, float]:
+        """The environment as the data plane sees it, two one-way
+        latencies: what it adds to EVERY edge (the proxy passes and the
+        free-form tax) and what it adds to the client -> entry edge
+        alone (the gateway's pass).  A sweep hands them to one engine a
+        topology as arguments (``Simulator.bound``); :meth:`apply`
+        bakes them into an engine of the environment's own."""
         passes = int(self.client_proxy) + int(self.server_proxy)
-        extra = self.extra_hop_latency_s + passes * self.proxy_latency_s
-        entry_extra = self.proxy_latency_s if self.gateway else 0.0
+        return (
+            self.extra_hop_latency_s + passes * self.proxy_latency_s,
+            self.proxy_latency_s if self.gateway else 0.0,
+        )
+
+    def apply(self, params: SimParams) -> SimParams:
+        extra, entry_extra = self.latencies()
         if not extra and not entry_extra:
             return params
         net = params.network

@@ -185,6 +185,7 @@ class _LazyTopology:
         self._entry_resp = 0.0
         self._graph = None
         self._sims = {}
+        self._shared = None               # (Simulator | None,) once asked
         self._policy_tables = None
         self._policy_tables_built = False
         self._rollout_tables = None
@@ -323,8 +324,64 @@ class _LazyTopology:
             self.mesh_layout = self._spec.describe()
         return self._spec
 
-    def sims(self, env):
-        """(Simulator, ShardedSimulator | None) for an environment."""
+    def _shared_sim(self):
+        """The one engine the topology's plain runs share, or None
+        where sharing is not exact: its environments ride as arguments
+        (``Simulator.bound``) only where nothing else reads the network
+        constants - no second stream (ensemble, search), no observer or
+        control plane, no mesh, and an engine that holds no host table
+        built from them (``Simulator.shareable``)."""
+        if self._shared is None:
+            config = self.config
+            exact = not (
+                config.chaos or config.churn or config.mtls is not None
+                or config.attribution or config.timeline
+                or config.ensemble > 0 or config.search_candidates > 0
+                or self.policy_tables is not None
+                or self.rollout_tables is not None
+                or self.lb_tables is not None
+                or self.mesh_spec().size > 1
+            )
+            sim = None
+            if exact:
+                sim = Simulator(self.compiled, config.sim_params())
+                if not sim.shareable:   # finite timeouts: retry feedback
+                    sim = None
+            self._shared = (sim,)
+        return self._shared[0]
+
+    def sims(self, env, load=None):
+        """(Simulator, ShardedSimulator | None) for an environment.
+
+        A paced or open-loop ``load`` of a plain topology gets the
+        shared engine bound to the environment's two latencies and the
+        grid's largest connection count (its lanes): every environment
+        and connection count of the sweep then resolves the same
+        programs.  A grid of ONE environment that adds nothing and ONE
+        connection count has nothing to share and keeps the engine of
+        its own, as does a saturated ``-qps max`` load (its MVA tables
+        are host-built from the network constants) and whatever
+        :meth:`_shared_sim` refuses."""
+        config = self.config
+        edge_s, entry_s = env.latencies()
+        share = (
+            load is not None
+            and (load.qps is not None or load.kind == OPEN_LOOP)
+            and (len(config.environments) > 1
+                 or len(config.connections) > 1 or edge_s or entry_s)
+            and self._shared_sim() is not None
+        )
+        if share:
+            # one view an environment; the lanes are the closed loop's
+            # (an open loop has no connection axis)
+            if (env.name, "bound") not in self._sims:
+                self._sims[env.name, "bound"] = (
+                    self._shared_sim().bound(
+                        edge_s, entry_s, max(config.connections)
+                    ),
+                    None,
+                )
+            return self._sims[env.name, "bound"]
         if env.name not in self._sims:
             params = env.apply(self.config.sim_params())
             policies = self.policy_tables
@@ -1326,7 +1383,7 @@ def run_experiment(
                         # compile triggers inside the run) is itself
                         # a supervised phase
                         sim, sharded = call_with_retries(
-                            lambda: topo.sims(env),
+                            lambda: topo.sims(env, load),
                             site="engine.build", policy=policy,
                         )
                         n = _num_requests(

@@ -41,6 +41,10 @@ class RunPlan(NamedTuple):
     sat_conns: int
     kind: str
     trim: bool
+    # a bound engine's static lane count (``Simulator.bound``): the
+    # program's connection axis, ``conns_local`` riding as an argument;
+    # 0: the connections are the program's own axis
+    lanes: int = 0
 
 
 def block_shape(load, num_requests: int, block_size: int = 65_536,
@@ -322,8 +326,12 @@ def block_scan(sim, collector, plan_shape, key, offered_qps, pace_gap,
     from isotope_tpu.sim import summary as summary_mod
 
     block, num_blocks, kind, connections, trim, sat_conns = plan_shape
-    per = block // max(connections, 1)
     core_kw = core_kw or {}
+    # requests a connection a block: of a bound engine's lanes
+    # (``conns`` traced, ``connections`` the lane count) a connection
+    # owns several
+    conns = core_kw.get("conns")
+    per = block // (max(connections, 1) if conns is None else conns)
 
     def body(carry, b):
         (t0, conn_t0, req_off), ctl, obs = carry

@@ -40,6 +40,7 @@ their specialized unrolled per-level trace, bit-identical either way.
 from __future__ import annotations
 
 import contextlib
+import copy
 import dataclasses
 from functools import partial
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -827,7 +828,87 @@ class Simulator:
         self._ensemble_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
         self._search_fns: Dict[tuple, "jax.stages.Wrapped"] = {}
         self._rate_cache: Dict[tuple, float] = {}
+        # a sweep's binding (:meth:`bound`): None on the engine itself
+        self._bound: Optional[Tuple[float, float, int]] = None
         telemetry.counter_inc("simulators_built")
+
+    # -- one engine a topology: the sweep's axes as arguments --------------
+
+    @property
+    def shareable(self) -> bool:
+        """True where an environment's two latencies and the closed
+        loop's connection count can ride a plain program as traced
+        arguments EXACTLY: nothing on the host was built from the
+        network constants per environment (a chaos schedule's reset
+        paths, the retry feedback's timeout probabilities, an lb law's
+        tables) and no layer reads a per-request tax of its own (the
+        phased mTLS tax; the control planes).  The saturated ``-qps
+        max`` tables are host-built too, but by load, not by engine:
+        :meth:`_saturated` runs refuse a bound engine by name."""
+        return not (
+            self.has_chaos or self._churn or self._mtls is not None
+            or self._policies is not None or self._rollouts is not None
+            or self._lb is not None or self._feedback is not None
+        )
+
+    def bound(self, edge_s: float, entry_s: float,
+              lanes: int = 0) -> "Simulator":
+        """This engine bound to one environment of a sweep: a view that
+        shares every table, program and cache with it, whose plain runs
+        (:meth:`run`, :meth:`run_summary`, the rate solver's pilots)
+        pass ``edge_s`` (one-way latency the environment adds to every
+        edge) and ``entry_s`` (added to the client -> entry edge alone)
+        as two traced float32 scalars, and a closed loop's connection
+        count as a third beside a STATIC lane count ``lanes`` (the
+        largest count of the sweep's grid; 0: the run's own).  So the
+        environments and connection counts of a sweep resolve the same
+        executables.  Only a :attr:`shareable` engine binds; every
+        other entry point of a view refuses by name."""
+        if not self.shareable:
+            raise ValueError(
+                "this engine holds host tables built from the network "
+                "constants (chaos, churn, mTLS, policies, rollouts, lb "
+                "or finite timeouts): build one per environment"
+            )
+        # the identity phase windows' one device copy, before the views
+        # would each make their own
+        self._windows_arg(0.0, False)
+        view = copy.copy(self)
+        view._bound = (float(edge_s), float(entry_s), int(lanes))
+        return view
+
+    def _plain_only(self, what: str) -> None:
+        if self._bound is not None:
+            raise ValueError(
+                f"{what} does not take an environment as arguments: "
+                "build the engine with the environment applied "
+                "(EnvironmentModel.apply)"
+            )
+
+    def _lanes(self, connections: int, n: int) -> int:
+        """The static connection axis of a closed-loop program of ``n``
+        requests a block.  On a bound engine: the sweep's lane count
+        where ``connections`` divides it and ``n`` fills whole lanes
+        (then the lanes hold the static layout's own row-major request
+        -> connection map); else the run's own count, one lane a
+        connection."""
+        c = max(connections, 1)
+        lanes = self._bound[2] if self._bound is not None else 0
+        if lanes > c and lanes % c == 0 and n % lanes == 0:
+            return lanes
+        return c
+
+    def _bound_kw(self, connections: int) -> dict:
+        """The traced keywords a bound program takes beside the plain
+        arguments - the environment's pair and the connection count -
+        and none on an engine that is not bound."""
+        if self._bound is None:
+            return {}
+        edge_s, entry_s, _ = self._bound
+        return dict(
+            env=jnp.asarray([edge_s, entry_s], jnp.float32),
+            conns=jnp.int32(max(connections, 1)),
+        )
 
     @telemetry.phase("engine.build.load")
     def _build_load(self, compiled, params, chaos, churn, mtls, policies,
@@ -2253,6 +2334,7 @@ class Simulator:
                     jnp.float32(load.qps), jnp.float32(0.0),
                     visits_pc=self._vis_arg(load.qps),
                     phase_windows=self._windows_arg(load.qps, False),
+                    **self._bound_kw(0),
                 )
         lam = self.solve_closed_rate(load, num_requests, key,
                                      fixed_point_iters)
@@ -2268,11 +2350,14 @@ class Simulator:
         nominal_gap = jnp.float32(load.connections / lam)
         sat = self._saturated(load)
         with self._detail_ctx():
-            return self._get(num_requests, CLOSED_LOOP, load.connections,
-                             sat=sat)(
+            return self._get(
+                num_requests, CLOSED_LOOP,
+                self._lanes(load.connections, num_requests), sat=sat,
+            )(
                 key, jnp.float32(lam), gap, jnp.float32(lam), nominal_gap,
                 visits_pc=self._vis_arg(lam),
                 phase_windows=self._windows_arg(lam, sat),
+                **self._bound_kw(load.connections),
             )
 
     @staticmethod
@@ -2336,6 +2421,7 @@ class Simulator:
         the RNG key, so it is memoized per load shape.
         """
         if self._saturated(load):
+            self._plain_only("a saturated -qps max run")
             # the closed network's throughput is what MVA computes exactly
             # (product-form) — no pilot runs needed.  Phased runs
             # time-weight the per-row rates over the chaos windows the
@@ -2343,15 +2429,33 @@ class Simulator:
             with telemetry.phase("closed_rate.mva"):
                 thr = self._closed_tables(load.connections)[0]
                 return self._sat_phased_rate(thr, num_requests)
+        # a bound engine's views share the cache: the rate is a property
+        # of the environment too
         cache_key = (load.qps, load.connections, min(num_requests, 2048),
-                     fixed_point_iters)
+                     fixed_point_iters, (self._bound or ())[:2])
         if cache_key in self._rate_cache:
             telemetry.counter_inc("closed_rate_memo_hits")
-            return self._rate_cache[cache_key]
+        else:
+            self._rate_cache[cache_key] = self._bisect_closed_rate(
+                load, num_requests, key, fixed_point_iters
+            )
+        lam = self._rate_cache[cache_key]
+        if load.qps is not None and lam < load.qps:
+            # the connections cannot carry the target: the run is paced
+            # by its own latency (ActualQPS < RequestedQPS is the law)
+            telemetry.counter_inc("closed_rate_throttled_runs")
+        return lam
+
+    def _bisect_closed_rate(self, load: LoadModel, num_requests: int,
+                            key: jax.Array,
+                            fixed_point_iters: int) -> float:
+        """:meth:`solve_closed_rate`'s bisection over pilot runs."""
         cap = 0.999 * self.capacity_qps()
         hi = min(load.qps, cap) if load.qps is not None else cap
         pilot_n = min(num_requests, 2048)
-        pilot = self._get(pilot_n, CLOSED_LOOP, load.connections)
+        pilot = self._get(pilot_n, CLOSED_LOOP,
+                          self._lanes(load.connections, pilot_n))
+        bound_kw = self._bound_kw(load.connections)
         gap = (
             jnp.float32(load.connections / load.qps)
             if load.qps is not None
@@ -2366,6 +2470,7 @@ class Simulator:
                 jnp.float32(lam), jnp.float32(load.connections / lam),
                 visits_pc=self._vis_arg(lam),
                 phase_windows=self._windows_arg(lam, False),
+                **bound_kw,
             )
             mean_lat = float(res.client_latency.mean())  # device sync
             out = load.connections / max(mean_lat, 1e-9)
@@ -2373,7 +2478,6 @@ class Simulator:
 
         if implied(hi, 0) >= hi:
             # pacing (or capacity) binds before self-throttling
-            self._rate_cache[cache_key] = hi
             return hi
         lo = 0.0
         for i in range(1, max(4 * fixed_point_iters, 10)):
@@ -2384,9 +2488,7 @@ class Simulator:
                 hi = mid
             if hi - lo < 1e-3 * hi:
                 break
-        lam = 0.5 * (lo + hi)
-        self._rate_cache[cache_key] = lam
-        return lam
+        return 0.5 * (lo + hi)
 
     def _sat_phased_rate(self, thr: np.ndarray, num_requests: int) -> float:
         """Average ``-qps max`` throughput over the chaos phases a run of
@@ -2446,6 +2548,10 @@ class Simulator:
             self, load, num_requests, key, block_size=block_size,
             trim=trim, fixed_point_iters=fixed_point_iters,
         )
+        if self._bound is not None and plan.kind == CLOSED_LOOP:
+            plan = plan._replace(
+                lanes=self._lanes(plan.conns_local, plan.block)
+            )
         # up to the return of the async call (the first call of a
         # program also traces and compiles in here)
         with telemetry.phase("summary.dispatch"):
@@ -2463,8 +2569,11 @@ class Simulator:
                          timeline: Optional[Tuple[int, float]] = None):
         """The block-scan program of a planned run
         (sim/blockscan.py ``RunPlan``), checked and gauged."""
+        if attr is not None or timeline is not None:
+            self._plain_only("an observed run")
         fn = self._get_summary(
-            plan.block, plan.num_blocks, plan.kind, plan.conns_local,
+            plan.block, plan.num_blocks, plan.kind,
+            plan.lanes or plan.conns_local,
             collector, plan.trim, sat=plan.sat_conns > 0, attr=attr,
             timeline=timeline,
         )
@@ -2485,6 +2594,7 @@ class Simulator:
                 self._vis_arg(plan.offered),
                 self._windows_arg(plan.offered, plan.sat_conns > 0),
                 *tail_cut,
+                **self._bound_kw(plan.conns_local),
             )
 
     # -- scenario ensembles (sim/ensemble.py) ---------------------------
@@ -2615,6 +2725,7 @@ class Simulator:
         trailing traced arguments."""
         from isotope_tpu.sim import blockscan
 
+        self._plain_only("a fleet")
         protected = prot is not None
         roll = prot == "rollouts"
         with_pol = protected and self._policies is not None
@@ -4236,6 +4347,10 @@ class Simulator:
             sds((P, self.compiled.num_services), f32),  # visits_pc
             sds((2, self._num_windows), f32),           # phase_windows
         )
+        if self._bound is not None:
+            # the bound program: the lanes static, (env, conns) traced
+            connections = self._lanes(connections, n)
+            args += (sds((2,), f32), sds((), jnp.int32))
         return partial(self._simulate, n, kind, connections, False), args
 
     def default_block_size(self, budget_elems: int = 33_554_432) -> int:
@@ -4264,7 +4379,12 @@ class Simulator:
 
     def _get(self, n: int, kind: str, connections: int = 0,
              sat: bool = False):
+        """The dense one-block program.  On a bound engine
+        (:meth:`bound`) ``connections`` is the lane count and the call
+        takes ``env=`` / ``conns=`` (:meth:`_bound_kw`)."""
         key = (n, kind, connections, sat)
+        if self._bound is not None:
+            key += ("bound",)
         if key not in self._fns:
             # process-wide AOT reuse: an equal signature means the
             # traced program would be identical (compiler/cache.py), so
@@ -4284,6 +4404,11 @@ class Simulator:
         """Jitted scan-over-blocks program (sim/blockscan.py) producing
         a RunSummary and, after it, what the run's observers reduce.
 
+        On a bound engine (:meth:`bound`) ``connections`` is the lane
+        count and the program takes the environment's pair and the
+        connection count by keyword (:meth:`_bound_kw`): one program
+        for every environment and connection count of a sweep.
+
         ``attr in ("mean", "tail")`` threads the blame reduction
         through the block scan (an AttributionSummary; ``"tail"``
         weights a second accumulator set by ``client_latency >=
@@ -4293,6 +4418,8 @@ class Simulator:
         so observer-off runs stay byte-identical."""
         cache_key = (block, num_blocks, kind, connections,
                      collector is not None, trim, sat, attr, timeline)
+        if self._bound is not None:
+            cache_key += ("bound",)
         if cache_key not in self._summary_fns:
             from isotope_tpu.sim import blockscan
 
@@ -4305,7 +4432,7 @@ class Simulator:
 
             def scanfn(key, offered_qps, pace_gap, arrival_qps,
                        nominal_gap, win_lo, win_hi, visits_pc,
-                       phase_windows, tail_cut=None):
+                       phase_windows, tail_cut=None, **bound_kw):
                 telemetry.record_trace(
                     ("summary", self.signature[3]) + cache_key,
                     tracing=isinstance(key, jax.core.Tracer),
@@ -4316,6 +4443,7 @@ class Simulator:
                     arrival_qps, nominal_gap, win_lo, win_hi,
                     visits_pc, phase_windows,
                     self._observers(block, attr, timeline, tail_cut),
+                    core_kw=bound_kw,
                 )
                 return (summary, *observed) if observed else summary
 
@@ -4390,6 +4518,8 @@ class Simulator:
         nominal_gap: Optional[jax.Array] = None,
         visits_pc: Optional[jax.Array] = None,
         phase_windows: Optional[jax.Array] = None,
+        env: Optional[jax.Array] = None,
+        conns: Optional[jax.Array] = None,
     ) -> SimResults:
         """One self-contained block starting at t=0 (see _simulate_core)."""
         # host-side telemetry: this body executes once per TRACE (jit)
@@ -4397,7 +4527,8 @@ class Simulator:
         # the counters survive the jit boundary by construction, and a
         # repeated trace of one signature is a retrace detection
         telemetry.record_trace(
-            ("simulate", self.signature[3], n, kind, connections, sat),
+            ("simulate", self.signature[3], n, kind, connections, sat)
+            + (("bound",) if env is not None else ()),
             tracing=isinstance(key, jax.core.Tracer),
             requests=n, hops=self.compiled.num_hops,
         )
@@ -4411,6 +4542,7 @@ class Simulator:
             sat_conns=connections if sat else 0,
             visits_pc=visits_pc,
             phase_windows=phase_windows,
+            env=env, conns=conns,
         )
         return res
 
@@ -4437,6 +4569,8 @@ class Simulator:
         cpu_scale: Optional[jax.Array] = None,
         err_scale: Optional[jax.Array] = None,
         chaos_fx=None,  # Optional[compile.ChaosFx] (ONE member's rows)
+        env: Optional[jax.Array] = None,
+        conns: Optional[jax.Array] = None,
     ) -> Tuple[SimResults, jax.Array, jax.Array]:
         """``offered_qps`` drives the queueing model (the rate the whole
         fleet of services sees); ``arrival_qps`` paces this batch's
@@ -4458,6 +4592,16 @@ class Simulator:
         ``cpu_scale`` / ``err_scale`` are the ensemble members'
         per-member physics perturbations (sim/ensemble.py): traced
         scalars so one vmapped fleet program serves every jitter draw.
+
+        ``env`` / ``conns`` are a bound engine's sweep axes
+        (:meth:`bound`), traced: ``env`` the (2,) float32 pair (one-way
+        latency added to every edge, and to the client -> entry edge
+        alone), riding where the mTLS tax rides; ``conns`` the int32
+        connection count of a closed loop whose ``connections`` is then
+        the static LANE count: a connection owns ``connections //
+        conns`` consecutive lanes of ``n // connections`` requests -
+        the row-major request -> connection map of the static layout.
+        With both ``None`` the program is the one it always was.
         ``cpu_scale`` multiplies the sampled service times and divides
         every station's mu inside the wait law (canary arm included);
         ``err_scale`` multiplies the per-hop error rates (clipped to
@@ -4660,6 +4804,16 @@ class Simulator:
                 rem_nominal = warp(
                     jnp.full((n - c * per,), req_offset + per)
                 )
+            elif conns is not None:
+                # a lane's requests follow those of its connection's
+                # earlier lanes
+                first = (jnp.arange(c, dtype=jnp.int32)
+                         % (c // conns)) * per
+                nominal = (
+                    req_offset + first.astype(jnp.float32)[:, None]
+                    + jnp.arange(per, dtype=jnp.float32)
+                ) * nominal_gap
+                rem_nominal = jnp.zeros((0,), jnp.float32)
             else:
                 nominal = (
                     req_offset + jnp.arange(per, dtype=jnp.float32)
@@ -4689,6 +4843,12 @@ class Simulator:
                 % len(self._mtls.taxes_s)
             )
             tax = self._mtls_taxes[t_idx]
+        entry_tax = None
+        if env is not None:
+            # the environment's per-edge latency is a tax every request
+            # pays alike (no mTLS schedule beside it: ``shareable``)
+            tax = jnp.broadcast_to(env[0], (n,))
+            entry_tax = env[1]
 
         # ---- traffic-split weights at each request's arrival time --------
         # (N, E+1): one column per schedule + a sentinel 1.0 column for
@@ -5605,6 +5765,9 @@ class Simulator:
         if tax is not None:
             # the client -> entry edge pays the tax on both legs too
             root_wire = root_wire + 2.0 * tax
+        if entry_tax is not None:
+            # the gateway's pass: the client -> entry edge alone
+            root_wire = root_wire + 2.0 * entry_tax
         if root_down is not None:
             root_lat = jnp.where(
                 root_down,
@@ -5620,8 +5783,29 @@ class Simulator:
                 rem = n - c * per
                 lat_conn = root_lat[: c * per].reshape(c, per)
                 spent = jnp.maximum(lat_conn, pace_gap)
-                starts = conn_t0[:, None] + jnp.cumsum(spent, axis=-1) - spent
-                conn_end = conn_t0 + spent.sum(-1)
+                if conns is not None:
+                    # lanes: a lane's clock starts where its
+                    # connection's earlier lanes end - a segmented
+                    # prefix over the c lane totals, the group size
+                    # traced; every lane of a connection carries the
+                    # connection's clock
+                    lane = jnp.arange(c, dtype=jnp.int32)
+                    owner = lane // (c // conns)
+                    mine = owner[:, None] == owner[None, :]
+                    total = spent.sum(-1)[None, :]
+                    ahead = jnp.where(
+                        mine & (lane[None, :] < lane[:, None]), total, 0.0
+                    ).sum(-1)
+                    starts = (
+                        (conn_t0 + ahead)[:, None]
+                        + jnp.cumsum(spent, axis=-1) - spent
+                    )
+                    conn_end = conn_t0 + jnp.where(mine, total, 0.0).sum(-1)
+                else:
+                    starts = (
+                        conn_t0[:, None] + jnp.cumsum(spent, axis=-1) - spent
+                    )
+                    conn_end = conn_t0 + spent.sum(-1)
                 if rem:
                     # remainder requests (n % c) continue on the first ``rem``
                     # connections — each starts when its connection frees up
@@ -5639,6 +5823,8 @@ class Simulator:
         entry_wire = self._entry_one_way
         if tax is not None:
             entry_wire = entry_wire + tax
+        if entry_tax is not None:
+            entry_wire = entry_wire + entry_tax
         start_cur: jax.Array = (arrivals + entry_wire)[:, None]
         start_chunks: List[jax.Array] = []
         telemetry.fence_reset()

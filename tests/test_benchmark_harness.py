@@ -29,7 +29,12 @@ stand, and every other listed metric is named here - and shadows the
 imported one; from ``benchmark/tests`` the original is red, the same
 ``benchmark`` PR's repair.  PR 49 appended
 ``engine_scatter_calls_per_call`` (a data file, no ``workloads`` list:
-every cell builds an engine), held by the last test below."""
+every cell builds an engine), held by the last test below.
+
+PR 50 appended ``canonical_envelope`` / ``canonical_envelope30`` and
+four data-file metrics listed to that cell alone; its four test files
+are collected here with the rest, and no older pin is positional in a
+way they break (PR 43's read the file cut off after its own entries)."""
 import json
 import os
 import sys
@@ -43,20 +48,24 @@ if ROOT not in sys.path:
 from benchmark import run  # noqa: E402
 from benchmark.tests import test_contract_multitier1000 as pr43  # noqa: E402
 from benchmark.tests.test_checks import *  # noqa: E402,F401,F403
+from benchmark.tests.test_checks_envelope import *  # noqa: E402,F401,F403
 from benchmark.tests.test_checks_observed import *  # noqa: E402,F401,F403
 from benchmark.tests.test_checks_outcomes import *  # noqa: E402,F401,F403
 from benchmark.tests.test_checks_retries import *  # noqa: E402,F401,F403
 from benchmark.tests.test_checks_retries1000 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
+from benchmark.tests.test_contract_envelope import *  # noqa: E402,F401,F403
 from benchmark.tests.test_contract_multitier1000 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_contract_observed import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deadline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_host_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_layer_metrics_retries import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference import *  # noqa: E402,F401,F403
+from benchmark.tests.test_reference_envelope import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference_outcomes import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference_retries import *  # noqa: E402,F401,F403
 from benchmark.tests.test_run import *  # noqa: E402,F401,F403
+from benchmark.tests.test_run_envelope import *  # noqa: E402,F401,F403
 from benchmark.tests.test_run_observed import *  # noqa: E402,F401,F403
 from benchmark.tests.test_scope_reader import *  # noqa: E402,F401,F403
 from benchmark.tests.test_stats import *  # noqa: E402,F401,F403
