@@ -2091,12 +2091,13 @@ class Simulator:
                 def census_at(c):
                     # the repairman sweep is itself a per-station fixed
                     # point in w; iterate it to convergence at cycle c
-                    pi_c = pi
-                    w_c = np.full(len(visits), 1.0 / self._mu)
-                    for _ in range(4):
-                        pi_c, w_c = closed.repairman_marginals(
-                            visits, reps, self._mu, c, w_c, connections
-                        )
+                    # (four sweeps in ONE call: the stations are grouped
+                    # and the census gathered back once, not once a sweep)
+                    pi_c, _ = closed.repairman_marginals(
+                        visits, reps, self._mu, c,
+                        np.full(len(visits), 1.0 / self._mu),
+                        connections, sweeps=4,
+                    )
                     return pi_c
 
                 c0 = cycle

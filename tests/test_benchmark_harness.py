@@ -34,7 +34,12 @@ every cell builds an engine), held by the last test below.
 PR 50 appended ``canonical_envelope`` / ``canonical_envelope30`` and
 four data-file metrics listed to that cell alone; its four test files
 are collected here with the rest, and no older pin is positional in a
-way they break (PR 43's read the file cut off after its own entries)."""
+way they break (PR 43's read the file cut off after its own entries).
+
+PR 51 appended ``closed_census_ms`` and
+``closed_rate_class_rows_per_call`` (data files, listed to
+``svc1000_mesh4`` alone: the one cell whose load is ``--qps max``), held
+by the last test below."""
 import json
 import os
 import sys
@@ -186,3 +191,49 @@ def test_the_scatter_calls_metric_reads_the_counter_the_engine_build_moves():
     # the parent keeps no such counter: 0, and nothing raises
     ctx["telemetry"]["window"]["counters"] = {}
     assert readers.read_metric(SCATTER_CALLS, ctx) == 0.0
+
+
+# PR 51's per-layer metrics, data files listed to the one saturated cell:
+# a span the program has had since PR 39 and a counter new in PR 51
+CLOSED_METRICS = {
+    "closed_census_ms": (
+        {"unit": "ms", "source": "program_span"},
+        {"kind": "telemetry_phase", "phases": ["closed_rate.census"],
+         "scope": "window", "per": "call", "scale": 1000.0}),
+    "closed_rate_class_rows_per_call": (
+        {"unit": "count", "source": "program_counter"},
+        {"kind": "telemetry_counter", "counter": "closed_rate_class_rows",
+         "scope": "window", "per": "call"}),
+}
+
+
+@pytest.mark.parametrize("name", CLOSED_METRICS)
+def test_the_station_class_metrics_read_the_saturated_solve(name):
+    from benchmark.harness import readers
+    from benchmark.harness.cells import BENCH_DIR, load_cell
+
+    entry_keys, spec_keys = CLOSED_METRICS[name]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == name)
+    assert entry == dict(
+        entry_keys, name=name, better="lower",
+        layer="closed-loop rate solve", moves="call_p50_s",
+        workloads=["svc1000_mesh4"])
+    with open(os.path.join(BENCH_DIR, "layer_metrics", name + ".json")) as f:
+        spec = json.load(f)
+    assert {k: spec[k] for k in spec_keys} == spec_keys
+    assert name in {m["name"] for m in load_cell("svc1000_mesh4").per_layer}
+    assert name not in {
+        m["name"] for m in load_cell("svc1000_served").per_layer}
+    # svc1000_mesh4: 51 sweeps and 6 fits a call, one station class
+    ctx = {"calls": 3, "telemetry": {"window": {
+        "phases": {"closed_rate.census": 0.0279},
+        "counters": {"closed_rate_class_rows": 171.0,
+                     "closed_rate_station_rows": 171000.0}}}}
+    want = {"closed_census_ms": 9.3, "closed_rate_class_rows_per_call": 57.0}
+    assert readers.read_metric(name, ctx) == pytest.approx(want[name])
+    # the parent keeps no such counter: 0, and nothing raises
+    if spec["kind"] == "telemetry_counter":
+        ctx["telemetry"]["window"]["counters"] = {}
+        assert readers.read_metric(name, ctx) == 0.0

@@ -18,9 +18,18 @@ every replica count, population, ``scv`` and census shape the function
 can be given - and to a bisection on the survival function at the top
 of the grid, where the reference's own CDF has run out of digits.
 
-The last test holds ``Simulator._closed_tables`` - the six arrays a
-``--qps max`` run keeps, five of them handed to the device - to values
-captured on the parent commit (``tests/data/closed_tables_parent.json``).
+``test_closed_tables_hold_the_parents_values`` holds
+``Simulator._closed_tables`` - the six arrays a ``--qps max`` run keeps,
+five of them handed to the device - to values captured on the parent
+commit (``tests/data/closed_tables_parent.json``).
+
+Since ISSUE 51 a sweep and a fit compute one row a station CLASS
+(stations whose inputs are the same bytes) and gather it back to the
+stations.  The per-station references above, and the station loop of
+``tables_from_pi`` as PR 50 had it (``ref_tables_from_pi``), hold that to
+the bit over the ``CLASSES`` networks; ``Simulator._closed_row`` of
+tree13 is held to the bits PR 50's commit gave
+(``tests/data/closed_row_parent.json``).
 """
 import json
 
@@ -265,6 +274,204 @@ def test_each_sweep_is_counted_and_timed():
     assert (telemetry.counter_get("closed_rate_census_sweeps") - count0
             == sweeps + 1)
     assert telemetry.phase_seconds("closed_rate.census") > seconds0
+
+
+# -- station classes (ISSUE 51) ----------------------------------------------
+
+def ref_tables_from_pi(pi, replicas, mu, degree=10, v_max=16.0, scv=1.0):
+    """The parent's ``tables_from_pi``: every station a turn of the loop,
+    the fit of a (rounded weights, k) key made at the first station that
+    has it."""
+    S = pi.shape[0]
+    k = np.asarray(replicas, int)
+    p_zero = np.empty(S)
+    coef = np.zeros((degree + 1, S))
+    mean_wait = np.zeros(S)
+    v_grid = np.linspace(0.0, v_max, 257)[1:]
+    cache = {}
+    for s in range(S):
+        ks = int(k[s])
+        p0 = float(pi[s, :ks].sum())
+        w = pi[s, ks:]
+        wsum = float(w.sum())
+        if wsum <= 1e-12:
+            p_zero[s] = 1.0
+            continue
+        w = w / wsum
+        rate = ks * mu
+        key = np.round(w, 12).tobytes() + bytes([ks & 0xFF])
+        if key not in cache:
+            t = closed._erlang_mixture_quantiles(w, rate, v_grid, scv)
+            c = np.polynomial.polynomial.polyfit(v_grid, t, degree)
+            m = np.arange(1, len(w) + 1)
+            cache[key] = (c, float((w * m).sum()) / rate)
+        c, cond_mean = cache[key]
+        p_zero[s] = p0
+        coef[:, s] = c
+        mean_wait[s] = (1.0 - p0) * cond_mean
+    return p_zero, coef, mean_wait
+
+
+def _classes(visits, replicas, population=24, w_prev=None):
+    visits = np.asarray(visits, np.float64)
+    rng = np.random.default_rng(51)
+    net = dict(
+        visits=visits,
+        # the fork-join overlap differs by station within a class
+        cycle_visits=visits * rng.uniform(0.1, 1.0, size=len(visits)),
+        replicas=np.asarray(replicas, np.float64), mu=MU,
+        delay_s=0.002, population=population,
+    )
+    if w_prev is None:
+        w_prev = np.full(len(visits), 1.0 / MU)
+    return net, np.asarray(w_prev, np.float64)
+
+
+#: two visit ratios a few last places apart: two classes by their bytes,
+#: one key of the fit's memo (the weights agree to 12 decimals).  The
+#: LARGER stands first among the stations and second by its bytes.
+NEAR = (1.0 + 2.0**-50, 1.0)
+
+
+def _own_w_prev():
+    rng = np.random.default_rng(7)
+    visits = np.tile([1.0, 2.0], 60)
+    w_prev = np.full(120, 1.0 / MU)
+    w_prev[::3] *= 1.5                       # splits both (v, k) classes
+    w_prev[5::7] = rng.uniform(1.0, 3.0, size=len(w_prev[5::7])) / MU
+    return _classes(visits, np.full(120, 2.0), w_prev=w_prev)
+
+
+#: name -> (network, the caller's w_prev), ISSUE 51's cases (a) to (f)
+CLASSES = {
+    "a-one-class-of-1000": (_svc1000(), np.full(1000, 1.0 / MU)),
+    "b-three-classes-and-an-unvisited": _classes(
+        np.tile([1.0, 0.25, 0.0, 3.0], 40), np.tile([2.0, 2.0, 2.0, 1.0], 40)),
+    "c-all-distinct": _classes(
+        np.linspace(0.01, 4.0, 97), np.tile([1.0, 2.0, 3.0, 5.0], 25)[:97]),
+    "d-two-classes-under-1e-12": _classes(
+        np.tile(NEAR, 50), np.full(100, 2.0), population=64),
+    "e-mixed-replicas": _classes(
+        np.ones(150), np.tile([1.0, 2.0, 3.0, 40.0, 2.0], 30)),
+    "f-own-w-prev": _own_w_prev(),
+}
+
+
+def _seen(name):
+    """The census ``name``'s stations see at a cycle near the solved one
+    (the per-station loops', so the fit's input is the reference's)."""
+    net, w_prev = CLASSES[name]
+    cycle = net["delay_s"] + float((net["cycle_visits"] * w_prev).sum())
+    return ref_repairman_marginals(
+        net["visits"], net["replicas"], MU, cycle, w_prev,
+        net["population"])[0]
+
+
+@pytest.mark.parametrize("name", [n for n in CLASSES if n[0] != "f"])
+def test_decomposition_over_classes_is_the_per_station_loops(name):
+    net, _ = CLASSES[name]
+    lam, pi, cycle = closed.fork_join_decomposition(**net)
+    lam_ref, pi_ref, cycle_ref, _ = ref_fork_join_decomposition(**net)
+    same(pi, pi_ref, f"{name}: pi")
+    same([lam, cycle], [lam_ref, cycle_ref], f"{name}: (lam, cycle)")
+
+
+@pytest.mark.parametrize("name", CLASSES)
+def test_marginals_over_classes_are_the_per_station_loops(name):
+    net, w_prev = CLASSES[name]
+    cycle = net["delay_s"] + float((net["cycle_visits"] * w_prev).sum())
+    args = (net["visits"], net["replicas"], MU, cycle)
+    w = w_ref = w_prev
+    for sweep in range(4):
+        pi, w = closed.repairman_marginals(*args, w, net["population"])
+        pi_ref, w_ref = ref_repairman_marginals(
+            *args, w_ref, net["population"])
+        same(pi, pi_ref, f"{name}: pi, sweep {sweep}")
+        same(w, w_ref, f"{name}: w, sweep {sweep}")
+    # the engine's census_at: the four sweeps in one call
+    pi4, w4 = closed.repairman_marginals(
+        *args, w_prev, net["population"], sweeps=4)
+    assert np.array_equal(pi4, pi) and np.array_equal(w4, w)
+
+
+@pytest.mark.parametrize("name", CLASSES)
+def test_the_fit_over_classes_is_the_station_loop(name):
+    net, _ = CLASSES[name]
+    pi = _seen(name)
+    got = closed.tables_from_pi(pi, net["replicas"], MU)
+    want = ref_tables_from_pi(pi, net["replicas"], MU)
+    for g, w, what in zip(got, want, ("p_zero", "coef", "mean_wait")):
+        assert g.shape == w.shape and np.array_equal(g, w), what
+
+
+def test_the_rounded_memo_keeps_the_first_station_it_met():
+    """Case (d) has teeth: the two classes' own fits differ, their memo
+    keys do not, and the one every station gets is the FIRST station's -
+    the class that a walk in the order of the bytes would meet second."""
+    net, _ = CLASSES["d-two-classes-under-1e-12"]
+    pi = _seen("d-two-classes-under-1e-12")
+    assert not np.array_equal(pi[0], pi[1])
+    assert pi[0].tobytes() > pi[1].tobytes()
+    tail = pi[:2, 2:] / pi[:2, 2:].sum(axis=1, keepdims=True)
+    assert np.array_equal(np.round(tail[0], 12), np.round(tail[1], 12))
+    alone = [closed.tables_from_pi(pi[s:s + 1], [2.0], MU)[1][:, 0]
+             for s in (0, 1)]
+    assert not np.array_equal(alone[0], alone[1])
+    coef = closed.tables_from_pi(pi, net["replicas"], MU)[1]
+    assert (coef == alone[0][:, None]).all()
+
+
+def test_unvisited_stations_and_sweeps_under_one():
+    net, w_prev = CLASSES["b-three-classes-and-an-unvisited"]
+    idle = net["visits"] == 0.0
+    assert not closed.fork_join_decomposition(**net)[1][idle].any()
+    pi, w = closed.repairman_marginals(
+        net["visits"], net["replicas"], MU, 0.01, w_prev,
+        net["population"], sweeps=2)
+    assert (pi[idle, 0] == 1.0).all() and not pi[idle, 1:].any()
+    assert (w[idle] == w_prev[idle]).all()
+    with pytest.raises(ValueError):
+        closed.repairman_marginals(
+            net["visits"], net["replicas"], MU, 0.01, w_prev,
+            net["population"], sweeps=0)
+
+
+@pytest.mark.parametrize("name, classes", [
+    ("a-one-class-of-1000", 1), ("c-all-distinct", 97),
+    ("b-three-classes-and-an-unvisited", 3)])
+def test_rows_asked_for_and_rows_computed_are_counted(name, classes):
+    """``closed_rate_station_rows``: the visited stations of every sweep
+    and the stations of every fit; ``closed_rate_class_rows``: the rows
+    computed for them.  One class: a thousandth; all distinct: the same
+    number."""
+    net, w_prev = CLASSES[name]
+    visited = int((net["visits"] > 1e-12).sum())
+    stations = len(net["visits"])
+
+    counters = ("closed_rate_census_sweeps", "closed_rate_station_rows",
+                "closed_rate_class_rows")
+
+    def counted(call):
+        before = [telemetry.counter_get(c) for c in counters]
+        out = call()
+        return out, [int(telemetry.counter_get(c) - b)
+                     for c, b in zip(counters, before)]
+
+    _, (sweeps, asked, computed) = counted(
+        lambda: closed.fork_join_decomposition(**net))
+    assert sweeps > 1
+    assert (asked, computed) == (sweeps * visited, sweeps * classes)
+    (pi, _), (sweeps, asked, computed) = counted(
+        lambda: closed.repairman_marginals(
+            net["visits"], net["replicas"], MU, 0.01, w_prev,
+            net["population"], sweeps=4))
+    assert (sweeps, asked, computed) == (4, 4 * visited, 4 * classes)
+    # a fit is asked for every station, the unvisited (a class of their
+    # own: a point mass at 0) among them
+    _, (sweeps, asked, computed) = counted(
+        lambda: closed.tables_from_pi(pi, net["replicas"], MU))
+    assert (sweeps, asked) == (0, stations)
+    assert computed == classes + (visited < stations)
 
 
 # -- the pin through the engine ---------------------------------------------
@@ -549,3 +756,27 @@ def test_closed_tables_hold_the_parents_values(graph):
     parents = fit.polyval(V_GRID, np.asarray(want["coef"])[:, col])
     np.testing.assert_allclose(values, parents, rtol=1e-6,
                                atol=1e-6 * np.abs(parents).max())
+
+
+with open("tests/data/closed_row_parent.json") as _f:
+    PARENT_ROW = json.load(_f)
+
+
+@pytest.mark.parametrize("refine", [True, False])
+def test_closed_row_is_the_parents_to_the_bit(refine):
+    """The station classes change no bit of what ``_closed_row`` hands
+    on: tree13 (thirteen stations, one class) at 64 connections against
+    the values PR 50's commit returned here, with the Little-law closure
+    (six censuses of four sweeps, five probes through the engine on the
+    CPU, six fits) and, ``refine=False``, as a phase row has it (the
+    decomposition and one fit: host arithmetic alone)."""
+    want = PARENT_ROW["refine" if refine else "decomposition"]
+    sim = Simulator(compile_graph(ServiceGraph.from_yaml(TREE13)))
+    got = sim._closed_row(64, 0, refine=refine)
+    names = ("throughput", "p_zero", "coef", "e", "center_c", "var_scale")
+    assert [np.shape(g) for g in got] == [
+        (), (13,), (11, 13), (13,), (), (13,)]
+    for name, g in zip(names, got):
+        parents = np.array([float.fromhex(x) for x in want[name]])
+        assert np.array_equal(
+            np.asarray(g, np.float64).ravel(), parents), name
