@@ -78,14 +78,11 @@ def _count(report: Report) -> None:
     """Fold a report into the telemetry registry."""
     telemetry.counter_inc("vet_runs_total")
     for f in report.findings:
-        telemetry.counter_inc("vet_findings")
         telemetry.counter_inc(f"vet_rule.{f.rule}")
         if f.severity == SEV_ERROR:
             telemetry.counter_inc("vet_errors_total")
         elif f.severity == SEV_WARN:
             telemetry.counter_inc("vet_warnings_total")
-    for _ in report.suppressed:
-        telemetry.counter_inc("vet_suppressed")
 
 
 def vet_simulator(

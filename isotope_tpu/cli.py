@@ -31,6 +31,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    telemetry.install_gc_hook()
     # the root span of a served call: every phase the call opens on
     # this thread lies under it, so their self times sum to its seconds
     with telemetry.phase("cli.main"):

@@ -141,9 +141,7 @@ def compile_policies(graph: ServiceGraph, compiled: CompiledGraph):
     )
     if pols.empty:
         return None
-    tables = policies_mod.build_tables(pols, compiled.services)
-    telemetry.counter_inc("policies_compiled")
-    return tables
+    return policies_mod.build_tables(pols, compiled.services)
 
 
 def compile_lb(graph: ServiceGraph, compiled: CompiledGraph):
@@ -163,9 +161,7 @@ def compile_lb(graph: ServiceGraph, compiled: CompiledGraph):
     lbs = lb_mod.LbSet.decode(graph.policies, compiled.services.names)
     if lbs.empty:
         return None
-    tables = lb_mod.build_tables(lbs, compiled.services)
-    telemetry.counter_inc("lb_compiled")
-    return tables
+    return lb_mod.build_tables(lbs, compiled.services)
 
 
 def compile_rollouts(graph: ServiceGraph, compiled: CompiledGraph):
@@ -187,9 +183,7 @@ def compile_rollouts(graph: ServiceGraph, compiled: CompiledGraph):
     )
     if rset.empty:
         return None
-    tables = rollout_mod.build_tables(rset, compiled.services)
-    telemetry.counter_inc("rollouts_compiled")
-    return tables
+    return rollout_mod.build_tables(rset, compiled.services)
 
 
 class EnsembleTables(NamedTuple):
@@ -230,7 +224,6 @@ def compile_ensemble(spec) -> EnsembleTables:
     qps = ones if spec.qps_scale is None else spec.qps_scale
     cpu = ones if spec.cpu_scale is None else spec.cpu_scale
     err = ones if spec.error_scale is None else spec.error_scale
-    telemetry.counter_inc("ensembles_compiled")
     return EnsembleTables(
         members=n,
         seeds=tuple(spec.seeds),
@@ -389,7 +382,6 @@ def compile_chaos_members(sim, member_events, with_pol: bool = False,
                 f"{P}); per-member chaos requires shape-aligned "
                 "schedules (same event count, distinct solo cuts)"
             )
-    telemetry.counter_inc("chaos_fleets_compiled")
     kw: dict = {}
     pol = with_pol and sim._policies is not None
     if pol:

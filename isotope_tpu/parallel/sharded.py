@@ -644,7 +644,6 @@ class ShardedSimulator:
         )
         n_mem = spec.members
         observed = attribution or timeline
-        telemetry.counter_inc("sharded_ensemble_runs")
         telemetry.gauge_set("ensemble_members", n_mem)
         telemetry.gauge_set("ensemble_members_per_shard", width)
         telemetry.gauge_set("ensemble_rounds", rounds)
@@ -794,7 +793,6 @@ class ShardedSimulator:
         )
         n_mem = spec.members
         observed = attribution or timeline
-        telemetry.counter_inc("sharded_ensemble_emulated_runs")
         fn = self.sim._get_ensemble(
             args["block"], args["num_blocks"], args["kind"],
             args["conns"], trim, args["sat"], width,
@@ -1151,10 +1149,6 @@ class ShardedSimulator:
             )
         )
         n_mem = spec.members
-        telemetry.counter_inc(
-            "sharded_rollout_fleet_runs" if roll
-            else "sharded_policy_fleet_runs"
-        )
         telemetry.gauge_set("ensemble_members", n_mem)
         telemetry.gauge_set("ensemble_members_per_shard", width)
         telemetry.gauge_set("ensemble_rounds", rounds)
@@ -1281,10 +1275,6 @@ class ShardedSimulator:
             )
         )
         n_mem = spec.members
-        telemetry.counter_inc(
-            "sharded_rollout_fleet_emulated_runs" if roll
-            else "sharded_policy_fleet_emulated_runs"
-        )
         fn = self.sim._get_protected_ensemble(
             args["block"], args["num_blocks"], args["kind"],
             args["conns"], trim, tl_plan, roll, width,
@@ -1342,7 +1332,6 @@ class ShardedSimulator:
             )
         plan = self._plan_run(load, num_requests, key, offered_qps,
                               block_size, trim)
-        telemetry.counter_inc("sharded_attributed_runs")
         return self._run_observed(
             plan, key, "tail" if tail else "mean", None,
             jnp.float32(tail_cut if tail else np.inf),
@@ -1412,7 +1401,6 @@ class ShardedSimulator:
         plan = self._plan_run(load, num_requests, key, offered_qps,
                               block_size, trim)
         tl_plan = self._timeline_plan(plan, window_s)
-        telemetry.counter_inc("sharded_timeline_runs")
         return self._run_observed(plan, key, None, tl_plan)
 
     def run_timeline_emulated(
@@ -1982,7 +1970,6 @@ class ShardedSimulator:
         """
         plan = self._plan_run(load, num_requests, key, offered_qps,
                               block_size, trim)
-        telemetry.counter_inc("sharded_emulated_runs")
         telemetry.gauge_set("shard_count", self.n_shards)
         return self._replay(plan, key)
 

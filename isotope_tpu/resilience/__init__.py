@@ -38,6 +38,7 @@ from isotope_tpu.resilience.supervisor import (  # noqa: F401
     backoff_seconds,
     call_with_retries,
     execution_rungs,
+    finish_summary,
     run_ladder,
 )
 from isotope_tpu.resilience.sentinels import (  # noqa: F401

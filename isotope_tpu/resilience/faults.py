@@ -256,7 +256,6 @@ def _load_env() -> None:
     spec = os.environ.get(ENV_FAULT_INJECT)
     if spec:
         _plan = FaultPlan.parse(spec)
-        telemetry.counter_inc("fault_plan_armed", 0.0)  # visibility key
 
 
 def install(spec: str) -> FaultPlan:
@@ -294,7 +293,6 @@ def check(site: str) -> None:
     if entry is None:
         return
     telemetry.counter_inc("faults_injected")
-    telemetry.counter_inc(f"faults_injected.{entry.kind}")
     msg, fault_class = _SHAPES[entry.kind]
     raise InjectedFault(msg.format(site=site), fault_class)
 

@@ -102,7 +102,6 @@ def call_with_retries(fn: Callable[[], object], site: str,
                 raise
             delay = backoff_seconds(site, attempt, policy)
             telemetry.counter_inc("retries_total")
-            telemetry.counter_inc(f"retries.{site}")
             telemetry.phase_add("resilience.backoff", delay)
             policy.sleep(delay)
             attempt += 1
@@ -143,6 +142,22 @@ def run_ladder(
     raise AssertionError("run_ladder: empty rung list")  # pragma: no cover
 
 
+@telemetry.phase("summary.wait")
+def finish_summary(summary):
+    """Block on a run's summary, then arm the numeric sentinels: the
+    time the host waited for this run's work, in its two parts."""
+    import jax
+
+    from isotope_tpu.resilience import sentinels
+
+    with telemetry.phase("summary.ready"):      # the device's part
+        jax.block_until_ready(summary.count)
+    # the summary read back a field at a time, and the checks in numpy
+    with telemetry.phase("summary.sentinels"):
+        sentinels.check_summary(summary)
+    return summary
+
+
 def execution_rungs(
     sim,
     sharded,
@@ -172,19 +187,10 @@ def execution_rungs(
 
     import jax
 
-    from isotope_tpu.resilience import sentinels
-
-    @telemetry.phase("summary.wait")
-    def _finish(summary):
-        # the time the host waited on the device for this run's work
-        jax.block_until_ready(summary.count)
-        sentinels.check_summary(summary)
-        return summary
-
     half = max(256, block_size // 2)
     if use_sharded:
         def _sharded(block):
-            return lambda: _finish(
+            return lambda: finish_summary(
                 sharded.run(load, num_requests, key, block_size=block,
                             trim=trim)
             )
@@ -196,7 +202,7 @@ def execution_rungs(
                     else contextlib.nullcontext()
                 )
                 with ctx:
-                    return _finish(sharded.run_emulated(
+                    return finish_summary(sharded.run_emulated(
                         load, num_requests, key, block_size=block_size,
                         trim=trim,
                     ))
@@ -210,14 +216,14 @@ def execution_rungs(
         ]
 
     def _scan(block):
-        return lambda: _finish(
+        return lambda: finish_summary(
             sim.run_summary(load, num_requests, key, block_size=block,
                             collector=collector, trim=trim)
         )
 
     def _eager():
         with jax.disable_jit():
-            return _finish(
+            return finish_summary(
                 sim.run_summary(load, num_requests, key, block_size=half,
                                 collector=collector, trim=trim)
             )

@@ -59,6 +59,7 @@ from isotope_tpu.resilience import (
     call_with_retries,
     classify,
     execution_rungs,
+    finish_summary,
     run_ladder,
 )
 from isotope_tpu.runner.config import ExperimentConfig
@@ -486,7 +487,6 @@ class _EnsembleGroups:
         )
 
         if label in self.results:
-            telemetry.counter_inc("ensemble_collapsed_cases")
             return self.results.pop(label)
         spec = self.spec
         n_seeds = spec.members
@@ -594,7 +594,6 @@ class _EnsembleGroups:
         )
 
         if label in self.results:
-            telemetry.counter_inc("ensemble_collapsed_cases")
             return self.results.pop(label)
         spec = self.spec
         n_seeds = spec.members
@@ -677,7 +676,6 @@ class _EnsembleGroups:
                 member_chaos=member_chaos, **obs_kw,
             )
             jax.block_until_ready(ens.summaries.count)
-        telemetry.counter_inc("protected_fleet_cases")
         self.completed.update(c["label"] for c in group)
         for i, c in enumerate(group):
             sl = slice(i * n_seeds, (i + 1) * n_seeds)
@@ -760,7 +758,6 @@ def _vet_gate(mode: str, sim, topo, config, load, block, rungs,
         telemetry.gauge_set("vet_peak_bytes_estimate", float(est))
     start = int(report.meta.get("start_rung", 0))
     if start:
-        telemetry.counter_inc("vet_rung_preselections")
         telemetry.set_meta("vet_start_rung", rungs[start][0])
         print(
             f"vet: memory verdict pre-selects ladder rung "
@@ -812,7 +809,6 @@ def _load_checkpoint(path: pathlib.Path, fingerprint: str) -> List[dict]:
         try:
             rec = json.loads(line)
         except json.JSONDecodeError:
-            telemetry.counter_inc("checkpoint_quarantined_records")
             print(
                 f"warning: quarantined corrupt checkpoint record "
                 f"{path}:{i} (its run will re-execute)",
@@ -820,7 +816,6 @@ def _load_checkpoint(path: pathlib.Path, fingerprint: str) -> List[dict]:
             )
             continue
         if not isinstance(rec, dict) or "label" not in rec:
-            telemetry.counter_inc("checkpoint_quarantined_records")
             continue
         records.append(rec)
     return records
@@ -936,10 +931,6 @@ def _protected_call(runner, method: str, spec, load, n, key, kwargs,
     ``spec``'s mode, blocking on the summary with the numeric
     sentinels armed (deferred device errors must surface inside the
     supervised scope)."""
-    import contextlib
-
-    from isotope_tpu.resilience import sentinels
-
     _, b, mode = spec
     fn = getattr(runner, f"{method}_emulated" if mode == "emu"
                  else method)
@@ -947,8 +938,7 @@ def _protected_call(runner, method: str, spec, load, n, key, kwargs,
         else contextlib.nullcontext()
     with ctx:
         out = fn(load, n, key, block_size=b, **kwargs, **extra)
-        jax.block_until_ready(out[0].count)
-    sentinels.check_summary(out[0])
+        finish_summary(out[0])
     return out
 
 
@@ -1144,14 +1134,11 @@ def _splitting_pass(sim, sharded, use_sharded, topo, load, n,
 
     try:
         with telemetry.phase("splitting.pass"):
-            doc = split_mod.subset_estimate(
+            return split_mod.subset_estimate(
                 evaluate, split,
                 chaos_components=max(len(chaos), 1),
             )
-        telemetry.counter_inc("splitting_passes")
-        return doc
     except Exception as e:  # pragma: no cover - best-effort surface
-        telemetry.counter_inc("splitting_pass_failures")
         print(f"warning: splitting pass failed: {e}", file=sys.stderr)
         return None
 
@@ -1456,17 +1443,11 @@ def run_experiment(
                                         attribution=attribution,
                                         timeline=timeline,
                                     )
-                                telemetry.counter_inc(
-                                    "ensemble_cases"
-                                )
                                 telemetry.set_meta(
                                     "ensemble",
                                     str(ens_summary.members),
                                 )
                             except Exception as e:
-                                telemetry.counter_inc(
-                                    "ensemble_fallbacks"
-                                )
                                 # the solo fallback serves this
                                 # cell: keep later groups from
                                 # re-dispatching its members
@@ -1568,17 +1549,11 @@ def run_experiment(
                                                 pol_attr,
                                             )
                                         )
-                                    telemetry.counter_inc(
-                                        "ensemble_cases"
-                                    )
                                     telemetry.set_meta(
                                         "ensemble",
                                         str(ens_summary.members),
                                     )
                                 except Exception as e:
-                                    telemetry.counter_inc(
-                                        "ensemble_fallbacks"
-                                    )
                                     # the solo fallback serves
                                     # this cell: keep later
                                     # groups from re-dispatching
@@ -1633,7 +1608,6 @@ def run_experiment(
                     # survive one broken deployment the same way
                     err_class = classify(e)
                     err_text = f"{type(e).__name__}: {e}"
-                    telemetry.counter_inc("run_failures")
                     print(
                         f"error: run {label} failed "
                         f"({err_class}): {err_text}",
@@ -1980,13 +1954,7 @@ def run_experiment(
                                 window_s=win_arr,
                             )
                             flat["_fleet_blame"] = True
-                            telemetry.counter_inc(
-                                "fleet_blame_docs"
-                            )
                         except Exception as e:
-                            telemetry.counter_inc(
-                                "fleet_blame_failures"
-                            )
                             print(
                                 f"warning: fleet-blame "
                                 f"explainer for {label} failed "
@@ -2027,15 +1995,11 @@ def run_experiment(
                         flat["_search"] = (
                             search_spec_cfg.members
                         )
-                        telemetry.counter_inc("search_cases")
                         telemetry.set_meta(
                             "search",
                             str(search_spec_cfg.members),
                         )
                     except Exception as e:
-                        telemetry.counter_inc(
-                            "search_fallbacks"
-                        )
                         print(
                             f"warning: config-search bracket "
                             f"for {label} failed "

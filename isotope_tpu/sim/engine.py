@@ -780,6 +780,7 @@ class Simulator:
         lb=None,  # Optional[lb.LbTables]
     ):
         telemetry.install_jax_hooks()
+        telemetry.install_gc_hook()
         faults.check("engine.build")
         if compiled.hop_subtree.any() and (
             len(chaos) or policies is not None or rollouts is not None
@@ -3369,7 +3370,6 @@ class Simulator:
             )
         chunk_sz = max(1, min(int(chunk_sz), n_mem))
         n_chunks = -(-n_mem // chunk_sz)
-        telemetry.counter_inc("ensemble_runs")
         telemetry.gauge_set("ensemble_members", n_mem)
         telemetry.gauge_set("ensemble_chunk", chunk_sz)
         telemetry.gauge_set("engine_block_requests", args["block"])
@@ -3535,12 +3535,10 @@ class Simulator:
             else self.params.timeline_window_s
         )
         expected = total_requests / max(float(offered), 1e-9)
-        w, dt_eff, clamped = timeline_mod.plan_windows(
+        w, dt_eff, _ = timeline_mod.plan_windows(
             expected, dt, self.params.timeline_max_windows,
             self.compiled.num_services,
         )
-        if clamped:
-            telemetry.counter_inc("timeline_window_clamps")
         return w, dt_eff
 
     def run_timeline(
@@ -3579,7 +3577,6 @@ class Simulator:
         )
         fn = self._prepare_summary(load, plan, collector,
                                    timeline=tl_plan)
-        telemetry.counter_inc("timeline_runs")
         return self._call_summary(fn, plan, key)
 
     def run_policies(
@@ -3695,7 +3692,6 @@ class Simulator:
         self._check_lb_load(load)
         telemetry.gauge_set("engine_block_requests", plan.block)
         telemetry.gauge_set("engine_num_blocks", plan.num_blocks)
-        telemetry.counter_inc("rollout_runs" if roll else "policy_runs")
         with self._detail_ctx():
             return fn(
                 key, jnp.float32(plan.offered), jnp.float32(plan.gap),
@@ -4168,9 +4164,6 @@ class Simulator:
             )
         chunk_sz = max(1, min(int(chunk_sz), n_mem))
         n_chunks = -(-n_mem // chunk_sz)
-        telemetry.counter_inc(
-            "rollout_fleet_runs" if roll else "policy_fleet_runs"
-        )
         telemetry.gauge_set("ensemble_members", n_mem)
         telemetry.gauge_set("ensemble_chunk", chunk_sz)
         telemetry.gauge_set("engine_block_requests", args["block"])
@@ -4320,7 +4313,6 @@ class Simulator:
         fn = self._prepare_summary(
             load, plan, collector, attr="tail" if tail else "mean"
         )
-        telemetry.counter_inc("attributed_runs")
         return self._call_summary(
             fn, plan, key, jnp.float32(tail_cut if tail else np.inf)
         )

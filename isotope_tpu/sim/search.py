@@ -581,7 +581,6 @@ def _run_bracket(sim, load, num_requests: int, key, spec: SearchSpec,
     )
     block = args["block"]
     plan = plan_bracket(spec, num_requests, block)
-    telemetry.counter_inc("search_runs")
     telemetry.gauge_set("search_candidates", pop.members)
     telemetry.gauge_set("search_rungs", spec.rungs)
     telemetry.set_meta("search_path", path)
@@ -872,7 +871,6 @@ def run_search_protected(sim, load, num_requests: int, key,
         window_s,
     )
     with_pol = sim._policies is not None
-    telemetry.counter_inc("search_protected_runs")
     telemetry.gauge_set("search_candidates", pop.members)
     telemetry.gauge_set("search_rungs", spec.rungs)
     telemetry.set_meta(
@@ -997,7 +995,6 @@ def run_search_sharded(sh, load, num_requests: int, key,
     stay the solo path's jnp ops, so lineage is bit-identical to
     :func:`run_search` and :func:`run_search_emulated`."""
     sh._require_mesh("run_search")
-    telemetry.counter_inc("sharded_search_runs")
     return _run_bracket(
         sh.sim, load, num_requests, key, spec, block_size,
         lambda args, tables, plan: _sharded_dispatch(
@@ -1012,7 +1009,6 @@ def run_search_emulated(sh, load, num_requests: int, key,
                         block_size: int = 65_536,
                         chunk: Optional[int] = None) -> SearchSummary:
     """The sharded bracket's laptop twin (EmulatedMesh-friendly)."""
-    telemetry.counter_inc("sharded_search_emulated_runs")
     return _run_bracket(
         sh.sim, load, num_requests, key, spec, block_size,
         lambda args, tables, plan: _emulated_dispatch(

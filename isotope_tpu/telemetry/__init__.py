@@ -20,6 +20,7 @@ from isotope_tpu.telemetry.core import (  # noqa: F401
     gauge_max,
     gauge_set,
     get_meta,
+    install_gc_hook,
     install_jax_hooks,
     iter_jsonl,
     phase,

@@ -15,16 +15,40 @@ report through:
   compiled programs.  Counters recorded inside a jitted function body
   therefore count *traces* (host executions), not executed requests —
   which is exactly what makes them retrace detectors.
-- **One span primitive, two clocks**: :func:`phase` accumulates host
-  seconds in the registry AND opens a ``jax.profiler.TraceAnnotation``
-  of the same name, so under a profiler session every phase lies in
-  the trace's host plane on the clock the device planes use (outside a
-  session: an inactive TraceMe).  It is a context manager and a
-  decorator.  Phases nest: each thread keeps a stack of its open
-  phases, so a phase knows the phase that opened it (``phase_parents``)
-  and its self time (``phase_self``: its seconds minus what its direct
-  children cover).  A phase with children is a container; what its
-  ``<name>.self`` reads is host time no leaf names yet.
+- **One span primitive, in the registry and in the profiler**:
+  :func:`phase` accumulates host seconds in the registry AND opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so under a
+  profiler session every phase lies in the trace's host plane on the
+  clock the device planes use (outside a session: an inactive
+  TraceMe).  It is a context manager and a decorator.  Phases nest:
+  each thread keeps a stack of its open phases, so a phase knows the
+  phase that opened it (``phase_parents``) and its self time
+  (``phase_self``: its seconds minus what its direct children cover).
+  A phase with children is a container; what its ``<name>.self`` reads
+  is host time no leaf names yet.
+- **Two clocks in the registry**: beside its wall seconds
+  (``time.perf_counter``) a phase records its thread's CPU seconds
+  (``time.thread_time``) in ``phase_cpu``.  Wall - CPU is the time the
+  thread was off the processor: asleep on the device, a transfer, a
+  file or another thread's work (XLA's compile pool, a put's copy), or
+  not scheduled.  Of a leaf that is pure Python it is what the machine
+  took.  The CPU clock is a system call (6 us in a loop and ~25 in
+  place on the sandboxed kernel of the benchmark's host, where the
+  wall clock's vDSO read costs 0.1): a phase with no parent always
+  reads it, every other phase only while someone is watching -
+  ``--telemetry`` asked for artifacts or a profiler session is open -
+  so the served path gains no system call a phase.
+- **What the machine did to a call** is read where a phase closes with
+  no parent (``cli.main`` is the served call's): the thread's
+  involuntary context switches and the process's CPU seconds move two
+  counters, and the slowest WARM call (one that compiled no program)
+  is kept whole in ``meta["slowest_warm_call"]``: its wall, CPU and
+  collector seconds, switches, major page faults, and the five phases
+  with the most self seconds.
+- **The cycle collector is a phase** (:func:`install_gc_hook`): every
+  collection is credited to ``host.gc`` (as ``compile.*``: no parent,
+  not taken from the phase it interrupts) and lies in a trace's host
+  plane under that name; ``gc_full_collections`` counts generation 2.
 - **Device scopes, on demand** (:func:`program_scopes`): the engine
   traces under ``jax.named_scope`` (SCOPE_ROOTS); the map from a
   compiled program's instructions to those scopes is computed only
@@ -53,19 +77,37 @@ jax is imported lazily throughout: the converter-only environment
 """
 from __future__ import annotations
 
+import atexit
 import contextlib
 import dataclasses
+import gc
 import json
 import re
+import sys
 import threading
 import time
 import weakref
-from typing import Any, Dict, Iterator, List, Optional, Set
+from typing import Any, Dict, Iterator, List, Optional, Set, Tuple
+
+try:  # the calling thread's own usage: Linux
+    import resource
+
+    _RUSAGE_THREAD = resource.RUSAGE_THREAD
+except (ImportError, AttributeError):  # pragma: no cover - other hosts
+    resource = None
 
 SCHEMA = "isotope-engine-telemetry/v1"
 
-#: ``snapshot().phases`` carries a container's self time as ``<name>.self``
+#: ``snapshot().phases`` carries a container's self time as
+#: ``<name>.self`` and every phase's CPU seconds as ``<name>.cpu``: the
+#: benchmark's ``telemetry_now`` copies ``phases`` and ``counters`` only.
+#: Both go when PERF.md section 7 item 13's ``benchmark`` PR lets it copy
+#: ``phase_self`` and ``phase_cpu``
 _SELF = ".self"
+_CPU = ".cpu"
+
+#: the phases of ``meta["slowest_warm_call"]["self_s"]``
+_SLOWEST_SELF = 5
 
 #: jax duration events -> phase names (the trace/lower/compile split)
 _JAX_EVENT_PHASES = {
@@ -96,6 +138,9 @@ class _State:
         # names it was opened under ("" = no phase open: a root)
         self.phase_self: Dict[str, float] = {}
         self.phase_parents: Dict[str, Set[str]] = {}
+        # the calling thread's CPU seconds inside a phase (inclusive,
+        # as ``phases``); phase_add's names have none
+        self.phase_cpu: Dict[str, float] = {}
         self.meta: Dict[str, Any] = {}  # run annotations (degraded_to, ...)
         self.emit = False          # artifact emission requested (--telemetry)
         self.detail = False        # segment fencing armed (--telemetry=detail)
@@ -105,18 +150,58 @@ class _State:
 
 _STATE = _State()
 _HOOKS_INSTALLED = False
+_GC_HOOK_INSTALLED = False
+
+
+def _thread_usage() -> Optional[Tuple[int, int]]:
+    """The calling thread's (involuntary context switches, major page
+    faults) so far, or nothing where the host cannot say."""
+    if resource is None:
+        return None
+    usage = resource.getrusage(_RUSAGE_THREAD)
+    return usage.ru_nivcsw, usage.ru_majflt
+
+
+class _Call:
+    """What a root phase keeps beside its clocks, from its opening (or
+    the last reset under it): the self seconds of every phase that
+    closed under it, the collector's seconds on its thread, and the
+    readings its closing is set against."""
+
+    __slots__ = ("self_s", "gc_s", "first_calls", "usage", "process_cpu")
+
+    def __init__(self) -> None:
+        self.start()
+
+    def start(self) -> None:
+        self.self_s: Dict[str, float] = {}
+        self.gc_s = 0.0
+        self.first_calls = counter_get("jit_first_calls")
+        self.usage = _thread_usage()
+        self.process_cpu = time.process_time()
 
 
 class _Frame:
     """One open phase: its name, when it opened (or the registry was
-    last reset under it) and the seconds its direct children took."""
+    last reset under it) on the wall's clock and - ``on_cpu`` - on its
+    thread's CPU clock, the wall seconds its direct children took, and
+    what the root phase it lies under keeps (its own, where it has no
+    parent)."""
 
-    __slots__ = ("name", "t0", "children")
+    __slots__ = ("name", "t0", "cpu0", "on_cpu", "children", "call")
 
-    def __init__(self, name: str) -> None:
+    def __init__(self, name: str, call: _Call, on_cpu: bool) -> None:
         self.name = name
-        self.t0 = time.perf_counter()
+        self.call = call
+        self.on_cpu = on_cpu
+        self.start()
+
+    def start(self) -> None:
         self.children = 0.0
+        # the CPU clock inside the wall clock, here and on exit: a
+        # phase's CPU seconds cannot pass its wall seconds
+        self.t0 = time.perf_counter()
+        self.cpu0 = time.thread_time() if self.on_cpu else 0.0
 
 
 class _Open(threading.local):
@@ -158,16 +243,17 @@ def reset() -> None:
     Leaves the emit/detail switches and installed jax hooks in place.
     A phase open on the calling thread (the runner resets inside
     ``cli.main``) starts again from here: what it records on exit is
-    its seconds, and its children's, since the reset.
+    its seconds, and its children's, on both clocks, since the reset.
     """
     _STATE.counters.clear()
     _STATE.gauges.clear()
     _STATE.phases.clear()
     _STATE.phase_self.clear()
     _STATE.phase_parents.clear()
-    now = time.perf_counter()
+    _STATE.phase_cpu.clear()
     for frame in _OPEN.frames:
-        frame.t0, frame.children = now, 0.0
+        frame.call.start()
+        frame.start()
     _STATE.meta.clear()
     _STATE.trace_keys.clear()
     _STATE.last_fence_t = None
@@ -224,7 +310,8 @@ def phase_add(name: str, seconds: float) -> None:
     (``time_first_call``, the jax hooks, the detail-mode fences).  It
     has no parent, and where a phase is open it is NOT subtracted from
     that phase's self time: a compile event overlaps the host phase it
-    fires in by design, so both keep their seconds."""
+    fires in by design, so both keep their seconds.  It has no CPU
+    reading either."""
     _STATE.phases[name] = _STATE.phases.get(name, 0.0) + seconds
     _STATE.phase_self[name] = _STATE.phase_self.get(name, 0.0) + seconds
 
@@ -236,12 +323,12 @@ def phase_seconds(name: str) -> float:
 def _trace_annotation(name: str, attrs: Dict[str, Any]):
     """The profiler's span for a phase: a ``TraceAnnotation`` (an
     inactive TraceMe outside a profiler session), or nothing where jax
-    is not installed."""
+    is not installed; and whether a session is open."""
     try:
         from jax.profiler import TraceAnnotation
     except ImportError:  # converter-only env: the timer alone
-        return contextlib.nullcontext()
-    return TraceAnnotation(name, **attrs)
+        return contextlib.nullcontext(), False
+    return TraceAnnotation(name, **attrs), TraceAnnotation.is_enabled()
 
 
 @contextlib.contextmanager
@@ -255,7 +342,13 @@ def phase(name: str, **attrs: Any) -> Iterator[None]:
     opened under goes to ``phase_parents[name]`` (``""`` for a root).
     So the self times of everything opened under one root sum to that
     root's seconds, and a container's ``<name>.self`` in a snapshot is
-    the host time none of its children names.
+    the host time none of its children names.  The calling thread's
+    CPU seconds over the same span go to ``phase_cpu[name]``
+    (inclusive, as ``phases[name]``): what is left of the wall seconds
+    is time the thread was off the processor.  A root reads that clock
+    always; a phase under it only where ``--telemetry`` is on or a
+    profiler session is open as it opens (two system calls a phase are
+    2 % of the shortest served call on a sandboxed kernel).
 
     Under a ``jax.profiler`` session (``sweep --profile``, ``telemetry
     --xla-trace``) the phase also lands in the trace's host plane as an
@@ -265,12 +358,17 @@ def phase(name: str, **attrs: Any) -> Iterator[None]:
     """
     frames = _OPEN.frames
     parent = frames[-1] if frames else None
-    frame = _Frame(name)
+    span, session = _trace_annotation(name, attrs)
+    if parent is None:
+        frame = _Frame(name, _Call(), True)
+    else:
+        frame = _Frame(name, parent.call, session or _STATE.emit)
     frames.append(frame)
     try:
-        with _trace_annotation(name, attrs):
+        with span:
             yield
     finally:
+        cpu = time.thread_time() - frame.cpu0 if frame.on_cpu else None
         seconds = time.perf_counter() - frame.t0
         # its own frame, wherever it lies: a phase held across a
         # generator's ``yield`` can close after the phase it was opened
@@ -278,13 +376,46 @@ def phase(name: str, **attrs: Any) -> Iterator[None]:
         frames.remove(frame)
         if parent is not None:
             parent.children += seconds
+        own = seconds - frame.children
         _STATE.phases[name] = _STATE.phases.get(name, 0.0) + seconds
-        _STATE.phase_self[name] = (
-            _STATE.phase_self.get(name, 0.0) + seconds - frame.children
-        )
+        _STATE.phase_self[name] = _STATE.phase_self.get(name, 0.0) + own
+        if cpu is not None:
+            _STATE.phase_cpu[name] = _STATE.phase_cpu.get(name, 0.0) + cpu
         _STATE.phase_parents.setdefault(name, set()).add(
             parent.name if parent is not None else ""
         )
+        call = frame.call
+        call.self_s[name] = call.self_s.get(name, 0.0) + own
+        if parent is None:
+            _close_root(name, call, seconds, cpu)
+
+
+def _close_root(name: str, call: _Call, seconds: float, cpu: float) -> None:
+    """A phase closed with no parent: what the machine did to the
+    calling thread over the span goes to two counters, and the span is
+    kept whole where it is the slowest warm one so far."""
+    counter_inc("process_cpu_seconds", time.process_time() - call.process_cpu)
+    usage = _thread_usage()
+    switches = faults = None
+    if usage is not None and call.usage is not None:
+        switches = usage[0] - call.usage[0]
+        faults = usage[1] - call.usage[1]
+        counter_inc("involuntary_context_switches", switches)
+    if counter_get("jit_first_calls") != call.first_calls:
+        return  # a program compiled under it: not a warm call
+    record = _STATE.meta.get("slowest_warm_call")
+    if record is not None and record["wall_s"] >= seconds:
+        return
+    top = sorted(call.self_s.items(), key=lambda kv: -kv[1])[:_SLOWEST_SELF]
+    _STATE.meta["slowest_warm_call"] = {
+        "root": name,
+        "wall_s": round(seconds, 6),
+        "cpu_s": round(cpu, 6),
+        "gc_s": round(call.gc_s, 6),
+        "involuntary_context_switches": switches,
+        "major_page_faults": faults,
+        "self_s": {k: round(v, 6) for k, v in top},
+    }
 
 
 def _under_disable_jit() -> bool:
@@ -485,7 +616,7 @@ def program_scopes() -> Dict[str, Dict[str, str]]:
     (:func:`hlo_scopes`).  The registry is left as found.
     """
     saved = (dict(_STATE.counters), dict(_STATE.phases),
-             dict(_STATE.phase_self),
+             dict(_STATE.phase_self), dict(_STATE.phase_cpu),
              {k: set(v) for k, v in _STATE.phase_parents.items()},
              dict(_STATE.gauges), set(_STATE.trace_keys))
     out: Dict[str, Dict[str, str]] = {}
@@ -503,7 +634,8 @@ def program_scopes() -> Dict[str, Dict[str, str]]:
     finally:
         for live, was in zip(
             (_STATE.counters, _STATE.phases, _STATE.phase_self,
-             _STATE.phase_parents, _STATE.gauges, _STATE.trace_keys),
+             _STATE.phase_cpu, _STATE.phase_parents, _STATE.gauges,
+             _STATE.trace_keys),
             saved,
         ):
             live.clear()
@@ -641,6 +773,52 @@ def install_jax_hooks() -> bool:
     return True
 
 
+def install_gc_hook() -> None:
+    """Make the cycle collector a phase (idempotent).
+
+    One function joins ``gc.callbacks``.  A collection's wall seconds
+    go to ``host.gc`` by :func:`phase_add` - no parent, and not taken
+    from the phase it interrupts - and to the ``gc_s`` of the root
+    phase open on the thread it ran on; a collection of the oldest
+    generation (the pass that costs tens of ms) moves
+    ``gc_full_collections``.  Under a profiler session it lies in the
+    trace's host plane as ``host.gc``, so an idle gap of the device
+    that it fills is labelled by it.  The collector itself is left
+    alone: nothing here collects, freezes or disables.
+    """
+    global _GC_HOOK_INSTALLED
+    if _GC_HOOK_INSTALLED:
+        return
+    open_span: List[Any] = []    # (t0, annotation) of the pass under way
+
+    def _on_gc(when: str, info: Dict[str, int]) -> None:
+        if when == "start":
+            # no import from inside a collection: one can run while jax
+            # itself is being imported, and a session needs jax.profiler
+            annotation = getattr(
+                sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+            span = None
+            if annotation is not None:
+                span = annotation("host.gc", generation=info["generation"])
+                span.__enter__()
+            open_span.append((time.perf_counter(), span))
+        elif open_span:
+            t0, span = open_span.pop()
+            seconds = time.perf_counter() - t0
+            if span is not None:
+                span.__exit__(None, None, None)
+            phase_add("host.gc", seconds)
+            if info["generation"] == 2:
+                counter_inc("gc_full_collections")
+            if _OPEN.frames:
+                _OPEN.frames[0].call.gc_s += seconds
+
+    gc.callbacks.append(_on_gc)
+    # the interpreter's last collections run while modules are torn down
+    atexit.register(gc.callbacks.remove, _on_gc)
+    _GC_HOOK_INSTALLED = True
+
+
 # -- derived views ---------------------------------------------------------
 
 def summary_block() -> Dict[str, Any]:
@@ -686,6 +864,10 @@ def summary_block() -> Dict[str, Any]:
         blk["vet_runs"] = int(c["vet_runs_total"])
         blk["vet_errors"] = int(c.get("vet_errors_total", 0.0))
         blk["vet_warnings"] = int(c.get("vet_warnings_total", 0.0))
+    # PRESENT only once a warm call (a root phase under which no
+    # program compiled) has closed in this record
+    if "slowest_warm_call" in _STATE.meta:
+        blk["slowest_warm_call"] = _STATE.meta["slowest_warm_call"]
     return blk
 
 
@@ -722,6 +904,8 @@ class RunTelemetry:
     phase_parents: Dict[str, List[str]] = dataclasses.field(
         default_factory=dict
     )
+    # additive since a phase reads two clocks, in the same way
+    phase_cpu: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> dict:
         return {
@@ -730,6 +914,7 @@ class RunTelemetry:
             "phases": self.phases,
             "phase_self": self.phase_self,
             "phase_parents": self.phase_parents,
+            "phase_cpu": self.phase_cpu,
             "counters": self.counters,
             "gauges": self.gauges,
             "meta": self.meta,
@@ -748,6 +933,7 @@ class RunTelemetry:
             phase_parents={
                 k: list(v) for k, v in d.get("phase_parents", {}).items()
             },
+            phase_cpu=dict(d.get("phase_cpu", {})),
         )
 
     def to_json_line(self) -> str:
@@ -773,8 +959,10 @@ class RunTelemetry:
     def prometheus_text(self) -> str:
         return _render_prometheus(
             {k: v for k, v in self.phases.items()
-             if not (k.endswith(_SELF) and k[:-len(_SELF)] in self.phases)},
-            self.phase_self, self.counters, self.gauges,
+             if not any(k.endswith(suffix)
+                        and k[:-len(suffix)] in self.phases
+                        for suffix in (_SELF, _CPU))},
+            self.phase_self, self.phase_cpu, self.counters, self.gauges,
         )
 
 
@@ -800,6 +988,8 @@ def snapshot(label: Optional[str] = None) -> RunTelemetry:
     containers = set().union(*_STATE.phase_parents.values()) - {""}
     phases.update((name + _SELF, phase_self[name])
                   for name in containers if name in phase_self)
+    phase_cpu = {k: round(v, 6) for k, v in sorted(_STATE.phase_cpu.items())}
+    phases.update((name + _CPU, cpu) for name, cpu in phase_cpu.items())
     return RunTelemetry(
         label=label,
         phases=dict(sorted(phases.items())),
@@ -810,12 +1000,14 @@ def snapshot(label: Optional[str] = None) -> RunTelemetry:
         phase_parents={
             k: sorted(v) for k, v in sorted(_STATE.phase_parents.items())
         },
+        phase_cpu=phase_cpu,
     )
 
 
 # -- Prometheus exposition -------------------------------------------------
 
-def _render_prometheus(phases, phase_self, counters, gauges) -> str:
+def _render_prometheus(phases, phase_self, phase_cpu, counters,
+                       gauges) -> str:
     out: List[str] = []
     for family, what, seconds in (
         ("phase_seconds_total",
@@ -824,6 +1016,9 @@ def _render_prometheus(phases, phase_self, counters, gauges) -> str:
         ("phase_self_seconds_total",
          "Wall seconds of each engine phase that no child phase"
          " covers.", phase_self),
+        ("phase_cpu_seconds_total",
+         "CPU seconds of the calling thread inside each engine phase,"
+         " its children's included.", phase_cpu),
     ):
         out.append(f"# HELP isotope_engine_{family} {what}")
         out.append(f"# TYPE isotope_engine_{family} counter")
@@ -871,7 +1066,8 @@ def _render_prometheus(phases, phase_self, counters, gauges) -> str:
 def prometheus_text() -> str:
     """Render the live registry as ``isotope_engine_*`` series."""
     return _render_prometheus(
-        _STATE.phases, _STATE.phase_self, _STATE.counters, _STATE.gauges
+        _STATE.phases, _STATE.phase_self, _STATE.phase_cpu,
+        _STATE.counters, _STATE.gauges
     )
 
 
@@ -923,7 +1119,8 @@ def validate_jsonl(path) -> int:
                 raise ValueError(
                     f"{path}:{i}: missing/invalid {section!r} section"
                 )
-        for section in ("phases", "phase_self", "counters", "gauges"):
+        for section in ("phases", "phase_self", "phase_cpu", "counters",
+                        "gauges"):
             for k, v in doc.get(section, {}).items():
                 if not isinstance(k, str) or not isinstance(
                     v, (int, float)

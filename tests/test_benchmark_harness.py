@@ -39,7 +39,13 @@ way they break (PR 43's read the file cut off after its own entries).
 PR 51 appended ``closed_census_ms`` and
 ``closed_rate_class_rows_per_call`` (data files, listed to
 ``svc1000_mesh4`` alone: the one cell whose load is ``--qps max``), held
-by the last test below."""
+by the test of the station-class metrics below.
+
+PR 52 appended twelve metrics of the host's second clock, the root
+span, the collector and ``summary.wait``'s two leaves (eight data files,
+five readers of their own), none with a ``workloads`` list: every cell
+reports them (the older contract tests pin the metrics that LIST a
+cell).  Held by the last tests below."""
 import json
 import os
 import sys
@@ -237,3 +243,104 @@ def test_the_station_class_metrics_read_the_saturated_solve(name):
     if spec["kind"] == "telemetry_counter":
         ctx["telemetry"]["window"]["counters"] = {}
         assert readers.read_metric(name, ctx) == 0.0
+
+
+# PR 52's per-layer metrics: none lists its cells, so every cell reports
+# them.  name -> (unit, source, layer, what it reads of a window in
+# which 4 calls accrued the telemetry of WINDOW)
+CLI_LAYER = "CLI / runner + artifacts"
+WINDOW = {
+    "phases": {
+        "cli.main": 2.0, "cli.main.cpu": 1.2,
+        "cli.parser_build": 0.10, "cli.parser_build.cpu": 0.09,
+        "cli.parse_args": 0.02, "cli.parse_args.cpu": 0.02,
+        "graph.decode.yaml": 0.30, "graph.decode.yaml.cpu": 0.27,
+        "graph.decode.model": 0.20, "graph.decode.model.cpu": 0.20,
+        "compile.unroll": 0.10, "compile.unroll.cpu": 0.08,
+        "engine.build.signature": 0.04, "engine.build.signature.cpu": 0.04,
+        "summary.wait": 0.5, "summary.ready": 0.3,
+        "summary.sentinels": 0.1996,
+        "closed_rate.pilot": 0.4, "closed_rate.pilot.cpu": 0.1,
+        "host.gc": 0.06},
+    "counters": {"gc_full_collections": 2.0,
+                 "involuntary_context_switches": 10.0,
+                 "process_cpu_seconds": 3.0}}
+SLOWEST = {"root": "cli.main", "wall_s": 0.7, "cpu_s": 0.3, "gc_s": 0.04,
+           "involuntary_context_switches": 3, "major_page_faults": 0,
+           "self_s": {"summary.ready": 0.2}}
+HOST_CLOCK_METRICS = {
+    "host_cpu_ms_per_call": ("ms", "program_span", CLI_LAYER, 300.0),
+    "host_offcpu_ms_per_call": ("ms", "program_span", CLI_LAYER, 200.0),
+    "host_leaf_stolen_ms_per_call": ("ms", "program_span", CLI_LAYER, 15.0),
+    "device_ready_wait_ms": ("ms", "program_span", CLI_LAYER, 75.0),
+    "sentinel_readback_ms": ("ms", "program_span", CLI_LAYER, 49.9),
+    "pilot_cpu_ms_per_call": (
+        "ms", "program_span", "closed-loop rate solve", 25.0),
+    "gc_ms_per_call": ("ms", "program_span", CLI_LAYER, 15.0),
+    "gc_full_collections_per_call": (
+        "count", "program_counter", CLI_LAYER, 0.5),
+    "involuntary_switches_per_call": (
+        "count", "program_counter", CLI_LAYER, 2.5),
+    "process_cpu_ms_per_call": ("ms", "program_counter", CLI_LAYER, 750.0),
+    "slowest_call_ms": ("ms", "program_span", CLI_LAYER, 700.0),
+    "slowest_call_cpu_ms": ("ms", "program_span", CLI_LAYER, 300.0),
+}
+
+
+@pytest.mark.parametrize("name", HOST_CLOCK_METRICS)
+def test_the_host_clock_metrics_read_the_second_clock(name):
+    from isotope_tpu import telemetry
+
+    from benchmark.harness import readers
+    from benchmark.harness.cells import load_cell
+
+    unit, source, layer, want = HOST_CLOCK_METRICS[name]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry == {"name": name, "unit": unit, "better": "lower",
+                     "source": source, "layer": layer,
+                     "moves": "call_p50_s"}
+    # no list of cells: the plain cell and the observed one report it
+    for cell in (w["name"] for w in bench["workloads"]):
+        assert name in {m["name"] for m in load_cell(cell).per_layer}
+    ctx = {"calls": 4, "telemetry": {"setup": WINDOW, "window": WINDOW}}
+    telemetry.reset()
+    telemetry.set_meta("slowest_warm_call", SLOWEST)
+    try:
+        assert readers.read_metric(name, ctx) == pytest.approx(want)
+        # the parent keeps neither the keys nor the record: nothing (0,
+        # a counter), and nothing raises
+        telemetry.reset()
+        bare = {"phases": {"cli.main": 2.0, "summary.wait": 0.5,
+                           "closed_rate.pilot": 0.4},
+                "counters": {"runs_served": 4.0}}
+        ctx["telemetry"] = {"setup": bare, "window": bare}
+        value = readers.read_metric(name, ctx)
+        assert value == (0.0 if source == "program_counter" else None)
+    finally:
+        telemetry.reset()
+
+
+def test_pilot_cpu_reads_zero_where_no_pilot_ran_in_the_window():
+    """``svc1000_mesh4``: the paced pre-check of set-up left the wall
+    phase in the registry, the ``--qps max`` calls of the window moved
+    neither clock of it."""
+    from benchmark.harness import readers
+
+    window = {"phases": {"cli.main": 2.0, "cli.main.cpu": 1.2,
+                         "closed_rate.pilot": 0.0}, "counters": {}}
+    ctx = {"calls": 4, "telemetry": {"window": window}}
+    assert readers.read_metric("pilot_cpu_ms_per_call", ctx) == 0.0
+    del window["phases"]["closed_rate.pilot"]
+    assert readers.read_metric("pilot_cpu_ms_per_call", ctx) == 0.0
+
+
+def test_summary_waits_two_leaves_add_up_to_device_wait_ms():
+    from benchmark.harness import readers
+
+    ctx = {"calls": 4, "telemetry": {"window": WINDOW}}
+    parts = (readers.read_metric("device_ready_wait_ms", ctx)
+             + readers.read_metric("sentinel_readback_ms", ctx))
+    assert parts == pytest.approx(
+        readers.read_metric("device_wait_ms", ctx), rel=0.01)

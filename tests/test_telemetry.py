@@ -41,10 +41,18 @@ KEY = jax.random.PRNGKey(0)
 
 @pytest.fixture(autouse=True)
 def clean_registry():
-    """Fresh registry per test; restore the off/off default after."""
+    """Fresh registry per test; restore the off/off default after.
+    The cycle collector is a phase (``host.gc``) once any test of the
+    process has installed its hook, and it runs when it will: it is
+    held off for the test, so that a registry a test pins key for key
+    holds what the test put there (``gc.collect()`` still collects)."""
+    import gc
+
     telemetry.reset()
     telemetry.disable()
+    gc.disable()
     yield
+    gc.enable()
     telemetry.reset()
     telemetry.disable()
 
@@ -83,12 +91,19 @@ def test_phase_records_on_exception():
 # -- parent and self time --------------------------------------------------
 
 class _Clock:
-    """``time`` for telemetry/core.py with a clock the test moves."""
+    """``time`` for telemetry/core.py with a clock the test moves; the
+    thread is on the processor for half of every tick."""
 
     def __init__(self):
         self.now = 100.0
 
     def perf_counter(self):
+        return self.now
+
+    def thread_time(self):
+        return self.now / 2
+
+    def process_time(self):
         return self.now
 
     def time(self):
@@ -242,16 +257,22 @@ def test_phase_parent_and_self_time(scenario, monkeypatch):
 
     clock = _Clock()
     monkeypatch.setattr(core, "time", clock)
+    telemetry.enable()                   # every phase reads both clocks
     phases, phase_self, parents = scenario(clock.tick)
     assert not core._OPEN.frames         # every frame popped
     snap = telemetry.snapshot()
     assert snap.phase_self == phase_self
     assert snap.phase_parents == parents
-    # the inclusive seconds as before, and a container's self time
-    # under <name>.self; a leaf has no such key
+    # the second clock: inclusive as the first, restarted by a reset as
+    # the first, and nothing for a name phase_add credits
+    assert snap.phase_cpu == {n: phases[n] / 2 for n in parents}
+    # the inclusive seconds as before, a container's self time under
+    # <name>.self (a leaf has no such key), every phase's CPU seconds
+    # under <name>.cpu
     containers = {p for ps in parents.values() for p in ps} - {""}
     assert snap.phases == {
-        **phases, **{f"{c}.self": phase_self[c] for c in containers}
+        **phases, **{f"{c}.self": phase_self[c] for c in containers},
+        **{f"{n}.cpu": phases[n] / 2 for n in parents},
     }
 
 
@@ -272,6 +293,152 @@ def test_a_thread_keeps_its_own_stack():
     assert snap.phase_parents == {"outer": [""], "on_thread": [""]}
     assert snap.phase_self["outer"] == snap.phases["outer"] >= 0.01
     assert "outer.self" not in snap.phases
+
+
+# -- the second clock, the root span and the collector ---------------------
+
+def test_phase_reads_wall_and_cpu_clocks():
+    telemetry.enable()
+    with telemetry.phase("probe.asleep"):
+        time.sleep(0.05)
+    snap = telemetry.snapshot()
+    # asleep: off the processor for all of it
+    assert snap.phases["probe.asleep"] >= 0.05
+    assert snap.phase_cpu["probe.asleep"] < 0.01
+    assert snap.phases["probe.asleep.cpu"] == snap.phase_cpu["probe.asleep"]
+    # spinning: on it, as far as the machine lets the thread be - the
+    # tests share their cores, so the phase is held to the thread's own
+    # clock read just inside it, not to the wall (within 20 % of it on
+    # an idle machine)
+    with telemetry.phase("probe.spinning"):
+        c0, t0 = time.thread_time(), time.perf_counter()
+        while time.perf_counter() - t0 < 0.05:
+            pass
+        inside = time.thread_time() - c0
+    snap = telemetry.snapshot()
+    wall, cpu = snap.phases["probe.spinning"], snap.phase_cpu[
+        "probe.spinning"]
+    assert wall >= 0.05 and inside <= cpu <= wall + 1e-3
+    assert cpu == pytest.approx(inside, abs=2e-3)
+    assert cpu > 5 * snap.phase_cpu["probe.asleep"]
+    # phase_add's names have no CPU reading
+    telemetry.phase_add("compile.probe", 1.0)
+    assert "compile.probe" not in telemetry.snapshot().phase_cpu
+
+
+def test_unwatched_phase_under_a_root_leaves_the_cpu_clock_alone(
+        monkeypatch):
+    """The CPU clock is a system call: a root reads it always, a phase
+    under it only while ``--telemetry`` is on or a profiler session is
+    open (tests/test_tracing.py has the session)."""
+    from isotope_tpu.telemetry import core
+
+    clock = _Clock()
+    reads = []
+    monkeypatch.setattr(
+        clock, "thread_time", lambda: reads.append(1) or clock.now / 2)
+    monkeypatch.setattr(core, "time", clock)
+    with telemetry.phase("root"):
+        for _ in range(3):
+            with telemetry.phase("child"):
+                clock.tick(1)
+    snap = telemetry.snapshot()
+    assert len(reads) == 2               # the root's two ends
+    assert snap.phase_cpu == {"root": 1.5}
+    assert "child.cpu" not in snap.phases and snap.phases["child"] == 3
+    telemetry.enable()
+    with telemetry.phase("root"):
+        with telemetry.phase("child"):
+            clock.tick(1)
+    assert len(reads) == 6
+    assert telemetry.snapshot().phase_cpu == {"root": 2.0, "child": 0.5}
+
+
+def test_reset_under_an_open_phase_restarts_both_clocks():
+    with telemetry.phase("probe.outer"):
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.1:
+            pass
+        telemetry.reset()
+    snap = telemetry.snapshot()
+    assert snap.phases["probe.outer"] < 0.05
+    assert snap.phase_cpu["probe.outer"] < 0.01
+
+
+def test_root_phase_moves_the_machines_counters_and_a_child_does_not():
+    from isotope_tpu.telemetry import core
+
+    with telemetry.phase("probe.root"):
+        with telemetry.phase("probe.child"):
+            pass
+        assert telemetry.counter_get("process_cpu_seconds") == 0.0
+        assert "involuntary_context_switches" not in \
+            telemetry.snapshot().counters
+    counters = telemetry.snapshot().counters
+    assert counters["process_cpu_seconds"] > 0
+    assert counters["involuntary_context_switches"] >= 0
+    assert core._thread_usage() is not None     # Linux: RUSAGE_THREAD
+
+
+def test_root_phase_without_rusage_thread(monkeypatch):
+    """Where the host cannot say, the first counter is not moved and
+    nothing else changes."""
+    from isotope_tpu.telemetry import core
+
+    monkeypatch.setattr(core, "resource", None)
+    with telemetry.phase("probe.root"):
+        pass
+    snap = telemetry.snapshot()
+    assert "involuntary_context_switches" not in snap.counters
+    assert snap.counters["process_cpu_seconds"] >= 0
+    record = snap.meta["slowest_warm_call"]
+    assert record["root"] == "probe.root"
+    assert record["involuntary_context_switches"] is None
+    assert record["major_page_faults"] is None
+
+
+def test_install_gc_hook_is_idempotent():
+    import gc
+
+    telemetry.install_gc_hook()
+    before = list(gc.callbacks)
+    telemetry.install_gc_hook()
+    assert gc.callbacks == before
+    assert sum(getattr(cb, "__name__", "") == "_on_gc"
+               for cb in gc.callbacks) == 1
+
+
+def test_collector_is_a_phase_of_its_own():
+    """A collection moves ``host.gc`` and, of the oldest generation,
+    ``gc_full_collections``; it has no parent and takes nothing from
+    the phase it interrupts."""
+    import gc
+
+    telemetry.install_gc_hook()
+    with telemetry.phase("probe.collecting"):
+        gc.collect()
+        gc.collect(0)
+    snap = telemetry.snapshot()
+    assert snap.counters["gc_full_collections"] == 1
+    assert snap.phases["host.gc"] > 0
+    assert "host.gc" not in snap.phase_parents
+    assert "host.gc" not in snap.phase_cpu
+    # the interrupted phase is still a leaf: its self time its seconds
+    assert snap.phase_self["probe.collecting"] == \
+        snap.phases["probe.collecting"] >= snap.phases["host.gc"]
+    assert "probe.collecting.self" not in snap.phases
+    # and its root's record says how much of it the collector took
+    assert snap.meta["slowest_warm_call"]["gc_s"] == \
+        pytest.approx(snap.phases["host.gc"], abs=1e-5)
+
+
+def test_a_cold_root_span_is_no_warm_call():
+    with telemetry.phase("probe.cold"):
+        telemetry.counter_inc("jit_first_calls")
+    assert telemetry.get_meta("slowest_warm_call") is None
+    with telemetry.phase("probe.warm"):
+        pass
+    assert telemetry.get_meta("slowest_warm_call")["root"] == "probe.warm"
 
 
 # -- counters across the jit boundary --------------------------------------
@@ -471,6 +638,42 @@ def test_prometheus_exposition_carries_the_self_times():
     assert own < rec.phases["probe.inner"]
 
 
+def test_prometheus_exposition_carries_the_cpu_seconds():
+    telemetry.enable()
+    with telemetry.phase("probe.outer"):
+        with telemetry.phase("probe.inner"):
+            time.sleep(0.001)
+    rec = telemetry.snapshot()
+    assert {"probe.outer.cpu", "probe.inner.cpu"} <= set(rec.phases)
+    for text in (telemetry.prometheus_text(), rec.prometheus_text()):
+        for line in text.splitlines():
+            if line and not line.startswith("#"):
+                assert PROM_LINE.match(line), f"unparseable line: {line!r}"
+        assert text.count("# TYPE isotope_engine_phase_cpu_seconds_total"
+                          " counter") == 1
+        for name in ("probe.outer", "probe.inner"):
+            assert ('isotope_engine_phase_cpu_seconds_total'
+                    f'{{phase="{name}"}} ') in text
+        # the .cpu keys are the benchmark readers' names for a CPU
+        # reading: not a phase of the inclusive series
+        assert '.cpu"}' not in text
+
+
+def test_from_dict_reads_a_line_without_the_second_clock():
+    line = {"schema": telemetry.SCHEMA, "label": "v1",
+            "phases": {"outer": 1.5, "outer.self": 0.5, "inner": 1.0},
+            "phase_self": {"outer": 0.5, "inner": 1.0},
+            "phase_parents": {"outer": [""], "inner": ["outer"]},
+            "counters": {"x": 1.0}, "gauges": {}, "meta": {}}
+    rec = telemetry.RunTelemetry.from_dict(line)
+    assert rec.phase_cpu == {}
+    assert rec.phases == line["phases"]
+    assert rec.to_dict() == dict(line, phase_cpu={})
+    text = rec.prometheus_text()
+    assert 'phase_seconds_total{phase="outer"} 1.5' in text
+    assert "phase_cpu_seconds_total{" not in text
+
+
 # -- JSONL round trip ------------------------------------------------------
 
 def test_run_telemetry_jsonl_round_trip(tmp_path):
@@ -488,6 +691,7 @@ def test_run_telemetry_jsonl_round_trip(tmp_path):
 
 
 def test_jsonl_round_trip_keeps_parents_and_self_times(tmp_path):
+    telemetry.enable()
     with telemetry.phase("outer"):
         with telemetry.phase("inner"):
             time.sleep(0.001)
@@ -499,10 +703,13 @@ def test_jsonl_round_trip_keeps_parents_and_self_times(tmp_path):
     assert back == rec
     path = tmp_path / "telemetry.jsonl"
     rec.append_jsonl(path)
-    # a record written before phases nested has neither section: it
-    # validates and reads, with nothing where the new sections are
+    # a record written before phases nested (or read two clocks) has
+    # none of the sections: it validates and reads, with nothing where
+    # the new sections are
     old = json.loads(rec.to_json_line())
     del old["phase_self"], old["phase_parents"], old["phases"]["outer.self"]
+    del old["phase_cpu"], old["phases"]["outer.cpu"]
+    del old["phases"]["inner.cpu"]
     with open(path, "a") as f:
         f.write(json.dumps(old) + "\n")
     assert telemetry.validate_jsonl(path) == 2
@@ -511,7 +718,9 @@ def test_jsonl_round_trip_keeps_parents_and_self_times(tmp_path):
     assert was.phases == {"outer": rec.phases["outer"],
                           "inner": rec.phases["inner"]}
     assert was.phase_self == {} and was.phase_parents == {}
+    assert was.phase_cpu == {}
     assert "phase_self_seconds_total{" not in was.prometheus_text()
+    assert "phase_cpu_seconds_total{" not in was.prometheus_text()
 
 
 def test_jsonl_tolerates_crash_torn_final_line(tmp_path):

@@ -259,6 +259,7 @@ PARENTS = {
     "engine.build.signature": "engine.build",
     "engine.build.copula": "engine.build",
     "closed_rate.pilot": "closed_rate.solve",
+    "summary.ready": "summary.wait", "summary.sentinels": "summary.wait",
 }
 CONTAINERS = set(PARENTS.values()) - {""}
 #: the experiment's PRNG key under ``cli.main``, a run's fold of it
@@ -293,6 +294,139 @@ def test_self_times_of_a_call_sum_to_its_root_span(served):
     assert snap.phases["engine.build.self"] < \
         0.1 * snap.phases["engine.build"]
     assert snap.phases["cli.parse.self"] < 0.1 * snap.phases["cli.parse"]
+
+
+def test_summary_wait_names_what_it_waits_for(served):
+    """``summary.wait`` keeps its name and seconds; under it the
+    device's part and the readbacks' are one leaf each."""
+    snap = served[0]
+    for leaf in ("summary.ready", "summary.sentinels"):
+        assert snap.phase_parents[leaf] == ["summary.wait"]
+        assert snap.phase_self[leaf] == snap.phases[leaf] > 0
+    assert snap.phases["summary.ready"] + snap.phases["summary.sentinels"] \
+        + snap.phases["summary.wait.self"] == \
+        pytest.approx(snap.phases["summary.wait"], abs=1e-5)
+    assert snap.phases["summary.wait.self"] < 1e-3
+
+
+@pytest.fixture(scope="module")
+def traced(served, tmp_path_factory):
+    """A warm served call under a profiler session, a collection of
+    the oldest generation beside it: (registry after it, the trace)."""
+    import gc
+
+    tmp = tmp_path_factory.mktemp("traced")
+    serve(tmp, "warm", seed=12)
+    telemetry.reset()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp / "trace"), profiler_options=options)
+    try:
+        rc, _, _ = serve(tmp, "traced", seed=12)
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    assert rc == 0
+    (path,) = (tmp / "trace").glob("**/*.xplane.pb")
+    return telemetry.snapshot(), jax.profiler.ProfileData.from_file(str(path))
+
+
+def test_unwatched_call_reads_the_cpu_clock_at_its_root_alone(served):
+    """The CPU clock is a system call: with no ``--telemetry`` and no
+    profiler session only ``cli.main`` has a reading."""
+    snap = served[0]
+    assert set(snap.phase_cpu) == {"cli.main"}
+    assert [n for n in snap.phases if n.endswith(".cpu")] == ["cli.main.cpu"]
+    # the call did real work on its own thread
+    assert 0.1 * snap.phases["cli.main"] < snap.phase_cpu["cli.main"] \
+        <= snap.phases["cli.main"] + 1e-3
+
+
+def test_traced_call_reads_its_threads_cpu_clock_at_every_phase(traced):
+    """Under a profiler session every phase of the call has a CPU
+    reading, carried in ``phases`` as ``<name>.cpu`` too; CPU seconds
+    never pass wall seconds, nor a child's its parent's."""
+    snap = traced[0]
+    assert set(snap.phase_cpu) == set(snap.phase_parents) == \
+        set(PARENTS) | set(TWO_PARENTS)
+    for name, cpu in snap.phase_cpu.items():
+        assert snap.phases[name + ".cpu"] == cpu
+        assert 0 <= cpu <= snap.phases[name] + 1e-3, name
+        parents = snap.phase_parents[name]
+        if parents != [""] and len(parents) == 1:
+            assert cpu <= snap.phase_cpu[parents[0]] + 1e-3, name
+    # phase_add's names (host.gc; compile.* in a cold call) have none
+    assert snap.phases["host.gc"] > 0 and "host.gc" not in snap.phase_cpu
+    # the device's part of the wait is a sleep, not work
+    assert snap.phase_cpu["summary.ready"] <= snap.phases["summary.ready"]
+
+
+def test_root_span_counts_what_the_machine_did(served):
+    """Two counters move where ``cli.main`` closes, and nowhere else."""
+    snap = served[0]
+    # every thread's CPU over the span: the calling thread's and more
+    assert snap.counters["process_cpu_seconds"] >= \
+        snap.phase_cpu["cli.main"] - 1e-3
+    assert snap.counters["involuntary_context_switches"] >= 0
+    assert snap.counters["involuntary_context_switches"] == \
+        int(snap.counters["involuntary_context_switches"])
+
+
+SLOWEST_KEYS = {"root", "wall_s", "cpu_s", "gc_s",
+                "involuntary_context_switches", "major_page_faults",
+                "self_s"}
+
+
+def test_slowest_warm_call_is_kept_whole(served, tmp_path):
+    snap = served[0]
+    # the fixture's call compiled its programs: a cold call leaves none
+    assert snap.counters["jit_first_calls"] > 0
+    assert "slowest_warm_call" not in snap.meta
+    telemetry.reset()
+    serve(tmp_path, "warm", seed=8)
+    record = telemetry.get_meta("slowest_warm_call")
+    assert set(record) == SLOWEST_KEYS
+    assert record["root"] == "cli.main"
+    after = telemetry.snapshot()
+    assert after.counters.get("jit_first_calls", 0) == 0
+    assert record["wall_s"] == pytest.approx(
+        after.phases["cli.main"], abs=1e-5)
+    assert record["cpu_s"] == pytest.approx(
+        after.phase_cpu["cli.main"], abs=1e-5)
+    assert 0 <= record["gc_s"] <= after.phases.get("host.gc", 0.0) + 1e-5
+    # the five phases with the most self seconds, the largest first
+    own = list(record["self_s"].items())
+    assert len(own) == 5
+    assert own == sorted(own, key=lambda kv: -kv[1])
+    assert own[0][1] == max(
+        after.phase_self[n] for n in after.phase_parents)
+    # it goes out with the record's meta and the headline block
+    assert after.meta["slowest_warm_call"] == record
+    assert telemetry.summary_block()["slowest_warm_call"] == record
+    # a faster warm call leaves the record; only a slower one takes it
+    with telemetry.phase("probe.quick"):
+        pass
+    assert telemetry.get_meta("slowest_warm_call") == record
+
+
+def test_collections_and_summary_leaves_lie_in_the_host_plane(traced):
+    """Under a profiler session a collection is a ``host.gc`` event and
+    each ``summary.wait`` holds its two leaves, on the device's clock."""
+    host = next(p for p in traced[1].planes if p.name == "/host:CPU")
+    spans = {}
+    for line in host.lines:
+        for e in line.events:
+            if e.name in ("host.gc", "summary.wait", "summary.ready",
+                          "summary.sentinels"):
+                spans.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    assert any(int(stats["generation"]) == 2
+               for _, _, stats in spans["host.gc"])
+    assert spans["summary.wait"]
+    for lo, hi, _ in spans["summary.wait"]:
+        for leaf in ("summary.ready", "summary.sentinels"):
+            assert sum(lo <= a and b <= hi
+                       for a, b, _ in spans[leaf]) == 1, leaf
 
 
 def test_observed_call_names_its_passes_and_its_documents(tmp_path):
@@ -590,9 +724,17 @@ def test_hlo_scopes_follow_a_bare_result_to_what_reads_it():
 
 
 def test_program_scopes_leaves_the_registry_as_found(served):
-    before = telemetry.snapshot()
-    telemetry.program_scopes()
-    after = telemetry.snapshot()
+    import gc
+
+    # the collector runs when it will, and it is a phase: hold it off
+    # between the two snapshots so that nothing else can move
+    gc.disable()
+    try:
+        before = telemetry.snapshot()
+        telemetry.program_scopes()
+        after = telemetry.snapshot()
+    finally:
+        gc.enable()
     assert after.counters == before.counters
     assert after.phases == before.phases
 
