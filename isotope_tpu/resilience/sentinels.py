@@ -40,7 +40,12 @@ def _violations(named: Iterable[Tuple[str, object]],
         if v is None:
             continue
         a = np.asarray(v)
-        if not np.issubdtype(a.dtype, np.floating):
+        if not np.issubdtype(a.dtype, np.floating) or not a.size:
+            continue
+        # one pass clears a sound field: the minimum of an array that
+        # holds a NaN is NaN, and no comparison with NaN holds
+        low = a.min()
+        if low >= 0 if nonneg else low > -np.inf:
             continue
         # win_hi is +inf when the trim window is off: finite-or-+inf is
         # the contract for bounds; NaN is never acceptable
@@ -54,7 +59,10 @@ def _violations(named: Iterable[Tuple[str, object]],
 
 
 def check_summary(summary, label: str = "run") -> None:
-    """Validate a :class:`~isotope_tpu.sim.summary.RunSummary`."""
+    """Validate a :class:`~isotope_tpu.sim.summary.RunSummary`: the
+    host copy ``finish_summary`` fetched (no device read here), or a
+    summary of device arrays (each checked field then read back on its
+    own, as ``check_results`` reads its four)."""
     fields = summary._asdict()
     bad = _violations(
         ((n, fields.get(n)) for n in _NONNEG_FIELDS), nonneg=True

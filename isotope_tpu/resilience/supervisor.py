@@ -144,18 +144,34 @@ def run_ladder(
 
 @telemetry.phase("summary.wait")
 def finish_summary(summary):
-    """Block on a run's summary, then arm the numeric sentinels: the
-    time the host waited for this run's work, in its two parts."""
+    """Block on a run's summary, bring it to the host once, then arm the
+    numeric sentinels: the time the host waited for this run's work, in
+    its two parts.
+
+    Returns the summary as HOST data: every leaf but ``metrics`` a numpy
+    array with the device leaf's bits, dtype and shape, fetched in one
+    batched transfer (``jax.device_get`` starts every leaf's copy before
+    it awaits the first), counted once in ``summary_fetches``.  Whatever
+    reads the run after this - the sentinels, the Fortio document, the
+    windowed series, the resource series, alarms, fidelity - reads that
+    copy and never the device.  The collector's ``metrics`` ride along
+    untouched, still on the device: megabytes on a 10,000-service graph,
+    wanted by the exposition alone, which reads them back once itself
+    (``MetricsCollector.full_text``).
+    """
     import jax
 
     from isotope_tpu.resilience import sentinels
 
     with telemetry.phase("summary.ready"):      # the device's part
         jax.block_until_ready(summary.count)
-    # the summary read back a field at a time, and the checks in numpy
+    # the summary read back in one batch, and the checks in numpy
     with telemetry.phase("summary.sentinels"):
-        sentinels.check_summary(summary)
-    return summary
+        host = jax.device_get(summary._replace(metrics=None))
+        telemetry.counter_inc("summary_fetches")
+        host = host._replace(metrics=summary.metrics)
+        sentinels.check_summary(host)
+    return host
 
 
 def execution_rungs(

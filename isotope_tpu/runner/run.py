@@ -930,7 +930,8 @@ def _protected_call(runner, method: str, spec, load, n, key, kwargs,
     """Invoke one protected rung: the co-sim entry point named by
     ``spec``'s mode, blocking on the summary with the numeric
     sentinels armed (deferred device errors must surface inside the
-    supervised scope)."""
+    supervised scope).  Returns the entry point's tuple with the
+    summary as :func:`finish_summary`'s host copy."""
     _, b, mode = spec
     fn = getattr(runner, f"{method}_emulated" if mode == "emu"
                  else method)
@@ -938,8 +939,7 @@ def _protected_call(runner, method: str, spec, load, n, key, kwargs,
         else contextlib.nullcontext()
     with ctx:
         out = fn(load, n, key, block_size=b, **kwargs, **extra)
-        finish_summary(out[0])
-    return out
+        return (finish_summary(out[0]), *out[1:])
 
 
 def _protected_run(sim, sharded, use_sharded, load, n, key, block,

@@ -33,7 +33,13 @@ from isotope_tpu.sim.engine import SimResults
 
 class RunSummary(NamedTuple):
     """Globally-reduced run summary (small; per-request tensors stay
-    device-local and are never materialized on host)."""
+    device-local and are never materialized on host).
+
+    The fields are ``jax.Array`` while the run is the device's, and
+    numpy arrays of the same bits, dtypes and shapes once
+    ``resilience.finish_summary`` has finished it: the copy every host
+    reader takes.  ``metrics`` alone stays on the device, until the
+    exposition reads it back."""
 
     count: jax.Array          # scalar — requests simulated
     error_count: jax.Array    # scalar — client-visible 500s

@@ -45,7 +45,11 @@ PR 52 appended twelve metrics of the host's second clock, the root
 span, the collector and ``summary.wait``'s two leaves (eight data files,
 five readers of their own), none with a ``workloads`` list: every cell
 reports them (the older contract tests pin the metrics that LIST a
-cell).  Held by the last tests below."""
+cell).  Held by the last tests below.
+
+PR 53 appended ``summary_fetches_per_call`` (a data file, no
+``workloads`` list: every run of every cell finishes its summary), a
+case of the host-clock metrics' test."""
 import json
 import os
 import sys
@@ -264,7 +268,8 @@ WINDOW = {
         "host.gc": 0.06},
     "counters": {"gc_full_collections": 2.0,
                  "involuntary_context_switches": 10.0,
-                 "process_cpu_seconds": 3.0}}
+                 "process_cpu_seconds": 3.0,
+                 "summary_fetches": 120.0}}
 SLOWEST = {"root": "cli.main", "wall_s": 0.7, "cpu_s": 0.3, "gc_s": 0.04,
            "involuntary_context_switches": 3, "major_page_faults": 0,
            "self_s": {"summary.ready": 0.2}}
@@ -284,6 +289,8 @@ HOST_CLOCK_METRICS = {
     "process_cpu_ms_per_call": ("ms", "program_counter", CLI_LAYER, 750.0),
     "slowest_call_ms": ("ms", "program_span", CLI_LAYER, 700.0),
     "slowest_call_cpu_ms": ("ms", "program_span", CLI_LAYER, 300.0),
+    # PR 53's: 30 runs a call of the envelope, one fetch a run
+    "summary_fetches_per_call": ("count", "program_counter", CLI_LAYER, 30.0),
 }
 
 

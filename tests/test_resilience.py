@@ -310,6 +310,130 @@ def test_single_device_ladder_halves_block():
     assert float(summary.count) >= 2048
 
 
+# -- the summary crosses to the host once ----------------------------------
+
+POLICED = """
+services:
+- name: entry
+  isEntrypoint: true
+  numReplicas: 4
+  script:
+  - call: {service: worker, timeout: 850us, retries: 2}
+- name: worker
+  numReplicas: 4
+policies:
+  worker:
+    breaker: {max_pending: 6, max_connections: 64,
+              consecutive_errors: 5, base_ejection: 2s}
+"""
+
+
+def _finished_and_device(rung: str):
+    """``(finished, device)``: what the named rung hands on, and the
+    summary of the same run as its entry point leaves it."""
+    from isotope_tpu.metrics import MetricsCollector
+
+    n, block = 1024, 512
+    if rung == "protected":
+        from isotope_tpu.compiler import compile_policies
+        from isotope_tpu.runner.run import _protected_call
+        from isotope_tpu.sim import SimParams
+
+        graph = ServiceGraph.from_yaml(POLICED)
+        compiled = compile_graph(graph)
+        sim = Simulator(
+            compiled, SimParams(timeline=True, timeline_window_s=0.5),
+            policies=compile_policies(graph, compiled),
+        )
+        kwargs = dict(trim=True, window_s=0.5,
+                      collector=MetricsCollector(compiled))
+        out = _protected_call(sim, "run_policies", ("scan", block, "dev"),
+                              OPEN, n, KEY, kwargs)
+        ref = sim.run_policies(OPEN, n, KEY, block_size=block, **kwargs)
+        assert len(out) == len(ref) == 3
+        # what rides beside the summary goes on as it came
+        for got, want in zip(jax.tree.leaves(out[1:]),
+                             jax.tree.leaves(ref[1:])):
+            assert isinstance(got, jax.Array)
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+        return out[0], ref[0]
+    compiled = compile_graph(ServiceGraph.from_yaml(FORK))
+    if rung == "single-device":
+        sharded = ShardedSimulator(compiled, make_mesh(4, 2))
+        rungs = execution_rungs(
+            sharded.sim, sharded, True, OPEN, n, KEY, block, trim=True
+        )
+        device = sharded.run_emulated(OPEN, n, KEY, block_size=block,
+                                      trim=True)
+    else:
+        import contextlib
+
+        sim = Simulator(compiled)
+        collector = MetricsCollector(compiled)
+        rungs = execution_rungs(
+            sim, None, False, OPEN, n, KEY, block,
+            collector=collector, trim=True,
+        )
+        eager = rung == "cpu-eager"
+        with jax.disable_jit() if eager else contextlib.nullcontext():
+            device = sim.run_summary(
+                OPEN, n, KEY, block_size=block // 2 if eager else block,
+                collector=collector, trim=True,
+            )
+    return dict(rungs)[rung](), device
+
+
+@pytest.mark.parametrize(
+    "rung", ["scan", "cpu-eager", "single-device", "protected"]
+)
+def test_finished_summary_is_the_device_summary_on_the_host(rung):
+    """``finish_summary`` hands on ONE host copy: every leaf but the
+    collector's ``metrics`` a numpy array with the device leaf's bits,
+    dtype and shape, fetched once; the artifacts built from it are the
+    bytes of those built from the device summary."""
+    import dataclasses
+    from datetime import datetime, timezone
+
+    from isotope_tpu.metrics import (
+        fortio_result_from_summary,
+        window_summary_from_summary,
+    )
+
+    finished, device = _finished_and_device(rung)
+    assert telemetry.counter_get("summary_fetches") == 1.0
+    assert type(finished) is type(device)
+    for name, got in finished._asdict().items():
+        want = getattr(device, name)
+        if name == "metrics":
+            # the collector's series ride along where they were
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                assert type(g) is type(w)
+                assert np.array_equal(np.asarray(g), np.asarray(w))
+            continue
+        assert isinstance(got, (np.ndarray, np.generic)), name
+        want = np.asarray(want)
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        assert got.tobytes() == want.tobytes(), name
+    if rung != "single-device":     # whose merge is the host's already
+        assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(device))
+    when = datetime(2026, 1, 1, tzinfo=timezone.utc)
+    docs = [
+        json.dumps(fortio_result_from_summary(
+            s, OPEN, labels="x", start_time=when, response_size_bytes=128.0
+        ))
+        for s in (finished, device)
+    ]
+    assert docs[0] == docs[1]
+    names = [f"s{i}" for i in range(finished.utilization.size)]
+    wins = [
+        json.dumps(dataclasses.asdict(window_summary_from_summary(
+            s, service_names=names
+        )))
+        for s in (finished, device)
+    ]
+    assert wins[0] == wins[1]
+
+
 # -- zero added sync points on the default path ----------------------------
 
 
@@ -362,6 +486,60 @@ def test_clean_run_passes_sentinels():
                                             block_size=256))
     sentinels.check_results(sim.run(OPEN, 64, KEY))
     assert telemetry.counter_get("numeric_sentinel_violations") == 0.0
+
+
+@pytest.fixture(scope="module")
+def clean_summary():
+    sim = Simulator(compile_graph(ServiceGraph.from_yaml(CHAIN)))
+    return jax.device_get(sim.run_summary(OPEN, 512, KEY, block_size=256))
+
+
+@pytest.mark.parametrize("side", ["device", "host"])
+@pytest.mark.parametrize(
+    "field,value,message",
+    [
+        ("latency_sum", np.nan, "latency_sum: NaN"),
+        ("latency_hist", np.nan, "latency_hist: NaN"),
+        ("latency_min", -np.inf, "latency_min: -inf"),
+        ("latency_min", -1.0, r"latency_min: negative \(-1\)"),
+        ("utilization", np.nan, "utilization: NaN"),
+        ("win_hi", np.inf, None),        # the trim window off
+        ("utilization", 1.5, None),      # overload, not a fault
+    ],
+)
+def test_sentinel_cases_on_device_and_host_summaries(
+    clean_summary, side, field, value, message
+):
+    """The checks read a summary of device arrays and ``finish_summary``'s
+    host copy alike: same fields, same order, same messages."""
+    import jax.numpy as jnp
+
+    leaf = np.array(getattr(clean_summary, field))
+    leaf.reshape(-1)[0] = value
+    summary = clean_summary._replace(**{field: leaf})
+    if side == "device":
+        summary = jax.tree.map(jnp.asarray, summary)
+    if message is None:
+        sentinels.check_summary(summary)
+        assert telemetry.counter_get("numeric_sentinel_violations") == 0.0
+        return
+    with pytest.raises(NumericSentinelError, match=message):
+        sentinels.check_summary(summary)
+    assert telemetry.counter_get("numeric_sentinel_violations") == 1.0
+
+
+def test_violations_come_in_the_fields_order(clean_summary):
+    bad = clean_summary._replace(
+        utilization=np.full_like(clean_summary.utilization, np.nan),
+        end_max=np.float32(-2.0),
+        count=np.float32(np.nan),
+    )
+    with pytest.raises(
+        NumericSentinelError,
+        match=r"on case-7: count: NaN; end_max: negative \(-2\); "
+              r"utilization: NaN \(re-run",
+    ):
+        sentinels.check_summary(bad, label="case-7")
 
 
 def test_nan_poisoned_trace_never_shares_executables():
@@ -450,7 +628,7 @@ TOPO = (
 )
 
 
-def _config(tmp_path):
+def _config(tmp_path, connections="[8]"):
     from isotope_tpu.runner import load_toml
 
     cfg = tmp_path / "exp.toml"
@@ -461,7 +639,7 @@ environments = ["NONE"]
 
 [client]
 qps = [200, 400]
-num_concurrent_connections = [8]
+num_concurrent_connections = {connections}
 duration = "30s"
 load_kind = "open"
 
@@ -501,3 +679,14 @@ def test_numeric_failure_fails_case_but_not_sweep(tmp_path):
     )
     assert len(ran) == 2
     assert not any(r.failed for r in results)
+
+
+def test_a_four_run_sweep_fetches_four_summaries(tmp_path):
+    from isotope_tpu.runner.run import run_experiment
+
+    results = run_experiment(
+        _config(tmp_path, connections="[8, 16]"),
+        out_dir=str(tmp_path / "out"), policy=NOSLEEP,
+    )
+    assert len(results) == 4 and not any(r.failed for r in results)
+    assert telemetry.counter_get("summary_fetches") == 4.0

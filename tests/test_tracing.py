@@ -309,6 +309,59 @@ def test_summary_wait_names_what_it_waits_for(served):
     assert snap.phases["summary.wait.self"] < 1e-3
 
 
+def test_summary_is_fetched_once_inside_summary_sentinels(served):
+    """One batched fetch a run, counted where it is made and read by the
+    benchmark's ``summary_fetches_per_call``."""
+    from benchmark.harness import readers
+
+    snap = served[0]
+    assert snap.counters["summary_fetches"] == snap.counters["runs_served"] == 1
+    tel = {"phases": snap.phases, "counters": snap.counters}
+    ctx = {"calls": 1, "telemetry": {"setup": tel, "window": tel}}
+    assert readers.read_metric("summary_fetches_per_call", ctx) == 1.0
+
+
+@pytest.mark.parametrize("connections", [4, 64])    # one device; the mesh
+def test_nothing_after_summary_wait_reads_a_summary_field_off_the_device(
+        tmp_path, monkeypatch, connections):
+    """What the artifact phases of a served call are handed is host data:
+    every summary leaf but the collector's ``metrics`` a numpy array, so
+    no phase after ``summary.wait`` holds a device->host read of one."""
+    import numpy as np
+
+    from isotope_tpu.metrics.prometheus import MetricsCollector
+    from isotope_tpu.runner import run as run_mod
+
+    seen = {}
+
+    def watch(phase_name, fn, arg):
+        def watched(*args, **kwargs):
+            seen[phase_name] = (args[arg], telemetry.phase_seconds(
+                "summary.wait"))
+            return fn(*args, **kwargs)
+        return watched
+
+    monkeypatch.setattr(run_mod, "fortio_result_from_summary", watch(
+        "artifacts.fortio", run_mod.fortio_result_from_summary, 0))
+    monkeypatch.setattr(run_mod, "window_summary_from_summary", watch(
+        "artifacts.window", run_mod.window_summary_from_summary, 0))
+    monkeypatch.setattr(MetricsCollector, "full_text", watch(
+        "artifacts.exposition", MetricsCollector.full_text, 1))
+    telemetry.reset()
+    rc, _, _ = serve(tmp_path, "host-copy", connections=connections)
+    assert rc == 0 and set(seen) == {
+        "artifacts.fortio", "artifacts.window", "artifacts.exposition"}
+    assert telemetry.counter_get("sharded_runs") == (connections == 64)
+    for name, (summary, waited) in seen.items():
+        assert waited == telemetry.phase_seconds("summary.wait") > 0, name
+        for field, leaf in summary._asdict().items():
+            if field == "metrics":
+                assert all(isinstance(x, jax.Array)
+                           for x in jax.tree.leaves(leaf))
+                continue
+            assert isinstance(leaf, np.ndarray), (name, field)
+
+
 @pytest.fixture(scope="module")
 def traced(served, tmp_path_factory):
     """A warm served call under a profiler session, a collection of
